@@ -10,8 +10,9 @@ usage() {
 usage: ci/run_tests.sh <function>
   unittest_cpu          full CPU suite (single run; ~30 min on 1 core)
   unittest_cpu_chunked  CPU suite in two halves (for constrained runners)
-  unittest_tpu          TPU tier (tests_tpu/: op sweep on the live chip
-                        + CPU-vs-TPU consistency; self-skips without one)
+  unittest_tpu          TPU tier (tests_tpu/: op sweep on the chip, CPU-vs-
+                        TPU consistency, Pallas kernels vs lax; errors
+                        without a TPU — run it through the chip tool)
   smoke                 60-second end-to-end slice (gluon MNIST)
   telemetry_smoke       MNIST slice under MXNET_TELEMETRY=1; asserts the
                         Prometheus dump has nonzero op/step/compile counters
@@ -107,7 +108,7 @@ usage: ci/run_tests.sh <function>
                         training loop — emergency checkpoint at the step
                         boundary, resume bit-identical to golden
   router_smoke          fleet drill (four parts): a fresh
-                        MXNET_COMPILE_CACHE_DIR makes a second replica's
+                        JAX_COMPILATION_CACHE_DIR makes a second replica's
                         warmup-to-first-200 >= 1.5x faster; SIGKILL one
                         of 3 replicas under 16 streaming clients — zero
                         failed requests (zero-token deaths fail over

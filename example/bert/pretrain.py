@@ -40,15 +40,7 @@ def main():
     import jax
     if args.cpu_mesh:
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", args.cpu_mesh)
-        except AttributeError:
-            # pre-0.4.38 jax: the XLA flag read at backend creation
-            # (which hasn't happened yet) does the same thing
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "") +
-                " --xla_force_host_platform_device_count="
-                f"{args.cpu_mesh}")
+        jax.config.update("jax_num_cpu_devices", args.cpu_mesh)
 
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import parallel

@@ -43,14 +43,7 @@ def main():
     n_dev = args.dp * args.sp
     if not args.accel:
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", n_dev)
-        except AttributeError:
-            # pre-0.4.38 jax: the XLA flag read at backend creation
-            # (which hasn't happened yet) does the same thing
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "") +
-                f" --xla_force_host_platform_device_count={n_dev}")
+        jax.config.update("jax_num_cpu_devices", n_dev)
     elif len(jax.devices()) < n_dev:
         raise SystemExit(f"--accel needs {n_dev} devices, have "
                          f"{len(jax.devices())}")

@@ -29,14 +29,7 @@ def main():
     if args.cpu:
         import jax
         jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", 8)
-        except AttributeError:
-            # pre-0.4.38 jax: the XLA flag read at backend creation
-            # (which hasn't happened yet) does the same thing
-            os.environ["XLA_FLAGS"] = (
-                os.environ.get("XLA_FLAGS", "") +
-                " --xla_force_host_platform_device_count=8")
+        jax.config.update("jax_num_cpu_devices", 8)
 
     import jax
     import incubator_mxnet_tpu as mx

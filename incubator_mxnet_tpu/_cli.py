@@ -400,8 +400,12 @@ def serve_main():
                  "synthesize bucket batches")
 
     from .base import getenv_int
+    from .compile_cache import ensure_compile_cache
     from .serving import InferenceEngine, ModelServer
 
+    # before anything compiles: parameter init below already does
+    cache_dir = ensure_compile_cache()
+    sys.stderr.write(f"mxtpu-serve: compile cache at {cache_dir}\n")
     batcher_kw = {}
     if ns.max_batch is not None:
         batcher_kw["max_batch_size"] = ns.max_batch
@@ -530,9 +534,11 @@ def supervise_main():
     ap.add_argument("--router-port", type=int, default=0,
                     help="router listen port (default 0: ephemeral)")
     ap.add_argument("--compile-cache", metavar="DIR", default=None,
-                    help="shared MXNET_COMPILE_CACHE_DIR for every "
-                         "replica — scale-up cold starts reuse warm "
-                         "compiled artifacts")
+                    help="exported to every replica as "
+                         "JAX_COMPILATION_CACHE_DIR — scale-up cold "
+                         "starts reuse warm compiled artifacts (default: "
+                         "the inherited variable, else each replica's "
+                         "in-checkout .jax_cache)")
     ap.add_argument("--log-dir", metavar="DIR", default=None,
                     help="per-replica stdout/stderr logs land here "
                          "(default: discarded)")
@@ -566,7 +572,7 @@ def supervise_main():
             + ["--host", "127.0.0.1", "--port", "{port}"]
     child_env = {}
     if ns.compile_cache is not None:
-        child_env["MXNET_COMPILE_CACHE_DIR"] = ns.compile_cache
+        child_env["JAX_COMPILATION_CACHE_DIR"] = ns.compile_cache
     policy = AutoscalePolicy(min_replicas=ns.min_replicas,
                              max_replicas=ns.max_replicas)
     sup = Supervisor(command, replicas=ns.replicas, policy=policy,

@@ -48,6 +48,21 @@ _BLOCK_Q = 128
 _BLOCK_K = 128
 
 
+def _platform_of(x):
+    """The platform a kernel over ``x`` will run on — the one place every
+    dispatcher below decides Pallas-vs-lax and compiled-vs-interpret
+    from.  A concrete array says where it lives (a CPU-committed array in
+    a TPU-default process must interpret); a tracer (or a host numpy
+    array) has no devices, so the answer is the current default context's
+    device — jax's default backend unless the caller is inside a
+    ``with ctx:`` scope."""
+    try:
+        return next(iter(x.devices())).platform
+    except (AttributeError, jax.errors.ConcretizationTypeError):
+        from ..context import current_context
+        return current_context().jax_device().platform
+
+
 def _causal_mask(s, q0, k0):
     """-inf the strictly-upper-triangular scores of one (BQ, BK) block;
     ``q0``/``k0`` are the absolute positions of the block's first
@@ -115,7 +130,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
         # per-row logsumexp of the (scaled, biased, masked) scores — the
         # one residual the FA2 backward needs to recompute P blockwise
         safe_m = jnp.where(jnp.isfinite(m), m, 0.0)
-        lse_ref[0] = (safe_m + jnp.log(jnp.maximum(l, 1e-30)))[:, 0]
+        lse_ref[0] = (safe_m + jnp.log(jnp.maximum(l, 1e-30))).reshape(
+            1, block_q)
 
 
 def _xla_attention(q, k, v, scale, causal, bias=None):
@@ -140,7 +156,10 @@ def _flash_fwd_impl(q, k, v, bias, scale, causal, interpret, n_heads,
     """``bias``: None, or a (B, 1, Tk) float32 additive key bias shared by
     the batch's ``n_heads`` grid rows (indexed bh -> bh // n_heads, so the
     per-head copies never materialize in HBM).  ``with_lse`` additionally
-    returns the per-row logsumexp (BH, T) float32 for the backward."""
+    returns the per-row logsumexp for the backward, as (BH, 1, T) float32
+    — per-row vectors travel as lane-dense rows with a unit second-minor
+    dim, the one layout whose (1, 1, block) blocks the TPU lowering
+    accepts (a (1, block) block over (BH, T) is refused)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -167,9 +186,10 @@ def _flash_fwd_impl(q, k, v, bias, scale, causal, interpret, n_heads,
     out_specs = spec_q
     if with_lse:
         out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((BH, T), jnp.float32)]
+                     jax.ShapeDtypeStruct((BH, 1, T), jnp.float32)]
         out_specs = [spec_q,
-                     pl.BlockSpec((1, block_q), lambda bh, qi: (bh, qi),
+                     pl.BlockSpec((1, 1, block_q),
+                                  lambda bh, qi: (bh, 0, qi),
                                   memory_space=pltpu.VMEM)]
     return pl.pallas_call(
         kernel,
@@ -191,9 +211,9 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref, *rest,
     dq_ref = rest[-1]
     q = q_ref[0].astype(jnp.float32)                  # (BQ, D)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0].astype(jnp.float32)              # (BQ,)
-    dd = dd_ref[0].astype(jnp.float32)                # (BQ,)
     block_q = q.shape[0]
+    lse = lse_ref[0].reshape(block_q, 1)              # rows -> columns
+    dd = dd_ref[0].reshape(block_q, 1)
     qi = pl.program_id(1)
     n_kb = seq_len // block_k
 
@@ -207,10 +227,10 @@ def _bwd_dq_kernel(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref, *rest,
             s = s + b_ref[0, :, pl.ds(j * block_k, block_k)]
         if causal:
             s = _causal_mask(s, qi * block_q, j * block_k)
-        p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - dd[:, None])                   # (BQ, BK)
+        ds = p * (dp - dd)                            # (BQ, BK)
         return dq + jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
@@ -244,8 +264,9 @@ def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref, *rest,
         dk, dv, dbs = carry
         q = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
         do = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * block_q, block_q)].astype(jnp.float32)
-        dd = dd_ref[0, pl.ds(i * block_q, block_q)].astype(jnp.float32)
+        lse = lse_ref[0, :, pl.ds(i * block_q, block_q)].reshape(
+            block_q, 1)
+        dd = dd_ref[0, :, pl.ds(i * block_q, block_q)].reshape(block_q, 1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32
                                 ) * scale
@@ -253,21 +274,21 @@ def _bwd_dkv_kernel(q_ref, do_ref, lse_ref, dd_ref, k_ref, v_ref, *rest,
             s = s + b_ref[0]                          # (1, BK) broadcast
         if causal:
             s = _causal_mask(s, i * block_q, kj * block_k)
-        p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse), 0.0)
         dv = dv + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)       # (BK, D)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - dd[:, None])                   # (BQ, BK)
+        ds = p * (dp - dd)                            # (BQ, BK)
         dk = dk + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        dbs = dbs + jnp.sum(ds, axis=0)               # (BK,)
+        dbs = dbs + jnp.sum(ds, axis=0, keepdims=True)    # (1, BK)
         return dk, dv, dbs
 
     z = jnp.zeros((block_k, k.shape[1]), jnp.float32)
-    carry0 = (z, z, jnp.zeros((block_k,), jnp.float32))
+    carry0 = (z, z, jnp.zeros((1, block_k), jnp.float32))
     if causal:
         # q blocks strictly above the diagonal see this k block masked out
         start = (kj * block_k) // block_q
@@ -295,8 +316,10 @@ def _flash_bwd_impl(q, k, v, bias, out, lse, g, scale, causal, interpret,
     block_q = min(_BLOCK_Q, T)
     block_k = min(_BLOCK_K, T)
     has_bias = bias is not None
-    # D_i = rowsum(dO * O): tiny elementwise reduce, XLA fuses it
-    dd = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), -1)
+    # D_i = rowsum(dO * O): tiny elementwise reduce, XLA fuses it.
+    # (BH, 1, T) like lse — see _flash_fwd_impl for the layout's why
+    dd = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32),
+                 -1)[:, None, :]
     if g_lse is not None:
         dd = dd - g_lse.astype(jnp.float32)
 
@@ -304,9 +327,9 @@ def _flash_bwd_impl(q, k, v, bias, out, lse, g, scale, causal, interpret,
                               memory_space=pltpu.VMEM)
     spec_full = pl.BlockSpec((1, T, D), lambda bh, i: (bh, 0, 0),
                              memory_space=pltpu.VMEM)
-    spec_vec_q = pl.BlockSpec((1, block_q), lambda bh, i: (bh, i),
+    spec_vec_q = pl.BlockSpec((1, 1, block_q), lambda bh, i: (bh, 0, i),
                               memory_space=pltpu.VMEM)
-    spec_vec_full = pl.BlockSpec((1, T), lambda bh, i: (bh, 0),
+    spec_vec_full = pl.BlockSpec((1, 1, T), lambda bh, i: (bh, 0, 0),
                                  memory_space=pltpu.VMEM)
 
     # dQ: grid over query blocks
@@ -331,7 +354,7 @@ def _flash_bwd_impl(q, k, v, bias, out, lse, g, scale, causal, interpret,
     # dK/dV (+ bias-cotangent rows): grid over key blocks
     spec_row_k = pl.BlockSpec((1, block_k, D), lambda bh, j: (bh, j, 0),
                               memory_space=pltpu.VMEM)
-    spec_vec_k = pl.BlockSpec((1, block_k), lambda bh, j: (bh, j),
+    spec_vec_k = pl.BlockSpec((1, 1, block_k), lambda bh, j: (bh, 0, j),
                               memory_space=pltpu.VMEM)
     in_specs = [spec_full, spec_full, spec_vec_full, spec_vec_full,
                 spec_row_k, spec_row_k]
@@ -346,7 +369,7 @@ def _flash_bwd_impl(q, k, v, bias, out, lse, g, scale, causal, interpret,
                           block_q=block_q, seq_len=T, has_bias=has_bias),
         out_shape=[jax.ShapeDtypeStruct((BH, T, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, T, D), v.dtype),
-                   jax.ShapeDtypeStruct((BH, T), jnp.float32)],
+                   jax.ShapeDtypeStruct((BH, 1, T), jnp.float32)],
         grid=(BH, T // block_k),
         in_specs=in_specs,
         out_specs=[spec_row_k, spec_row_k, spec_vec_k],
@@ -355,8 +378,8 @@ def _flash_bwd_impl(q, k, v, bias, out, lse, g, scale, causal, interpret,
 
     dbias = None
     if has_bias:
-        # (BH, Tk) rows -> the (B, 1, Tk) bias: sum the head axis out
-        dbias = dbs.reshape(-1, n_heads, T).sum(1)[:, None, :]
+        # (BH, 1, Tk) rows -> the (B, 1, Tk) bias: sum the head axis out
+        dbias = dbs.reshape(-1, n_heads, 1, T).sum(1)
     return dq, dk, dv, dbias
 
 
@@ -427,6 +450,7 @@ def _flash_lse_bwd(scale, causal, interpret, n_heads, res, g):
     if (getenv("MXNET_FLASH_BWD") or "pallas").lower() != "xla":
         return _flash_bwd_impl(q, k, v, bias, out, lse, g_out, scale,
                                causal, interpret, n_heads, g_lse=g_lse)
+    g_lse = g_lse[:, 0, :]      # the oracle's lse is (BH, T)
     # MXNET_FLASH_BWD=xla — the recompute oracle (same switch as the
     # no-lse path; AD produces the g_lse term naturally here)
     BH = q.shape[0]
@@ -502,14 +526,7 @@ def _dispatch(q, k, v, scale, causal, mask, with_lse):
             return out.reshape(B, H, T, D), lse.reshape(B, H, T)
         return _xla_attention(qf, kf, vf, scale, causal,
                               bias=bb).reshape(B, H, T, D)
-    # interpret on CPU: decide from where the DATA lives (a concrete
-    # array on the CPU backend of a TPU-default process must interpret);
-    # tracers have no devices — fall back to the default backend
-    try:
-        platform = next(iter(q.devices())).platform
-    except Exception:
-        platform = jax.default_backend()
-    interpret = platform == "cpu"
+    interpret = _platform_of(q) == "cpu"
     if with_lse:
         out, lse = _flash_lse(qf, kf, vf, bias, scale, causal,
                               interpret, H)
@@ -549,78 +566,11 @@ def _xla_decode_attention(q, k, v, positions, scale):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, scale, block_k, n_kb):
-    """Grid (S, H, n_kb): one query row (1, D) against K/V blocks
-    (block_k, D) of its slot+head, online softmax across the kb axis.
-    Scratch persists along the innermost (kb) grid dim."""
-    from jax.experimental import pallas as pl
-    kb = pl.program_id(2)
-
-    @pl.when(kb == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -1e30)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    pos = pos_ref[0]
-    D = q_ref.shape[-1]
-    q = q_ref[...].reshape(1, D).astype(jnp.float32)
-    k = k_ref[...].reshape(block_k, D).astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # (1, block_k)
-    idx = kb * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_k), 1)
-    s = jnp.where(idx <= pos, s, -1e30)
-    m_prev, l_prev = m_ref[:], l_ref[:]               # (1, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                            # (1, block_k)
-    l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    m_ref[:] = m_new
-    v_blk = v_ref[...].reshape(block_k, D).astype(jnp.float32)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p, v_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # (1, D)
-
-    @pl.when(kb == n_kb - 1)
-    def _fin():
-        o_ref[...] = (acc_ref[:] / l_ref[:]).reshape(
-            o_ref.shape).astype(o_ref.dtype)
-
-
 def _decode_pallas(q, k, v, positions, scale, interpret):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    S, H, T, D = k.shape
-    block_k = min(_BLOCK_K, T)
-    n_kb = T // block_k
-    kernel = functools.partial(_decode_kernel, scale=scale,
-                               block_k=block_k, n_kb=n_kb)
-    return pl.pallas_call(
-        kernel,
-        grid=(S, H, n_kb),
-        in_specs=[
-            pl.BlockSpec((1,), lambda s, h, kb: (s,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, D), lambda s, h, kb: (s, h, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_k, D), lambda s, h, kb: (s, h, kb, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_k, D), lambda s, h, kb: (s, h, kb, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda s, h, kb: (s, h, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(positions.astype(jnp.int32), q, k, v)
+    """Single-query decode IS verify at query width 1 (one kernel body,
+    :func:`_verify_kernel`, serves all four decode-shaped entry points)."""
+    return _verify_pallas(q[:, :, None, :], k, v, positions, scale,
+                          interpret)[:, :, 0, :]
 
 
 def decode_attention(q, k, v, positions, scale=None):
@@ -652,10 +602,7 @@ def decode_attention(q, k, v, positions, scale=None):
     S, H, T, D = k.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    try:
-        platform = next(iter(q.devices())).platform
-    except Exception:
-        platform = jax.default_backend()
+    platform = _platform_of(q)
     force = getenv_bool("MXNET_FA_DECODE_FORCE_PALLAS")
     kv_bytes = 2 * T * D * q.dtype.itemsize
     aligned = T % _BLOCK_K == 0 and kv_bytes <= 8 * 2 ** 20
@@ -686,85 +633,11 @@ def _xla_paged_decode_attention(q, k_pages, v_pages, tables, positions,
     return _xla_decode_attention(q, k, v, positions, scale)
 
 
-def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, scale, block_k, n_kb):
-    """Grid (S, H, n_kb): like :func:`_decode_kernel`, but the K/V block
-    for grid step ``kb`` was fetched through the scalar-prefetched block
-    table (see the index maps in :func:`_paged_decode_pallas`), so the
-    kernel body only differs in where ``pos`` comes from."""
-    from jax.experimental import pallas as pl
-    s = pl.program_id(0)
-    kb = pl.program_id(2)
-
-    @pl.when(kb == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -1e30)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    pos = pos_ref[s]
-    D = q_ref.shape[-1]
-    q = q_ref[...].reshape(1, D).astype(jnp.float32)
-    k = k_ref[...].reshape(block_k, D).astype(jnp.float32)
-    sc = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # (1, block_k)
-    idx = kb * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_k), 1)
-    sc = jnp.where(idx <= pos, sc, -1e30)
-    m_prev, l_prev = m_ref[:], l_ref[:]               # (1, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(sc - m_new)                           # (1, block_k)
-    l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    m_ref[:] = m_new
-    v_blk = v_ref[...].reshape(block_k, D).astype(jnp.float32)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p, v_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # (1, D)
-
-    @pl.when(kb == n_kb - 1)
-    def _fin():
-        o_ref[...] = (acc_ref[:] / l_ref[:]).reshape(
-            o_ref.shape).astype(o_ref.dtype)
-
-
 def _paged_decode_pallas(q, k_pages, v_pages, tables, positions, scale,
                          interpret):
-    """Block tables + positions ride as scalar-prefetch operands, so the
-    BlockSpec index maps can route grid step (s, h, kb) straight to
-    physical block ``tables[s, kb]`` — the gather never materializes a
-    dense (S, H, T, D) view."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    S, n_kb = tables.shape
-    _, H, bs, D = k_pages.shape
-    kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               block_k=bs, n_kb=n_kb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, H, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, 1, D), lambda s, h, kb, tbl, pos: (s, h, 0)),
-            pl.BlockSpec((1, 1, bs, D),
-                         lambda s, h, kb, tbl, pos: (tbl[s, kb], h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, D),
-                         lambda s, h, kb, tbl, pos: (tbl[s, kb], h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, D), lambda s, h, kb, tbl, pos: (s, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), positions.astype(jnp.int32),
-      q, k_pages, v_pages)
+    """Paged single-query decode as paged verify at query width 1."""
+    return _paged_verify_pallas(q[:, :, None, :], k_pages, v_pages, tables,
+                                positions, scale, interpret)[:, :, 0, :]
 
 
 def paged_decode_attention(q, k_pages, v_pages, tables, positions,
@@ -793,10 +666,7 @@ def paged_decode_attention(q, k_pages, v_pages, tables, positions,
     _, H, bs, D = k_pages.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    try:
-        platform = next(iter(q.devices())).platform
-    except Exception:
-        platform = jax.default_backend()
+    platform = _platform_of(q)
     force = getenv_bool("MXNET_FA_DECODE_FORCE_PALLAS")
     aligned = bs % 8 == 0 and D % 8 == 0
     if force and aligned:
@@ -838,12 +708,18 @@ def _xla_verify_decode_attention(q, k, v, positions, scale):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _verify_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, scale, n_q, block_k, n_kb):
+def _verify_kernel(*refs, scale, n_q, block_k, n_kb):
     """Grid (S, H, n_kb): a (Q, D) query block against K/V blocks
-    (block_k, D), online softmax across the kb axis with per-row
-    running max / denominator (scratch (Q, 1) instead of (1, 1))."""
+    (block_k, D), online softmax across the kb axis with per-row running
+    max / denominator in scratch (persists along the innermost grid dim).
+    ``refs``: the scalar-prefetch operands (positions last — the paged
+    variant prefetches its block table before it, consumed only by the
+    index maps), then q, k, v, out and the three scratch buffers.  Which
+    K/V block grid step ``kb`` sees is entirely the index maps' business,
+    so dense and paged caches share this body."""
     from jax.experimental import pallas as pl
+    pos_ref = refs[-8]
+    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs[-7:]
     kb = pl.program_id(2)
 
     @pl.when(kb == 0)
@@ -852,7 +728,7 @@ def _verify_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    pos = pos_ref[0]
+    pos = pos_ref[pl.program_id(0)]
     D = q_ref.shape[-1]
     q = q_ref[...].reshape(n_q, D).astype(jnp.float32)
     k = k_ref[...].reshape(block_k, D).astype(jnp.float32)
@@ -880,38 +756,48 @@ def _verify_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
             o_ref.shape).astype(o_ref.dtype)
 
 
-def _verify_pallas(q, k, v, positions, scale, interpret):
+def _verify_call(q, k, v, prefetch, kv_index, block_k, n_kb, scale,
+                 interpret):
+    """One ``pallas_call`` for dense and paged caches: ``prefetch`` are
+    the int32 scalar-prefetch operands (positions last), ``kv_index`` the
+    K/V index map ``(s, h, kb, *prefetch_refs) -> block index``.  The
+    query/output blocks span the array's whole last two dims (Q, D), and
+    the per-slot position is read from prefetched SMEM inside the kernel
+    — both are what the TPU lowering requires (a (1,)-blocked SMEM
+    operand or a block whose second-minor dim is 1 of H is refused)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    S, H, T, D = k.shape
-    n_q = q.shape[2]
-    block_k = min(_BLOCK_K, T)
-    n_kb = T // block_k
+    S, H, n_q, D = q.shape
     kernel = functools.partial(_verify_kernel, scale=scale, n_q=n_q,
                                block_k=block_k, n_kb=n_kb)
-    return pl.pallas_call(
-        kernel,
+    spec_q = pl.BlockSpec((1, 1, n_q, D),
+                          lambda s, h, kb, *_: (s, h, 0, 0))
+    spec_kv = pl.BlockSpec((1, 1, block_k, D), kv_index)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
         grid=(S, H, n_kb),
-        in_specs=[
-            pl.BlockSpec((1,), lambda s, h, kb: (s,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, n_q, D), lambda s, h, kb: (s, h, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_k, D), lambda s, h, kb: (s, h, kb, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, block_k, D), lambda s, h, kb: (s, h, kb, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, n_q, D), lambda s, h, kb: (s, h, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[spec_q, spec_kv, spec_kv],
+        out_specs=spec_q,
         scratch_shapes=[
             pltpu.VMEM((n_q, D), jnp.float32),
             pltpu.VMEM((n_q, 1), jnp.float32),
             pltpu.VMEM((n_q, 1), jnp.float32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(positions.astype(jnp.int32), q, k, v)
+    )(*(x.astype(jnp.int32) for x in prefetch), q, k, v)
+
+
+def _verify_pallas(q, k, v, positions, scale, interpret):
+    T = k.shape[2]
+    block_k = min(_BLOCK_K, T)
+    return _verify_call(q, k, v, (positions,),
+                        lambda s, h, kb, pos: (s, h, kb, 0),
+                        block_k, T // block_k, scale, interpret)
 
 
 def verify_decode_attention(q, k, v, positions, scale=None):
@@ -932,10 +818,7 @@ def verify_decode_attention(q, k, v, positions, scale=None):
     S, H, T, D = k.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    try:
-        platform = next(iter(q.devices())).platform
-    except Exception:
-        platform = jax.default_backend()
+    platform = _platform_of(q)
     force = getenv_bool("MXNET_FA_DECODE_FORCE_PALLAS")
     kv_bytes = 2 * T * D * q.dtype.itemsize
     aligned = T % _BLOCK_K == 0 and kv_bytes <= 8 * 2 ** 20
@@ -959,85 +842,17 @@ def _xla_paged_verify_decode_attention(q, k_pages, v_pages, tables,
     return _xla_verify_decode_attention(q, k, v, positions, scale)
 
 
-def _paged_verify_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, scale, n_q, block_k,
-                         n_kb):
-    """Grid (S, H, n_kb): :func:`_verify_kernel` with the K/V block for
-    grid step ``kb`` fetched through the scalar-prefetched block table
-    (index maps in :func:`_paged_verify_pallas`)."""
-    from jax.experimental import pallas as pl
-    s = pl.program_id(0)
-    kb = pl.program_id(2)
-
-    @pl.when(kb == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -1e30)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    pos = pos_ref[s]
-    D = q_ref.shape[-1]
-    q = q_ref[...].reshape(n_q, D).astype(jnp.float32)
-    k = k_ref[...].reshape(block_k, D).astype(jnp.float32)
-    sc = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # (Q, block_k)
-    idx = kb * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (n_q, block_k), 1)
-    head = pos + jax.lax.broadcasted_iota(jnp.int32, (n_q, block_k), 0)
-    sc = jnp.where(idx <= head, sc, -1e30)
-    m_prev, l_prev = m_ref[:], l_ref[:]               # (Q, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(sc - m_new)                           # (Q, block_k)
-    l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    m_ref[:] = m_new
-    v_blk = v_ref[...].reshape(block_k, D).astype(jnp.float32)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p, v_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # (Q, D)
-
-    @pl.when(kb == n_kb - 1)
-    def _fin():
-        o_ref[...] = (acc_ref[:] / l_ref[:]).reshape(
-            o_ref.shape).astype(o_ref.dtype)
-
-
 def _paged_verify_pallas(q, k_pages, v_pages, tables, positions, scale,
                          interpret):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    S, n_kb = tables.shape
-    _, H, bs, D = k_pages.shape
-    n_q = q.shape[2]
-    kernel = functools.partial(_paged_verify_kernel, scale=scale, n_q=n_q,
-                               block_k=bs, n_kb=n_kb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, H, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, 1, n_q, D),
-                         lambda s, h, kb, tbl, pos: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, D),
-                         lambda s, h, kb, tbl, pos: (tbl[s, kb], h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, D),
-                         lambda s, h, kb, tbl, pos: (tbl[s, kb], h, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, n_q, D),
-                               lambda s, h, kb, tbl, pos: (s, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((n_q, D), jnp.float32),
-            pltpu.VMEM((n_q, 1), jnp.float32),
-            pltpu.VMEM((n_q, 1), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(tables.astype(jnp.int32), positions.astype(jnp.int32),
-      q, k_pages, v_pages)
+    """Block tables ride as a scalar-prefetch operand, so the K/V index
+    map routes grid step (s, h, kb) straight to physical block
+    ``tables[s, kb]`` — the gather never materializes a dense
+    (S, H, T, D) view."""
+    n_kb = tables.shape[1]
+    bs = k_pages.shape[2]
+    return _verify_call(q, k_pages, v_pages, (tables, positions),
+                        lambda s, h, kb, tbl, pos: (tbl[s, kb], h, 0, 0),
+                        bs, n_kb, scale, interpret)
 
 
 def paged_verify_decode_attention(q, k_pages, v_pages, tables, positions,
@@ -1055,10 +870,7 @@ def paged_verify_decode_attention(q, k_pages, v_pages, tables, positions,
     _, H, bs, D = k_pages.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    try:
-        platform = next(iter(q.devices())).platform
-    except Exception:
-        platform = jax.default_backend()
+    platform = _platform_of(q)
     force = getenv_bool("MXNET_FA_DECODE_FORCE_PALLAS")
     aligned = bs % 8 == 0 and D % 8 == 0
     if force and aligned:

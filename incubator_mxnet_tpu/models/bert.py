@@ -77,7 +77,7 @@ def _sdpa(q, k, v, num_heads, mask=None, seq_axis=None, mesh=None,
                 from ..parallel.ring import _ring_body
                 from functools import partial
                 from jax.sharding import PartitionSpec as P
-                from ..parallel._shmap import shard_map
+                from jax import shard_map
                 spec = P(None, None, seq_axis, None)
                 from ..base import getenv_bool as _gb
                 body = partial(_ring_body, axis_name=seq_axis,

@@ -28,6 +28,7 @@ from __future__ import annotations
 from typing import Any, Callable
 
 from ..base import MXNetError
+from ..compile_cache import ensure_compile_cache
 
 
 def _require_single_output(outs):
@@ -140,7 +141,7 @@ def gpipe(stage_fn: Callable[[Any, Any], Any], stacked_params, xs,
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from ._shmap import shard_map
+    from jax import shard_map
 
     if axis not in mesh.shape:
         raise MXNetError(f"mesh has no axis {axis!r}")
@@ -282,6 +283,7 @@ class PipelineTrainer(_SPMDTrainer):
                 "DO compose: tensor-parallel specs apply on top of the "
                 "stage stacking (3D dp x pipe x model parallelism)")
         self._rules = list(sharding_rules or [])
+        ensure_compile_cache()
         self._net = net
         self._loss = loss_fn
         self._mesh = mesh or mesh_mod.current_mesh()
@@ -521,7 +523,7 @@ class PipelineTrainer(_SPMDTrainer):
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from ._shmap import shard_map
+        from jax import shard_map
 
         mesh, S, M = self._mesh, self._S, self._M
         pipe, data = self._pipe_axis, self._data_axis
@@ -652,7 +654,7 @@ class PipelineTrainer(_SPMDTrainer):
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from ._shmap import shard_map
+        from jax import shard_map
 
         mesh, S, M = self._mesh, self._S, self._M
         pipe, data = self._pipe_axis, self._data_axis

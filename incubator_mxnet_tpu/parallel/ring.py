@@ -189,7 +189,7 @@ def ring_attention(q, k, v, mesh=None, axis_name="seq", scale=None,
     """
     import jax
     from jax.sharding import PartitionSpec as P
-    from ._shmap import shard_map
+    from jax import shard_map
     from . import mesh as mesh_mod
     from ..ndarray.ndarray import NDArray
 

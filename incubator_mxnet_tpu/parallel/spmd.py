@@ -28,6 +28,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as _np
 
 from ..base import MXNetError
+from ..compile_cache import ensure_compile_cache
 from ..context import current_context
 from .. import health as _health
 from .. import telemetry as _telemetry
@@ -213,6 +214,7 @@ class SPMDTrainer:
             raise MXNetError(
                 "pipeline_schedule without pipeline_axis — pass "
                 "pipeline_axis=<mesh axis> to request pipelining")
+        ensure_compile_cache()
         self._net = net
         self._loss = loss_fn
         self._mesh = mesh or mesh_mod.current_mesh()

@@ -67,7 +67,7 @@ def ulysses_attention(q, k, v, mesh=None, axis_name="seq", scale=None,
     key-validity array, sequence-sharded like K/V.  Accepts jax arrays
     or NDArrays; returns the same sharding as the inputs."""
     from jax.sharding import PartitionSpec as P
-    from ._shmap import shard_map
+    from jax import shard_map
     from . import mesh as mesh_mod
     from ..ndarray.ndarray import NDArray
 

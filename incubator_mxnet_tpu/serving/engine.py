@@ -33,6 +33,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import numpy as _np
 
 from ..base import MXNetError
+from ..compile_cache import ensure_compile_cache
 from ..context import current_context
 from ..ndarray.ndarray import NDArray
 from .. import telemetry as _telemetry
@@ -40,51 +41,7 @@ from .. import telemetry_device as _telemetry_device
 from .. import health as _health
 
 __all__ = ["InferenceEngine", "GenerationEngine", "derive_buckets",
-           "derive_prefill_buckets", "ensure_compile_cache"]
-
-
-_compile_cache_dir: Optional[str] = None
-
-
-def ensure_compile_cache() -> Optional[str]:
-    """Point JAX's persistent compilation cache at
-    ``MXNET_COMPILE_CACHE_DIR`` (idempotent; returns the active dir or
-    None when the env var is unset).
-
-    Every engine constructor calls this BEFORE building its jitted
-    programs, so a fresh replica's ``warmup()`` loads compiled
-    executables from disk instead of re-tracing through XLA — the
-    instant-start half of the serve-fleet story (docs/serving.md):
-    replica N pays the compile once, replicas N+1.. hit the shared
-    directory.  The entry-size/compile-time floors are dropped to zero
-    because serving programs are many small programs (one per bucket)
-    — exactly the population the default floors would skip."""
-    global _compile_cache_dir
-    from ..base import getenv
-    cache_dir = getenv("MXNET_COMPILE_CACHE_DIR")
-    if not cache_dir or _compile_cache_dir is not None:
-        # Configure-once: jax's compilation cache dir cannot be safely
-        # re-pointed mid-process, so later engine inits (even with a
-        # changed env) keep the first wiring.
-        return _compile_cache_dir
-    import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # jax snapshots the cache at the FIRST compile; if anything
-        # compiled before we got here (eager param init, a warmup
-        # forward) the cache latched "disabled" — reset so the next
-        # compile re-initializes against the dir we just set.
-        from jax._src import compilation_cache as _jax_cc
-        _jax_cc.reset_cache()
-    except Exception as e:      # an old jax without the knobs serves
-        warnings.warn(          # fine, just without instant starts
-            f"MXNET_COMPILE_CACHE_DIR ignored: {e}")
-        return _compile_cache_dir
-    _compile_cache_dir = str(cache_dir)
-    return _compile_cache_dir
+           "derive_prefill_buckets"]
 
 
 def derive_buckets(max_batch_size: int) -> Tuple[int, ...]:
@@ -1460,11 +1417,15 @@ class GenerationEngine:
         if getattr(self, "draft", None) is not None:
             self.draft.reset()
         self._samp_dev = None
+        # committed to the engine's device, like the parameters: an
+        # uncommitted pool would follow jax's default device instead
+        dev = self._ctx.jax_device()
         if self.paged:
             N, H, bs, D = (self.num_blocks, self.num_heads,
                            self.block_size, self.head_dim)
-            self._cache = tuple(jnp.zeros((N, H, bs, D), jnp.float32)
-                                for _ in range(2 * self.num_layers))
+            self._cache = tuple(
+                jnp.zeros((N, H, bs, D), jnp.float32, device=dev)
+                for _ in range(2 * self.num_layers))
             self.pool.reset()
             # bytes behind one block across all layers — lets the pool
             # report occupancy in bytes (device-memory attribution)
@@ -1476,8 +1437,9 @@ class GenerationEngine:
             return
         S, H, T, D = (self.max_slots, self.num_heads, self.max_len,
                       self.head_dim)
-        self._cache = tuple(jnp.zeros((S, H, T, D), jnp.float32)
-                            for _ in range(2 * self.num_layers))
+        self._cache = tuple(
+            jnp.zeros((S, H, T, D), jnp.float32, device=dev)
+            for _ in range(2 * self.num_layers))
 
     @property
     def cache_bytes(self) -> int:

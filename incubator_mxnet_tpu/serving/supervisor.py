@@ -27,10 +27,22 @@ thesis the training side applies to whole-step capture:
   worst-replica KV utilization) with hysteresis: separate up/down
   thresholds, a cooldown between actions, and min/max clamps.
   Scale-up spawns a fresh slot (cold-start is cheap when the replicas
-  share ``MXNET_COMPILE_CACHE_DIR``); scale-down always routes through
+  share ``JAX_COMPILATION_CACHE_DIR``); scale-down always routes through
   the router's drain, so it is zero-downtime by construction.
   Rendezvous hashing (PR 12) keeps either event to a ~1/N prefix-cache
   remap.
+
+One process per chip: this process (and the router it embeds) never
+brings a jax backend up — its flight-recorder dumps and ``/metrics``
+carry no device providers (``telemetry_device`` registers those only in
+processes that own device memory).  Replicas inherit the supervisor's
+whole environment, so on a TPU host each one claims EVERY local chip: one
+chip host runs one replica, and a second slot's replica dies at backend
+start-up — loudly: every replica death is written to stderr with its
+exit code and log path, then restarted, then quarantined by the flap
+breaker.  Running several one-chip replicas on a multi-chip host needs
+each slot pinned to its own chip from outside (``--command`` with a
+per-port wrapper that sets the TPU runtime's visible-chip variables).
 
 Every transition is published on the FAULT topic (event sites
 ``supervisor.replica`` and ``supervisor.autoscale``) and counted in the
@@ -45,6 +57,7 @@ from __future__ import annotations
 import http.client
 import os
 import subprocess
+import sys
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence
@@ -352,7 +365,7 @@ class Supervisor:
 
     ``command`` is the replica argv; every element has ``{port}``
     substituted with the slot's allocated port.  ``child_env`` overlays
-    the inherited environment (set ``MXNET_COMPILE_CACHE_DIR`` here so
+    the inherited environment (set ``JAX_COMPILATION_CACHE_DIR`` here so
     replicas share compiled artifacts and cold-start stays cheap).
     ``autoscale=False`` supervises a fixed-size fleet.  Pass
     ``router=`` to adopt an externally-owned router (it will NOT be
@@ -573,6 +586,13 @@ class Supervisor:
         _telemetry.FAULT.publish(site=REPLICA_SITE, event="died",
                                  kind=kind, replica=slot.id,
                                  exit_code=slot.last_exit)
+        # loud, not just counted: on a chip host a second replica dies
+        # here at backend start-up, and the operator must see why
+        sys.stderr.write(
+            f"mxtpu-supervise: replica {slot.id} died ({kind}, exit "
+            f"code {slot.last_exit}, restart {slot.restarts})"
+            + (f" — log: {slot.log_path}" if slot.log_path else
+               " — pass --log-dir to keep its output") + "\n")
         _m.SUPERVISE_REPLICAS.set(self.alive_count())
         if slot.breaker.record(now):
             self._quarantine(slot)

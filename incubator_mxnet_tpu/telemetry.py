@@ -1013,8 +1013,10 @@ def traced(arg=None, cat: str = "span"):
 # ---------------------------------------------------------------------------
 # Device peak FLOP/s detection (MFU denominator)
 # ---------------------------------------------------------------------------
-# bf16 peak FLOP/s PER CHIP by TPU generation (public specs); longest key
-# wins so 'v5 lite' beats 'v5'.  bench.py delegates here.
+# bf16 peak FLOP/s PER CHIP by TPU generation (public specs: Google Cloud
+# TPU documentation); longest key wins so 'v5 lite' beats 'v5'.  The ONE
+# table — bench.py delegates here — and a kind it does not list is an
+# error, never a default.
 TPU_PEAK_FLOPS = {
     "v2": 46e12,
     "v3": 123e12,
@@ -1030,13 +1032,18 @@ TPU_PEAK_FLOPS = {
 
 def tpu_peak_flops(kind: str) -> float:
     """Per-chip bf16 peak for a jax ``device_kind`` string (e.g. 'TPU v5
-    lite'); unknown kinds fall back to the v5e-class 197 TFLOP/s."""
+    lite').  An unknown kind raises ``MXNetError``: a utilization computed
+    against a guessed peak is a number about no device."""
     k = (kind or "").lower().replace("tpu", "").strip()
     best = None
     for key, val in TPU_PEAK_FLOPS.items():
         if key in k and (best is None or len(key) > len(best[0])):
             best = (key, val)
-    return best[1] if best else 197e12
+    if best is None:
+        raise MXNetError(
+            f"no peak FLOP/s on record for device kind {kind!r}; add it to "
+            "telemetry.TPU_PEAK_FLOPS with its source")
+    return best[1]
 
 
 def cpu_peak_flops() -> float:
@@ -1083,11 +1090,13 @@ def device_peak_flops() -> Optional[float]:
 def sample_device_memory() -> None:
     """Refresh the device-memory gauges from the live jax client.  Never
     raises: backends without memory_stats (CPU) just contribute the
-    live-array total."""
-    try:
-        import jax
-    except Exception:
+    live-array total.  A no-op in a process that holds no device yet
+    (``context.backend_in_use``): a scrape must never be what opens the
+    chip."""
+    from . import context as _context
+    if not _context.backend_in_use():
         return
+    import jax
     g_live = registry.gauge(
         "mx_device_live_array_bytes",
         "total bytes of live jax arrays (all devices)")

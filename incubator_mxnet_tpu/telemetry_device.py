@@ -43,6 +43,7 @@ import time
 from typing import Callable, Dict, Optional
 
 from .base import MXNetError, getenv, getenv_float
+from . import context as _context
 from . import telemetry as _telemetry
 from . import telemetry_ring as _ring
 
@@ -98,6 +99,7 @@ def register_owner(owner: str, fn: Callable[[], float]) -> None:
     ``optimizer`` (ZeRO-1 local shard)."""
     with _lock:
         _owners[owner] = fn
+    _register_flight_providers()
 
 
 def unregister_owner(owner: str) -> None:
@@ -129,6 +131,7 @@ def register_inventory(name: str, fn: Callable[[], dict]) -> None:
     per-slot KV occupancy)."""
     with _lock:
         _inventories[name] = fn
+    _register_flight_providers()
 
 
 def unregister_inventory(name: str) -> None:
@@ -160,30 +163,30 @@ def memory_breakdown() -> dict:
     live-array total, and the per-owner attribution.  Never raises."""
     out = {"devices": {}, "owners": owned_bytes(),
            "live_array_bytes": 0.0}
-    try:
+    # a process that never placed anything (router, supervisor) has no
+    # device memory, and asking jax would take the chip from the replica
+    # that does
+    if _context.backend_in_use():
         import jax
-    except Exception:
-        out["error"] = "jax unavailable"
-        return out
-    try:
-        out["live_array_bytes"] = float(sum(
-            getattr(a, "nbytes", 0) or 0 for a in jax.live_arrays()))
-    except Exception:
-        pass
-    try:
-        for d in jax.devices():
-            try:
-                stats = d.memory_stats()
-            except Exception:
-                continue
-            if not stats:
-                continue
-            out["devices"][f"{d.platform}:{d.id}"] = {
-                k: stats[k] for k in
-                ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
-                if k in stats}
-    except Exception:
-        pass
+        try:
+            out["live_array_bytes"] = float(sum(
+                getattr(a, "nbytes", 0) or 0 for a in jax.live_arrays()))
+        except Exception:
+            pass
+        try:
+            for d in jax.devices():
+                try:
+                    stats = d.memory_stats()
+                except Exception:
+                    continue
+                if not stats:
+                    continue
+                out["devices"][f"{d.platform}:{d.id}"] = {
+                    k: stats[k] for k in
+                    ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+                    if k in stats}
+        except Exception:
+            pass
     total_owned = sum(out["owners"].values())
     out["owned_bytes"] = total_owned
     out["unattributed_bytes"] = max(
@@ -326,6 +329,11 @@ def capture_profile(seconds: float,
     return path
 
 
-# the two providers every oom/watchdog/breaker flight dump should carry
-_ring.recorder.register_provider("device_memory", memory_breakdown)
-_ring.recorder.register_provider("programs", program_report)
+def _register_flight_providers() -> None:
+    """The two providers every oom/watchdog/breaker flight dump of a
+    DEVICE-HOLDING process should carry.  Registered with the first owner
+    or inventory, not at import: the router and the supervisor import
+    this package and dump through the same recorder, and they hold no
+    device (idempotent — the recorder keys providers by name)."""
+    _ring.recorder.register_provider("device_memory", memory_breakdown)
+    _ring.recorder.register_provider("programs", program_report)

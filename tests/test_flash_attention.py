@@ -222,7 +222,7 @@ def test_flash_pallas_backward_matches_xla_oracle(T, causal):
 
 def _ring_variant(use_flash, causal, mask, q, k, v):
     import jax
-    from incubator_mxnet_tpu.parallel._shmap import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from functools import partial
     from incubator_mxnet_tpu import parallel

@@ -630,32 +630,6 @@ def test_kv_starvation_blocks_readiness(monkeypatch):
         batcher.close()
 
 
-def test_compile_cache_env_wires_jax_config(monkeypatch, tmp_path):
-    """Satellite: ``MXNET_COMPILE_CACHE_DIR`` flips on the JAX
-    persistent compilation cache at engine init."""
-    import jax
-
-    from incubator_mxnet_tpu.serving import engine as eng_mod
-
-    cache_dir = str(tmp_path / "cc")
-    prev = {k: getattr(jax.config, k) for k in
-            ("jax_compilation_cache_dir",
-             "jax_persistent_cache_min_compile_time_secs",
-             "jax_persistent_cache_min_entry_size_bytes")}
-    monkeypatch.setattr(eng_mod, "_compile_cache_dir", None)
-    monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", cache_dir)
-    try:
-        eng_mod.ensure_compile_cache()
-        assert jax.config.jax_compilation_cache_dir == cache_dir
-        # idempotent — a second engine init must not re-configure
-        monkeypatch.setenv("MXNET_COMPILE_CACHE_DIR", "/elsewhere")
-        eng_mod.ensure_compile_cache()
-        assert jax.config.jax_compilation_cache_dir == cache_dir
-    finally:
-        for k, v in prev.items():
-            jax.config.update(k, v)
-
-
 def test_retry_after_hint_extractor():
     class E(Exception):
         retry_after = 0.25

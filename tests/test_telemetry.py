@@ -290,7 +290,9 @@ def test_peak_flops_detection():
     # longest-key match: 'v5 lite' must not lose to a shorter key
     assert telemetry.tpu_peak_flops("TPU v5 lite") == 197e12
     assert telemetry.tpu_peak_flops("TPU v5p") == 459e12
-    assert telemetry.tpu_peak_flops("never-heard-of-it") == 197e12
+    # a kind the table does not list is an error, never the v5e default
+    with pytest.raises(mx.MXNetError, match="never-heard-of-it"):
+        telemetry.tpu_peak_flops("never-heard-of-it")
     assert telemetry.cpu_peak_flops() > 0
     assert (telemetry.device_peak_flops() or 0) > 0   # CPU host estimate
 

@@ -80,7 +80,7 @@ def _replica_command(cache_dir):
     return [sys.executable, os.path.abspath(__file__), "replica",
             "--port", "{port}"], {
         "JAX_PLATFORMS": "cpu",
-        "MXNET_COMPILE_CACHE_DIR": cache_dir,
+        "JAX_COMPILATION_CACHE_DIR": cache_dir,
         "MXNET_DRAIN_SECONDS": "5",
         # The drill torches the error budget on purpose (queue-full
         # 429s drive the scale-up).  Park the replica-side SLO
@@ -99,7 +99,7 @@ def _prewarm(cache_dir):
     if os.listdir(cache_dir):
         return
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               MXNET_COMPILE_CACHE_DIR=cache_dir)
+               JAX_COMPILATION_CACHE_DIR=cache_dir)
     child = subprocess.Popen(
         [sys.executable, os.path.abspath(__file__), "replica", "--port", "0"],
         stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, env=env,

@@ -73,7 +73,7 @@ def run_replica(port):
 def _spawn(cache_dir, profile_dir, spec=False, scan0=False):
     import subprocess
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               MXNET_COMPILE_CACHE_DIR=cache_dir,
+               JAX_COMPILATION_CACHE_DIR=cache_dir,
                MXNET_PROFILE_DIR=profile_dir,
                MXNET_TELEMETRY="1",
                MXNET_DRAIN_SECONDS="5")

@@ -61,7 +61,7 @@ def run_replica(port):
 def _spawn(cache_dir, fault_plan=None):
     import subprocess
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               MXNET_COMPILE_CACHE_DIR=cache_dir,
+               JAX_COMPILATION_CACHE_DIR=cache_dir,
                MXNET_TELEMETRY="1",         # spans + /trace on replicas
                MXNET_DRAIN_SECONDS="5")
     if fault_plan:

@@ -9,8 +9,15 @@ processes wired to one jax.distributed coordinator via the SAME DMLC_*
 environment variables the reference uses, so reference launch scripts keep
 working:
 
-    # single machine (the reference's no-cluster test mode)
+    # single machine (the reference's no-cluster test mode; CPU hosts)
     python tools/launch.py -n 2 python train.py --kv-store dist_sync
+
+On a TPU host a training job is ONE process over all the local chips
+(``parallel.make_mesh`` + ``SPMDTrainer``): a chip belongs to one process,
+every worker inherits the same environment and so claims every local chip,
+and the second worker of ``-n N`` on one chip host fails at backend
+start-up.  Use ``-n`` greater than one per host only on CPU hosts, or one
+process per HOST across TPU hosts with ``--launcher ssh``.
 
     # multi-machine over ssh (reference: dmlc_tracker/ssh.py)
     python tools/launch.py -n 8 -H hostfile --launcher ssh \

@@ -5,7 +5,7 @@ Four drills over the ``mxtpu-router`` front tier (docs/serving.md
 "Serving a fleet"), each against real ``replica`` child processes
 serving a tiny GPT through the full ``:generate`` SSE path:
 
-* ``coldstart`` — ``MXNET_COMPILE_CACHE_DIR`` drill: first replica
+* ``coldstart`` — ``JAX_COMPILATION_CACHE_DIR`` drill: first replica
   pays the jit compiles into a fresh cache dir; a second process with
   the populated cache must reach its first ``:generate`` 200 at least
   1.5x faster (typically several times).  Side effect: warms the cache
@@ -74,7 +74,7 @@ def run_replica(port):
 # ------------------------------------------------------------ fleet helpers
 def _spawn(cache_dir, port=0):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
-               MXNET_COMPILE_CACHE_DIR=cache_dir,
+               JAX_COMPILATION_CACHE_DIR=cache_dir,
                MXNET_DRAIN_SECONDS="5")
     child = subprocess.Popen(
         [sys.executable, os.path.abspath(__file__), "replica",
@@ -260,7 +260,7 @@ def run_coldstart(cache_dir):
 
     cold = first_200("cold")
     assert os.listdir(cache_dir), \
-        "MXNET_COMPILE_CACHE_DIR never populated by the cold replica"
+        "JAX_COMPILATION_CACHE_DIR never populated by the cold replica"
     warm = first_200("warm")
     ratio = cold / max(warm, 1e-9)
     assert warm * 1.5 <= cold, \
