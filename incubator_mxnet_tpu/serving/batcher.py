@@ -700,15 +700,19 @@ class _GenRequest:
         k ``put()`` round-trips on the consumer's mutex.  ``Queue`` is
         unbounded here so skipping ``not_full`` is safe; the manual
         bookkeeping mirrors ``Queue.put`` exactly (``not_empty`` shares
-        ``mutex``).  The inter-burst gap is amortized evenly across the
-        burst's tokens so the token-latency SLI keeps per-token units."""
+        ``mutex``).  The token-latency histogram observes what the
+        client receives: the whole gap since the last emission on the
+        burst's first token and none on the rest (as the speculative
+        path's bursts do).  Returns that whole gap."""
         now = time.monotonic()
         if self.t_first is None:
             self.t_first = now
         n = len(toks)
-        gap = (now - self.t_emit) / max(1, n)
-        for _ in range(n):
+        gap = now - self.t_emit
+        if n:
             _m.TOKEN_LATENCY.observe(gap)
+            for _ in range(n - 1):
+                _m.TOKEN_LATENCY.observe(0.0)
         self.t_emit = now
         self.tokens_out.extend(toks)
         q = self._q
@@ -872,7 +876,7 @@ class ContinuousBatcher(DynamicBatcher):
       requests into the freed slots (one ``prefill`` dispatch each,
       emitting the first token); then advance ALL live slots with a
       single ``decode`` dispatch — one token per step, or up to
-      ``engine.scan_steps`` tokens when :meth:`_burst_ready` sees
+      ``engine.scan_steps`` tokens when :meth:`_burst_gate` sees
       steady state (no queued joins, cancels, or near deadlines) and
       the scanned ``decode_burst`` program takes over;
     * tokens stream back per-request as they are produced
@@ -886,7 +890,15 @@ class ContinuousBatcher(DynamicBatcher):
       not trusted), request ids on every event, SLO accounting per
       finished generation, and ``serve.batch`` spans per decode step
       with ``slot.join``/``slot.leave`` child events so ``/trace``
-      shows a request's whole decode lifetime.
+      shows a request's whole decode lifetime;
+    * the worker thread is in exactly one named phase of its loop at
+      any time (``metrics.PHASES``: wait, admit, prefill_host,
+      prefill_wait, operands, decode_wait, emit).  The loop is cut once,
+      where the work happens, and every boundary feeds both
+      ``mxtpu_serve_loop_seconds{phase}`` and — while the tracer is
+      active — a ``serve.*`` span, which a profiler capture also puts
+      into the device trace (docs/observability.md "Worker-loop
+      phases").
     """
 
     def __init__(self, engine, token_strs=None, **kw):
@@ -895,6 +907,11 @@ class ContinuousBatcher(DynamicBatcher):
             [None] * int(engine.max_slots)
         self._step = 0
         self._tokens_emitted = 0
+        # tokens by the dispatch that produced them, and the worker
+        # thread's seconds by phase of its loop (metrics.LoopClock)
+        self._tokens_by_path = dict.fromkeys(
+            ("prefill", "step", "burst", "spec"), 0)
+        self._loop_seconds = dict.fromkeys(_m.PHASES, 0.0)
         self._peak_slots = 0
         # sampling plane: token id -> string mapping for the
         # constrained-output (json_mode) machine (default: byte-level,
@@ -1127,6 +1144,9 @@ class ContinuousBatcher(DynamicBatcher):
 
     # -- worker: the continuous loop ------------------------------------
     def _worker(self, gen: int):
+        # this thread's phase clock; a replaced worker brings its own,
+        # so one that comes back from a hang cannot disturb it
+        _m.LoopClock(self.name, self._loop_seconds).bind()
         # a replaced worker's slots (and the donated cache a dying
         # dispatch may have consumed) are not trusted: start clean
         with self._cv:
@@ -1156,64 +1176,97 @@ class ContinuousBatcher(DynamicBatcher):
         catch cancels and deadline expiries), admit queued requests into
         free slots, and decide whether there is work.  Returns
         ``(leavers, joins, live)`` — or ``(None, None, None)`` when this
-        worker generation is done (closed+drained or replaced)."""
+        worker generation is done (closed+drained or replaced).  With
+        nothing to do the worker sleeps on ``_cv``: the loop's ``wait``
+        phase, the only one in which it is idle."""
         with self._cv:
             while True:
-                if gen != self._worker_gen:
-                    return None, None, None
-                now = time.monotonic()
-                self._heartbeat = now
-                leavers = []
-                for s, r in enumerate(self._slots):
-                    if r is None:
-                        continue
-                    if r._cancelled:
-                        leavers.append((s, r, "cancelled"))
-                        self._slots[s] = None
-                    elif r.deadline is not None and r.deadline <= now:
-                        leavers.append((s, r, "deadline"))
-                        self._slots[s] = None
-                while self._queue \
-                        and self._queue[0].deadline is not None \
-                        and self._queue[0].deadline <= now:
-                    self._expire_locked(self._queue.popleft())
-                joins = []
-                free = [s for s, r in enumerate(self._slots)
-                        if r is None]
-                can = getattr(self.engine, "can_admit", None)
-                est = getattr(self.engine, "reserve_estimate", None)
-                reserved = 0    # blocks promised to earlier admits
-                while self._queue and free:
-                    req = self._queue[0]
-                    if can is not None and not can(
-                            req.tokens, req.n + req.budget, reserved):
-                        break   # head-of-line waits for blocks to free
-                    self._queue.popleft()
-                    if est is not None:
-                        reserved += est(req.n + req.budget)
-                    slot = free.pop(0)
-                    req.slot = slot
-                    self._slots[slot] = req
-                    joins.append((slot, req))
-                live = [(s, r) for s, r in enumerate(self._slots)
-                        if r is not None]
-                _m.QUEUE_DEPTH.set(len(self._queue), model=self.name)
-                _m.SLOTS_IN_USE.set(len(live), model=self.name)
-                self._peak_slots = max(self._peak_slots, len(live))
-                if leavers or joins or live:
-                    self._busy_since = now
-                    self._inflight = [r for _, r in live]
-                    return leavers, joins, live
-                if self._closed and not self._queue:
-                    return None, None, None
-                self._cv.wait(0.05)
+                out = self._admit_locked(gen)
+                if out is not None:
+                    return out
+                with _m.loop_phase("wait"):
+                    self._cv.wait(0.05)
+
+    def _admit_locked(self, gen: int):
+        """One pass of :meth:`_boundary`; None when there is nothing to
+        do but wait.  The loop's ``admit`` phase — a pass that finds a
+        queue or a live slot runs under a ``serve.admit`` span; the idle
+        worker's polls open none."""
+        busy = bool(self._queue) or any(r is not None for r in self._slots)
+        with _m.loop_phase("admit", "serve.admit" if busy else None,
+                           step=self._step + 1):
+            if gen != self._worker_gen:
+                return None, None, None
+            now = time.monotonic()
+            self._heartbeat = now
+            leavers = []
+            for s, r in enumerate(self._slots):
+                if r is None:
+                    continue
+                if r._cancelled:
+                    leavers.append((s, r, "cancelled"))
+                    self._slots[s] = None
+                elif r.deadline is not None and r.deadline <= now:
+                    leavers.append((s, r, "deadline"))
+                    self._slots[s] = None
+            while self._queue \
+                    and self._queue[0].deadline is not None \
+                    and self._queue[0].deadline <= now:
+                self._expire_locked(self._queue.popleft())
+            joins = []
+            free = [s for s, r in enumerate(self._slots)
+                    if r is None]
+            can = getattr(self.engine, "can_admit", None)
+            est = getattr(self.engine, "reserve_estimate", None)
+            reserved = 0    # blocks promised to earlier admits
+            while self._queue and free:
+                req = self._queue[0]
+                if can is not None and not can(
+                        req.tokens, req.n + req.budget, reserved):
+                    break   # head-of-line waits for blocks to free
+                self._queue.popleft()
+                if est is not None:
+                    reserved += est(req.n + req.budget)
+                slot = free.pop(0)
+                req.slot = slot
+                self._slots[slot] = req
+                joins.append((slot, req))
+                self._observe_queue_wait(req, now)
+            live = [(s, r) for s, r in enumerate(self._slots)
+                    if r is not None]
+            _m.QUEUE_DEPTH.set(len(self._queue), model=self.name)
+            _m.SLOTS_IN_USE.set(len(live), model=self.name)
+            self._peak_slots = max(self._peak_slots, len(live))
+            if leavers or joins or live:
+                self._busy_since = now
+                self._inflight = [r for _, r in live]
+                return leavers, joins, live
+            if self._closed and not self._queue:
+                return None, None, None
+            return None
+
+    def _observe_queue_wait(self, req: _GenRequest, now: float):
+        """Admission: the request's wait in the queue, into the
+        histogram and — for a traced request — as a ``serve.queue`` span
+        under its ``serve.request`` (no thread sat in that interval, so
+        it is recorded with explicit times)."""
+        wait = now - req.t_submit
+        _m.QUEUE_WAIT.observe(wait)
+        if req.trace_ctx is not None:
+            t1 = time.perf_counter()
+            _telemetry.tracer.record(
+                "serve.queue", t1 - wait, t1, parent=req.trace_ctx,
+                cat="serving", model=self.name,
+                request_id=req.request_id)
 
     def _run_step(self, gen: int, leavers, joins):
         """One continuous-batching step OUTSIDE the lock: emit
         ``slot.leave`` events for boundary leavers, prefill the joins
         (first token each), then ONE decode dispatch advancing every
         live slot.  The ``serve.batch`` span wraps the whole step; its
-        ``links`` carry every live request id."""
+        ``links`` carry every live request id, ``path`` the decode
+        dispatch taken (``burst|step|spec``) and, when that was not a
+        burst, ``gate`` the reason the burst gate said no."""
         self._step += 1
         with self._cv:
             live = [(s, r) for s, r in enumerate(self._slots)
@@ -1225,141 +1278,168 @@ class ContinuousBatcher(DynamicBatcher):
         with attach, \
                 _telemetry.trace_span("serve.batch", cat="serving",
                                       model=self.name, step=self._step,
-                                      slots=len(live), links=rids):
-            for slot, req, reason in leavers:
-                self._leave(slot, req, reason)
+                                      slots=len(live),
+                                      links=rids) as batch:
+            if leavers:
+                with _m.loop_phase("emit", "serve.emit", step=self._step):
+                    for slot, req, reason in leavers:
+                        self._leave(slot, req, reason)
             for slot, req in joins:
                 self._join(slot, req, gen)
             with self._cv:
                 live = [(s, r) for s, r in enumerate(self._slots)
                         if r is not None]
-            if live:
-                # constrained slots update their vocab mask host-side
-                # at every emit boundary — the k+1-wide spec verify
-                # (like the burst scan) would sample past a stale mask
-                dyn = any(r._machine is not None for _, r in live)
-                if not dyn and \
-                        getattr(self.engine, "draft", None) is not None:
-                    self._spec_once(gen, live)
-                elif not dyn and self._burst_ready(live):
-                    self._decode_burst_once(gen, live)
-                else:
-                    self._decode_once(gen, live)
+            if not live:
+                return
+            if getattr(self.engine, "draft", None) is not None \
+                    and not any(r._machine is not None for _, r in live):
+                path, gate = "spec", None
+            else:
+                gate = self._burst_gate(live)
+                path = "step" if gate else "burst"
+            if gate:
+                _m.BURST_GATE.inc(model=self.name, reason=gate)
+            if batch is not None:
+                batch.attrs["path"] = path
+                if gate:
+                    batch.attrs["gate"] = gate
+            {"spec": self._spec_once, "burst": self._decode_burst_once,
+             "step": self._decode_once}[path](gen, live)
 
     def _join(self, slot: int, req: _GenRequest, gen: int):
         """Admit one request mid-flight: its prefill dispatch runs
-        between decode steps and emits the first token."""
+        between decode steps and emits the first token.  Loop phases:
+        ``prefill_host`` up to the program's enqueue, ``prefill_wait``
+        (inside the engine) for the pull of the first token, ``emit``
+        for handing it to the stream."""
         with _telemetry.trace_span("slot.join", cat="serving",
                                    model=self.name, slot=slot,
                                    request_id=req.request_id,
                                    prompt_tokens=req.n):
             try:
-                # sampling state rides the slot: params (and the
-                # constraint mask row, for json_mode) must be installed
-                # BEFORE prefill so the first sampled token is keyed
-                self.engine.set_slot_sampling(slot, req.sampling)
-                if req._machine is not None:
-                    self.engine.update_slot_bias(
-                        slot, req._machine.mask(budget=req.budget))
-                first = self.engine.prefill(
-                    req.tokens, slot, reserve_tokens=req.n + req.budget)
+                with _m.loop_phase("prefill_host"):
+                    # sampling state rides the slot: params (and the
+                    # constraint mask row, for json_mode) must be
+                    # installed BEFORE prefill so the first sampled
+                    # token is keyed
+                    self.engine.set_slot_sampling(slot, req.sampling)
+                    if req._machine is not None:
+                        self.engine.update_slot_bias(
+                            slot, req._machine.mask(budget=req.budget))
+                    first = self.engine.prefill(
+                        req.tokens, slot,
+                        reserve_tokens=req.n + req.budget,
+                        request_id=req.request_id)
             except Exception as e:
                 with self._cv:
                     if self._slots[slot] is req:
                         self._slots[slot] = None
                 self._fail(req, e)
                 return
-        lp = getattr(self.engine, "last_prefill_logprobs",
-                     lambda: None)()
-        if lp is not None:
-            self._push_logprobs(req, lp[0], lp[1])
-        self._emit(req, first)
-        self._advance_machine(slot, req, first)
-        if self._maybe_finished(req):
-            self._free_slot(slot, req, "finished")
+            with _m.loop_phase("emit", "serve.emit", slot=slot,
+                               request_id=req.request_id):
+                lp = getattr(self.engine, "last_prefill_logprobs",
+                             lambda: None)()
+                if lp is not None:
+                    self._push_logprobs(req, lp[0], lp[1])
+                self._emit(req, first, "prefill")
+                self._advance_machine(slot, req, first)
+                if self._maybe_finished(req):
+                    self._free_slot(slot, req, "finished")
 
     # mxtpu-lint: hot-path
     def _decode_once(self, gen: int, live):
         """ONE decode dispatch for every slot (free slots ride along at
         position 0); emit each live slot's token and free finished slots
-        immediately."""
+        immediately.  Loop phases: ``operands`` until the program is
+        enqueued, ``decode_wait`` (the engine switches) while the host
+        blocks on its tokens, then ``emit``."""
         import numpy as _np
-        S = int(self.engine.max_slots)
-        last = _np.zeros(S, _np.int32)
-        pos = _np.zeros(S, _np.int32)
-        for s, r in live:
-            last[s] = r.tokens_out[-1]
-            pos[s] = r.n + len(r.tokens_out) - 1
-        rids = [r.request_id for _, r in live]
-        _m.BATCHES.inc(model=self.name)
-        _m.BATCH_SIZE.observe(len(live))
+        with _m.loop_phase("operands", "serve.operands", step=self._step):
+            S = int(self.engine.max_slots)
+            last = _np.zeros(S, _np.int32)
+            pos = _np.zeros(S, _np.int32)
+            for s, r in live:
+                last[s] = r.tokens_out[-1]
+                pos[s] = r.n + len(r.tokens_out) - 1
+            rids = [r.request_id for _, r in live]
+            _m.BATCHES.inc(model=self.name)
+            _m.BATCH_SIZE.observe(len(live))
 
-        def run():
-            _fault.inject("serving.infer", model=self.name,
-                          request_ids=rids)
-            if self._current_gen() != gen:
-                raise _lc.RequestAborted(
-                    f"{self.name}: stale worker generation")
-            return self.engine.decode(last, pos)
+            def run():
+                _fault.inject("serving.infer", model=self.name,
+                              request_ids=rids)
+                if self._current_gen() != gen:
+                    raise _lc.RequestAborted(
+                        f"{self.name}: stale worker generation")
+                return self.engine.decode(last, pos)
 
-        t0 = time.monotonic()
-        try:
-            nxt = _fault.retry_call(run, site="serving.infer",
-                                    policy=self.retry_policy)
-        except Exception as e:
-            self._decode_failed(gen, live, e)
-            return
-        dt = time.monotonic() - t0
+            t0 = time.monotonic()
+            try:
+                nxt = _fault.retry_call(run, site="serving.infer",
+                                        policy=self.retry_policy)
+            except Exception as e:
+                self._decode_failed(gen, live, e)
+                return
+            dt = time.monotonic() - t0
+        with _m.loop_phase("emit", "serve.emit", step=self._step):
+            self._dispatch_ok(dt)
+            self._dpt_dispatches += 1
+            self._dpt_tokens += 1.0     # one token per live slot, per slot
+            _m.DISPATCHES_PER_TOKEN.set(
+                self._dpt_dispatches / max(self._dpt_tokens, 1e-9),
+                model=self.name)
+            self._fold_decode_health(live)
+            lp = self.engine.last_logprobs()    # (S, N) pair or None
+            for s, r in live:
+                if lp is not None:
+                    self._push_logprobs(r, lp[0][s], lp[1][s])
+                # the stream boundary: ONE scalar pull per emitted token
+                tok = int(nxt[s])  # mxtpu-lint: disable=host-sync-in-hot-path
+                self._emit(r, tok, "step")
+                self._advance_machine(s, r, tok)
+                if self._maybe_finished(r):
+                    self._free_slot(s, r, "finished")
+
+    def _dispatch_ok(self, dt: float):
+        """A decode dispatch of any path came back after ``dt`` seconds."""
         _m.DECODE_STEP.observe(dt)
         self._avg_batch_seconds = dt if self._avg_batch_seconds <= 0.0 \
             else 0.8 * self._avg_batch_seconds + 0.2 * dt
         self._degraded = False
         self.breaker.record_success()
-        self._dpt_dispatches += 1
-        self._dpt_tokens += 1.0     # one token per live slot, per slot
-        _m.DISPATCHES_PER_TOKEN.set(
-            self._dpt_dispatches / max(self._dpt_tokens, 1e-9),
-            model=self.name)
-        self._fold_decode_health(live)
-        lp = self.engine.last_logprobs()    # (S, N) pair or None
-        for s, r in live:
-            if lp is not None:
-                self._push_logprobs(r, lp[0][s], lp[1][s])
-            # the stream boundary: ONE scalar pull per emitted token
-            tok = int(nxt[s])  # mxtpu-lint: disable=host-sync-in-hot-path
-            self._emit(r, tok)
-            self._advance_machine(s, r, tok)
-            if self._maybe_finished(r):
-                self._free_slot(s, r, "finished")
 
-    def _burst_ready(self, live) -> bool:
-        """Steady-state gate for the multi-token burst path.  The
-        k-step scanned dispatch is opaque to the scheduler — no join,
-        cancel, or deadline check can land mid-burst — so only take it
-        when none of that boundary work could be pending: the queue is
-        empty (an admit would otherwise wait up to k tokens for its
-        slot), no rider has asked to cancel, and every live deadline
-        clears a conservative k×(per-dispatch EWMA) worst case.  Any
-        `no` falls back to the per-step path, which is always correct —
-        the gate only trades throughput for boundary granularity."""
+    def _burst_gate(self, live) -> Optional[str]:
+        """Steady-state gate for the multi-token burst path: None when
+        the burst may be taken, else the reason it may not
+        (``mxtpu_serve_burst_gate{reason}``).  The k-step scanned
+        dispatch is opaque to the scheduler — no join, cancel, or
+        deadline check can land mid-burst — so only take it when none
+        of that boundary work could be pending: the queue is empty
+        (``queue``: an admit would otherwise wait up to k tokens for its
+        slot), no rider has asked to cancel (``cancel``), and every live
+        deadline clears a conservative k×(per-dispatch EWMA) worst case
+        (``deadline``).  Any reason falls back to the per-step path,
+        which is always correct — the gate only trades throughput for
+        boundary granularity."""
         k = int(getattr(self.engine, "scan_steps", 0) or 0)
         if k < 1:
-            return False
+            return "disabled"
         with self._cv:
             if self._queue:
-                return False
+                return "queue"
         horizon = time.monotonic() \
             + k * max(self._avg_batch_seconds, 1e-4)
         for _, r in live:
             if r._cancelled:
-                return False
+                return "cancel"
             if r.deadline is not None and r.deadline <= horizon:
-                return False
+                return "deadline"
             # a constrained slot needs its mask refreshed at EVERY emit
             # boundary — the k-step scan can't see host-side updates
             if r._machine is not None:
-                return False
-        return True
+                return "constrained"
+        return None
 
     # mxtpu-lint: hot-path
     def _decode_burst_once(self, gen: int, live):
@@ -1369,88 +1449,88 @@ class ContinuousBatcher(DynamicBatcher):
         ``GenerationEngine.decode_burst``); fan each slot's emitted
         prefix out to its SSE queue as a batch and free finished slots.
         Token-for-token identical to k calls of :meth:`_decode_once` —
-        only the dispatch grouping and the emit batching change."""
+        only the dispatch grouping and the emit batching change.  Loop
+        phases as in :meth:`_decode_once`."""
         import numpy as _np
-        S = int(self.engine.max_slots)
-        last = _np.zeros(S, _np.int32)
-        pos = _np.zeros(S, _np.int32)
-        bud = _np.ones(S, _np.int32)
-        eos = _np.full(S, -1, _np.int32)
-        act = _np.zeros(S, bool)
-        for s, r in live:
-            last[s] = r.tokens_out[-1]
-            pos[s] = r.n + len(r.tokens_out) - 1
-            bud[s] = r.budget - len(r.tokens_out)
-            if r.eos_id is not None:
-                eos[s] = int(r.eos_id)
-            act[s] = True
-        rids = [r.request_id for _, r in live]
-        _m.BATCHES.inc(model=self.name)
-        _m.BATCH_SIZE.observe(len(live))
+        with _m.loop_phase("operands", "serve.operands", step=self._step):
+            S = int(self.engine.max_slots)
+            last = _np.zeros(S, _np.int32)
+            pos = _np.zeros(S, _np.int32)
+            bud = _np.ones(S, _np.int32)
+            eos = _np.full(S, -1, _np.int32)
+            act = _np.zeros(S, bool)
+            for s, r in live:
+                last[s] = r.tokens_out[-1]
+                pos[s] = r.n + len(r.tokens_out) - 1
+                bud[s] = r.budget - len(r.tokens_out)
+                if r.eos_id is not None:
+                    eos[s] = int(r.eos_id)
+                act[s] = True
+            rids = [r.request_id for _, r in live]
+            _m.BATCHES.inc(model=self.name)
+            _m.BATCH_SIZE.observe(len(live))
 
-        def run():
-            _fault.inject("serving.infer", model=self.name,
-                          request_ids=rids)
-            if self._current_gen() != gen:
-                raise _lc.RequestAborted(
-                    f"{self.name}: stale worker generation")
-            return self.engine.decode_burst(last, pos, bud, eos, act)
+            def run():
+                _fault.inject("serving.infer", model=self.name,
+                              request_ids=rids)
+                if self._current_gen() != gen:
+                    raise _lc.RequestAborted(
+                        f"{self.name}: stale worker generation")
+                return self.engine.decode_burst(last, pos, bud, eos, act)
 
-        t0 = time.monotonic()
-        try:
-            toks, emitted = _fault.retry_call(
-                run, site="serving.infer", policy=self.retry_policy)
-        except Exception as e:
-            self._decode_failed(gen, live, e)
-            return
-        dt = time.monotonic() - t0
-        _m.DECODE_STEP.observe(dt)
-        self._avg_batch_seconds = dt if self._avg_batch_seconds <= 0.0 \
-            else 0.8 * self._avg_batch_seconds + 0.2 * dt
-        self._degraded = False
-        self.breaker.record_success()
-        self._fold_decode_health(live)
-        self._burst_dispatches += 1
-        lp = self.engine.last_logprobs()    # (k, S, N) pair or None
-        total = 0
-        for s, r in live:
-            # the stream boundary: one bounded pull per rider burst
-            n = int(emitted[s])  # mxtpu-lint: disable=host-sync-in-hot-path
-            if n < 1:
-                continue
-            # mxtpu-lint: disable=host-sync-in-hot-path
-            new = [int(t) for t in toks[:n, s]]
-            stopped = False
-            if r.stops:
-                # stop sequences are detected host-side AT the emit
-                # boundary: keep through the stop, discard the
-                # over-generated tail BEFORE anything reaches the
-                # client's stream
-                kept, stopped = stop_trim(r.tokens_out, new, r.stops)
-                if stopped:
-                    self._stop_hits += 1
-                    self._stop_trimmed += n - kept
-                    _m.SAMPLE_STOP_HITS.inc(model=self.name)
-                    _m.SAMPLE_STOP_TRIMMED.inc(n - kept,
-                                               model=self.name)
-                    new = new[:kept]
-                    n = kept
-            if lp is not None:
-                for j in range(n):
-                    self._push_logprobs(r, lp[0][j, s], lp[1][j, s])
-            self._emit_burst(r, new)
-            total += n
-            # `stopped` already counted the hit — bypass the endswith
-            # re-check in _maybe_finished to keep the counter honest
-            if stopped or self._maybe_finished(r):
-                self._free_slot(s, r, "finished")
-        _m.DECODE_BURST_TOKENS.observe(total)
-        # dispatch economy: ONE dispatch bought up to k tokens per slot
-        self._dpt_dispatches += 1
-        self._dpt_tokens += total / max(1, len(live))
-        _m.DISPATCHES_PER_TOKEN.set(
-            self._dpt_dispatches / max(self._dpt_tokens, 1e-9),
-            model=self.name)
+            t0 = time.monotonic()
+            try:
+                toks, emitted = _fault.retry_call(
+                    run, site="serving.infer", policy=self.retry_policy)
+            except Exception as e:
+                self._decode_failed(gen, live, e)
+                return
+            dt = time.monotonic() - t0
+        with _m.loop_phase("emit", "serve.emit", step=self._step):
+            self._dispatch_ok(dt)
+            self._fold_decode_health(live)
+            self._burst_dispatches += 1
+            lp = self.engine.last_logprobs()    # (k, S, N) pair or None
+            total = 0
+            for s, r in live:
+                # the stream boundary: one bounded pull per rider burst
+                n = int(emitted[s])  # mxtpu-lint: disable=host-sync-in-hot-path
+                if n < 1:
+                    continue
+                # mxtpu-lint: disable=host-sync-in-hot-path
+                new = [int(t) for t in toks[:n, s]]
+                stopped = False
+                if r.stops:
+                    # stop sequences are detected host-side AT the emit
+                    # boundary: keep through the stop, discard the
+                    # over-generated tail BEFORE anything reaches the
+                    # client's stream
+                    kept, stopped = stop_trim(r.tokens_out, new, r.stops)
+                    if stopped:
+                        self._stop_hits += 1
+                        self._stop_trimmed += n - kept
+                        _m.SAMPLE_STOP_HITS.inc(model=self.name)
+                        _m.SAMPLE_STOP_TRIMMED.inc(n - kept,
+                                                   model=self.name)
+                        new = new[:kept]
+                        n = kept
+                if lp is not None:
+                    for j in range(n):
+                        self._push_logprobs(r, lp[0][j, s], lp[1][j, s])
+                self._emit_burst(r, new)
+                total += n
+                # `stopped` already counted the hit — bypass the
+                # endswith re-check in _maybe_finished to keep the
+                # counter honest
+                if stopped or self._maybe_finished(r):
+                    self._free_slot(s, r, "finished")
+            _m.DECODE_BURST_TOKENS.observe(total)
+            # dispatch economy: ONE dispatch bought up to k tokens per slot
+            self._dpt_dispatches += 1
+            self._dpt_tokens += total / max(1, len(live))
+            _m.DISPATCHES_PER_TOKEN.set(
+                self._dpt_dispatches / max(self._dpt_tokens, 1e-9),
+                model=self.name)
 
     def _fold_decode_health(self, live):
         """Health plane: fold the dispatch's device-side logit stats
@@ -1496,90 +1576,91 @@ class ContinuousBatcher(DynamicBatcher):
         grouping into dispatches changes.  Join/leave stays at step
         boundaries, so a stream that joined mid-flight never observes a
         neighbor's rejected-token rollback (rollback happens inside
-        ``spec_step``, before any rider's next dispatch)."""
+        ``spec_step``, before any rider's next dispatch).  Loop phases:
+        ``operands`` and ``decode_wait`` alternate inside (once for the
+        draft's dispatch, once for the verify), then ``emit``."""
         import numpy as _np
-        S = int(self.engine.max_slots)
-        k = int(self.engine.spec_k)
-        last = _np.zeros(S, _np.int32)
-        pos = _np.zeros(S, _np.int32)
-        for s, r in live:
-            last[s] = r.tokens_out[-1]
-            pos[s] = r.n + len(r.tokens_out) - 1
-        rids = [r.request_id for _, r in live]
-        _m.BATCHES.inc(model=self.name)
-        _m.BATCH_SIZE.observe(len(live))
+        with _m.loop_phase("operands", "serve.operands", step=self._step):
+            S = int(self.engine.max_slots)
+            k = int(self.engine.spec_k)
+            last = _np.zeros(S, _np.int32)
+            pos = _np.zeros(S, _np.int32)
+            for s, r in live:
+                last[s] = r.tokens_out[-1]
+                pos[s] = r.n + len(r.tokens_out) - 1
+            rids = [r.request_id for _, r in live]
+            _m.BATCHES.inc(model=self.name)
+            _m.BATCH_SIZE.observe(len(live))
 
-        def run():
-            _fault.inject("serving.infer", model=self.name,
-                          request_ids=rids)
-            if self._current_gen() != gen:
-                raise _lc.RequestAborted(
-                    f"{self.name}: stale worker generation")
-            return self.engine.spec_step(last, pos)
+            def run():
+                _fault.inject("serving.infer", model=self.name,
+                              request_ids=rids)
+                if self._current_gen() != gen:
+                    raise _lc.RequestAborted(
+                        f"{self.name}: stale worker generation")
+                return self.engine.spec_step(last, pos)
 
-        t0 = time.monotonic()
-        try:
-            burst, accepted = _fault.retry_call(
-                run, site="serving.infer", policy=self.retry_policy)
-        except Exception as e:
-            self._decode_failed(gen, live, e)
-            return
-        dt = time.monotonic() - t0
-        _m.DECODE_STEP.observe(dt)
-        _m.SPEC_STEP.observe(dt)
-        self._avg_batch_seconds = dt if self._avg_batch_seconds <= 0.0 \
-            else 0.8 * self._avg_batch_seconds + 0.2 * dt
-        self._degraded = False
-        self.breaker.record_success()
-        # accounting lives HERE, not in the engine: free slots ride
-        # along in the dispatch at position 0 and their accepts are
-        # meaningless.  Of a request's emitted burst, everything past
-        # the first token is a draft proposal the target kept — a
-        # budget/eos cut mid-burst caps the accepted count to match.
-        self._spec_dispatches += 1
-        lp = getattr(self.engine, "last_verify_logprobs",
-                     lambda: None)()     # (S, Q, N) pair or None
-        step_emitted = 0
-        step_accepted = 0
-        for s, r in live:
-            n_emit = 0
-            # the stream boundary: scalar pulls gate each emitted token
-            # mxtpu-lint: disable=host-sync-in-hot-path
-            for j in range(int(accepted[s]) + 1):
-                if lp is not None:
-                    self._push_logprobs(r, lp[0][s, j], lp[1][s, j])
+            t0 = time.monotonic()
+            try:
+                burst, accepted = _fault.retry_call(
+                    run, site="serving.infer", policy=self.retry_policy)
+            except Exception as e:
+                self._decode_failed(gen, live, e)
+                return
+            dt = time.monotonic() - t0
+        with _m.loop_phase("emit", "serve.emit", step=self._step):
+            self._dispatch_ok(dt)
+            _m.SPEC_STEP.observe(dt)
+            # accounting lives HERE, not in the engine: free slots ride
+            # along in the dispatch at position 0 and their accepts are
+            # meaningless.  Of a request's emitted burst, everything past
+            # the first token is a draft proposal the target kept — a
+            # budget/eos cut mid-burst caps the accepted count to match.
+            self._spec_dispatches += 1
+            lp = getattr(self.engine, "last_verify_logprobs",
+                         lambda: None)()     # (S, Q, N) pair or None
+            step_emitted = 0
+            step_accepted = 0
+            for s, r in live:
+                n_emit = 0
+                # the stream boundary: scalar pulls gate each emitted token
                 # mxtpu-lint: disable=host-sync-in-hot-path
-                self._emit(r, int(burst[s, j]))
-                n_emit += 1
-                if self._maybe_finished(r):
-                    self._free_slot(s, r, "finished")
-                    break
-            r.draft_tokens += k
-            r.accepted_tokens += n_emit - 1
-            step_emitted += n_emit
-            step_accepted += n_emit - 1
-        self._spec_emitted += step_emitted
-        self._spec_accepted += step_accepted
-        self._spec_drafted += len(live) * k
-        self._spec_slot_steps += len(live)
-        _m.SPEC_DISPATCHES.inc(model=self.name)
-        _m.SPEC_DRAFT_TOKENS.inc(len(live) * k, model=self.name)
-        _m.SPEC_ACCEPTED_TOKENS.inc(step_accepted, model=self.name)
-        # per live slot per verify dispatch: 1.0 means the draft never
-        # helps, k+1 is the ceiling (full accept + bonus token)
-        _m.SPEC_TOKENS_PER_DISPATCH.set(
-            self._spec_emitted / self._spec_slot_steps, model=self.name)
-        _m.SPEC_ACCEPT_RATE.set(
-            self._spec_accepted / max(1, self._spec_drafted),
-            model=self.name,
-            mode="sampled" if any(
-                r.sampling is not None and r.sampling.sampled
-                for _, r in live) else "greedy")
-        self._dpt_dispatches += 1
-        self._dpt_tokens += step_emitted / max(1, len(live))
-        _m.DISPATCHES_PER_TOKEN.set(
-            self._dpt_dispatches / max(self._dpt_tokens, 1e-9),
-            model=self.name)
+                for j in range(int(accepted[s]) + 1):
+                    if lp is not None:
+                        self._push_logprobs(r, lp[0][s, j], lp[1][s, j])
+                    # mxtpu-lint: disable=host-sync-in-hot-path
+                    self._emit(r, int(burst[s, j]), "spec")
+                    n_emit += 1
+                    if self._maybe_finished(r):
+                        self._free_slot(s, r, "finished")
+                        break
+                r.draft_tokens += k
+                r.accepted_tokens += n_emit - 1
+                step_emitted += n_emit
+                step_accepted += n_emit - 1
+            self._spec_emitted += step_emitted
+            self._spec_accepted += step_accepted
+            self._spec_drafted += len(live) * k
+            self._spec_slot_steps += len(live)
+            _m.SPEC_DISPATCHES.inc(model=self.name)
+            _m.SPEC_DRAFT_TOKENS.inc(len(live) * k, model=self.name)
+            _m.SPEC_ACCEPTED_TOKENS.inc(step_accepted, model=self.name)
+            # per live slot per verify dispatch: 1.0 means the draft never
+            # helps, k+1 is the ceiling (full accept + bonus token)
+            _m.SPEC_TOKENS_PER_DISPATCH.set(
+                self._spec_emitted / self._spec_slot_steps,
+                model=self.name)
+            _m.SPEC_ACCEPT_RATE.set(
+                self._spec_accepted / max(1, self._spec_drafted),
+                model=self.name,
+                mode="sampled" if any(
+                    r.sampling is not None and r.sampling.sampled
+                    for _, r in live) else "greedy")
+            self._dpt_dispatches += 1
+            self._dpt_tokens += step_emitted / max(1, len(live))
+            _m.DISPATCHES_PER_TOKEN.set(
+                self._dpt_dispatches / max(self._dpt_tokens, 1e-9),
+                model=self.name)
 
     # -- step-boundary helpers ------------------------------------------
     def _push_logprobs(self, req: _GenRequest, vals, ids):
@@ -1608,10 +1689,13 @@ class ContinuousBatcher(DynamicBatcher):
             self.engine.update_slot_bias(
                 slot, m.mask(budget=req.budget - len(req.tokens_out)))
 
-    def _emit(self, req: _GenRequest, tok: int):
+    def _emit(self, req: _GenRequest, tok: int, path: str):
+        """Hand one token of the ``path`` dispatch
+        (``prefill|step|spec``) to the request's stream."""
         gap = req._emit(tok)
         self._tokens_emitted += 1
-        _m.GENERATE_TOKENS.inc(model=self.name)
+        self._tokens_by_path[path] += 1
+        _m.GENERATE_TOKENS.inc(model=self.name, path=path)
         if req.sampling is not None and req.sampling.sampled:
             _m.SAMPLE_TOKENS.inc(model=self.name)
         # feed the token-latency SLI (MXNET_SERVE_SLO_TOKEN_P99_MS)
@@ -1619,17 +1703,21 @@ class ContinuousBatcher(DynamicBatcher):
 
     def _emit_burst(self, req: _GenRequest, toks):
         """Burst-path twin of :meth:`_emit`: one queue flush for the
-        whole burst, but the SLI and counters stay per-token — each of
-        the n tokens records the amortized gap, so ``token_window``
-        counts and the p99 keep their per-token meaning."""
-        gap = req._emit_burst(toks)
+        whole burst; counters and the SLI window stay per-token, and the
+        gaps are those the client sees — the whole wait on the burst's
+        first token, none on the rest (``ModelSLO.record_token``)."""
         n = len(toks)
+        if not n:
+            return
+        gap = req._emit_burst(toks)
         self._tokens_emitted += n
-        _m.GENERATE_TOKENS.inc(n, model=self.name)
-        if n and req.sampling is not None and req.sampling.sampled:
+        self._tokens_by_path["burst"] += n
+        _m.GENERATE_TOKENS.inc(n, model=self.name, path="burst")
+        if req.sampling is not None and req.sampling.sampled:
             _m.SAMPLE_TOKENS.inc(n, model=self.name)
-        for _ in range(n):
-            _slo.tracker.record_token(self.name, gap)
+        _slo.tracker.record_token(self.name, gap)
+        for _ in range(n - 1):
+            _slo.tracker.record_token(self.name, 0.0)
 
     def _maybe_finished(self, req: _GenRequest) -> bool:
         if len(req.tokens_out) >= req.budget:
@@ -1786,6 +1874,8 @@ class ContinuousBatcher(DynamicBatcher):
                     int(getattr(self.engine, "scan_steps", 0) or 0),
                 "decode_burst_dispatches": self._burst_dispatches,
                 "tokens_emitted": self._tokens_emitted,
+                "tokens_by_path": dict(self._tokens_by_path),
+                "loop_seconds": dict(self._loop_seconds),
                 "peak_slots_in_use": self._peak_slots,
                 "prefill_buckets": list(self.engine.prefill_buckets),
                 "kv_cache_bytes": int(self.engine.cache_bytes),

@@ -39,6 +39,7 @@ from ..ndarray.ndarray import NDArray
 from .. import telemetry as _telemetry
 from .. import telemetry_device as _telemetry_device
 from .. import health as _health
+from . import metrics as _m
 
 __all__ = ["InferenceEngine", "GenerationEngine", "derive_buckets",
            "derive_prefill_buckets"]
@@ -1482,21 +1483,32 @@ class GenerationEngine:
                                              model=self.name)
             raise
 
+    def _enqueued(self, out) -> list:
+        """A decode, burst or verify program is enqueued and ``out`` are
+        its (future) results: what follows pulls them, so the worker
+        loop's ``operands`` phase ends here and ``decode_wait`` begins."""
+        _m.loop_phase_switch("decode_wait", "serve.decode.wait")
+        return list(out)
+
     def _unpack_prefill(self, out) -> int:
-        """Rebind the cache and stash the prefill logprobs (arity is
-        baked by ``logprobs_topn``, exactly like the health plane)."""
-        if self.logprobs_topn:
-            cache, first, lp = out
-            self._last_prefill_logprobs = tuple(_np.asarray(a)
-                                                for a in lp)
-        else:
-            cache, first = out
-            self._last_prefill_logprobs = None
-        self._cache = cache
-        return int(first)
+        """Rebind the cache, stash the prefill logprobs (arity is baked
+        by ``logprobs_topn``, exactly like the health plane) and pull the
+        first token: the host blocks here until the device has done the
+        prefill (the worker loop's ``prefill_wait`` phase)."""
+        with _m.loop_phase("prefill_wait", "serve.prefill.wait"):
+            if self.logprobs_topn:
+                cache, first, lp = out
+                self._last_prefill_logprobs = tuple(_np.asarray(a)
+                                                    for a in lp)
+            else:
+                cache, first = out
+                self._last_prefill_logprobs = None
+            self._cache = cache
+            return int(first)
 
     def prefill(self, tokens, slot: int,
-                reserve_tokens: Optional[int] = None) -> int:
+                reserve_tokens: Optional[int] = None,
+                request_id: Optional[str] = None) -> int:
         """Admit a prompt into ``slot``: pad to the prompt-length bucket,
         dispatch the bucket's prefill program, return the FIRST generated
         token.  After this the slot's write head is at ``len(tokens)``
@@ -1507,8 +1519,13 @@ class GenerationEngine:
         positions (prompt + budget) the request may ever write, so decode
         NEVER allocates and can never fail mid-flight.  A prefix-cache
         hit dispatches the suffix program instead, skipping the shared
-        span's prefill work entirely."""
-        import jax.numpy as jnp
+        span's prefill work entirely.
+
+        The ``serve.prefill`` span (stamped with ``request_id`` when the
+        caller has one) covers all of it: block allocation, padding,
+        uploads and the program's enqueue, then — as its
+        ``serve.prefill.wait`` child — the pull of the first token,
+        which is where the host waits for the device."""
         toks = _np.asarray(tokens, _np.int32).reshape(-1)
         n = int(toks.shape[0])
         if not 0 <= int(slot) < self.max_slots:
@@ -1520,6 +1537,16 @@ class GenerationEngine:
             raise MXNetError(
                 f"{self.name}: prompt length {n} leaves no room to "
                 f"generate (max_len {self.max_len})")
+        ids = {"request_id": request_id} if request_id is not None else {}
+        with _telemetry.trace_span("serve.prefill", cat="serving",
+                                   model=self.name, slot=int(slot),
+                                   tokens=n, **ids) as span:
+            return self._prefill_slot(toks, n, int(slot), reserve_tokens,
+                                      span)
+
+    def _prefill_slot(self, toks, n: int, slot: int, reserve_tokens,
+                      span):
+        import jax.numpy as jnp
         if self.draft is not None:
             # The draft mirrors the target's slot layout: prefill it with
             # the same prompt so its write head tracks ours.  Its own
@@ -1533,19 +1560,11 @@ class GenerationEngine:
                                    reserve_tokens or self.max_len)
                                + self.spec_k)
         if not self.paged:
-            bucket = self.prefill_bucket_for(n)
-            padded = _np.zeros((1, bucket), _np.int32)
-            padded[0, :n] = toks
-            with _telemetry.trace_span("serve.prefill", cat="serving",
-                                       model=self.name, slot=int(slot),
-                                       tokens=n, bucket=bucket):
-                out = self._guarded(
-                    self._prefill, jnp.asarray(padded),
-                    jnp.asarray(n, jnp.int32),
-                    jnp.asarray(int(slot), jnp.int32),
-                    self._slot_samp(slot))
-            return self._unpack_prefill(out)
-        slot = int(slot)
+            padded = self._padded(toks, n, span)
+            return self._unpack_prefill(self._guarded(
+                self._prefill, jnp.asarray(padded),
+                jnp.asarray(n, jnp.int32), jnp.asarray(slot, jnp.int32),
+                self._slot_samp(slot)))
         if self._slot_blocks[slot]:
             self.release_slot(slot)
         reserve = int(reserve_tokens or self.max_len) \
@@ -1559,7 +1578,7 @@ class GenerationEngine:
         self._tables[slot] = row
         self._tables_dev = None
         try:
-            return self._prefill_paged_dispatch(toks, n, m, row, slot)
+            return self._prefill_paged_dispatch(toks, n, m, row, slot, span)
         except Exception:
             # The fresh (non-shared) blocks never got their K/V written;
             # allocate() already registered the full ones in the prefix
@@ -1571,39 +1590,44 @@ class GenerationEngine:
             raise
 
     def _prefill_paged_dispatch(self, toks, n: int, m: int, row,
-                                slot: int) -> int:
+                                slot: int, span) -> int:
         import jax.numpy as jnp
         ss = self._slot_samp(slot)
         if m == 0:
-            bucket = self.prefill_bucket_for(n)
-            padded = _np.zeros((1, bucket), _np.int32)
-            padded[0, :n] = toks
-            with _telemetry.trace_span("serve.prefill", cat="serving",
-                                       model=self.name, slot=slot,
-                                       tokens=n, bucket=bucket):
-                out = self._guarded(
-                    self._prefill, jnp.asarray(padded),
-                    jnp.asarray(n, jnp.int32), jnp.asarray(row), ss)
+            padded = self._padded(toks, n, span)
+            out = self._guarded(
+                self._prefill, jnp.asarray(padded),
+                jnp.asarray(n, jnp.int32), jnp.asarray(row), ss)
         else:
-            sn = n - m
-            bucket = self.prefill_bucket_for(sn)
-            padded = _np.zeros((1, bucket), _np.int32)
-            padded[0, :sn] = toks[m:]
-            with _telemetry.trace_span("serve.prefill", cat="serving",
-                                       model=self.name, slot=slot,
-                                       tokens=n, bucket=bucket,
-                                       prefix_hit_tokens=m):
-                out = self._guarded(
-                    self._prefill_ext, jnp.asarray(padded),
-                    jnp.asarray(sn, jnp.int32), jnp.asarray(m, jnp.int32),
-                    jnp.asarray(row), ss)
+            if span is not None:
+                span.attrs["prefix_hit_tokens"] = m
+            padded = self._padded(toks[m:], n - m, span)
+            out = self._guarded(
+                self._prefill_ext, jnp.asarray(padded),
+                jnp.asarray(n - m, jnp.int32), jnp.asarray(m, jnp.int32),
+                jnp.asarray(row), ss)
         return self._unpack_prefill(out)
+
+    def _padded(self, toks, n: int, span):
+        """``toks`` padded to its prompt-length bucket, (1, bucket)."""
+        bucket = self.prefill_bucket_for(n)
+        if span is not None:
+            span.attrs["bucket"] = bucket
+        padded = _np.zeros((1, bucket), _np.int32)
+        padded[0, :n] = toks
+        return padded
 
     def decode(self, last_tokens, positions):
         """Advance EVERY slot one position in one dispatch: last_tokens
         (S,) int32 (free slots: 0), positions (S,) int32 (free slots: 0).
-        Returns the next token per slot as a host int32 array."""
+        Returns the next token per slot as a host int32 array.
+
+        On a generation worker's thread the call spans two phases of its
+        loop: ``operands`` (uploads, sampling and parameter operands, the
+        key, the program's enqueue) and, from :meth:`_enqueued` on,
+        ``decode_wait`` — the host blocked on the device's result."""
         import jax.numpy as jnp
+        _m.loop_phase_switch("operands", "serve.operands")
         lt = jnp.asarray(_np.asarray(last_tokens, _np.int32).reshape(
             self.max_slots, 1))
         pos = jnp.asarray(_np.asarray(positions, _np.int32).reshape(
@@ -1616,7 +1640,7 @@ class GenerationEngine:
         else:
             out = self._guarded(self._decode, lt, pos,
                                 self._samp_tuple())
-        out = list(out)
+        out = self._enqueued(out)
         if self.logprobs_topn:
             self._last_logprobs = tuple(_np.asarray(a)
                                         for a in out.pop())
@@ -1644,6 +1668,7 @@ class GenerationEngine:
                 f"{self.name}: decode bursts disabled (scan_steps "
                 f"{self.scan_steps}; set MXNET_DECODE_SCAN_STEPS >= 1)")
         S = self.max_slots
+        _m.loop_phase_switch("operands", "serve.operands")
         lt = jnp.asarray(_np.asarray(last_tokens, _np.int32).reshape(S, 1))
         pos = jnp.asarray(_np.asarray(positions, _np.int32).reshape(S))
         bud = jnp.asarray(_np.asarray(budgets, _np.int32).reshape(S))
@@ -1659,7 +1684,7 @@ class GenerationEngine:
         else:
             out = self._guarded(self._decode_burst, lt, pos, bud, eos,
                                 done0, self._samp_tuple())
-        out = list(out)
+        out = self._enqueued(out)
         if self.logprobs_topn:          # (k, S, N) per burst step
             self._last_logprobs = tuple(_np.asarray(a)
                                         for a in out.pop())
@@ -1734,6 +1759,7 @@ class GenerationEngine:
         host array: ``out[s, j]`` is the next token after consuming
         ``tokens[s, :j + 1]``."""
         import jax.numpy as jnp
+        _m.loop_phase_switch("operands", "serve.operands")
         toks = _np.asarray(tokens, _np.int32).reshape(self.max_slots, -1)
         lt = jnp.asarray(toks)
         pos = jnp.asarray(_np.asarray(positions, _np.int32).reshape(
@@ -1746,6 +1772,7 @@ class GenerationEngine:
         else:
             res = self._guarded(self._verify, lt, pos,
                                 self._samp_tuple())
+        res = self._enqueued(res)
         if self.logprobs_topn:          # (S, Q, N) per verify
             cache, out, lp = res
             self._last_verify_logprobs = tuple(_np.asarray(a)

@@ -9,6 +9,9 @@ p50/p95/max in the summary exposition).
 """
 from __future__ import annotations
 
+import threading
+import time
+
 from .. import telemetry as _telemetry
 
 # counters -----------------------------------------------------------------
@@ -31,7 +34,17 @@ DEADLINE_EXCEEDED = _telemetry.registry.counter(
     "(stage=admission|queue|wait|decode)")
 GENERATE_TOKENS = _telemetry.registry.counter(
     "mxtpu_generate_tokens",
-    "tokens emitted by the continuous-batching generation path")
+    "tokens emitted by the continuous-batching generation path, by the "
+    "dispatch that produced them (path=prefill|step|burst|spec)")
+LOOP_SECONDS = _telemetry.registry.counter(
+    "mxtpu_serve_loop_seconds",
+    "seconds the generation worker thread spent in each phase of its "
+    "loop (phase=wait|admit|prefill_host|prefill_wait|operands|"
+    "decode_wait|emit); the phases partition the thread's time")
+BURST_GATE = _telemetry.registry.counter(
+    "mxtpu_serve_burst_gate",
+    "decode dispatches that were not a burst, by the reason the gate "
+    "said no (reason=queue|cancel|deadline|constrained|disabled)")
 CANCELLED = _telemetry.registry.counter(
     "mxtpu_serve_cancelled",
     "generation requests cancelled mid-decode (client disconnect); the "
@@ -278,3 +291,112 @@ SLO_BUDGET = _telemetry.registry.gauge(
     "mxtpu_slo_error_budget_remaining",
     "fraction of the error budget left in the rolling window "
     "(0 = exhausted -> readiness blocker), per model")
+
+
+# the generation worker's loop, cut into phases -----------------------------
+#: every phase of the ContinuousBatcher worker's loop; the thread is in
+#: exactly one at any time (docs/observability.md "Worker-loop phases")
+PHASES = ("wait", "admit", "prefill_host", "prefill_wait", "operands",
+          "decode_wait", "emit")
+
+_loop_tl = threading.local()
+
+
+class LoopClock:
+    """Phase accounting of ONE generation worker thread, cut once where
+    the work happens and read two ways: each boundary adds the elapsed
+    time to ``mxtpu_serve_loop_seconds{model, phase}`` (always) and
+    opens a ``telemetry.trace_span`` (while the tracer is active, which
+    during a profiler capture also puts it into the device trace).
+
+    Phases nest as ``with`` blocks and time is exclusive: a phase is
+    charged only while it is the innermost.  Outside any block the
+    thread is in ``admit`` — the batcher's own scheduling between
+    phases.  The batcher binds a clock to its worker thread
+    (:meth:`bind`); the engine reaches it through :func:`loop_phase` and
+    :func:`loop_phase_switch`, which do nothing on a thread that has
+    none (an engine driven directly)."""
+
+    def __init__(self, model: str, seconds: dict):
+        self.model = model
+        self.seconds = seconds      # the batcher's totals, by PHASES
+        self.phase = "admit"
+        self._t = time.perf_counter()
+        self._open = []             # the nested _Phase blocks
+
+    def bind(self) -> None:
+        _loop_tl.clock = self
+
+    def _turn(self, phase: str) -> None:
+        now = time.perf_counter()
+        dt, self._t = now - self._t, now
+        self.seconds[self.phase] += dt
+        LOOP_SECONDS.inc(dt, model=self.model, phase=self.phase)
+        self.phase = phase
+
+
+class _Phase:
+    __slots__ = ("clock", "name", "span_name", "attrs", "span", "outer")
+
+    def __init__(self, clock, name, span_name, attrs):
+        self.clock = clock
+        self.name = name
+        self.span_name = span_name
+        self.attrs = attrs
+        self.span = None
+        self.outer = None
+
+    def _begin_span(self):
+        if self.span_name is not None and _telemetry.tracer.active:
+            attrs = dict(self.attrs, model=self.clock.model)
+            self.span = _telemetry.tracer._begin(self.span_name, "serving",
+                                                 attrs=attrs)
+
+    def _end_span(self):
+        if self.span is not None:
+            _telemetry.tracer._end(self.span)
+            self.span = None
+
+    def __enter__(self):
+        clock = self.clock
+        if clock is not None:
+            self.outer = clock.phase
+            clock._turn(self.name)
+            clock._open.append(self)
+            self._begin_span()
+        return self
+
+    def __exit__(self, *exc):
+        clock = self.clock
+        if clock is not None:
+            self._end_span()
+            clock._open.pop()       # ``with`` blocks close innermost first
+            clock._turn(self.outer)
+        return False
+
+
+def loop_phase(name: str, span: str = None, **attrs) -> _Phase:
+    """``with loop_phase("emit", "serve.emit", step=n): ...`` — this
+    thread's worker loop is in phase ``name`` for the block, under a
+    span named ``span`` (None: counted only)."""
+    return _Phase(getattr(_loop_tl, "clock", None), name, span, attrs)
+
+
+def loop_phase_switch(name: str, span: str = None, **attrs) -> None:
+    """End the innermost open phase of this thread's loop here and begin
+    ``name`` in its place, as its sibling; the ``with`` that opened the
+    first then closes the second.  For a boundary that lies inside a
+    callee: the batcher opens ``operands`` around the engine call, and
+    the engine, once the program is enqueued, switches to
+    ``decode_wait``."""
+    clock = getattr(_loop_tl, "clock", None)
+    if clock is None or not clock._open:
+        return
+    ph = clock._open[-1]
+    if ph.name == name:
+        return
+    ph._end_span()
+    clock._turn(name)
+    # the sibling goes on with the same work: it keeps the ids
+    ph.name, ph.span_name, ph.attrs = name, span, attrs or ph.attrs
+    ph._begin_span()

@@ -686,10 +686,11 @@ class Span:
     while another is current on the same thread (or under an explicit
     ``parent=``) becomes its child.  ``sid`` is a process-unique hex id
     so a span can be referenced from outside its tree (batch-span links,
-    ``/trace`` lookups)."""
+    ``/trace`` lookups).  ``ann`` holds the profiler annotation entered
+    with the span while ``tracer.annotate`` is set (else None)."""
 
     __slots__ = ("name", "cat", "t0", "t1", "attrs", "children", "tid",
-                 "parent", "sid")
+                 "parent", "sid", "ann")
 
     def __init__(self, name: str, cat: str = "span", attrs: dict = None):
         self.name = name
@@ -699,6 +700,7 @@ class Span:
         self.t1 = None
         self.tid = 0
         self.sid = f"{next(_span_seq):08x}"
+        self.ann = None
         self.parent: Optional["Span"] = None
         self.children: List["Span"] = []
 
@@ -764,12 +766,26 @@ class Tracer:
     Cross-thread propagation: capture the current span in the parent
     thread (``ctx = tracer.current()``) and either open child spans with
     ``trace_span(..., parent=ctx)`` or wrap the worker's body in
-    ``with tracer.attach(ctx): ...`` so its spans nest under ``ctx``."""
+    ``with tracer.attach(ctx): ...`` so its spans nest under ``ctx``.
+
+    The profiler bridge: while :attr:`annotate` is set (to a factory
+    ``(name, **ids) -> context manager``;
+    ``telemetry_device.capture_profile`` sets it to
+    ``jax.profiler.TraceAnnotation`` for the length of a capture), every
+    span that opens also enters an annotation of the same name, on the
+    opening thread, and exits it when the span ends — so the program's
+    spans lie in the profiler's trace on the clock of the device's
+    lines.  This module never imports jax: the router and the supervisor
+    import it and hold no device."""
+
+    #: span attrs that identify work and ride into the annotation
+    ANNOTATION_IDS = ("request_id", "slot", "step", "model")
 
     def __init__(self, max_finished: int = 512):
         self._tl = threading.local()
         self._lock = threading.Lock()
         self._enable_count = 0
+        self.annotate: Optional[Callable] = None
         self._live: Dict[int, Span] = {}
         self._finished = deque(maxlen=max_finished)
         self._epoch = time.perf_counter()
@@ -823,9 +839,26 @@ class Tracer:
             with self._lock:
                 self._live[id(sp)] = sp
         stack.append(sp)
+        factory = self.annotate
+        if factory is not None:
+            ids = {k: attrs[k] for k in self.ANNOTATION_IDS
+                   if k in attrs} if attrs else {}
+            try:
+                ann = factory(name, **ids)
+                ann.__enter__()
+                sp.ann = ann
+            except Exception:       # a profiler fault never fails the span
+                pass
         return sp
 
     def _end(self, sp: Span) -> None:
+        ann = sp.ann
+        if ann is not None:     # also after the capture stopped: a no-op
+            sp.ann = None
+            try:
+                ann.__exit__(None, None, None)
+            except Exception:
+                pass
         sp.t1 = time.perf_counter()
         stack = self._stack()
         if stack and stack[-1] is sp:
@@ -842,6 +875,27 @@ class Tracer:
     def span(self, name: str, cat: str = "span", parent: Span = None,
              **attrs) -> _SpanCtx:
         return _SpanCtx(name, cat, parent, attrs)
+
+    def record(self, name: str, t0: float, t1: float, parent: Span = None,
+               cat: str = "span", **attrs) -> Optional[Span]:
+        """A finished span with explicit ``time.perf_counter`` times, for
+        an interval no thread sat inside (a request's wait in the queue).
+        Lands under ``parent`` or, without one, among the finished roots;
+        it carries no profiler annotation.  None while tracing is off."""
+        if not self.active:
+            return None
+        sp = Span(name, cat, attrs)
+        sp.t0, sp.t1 = float(t0), float(t1)
+        sp.tid = threading.get_ident()
+        sp.parent = parent
+        if parent is not None:
+            parent.children.append(sp)
+        else:
+            with self._lock:
+                self._finished.append(sp)
+            if SPAN.subscribers:
+                SPAN.publish(sp)
+        return sp
 
     def current(self) -> Optional[Span]:
         stack = getattr(self._tl, "stack", None)
@@ -903,13 +957,19 @@ class Tracer:
         with self._lock:
             return list(self._finished) + list(self._live.values())
 
-    def tree(self, max_finished: int = 64,
+    def now(self) -> float:
+        """The present on the clock of :meth:`tree`: seconds since
+        tracer creation."""
+        return time.perf_counter() - self._epoch
+
+    def tree(self, max_finished: Optional[int] = 64,
              since: Optional[float] = None) -> dict:
         """JSON-ready view for the HTTP ``/trace`` endpoint: currently
-        open root spans plus the most recent finished ones.  Times are
-        seconds since tracer creation; ``since`` (same clock) drops
-        roots that started before it, so a long-running server can be
-        polled incrementally instead of re-serialized whole."""
+        open root spans plus the most recent finished ones (None: every
+        one still buffered).  Times are seconds since tracer creation;
+        ``since`` (same clock) drops roots that started before it, so a
+        long-running server can be polled incrementally instead of
+        re-serialized whole."""
         now = time.perf_counter()
         with self._lock:
             live = list(self._live.values())
@@ -918,7 +978,8 @@ class Tracer:
             cutoff = self._epoch + float(since)
             live = [s for s in live if s.t0 is None or s.t0 >= cutoff]
             fin = [s for s in fin if s.t0 is None or s.t0 >= cutoff]
-        fin = fin[-max(0, int(max_finished)):]
+        if max_finished is not None:
+            fin = fin[max(0, len(fin) - max(0, int(max_finished))):]
         return {
             "epoch_perf_counter": self._epoch,
             "live": [s.to_dict(self._epoch, now) for s in live],
@@ -1192,7 +1253,8 @@ _ledger_dispatches = registry.counter(
     "compiled-program dispatches, by instrumented jit site")
 _ledger_seconds = registry.histogram(
     "mxtpu_dispatch_seconds",
-    "host wall seconds per compiled-program dispatch (all sites)")
+    "host wall seconds per compiled-program dispatch (all sites): the "
+    "call that enqueues the program, not the device's time")
 _ledger: Dict[str, _LedgerEntry] = {}
 _ledger_lock = threading.Lock()
 
@@ -1363,7 +1425,10 @@ def instrument_jit(where: str, jitted: Callable) -> Callable:
     Independent of all three consumers, every call lands in the
     process-wide **dispatch ledger** (:func:`dispatch_ledger`): per-site
     dispatch counts, host wall-time histograms and last-dispatch age —
-    the always-on runtime program inventory.  Cost on the unobserved
+    the always-on runtime program inventory.  The wall time is that of
+    the ``jitted(...)`` call, i.e. of the enqueue: jax returns before
+    the device has done the work, and the wait is wherever the caller
+    pulls the result.  Cost on the unobserved
     fast path: two ``perf_counter`` reads and two dict updates per
     dispatch."""
     size_fn = getattr(jitted, "_cache_size", None)

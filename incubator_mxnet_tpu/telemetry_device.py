@@ -25,9 +25,11 @@ Three cooperating pieces, all riding the shared telemetry spine:
 * **Profiler capture** — :func:`capture_profile` wraps
   ``jax.profiler.start_trace``/``stop_trace`` with a single-capture
   guard, writing one artifact directory per capture under
-  ``MXNET_PROFILE_DIR`` (default ``<tmpdir>/mxtpu_profile``).  Works on
-  the CPU backend, so the serving route (``POST /debug/profile``) and
-  the router fan-out round-trip in tests without a TPU.
+  ``MXNET_PROFILE_DIR`` (default ``<tmpdir>/mxtpu_profile``): the
+  device trace with the program's spans in it as annotations (Python
+  tracer off) and ``spans.json``.  Works on the CPU backend, so the
+  serving route (``POST /debug/profile``) and the router fan-out
+  round-trip in tests without a TPU.
 
 A background sampler (:func:`start_sampler`) refreshes the memory
 gauges every ``MXNET_DEVICE_MEM_INTERVAL_SECONDS`` (0 = disabled, the
@@ -36,6 +38,7 @@ for processes nobody scrapes.
 """
 from __future__ import annotations
 
+import json
 import os
 import tempfile
 import threading
@@ -302,7 +305,16 @@ def capture_profile(seconds: float,
     [0.05, 60]) into a fresh artifact directory under ``out_dir`` /
     ``MXNET_PROFILE_DIR`` and return its path.  Blocks for the capture
     window.  Raises :class:`CaptureBusy` while another capture runs —
-    the serving route maps that to HTTP 409."""
+    the serving route maps that to HTTP 409.
+
+    The capture runs with the Python tracer off: the host's share of the
+    trace is the program's own spans, which the tracer enters as
+    ``TraceAnnotation``s for the length of the capture
+    (``Tracer.annotate``), so they lie on the clock of the device's
+    lines and the host code in question runs undisturbed.  The capture
+    holds one ``tracer.enable()`` reference, so a plain server records
+    spans during a capture and none outside it; the trees of the window
+    are written to ``spans.json`` beside the device trace."""
     global _capture_active, _capture_seq
     import jax
     seconds = min(CAPTURE_MAX_SECONDS,
@@ -316,12 +328,32 @@ def capture_profile(seconds: float,
     base = out_dir or default_profile_dir()
     path = os.path.join(base, f"capture_{os.getpid()}_{seq:03d}")
     os.makedirs(path, exist_ok=True)
+    tracer = _telemetry.tracer
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
     try:
-        jax.profiler.start_trace(path)
+        tracer.enable()
+        since = tracer.now()
+        spans = None
         try:
-            time.sleep(seconds)
+            jax.profiler.start_trace(path, profiler_options=options)
+            tracer.annotate = jax.profiler.TraceAnnotation
+            try:
+                time.sleep(seconds)
+                # the window's spans; stop_trace below also exports the
+                # trace, which on a chip takes far longer than the window
+                spans = tracer.tree(max_finished=None, since=since)
+            finally:
+                # annotate until the profiler has stopped: what the device
+                # records last has its host span too
+                try:
+                    jax.profiler.stop_trace()
+                finally:
+                    tracer.annotate = None
         finally:
-            jax.profiler.stop_trace()
+            tracer.disable()
+        with open(os.path.join(path, "spans.json"), "w") as f:
+            json.dump(spans, f, default=str)   # attrs are free-form
         _c_captures.inc()
     finally:
         with _capture_lock:

@@ -405,8 +405,9 @@ def test_watchdog_restart_rewipes_pool():
         fault.clear_plan()
         # the replacement worker resets the engine: pool fully free
         deadline = time.monotonic() + 5
-        while bat.slots_in_use() and time.monotonic() < deadline:
-            time.sleep(0.005)
+        while (bat.slots_in_use() or paged.pool.blocks_in_use) \
+                and time.monotonic() < deadline:
+            time.sleep(0.005)   # the reset runs outside the batcher's lock
         assert paged.pool.blocks_in_use == 0
         assert paged.pool.cached_blocks == 0
         # first request after the cooldown is the breaker's probe
