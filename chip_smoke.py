@@ -19,7 +19,8 @@ Legs (random weights from a seed, nothing read from outside the repo):
   32-token prefix (prompts of 37 and 72 tokens: two prefill buckets), the
   second joining mid-flight.  The same prompt must give
   the same tokens sync, streamed, alone and beside another stream;
-  ``/programs`` must balance; ``/memory`` must show parameters and KV pool
+  ``/programs`` must balance and name ``"paged_attention": "pallas"`` (the
+  lax gather is the CPU's); ``/memory`` must show parameters and KV pool
   inside a ``tpu:*`` device's bytes-in-use; SIGTERM must drain to exit 0.
 * ``train`` — BERT-large (24 x 1024, 16 heads, vocab 30522) MLM+NSP, seq 128,
   bf16, ``SPMDTrainer`` then ``CompiledLoop`` on one chip: loss finite and
@@ -406,6 +407,12 @@ def _serve_traffic(port):
         raise AssertionError(
             f"/programs: compiled {inv['compiled_programs']} != expected "
             f"{inv['expected_programs']}")
+    # on the chip decode reads the pool in place; the dense gather is the
+    # CPU's path and must not be what a TPU serves with
+    if inv.get("paged_attention") != "pallas":
+        raise AssertionError(
+            f"/programs: paged_attention is {inv.get('paged_attention')!r} "
+            "on a TPU, not 'pallas'")
     # with an empty queue every step is a burst, so the per-step decode
     # program has only its warm-up dispatch to show; the others must move
     after = _dispatches(port)

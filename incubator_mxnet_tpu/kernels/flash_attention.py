@@ -42,10 +42,12 @@ import jax.numpy as jnp
 
 __all__ = ["flash_attention", "flash_attention_lse", "decode_attention",
            "paged_decode_attention", "verify_decode_attention",
-           "paged_verify_decode_attention"]
+           "paged_verify_decode_attention", "paged_attention_impl"]
 
 _BLOCK_Q = 128
 _BLOCK_K = 128
+# keys one grid step of the paged decode kernel takes (a group of pool pages)
+_PAGED_GROUP_KEYS = 128
 
 
 def _platform_of(x):
@@ -633,11 +635,26 @@ def _xla_paged_decode_attention(q, k_pages, v_pages, tables, positions,
     return _xla_decode_attention(q, k, v, positions, scale)
 
 
-def _paged_decode_pallas(q, k_pages, v_pages, tables, positions, scale,
-                         interpret):
-    """Paged single-query decode as paged verify at query width 1."""
-    return _paged_verify_pallas(q[:, :, None, :], k_pages, v_pages, tables,
-                                positions, scale, interpret)[:, :, 0, :]
+def paged_attention_impl(q, k_pages):
+    """Which implementation the two paged entry points trace for a call
+    with operand ``q`` (any of them: it names the platform) over the pool
+    ``k_pages`` (num_blocks, H, block_size, D):
+    ``"pallas"`` — the kernel that reads the pool in place, live blocks
+    only — on a TPU for a float32 pool (the one the engine allocates, and
+    the one timed on the chip) with ``block_size`` and ``D`` multiples of
+    8, else ``"lax_gather"``, the dense gather (the CPU path, and the
+    reference the kernel is tested against).  Decided from what is
+    visible at trace time, never from the environment;
+    ``MXNET_FA_DECODE_FORCE_PALLAS=1`` is the test hook that takes the
+    kernel (interpreted on a CPU) wherever the shapes allow it.
+    ``GenerationEngine.program_inventory()`` reports it."""
+    from ..base import getenv_bool
+    _, _, bs, D = k_pages.shape
+    fits = k_pages.dtype == jnp.float32 and bs % 8 == 0 and D % 8 == 0
+    if fits and (_platform_of(q) == "tpu"
+                 or getenv_bool("MXNET_FA_DECODE_FORCE_PALLAS")):
+        return "pallas"
+    return "lax_gather"
 
 
 def paged_decode_attention(q, k_pages, v_pages, tables, positions,
@@ -657,27 +674,17 @@ def paged_decode_attention(q, k_pages, v_pages, tables, positions,
     traced value — a slot frozen mid-burst attends over exactly its old
     prefix while its redirected null-block writes stay invisible.
 
-    The lax gather reference is the default (and the CPU path); the
-    Pallas kernel — the table-driven gather XLA has no good lowering
-    for — sits behind ``MXNET_USE_FUSION`` on accelerators and
-    ``MXNET_FA_DECODE_FORCE_PALLAS=1`` (interpret mode) for parity
-    tests."""
-    from ..base import getenv_bool
-    _, H, bs, D = k_pages.shape
+    :func:`paged_attention_impl` picks the implementation at trace time:
+    the Pallas kernel (single-query decode IS verify at query width 1)
+    or the lax gather."""
     if scale is None:
-        scale = 1.0 / math.sqrt(D)
-    platform = _platform_of(q)
-    force = getenv_bool("MXNET_FA_DECODE_FORCE_PALLAS")
-    aligned = bs % 8 == 0 and D % 8 == 0
-    if force and aligned:
-        return _paged_decode_pallas(q, k_pages, v_pages, tables, positions,
-                                    scale, interpret=platform == "cpu")
-    if platform == "cpu" or not aligned \
-            or not getenv_bool("MXNET_USE_FUSION"):
-        return _xla_paged_decode_attention(q, k_pages, v_pages, tables,
-                                           positions, scale)
-    return _paged_decode_pallas(q, k_pages, v_pages, tables, positions,
-                                scale, interpret=False)
+        scale = 1.0 / math.sqrt(k_pages.shape[-1])
+    if paged_attention_impl(q, k_pages) == "pallas":
+        return _paged_verify_pallas(
+            q[:, :, None, :], k_pages, v_pages, tables, positions, scale,
+            interpret=_platform_of(q) == "cpu")[:, :, 0, :]
+    return _xla_paged_decode_attention(q, k_pages, v_pages, tables,
+                                       positions, scale)
 
 
 # ---------------------------------------------------------------------------
@@ -708,18 +715,13 @@ def _xla_verify_decode_attention(q, k, v, positions, scale):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _verify_kernel(*refs, scale, n_q, block_k, n_kb):
-    """Grid (S, H, n_kb): a (Q, D) query block against K/V blocks
-    (block_k, D), online softmax across the kb axis with per-row running
-    max / denominator in scratch (persists along the innermost grid dim).
-    ``refs``: the scalar-prefetch operands (positions last — the paged
-    variant prefetches its block table before it, consumed only by the
-    index maps), then q, k, v, out and the three scratch buffers.  Which
-    K/V block grid step ``kb`` sees is entirely the index maps' business,
-    so dense and paged caches share this body."""
+def _verify_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+                   l_ref, *, scale, n_q, block_k, n_kb):
+    """Grid (S, H, n_kb) over a DENSE cache: a (Q, D) query block against
+    K/V blocks (block_k, D), online softmax across the kb axis with
+    per-row running max / denominator in scratch (persists along the
+    innermost grid dim)."""
     from jax.experimental import pallas as pl
-    pos_ref = refs[-8]
-    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs[-7:]
     kb = pl.program_id(2)
 
     @pl.when(kb == 0)
@@ -756,25 +758,26 @@ def _verify_kernel(*refs, scale, n_q, block_k, n_kb):
             o_ref.shape).astype(o_ref.dtype)
 
 
-def _verify_call(q, k, v, prefetch, kv_index, block_k, n_kb, scale,
-                 interpret):
-    """One ``pallas_call`` for dense and paged caches: ``prefetch`` are
-    the int32 scalar-prefetch operands (positions last), ``kv_index`` the
-    K/V index map ``(s, h, kb, *prefetch_refs) -> block index``.  The
-    query/output blocks span the array's whole last two dims (Q, D), and
-    the per-slot position is read from prefetched SMEM inside the kernel
-    — both are what the TPU lowering requires (a (1,)-blocked SMEM
-    operand or a block whose second-minor dim is 1 of H is refused)."""
+def _verify_pallas(q, k, v, positions, scale, interpret):
+    """The dense cache's ``pallas_call``.  The query/output blocks span
+    the array's whole last two dims (Q, D), and the per-slot position is
+    read from prefetched SMEM inside the kernel — both are what the TPU
+    lowering requires (a (1,)-blocked SMEM operand or a block whose
+    second-minor dim is 1 of H is refused)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     S, H, n_q, D = q.shape
+    T = k.shape[2]
+    block_k = min(_BLOCK_K, T)
+    n_kb = T // block_k
     kernel = functools.partial(_verify_kernel, scale=scale, n_q=n_q,
                                block_k=block_k, n_kb=n_kb)
     spec_q = pl.BlockSpec((1, 1, n_q, D),
-                          lambda s, h, kb, *_: (s, h, 0, 0))
-    spec_kv = pl.BlockSpec((1, 1, block_k, D), kv_index)
+                          lambda s, h, kb, pos: (s, h, 0, 0))
+    spec_kv = pl.BlockSpec((1, 1, block_k, D),
+                           lambda s, h, kb, pos: (s, h, kb, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
+        num_scalar_prefetch=1,
         grid=(S, H, n_kb),
         in_specs=[spec_q, spec_kv, spec_kv],
         out_specs=spec_q,
@@ -789,15 +792,7 @@ def _verify_call(q, k, v, prefetch, kv_index, block_k, n_kb, scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(*(x.astype(jnp.int32) for x in prefetch), q, k, v)
-
-
-def _verify_pallas(q, k, v, positions, scale, interpret):
-    T = k.shape[2]
-    block_k = min(_BLOCK_K, T)
-    return _verify_call(q, k, v, (positions,),
-                        lambda s, h, kb, pos: (s, h, kb, 0),
-                        block_k, T // block_k, scale, interpret)
+    )(positions.astype(jnp.int32), q, k, v)
 
 
 def verify_decode_attention(q, k, v, positions, scale=None):
@@ -842,17 +837,157 @@ def _xla_paged_verify_decode_attention(q, k_pages, v_pages, tables,
     return _xla_verify_decode_attention(q, k, v, positions, scale)
 
 
+def _paged_kernel(slot_ref, group_ref, page_ref, pos_ref, q_ref, *refs,
+                  scale, n_pages, n_cols):
+    """One step of :func:`_paged_verify_pallas`'s work list: every head
+    of slot ``slot_ref[i]`` against its ``group_ref[i]``-th group of
+    ``n_pages`` pool pages, online softmax across a slot's consecutive
+    steps.  ``refs``: the group's K pages, then its V pages — each a
+    whole (1, bs, H, D) pool block the index maps picked from
+    ``page_ref`` — the output block and the three scratch buffers.
+
+    A page holds (H, D) tiles, one per key: the scores' sum over D is the
+    only reduction across lanes, everything over keys runs down the
+    leading axis on whole registers, and no operand is ever rounded —
+    the float32 pool meets float32 arithmetic, with no MXU pass."""
+    from jax.experimental import pallas as pl
+    del page_ref                        # the index maps' business
+    k_refs, v_refs = refs[:n_pages], refs[n_pages:2 * n_pages]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * n_pages:]
+    i = pl.program_id(0)
+    g = group_ref[i]
+    pos = pos_ref[slot_ref[i]]
+    n_q = q_ref.shape[1]
+    bs = k_refs[0].shape[1]
+    T = n_pages * bs
+    n_keys = n_cols * bs
+
+    @pl.when(g == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    idx = g * T + jax.lax.broadcasted_iota(jnp.int32, (T, 1, 1), 0)
+    k = jnp.concatenate([r[0] for r in k_refs], axis=0).astype(jnp.float32)
+    v = jnp.concatenate([r[0] for r in v_refs], axis=0).astype(jnp.float32)
+    for j in range(n_q):            # query row j sits at position pos + j
+        q = q_ref[0, j].astype(jnp.float32)                   # (H, D)
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+        # a column past the table is no key, whatever the head says
+        s = jnp.where(idx <= jnp.minimum(pos + j, n_keys - 1), s, -1e30)
+        m_prev = m_ref[j]                                     # (H, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[None])                          # (T, H, 1)
+        l_ref[j] = l_ref[j] * alpha + jnp.sum(p, axis=0)
+        acc_ref[j] = acc_ref[j] * alpha + jnp.sum(p * v, axis=0)
+        m_ref[j] = m_new
+
+    @pl.when((g + 1) * T > jnp.minimum(pos + n_q - 1, n_keys - 1))
+    def _fin():                         # the slot's last live group
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _paged_work_list(tables, positions, n_q, bs, n_pages):
+    """The (slot, group) steps that hold a live key, slot-major, and the
+    pool page each of a step's ``n_pages`` operands reads: every slot's
+    groups up to its write head (at least its first), none past it.
+    Returns ``(n_steps, slot, group, page)``; the arrays are padded to
+    the static bound ``S * n_groups`` by repeating the last live step.
+
+    A column of a live group that lies past the write head names the
+    page the same operand read one step earlier — Pallas fetches an
+    operand only when its block index moves, so it costs nothing."""
+    S, n_cols = tables.shape
+    n_groups = -(-n_cols // n_pages)
+    last = jnp.minimum((positions + n_q - 1) // bs, n_cols - 1)    # (S,)
+    n_live = last // n_pages + 1                     # live groups a slot
+    ends = jnp.cumsum(n_live)
+    n_steps = ends[-1]
+    step = jnp.minimum(jnp.arange(S * n_groups, dtype=jnp.int32),
+                       n_steps - 1)
+    slot = jnp.sum(step[:, None] >= ends[None, :], axis=1,
+                   dtype=jnp.int32)                  # the slot of a step
+    group = step - (ends - n_live)[slot]
+    col = group[:, None] * n_pages \
+        + jnp.arange(n_pages, dtype=jnp.int32)[None, :]   # (steps, pages)
+    live = col <= last[slot][:, None]
+    page = tables[slot[:, None], jnp.minimum(col, n_cols - 1)]
+    # forward-fill the dead columns from the operand's last live step
+    # (the null block 0 before any)
+    src = jax.lax.cummax(jnp.where(live, step[:, None], -1), axis=0)
+    page = jnp.where(src >= 0, jnp.take_along_axis(
+        page, jnp.maximum(src, 0), axis=0), 0)
+    return n_steps, slot, group, page.reshape(-1)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _paged_verify_pallas(q, k_pages, v_pages, tables, positions, scale,
                          interpret):
-    """Block tables ride as a scalar-prefetch operand, so the K/V index
-    map routes grid step (s, h, kb) straight to physical block
-    ``tables[s, kb]`` — the gather never materializes a dense
-    (S, H, T, D) view."""
-    n_kb = tables.shape[1]
+    """The paged cache's ``pallas_call``: K and V are read from the pool's
+    own buffers, a page (one block, all H heads) at a time, and only up
+    to each slot's write head.  No dense (S, H, T, D) view exists.
+
+    The grid is :func:`_paged_work_list`: one step per (slot, group of
+    ``_PAGED_GROUP_KEYS`` keys) that holds a live key, its length a
+    runtime value — a free slot, table padding and the reserved tail of
+    a stream are not visited at all.  The pool is passed once per page
+    of a group, each operand with its own index map into the list.
+
+    The kernel takes the pool as ``[N, bs, H, D]``: on the TPU the
+    compiler keeps a pool that the decode programs scatter (S, H, D) rows
+    into with H and D minor-most, so the ``swapaxes`` below is a bitcast
+    there and a page is one contiguous run of (H, D) tiles
+    (``tests/test_paged_attention.py`` compiles the burst program for the
+    chip and holds it to that).
+
+    Jitted on its own so that a program's 24 layers trace and lower the
+    kernel once: unrolled per layer it added 4 s to a decode program's
+    trace, paid at every start-up whatever the compile cache holds."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    S, H, n_q, D = q.shape
+    n_cols = tables.shape[1]
     bs = k_pages.shape[2]
-    return _verify_call(q, k_pages, v_pages, (tables, positions),
-                        lambda s, h, kb, tbl, pos: (tbl[s, kb], h, 0, 0),
-                        bs, n_kb, scale, interpret)
+    n_pages = min(max(1, _PAGED_GROUP_KEYS // bs), n_cols)
+    positions = positions.astype(jnp.int32)
+    n_steps, slot, group, page = _paged_work_list(
+        tables.astype(jnp.int32), positions, n_q, bs, n_pages)
+
+    spec_q = pl.BlockSpec((1, n_q, H, D),
+                          lambda i, slot, *_: (slot[i], 0, 0, 0))
+    spec_pages = [
+        pl.BlockSpec((1, bs, H, D),
+                     lambda i, slot, group, page, pos, j=j:
+                     (page[i * n_pages + j], 0, 0, 0))
+        for j in range(n_pages)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(n_steps,),
+        in_specs=[spec_q] + spec_pages + spec_pages,
+        out_specs=spec_q,
+        scratch_shapes=[
+            pltpu.VMEM((n_q, H, D), jnp.float32),
+            pltpu.VMEM((n_q, H, 1), jnp.float32),
+            pltpu.VMEM((n_q, H, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_paged_kernel, scale=scale, n_pages=n_pages,
+                               n_cols=n_cols)
+    qt = jnp.swapaxes(q, 1, 2)                                # (S, Q, H, D)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=32 * 2 ** 20),
+        interpret=interpret,
+    )(slot, group, page, positions, qt,
+      *([jnp.swapaxes(k_pages, 1, 2)] * n_pages),
+      *([jnp.swapaxes(v_pages, 1, 2)] * n_pages))
+    return jnp.swapaxes(out, 1, 2)
 
 
 def paged_verify_decode_attention(q, k_pages, v_pages, tables, positions,
@@ -863,22 +998,13 @@ def paged_verify_decode_attention(q, k_pages, v_pages, tables, positions,
     ``positions[s] + j``; ``k_pages``/``v_pages`` (num_blocks, H,
     block_size, D); ``tables`` (S, max_blocks) int32 padded with null
     block 0; ``positions`` (S,) int32 base positions.  Returns
-    (S, H, Q, D).  Gates mirror :func:`paged_decode_attention` (lax is
-    the CPU/default path, Pallas behind ``MXNET_USE_FUSION``,
-    ``MXNET_FA_DECODE_FORCE_PALLAS=1`` interprets for parity)."""
-    from ..base import getenv_bool
-    _, H, bs, D = k_pages.shape
+    (S, H, Q, D).  :func:`paged_attention_impl` picks the Pallas kernel
+    or the lax gather at trace time."""
     if scale is None:
-        scale = 1.0 / math.sqrt(D)
-    platform = _platform_of(q)
-    force = getenv_bool("MXNET_FA_DECODE_FORCE_PALLAS")
-    aligned = bs % 8 == 0 and D % 8 == 0
-    if force and aligned:
+        scale = 1.0 / math.sqrt(k_pages.shape[-1])
+    if paged_attention_impl(q, k_pages) == "pallas":
         return _paged_verify_pallas(q, k_pages, v_pages, tables, positions,
-                                    scale, interpret=platform == "cpu")
-    if platform == "cpu" or not aligned \
-            or not getenv_bool("MXNET_USE_FUSION"):
-        return _xla_paged_verify_decode_attention(q, k_pages, v_pages,
-                                                  tables, positions, scale)
-    return _paged_verify_pallas(q, k_pages, v_pages, tables, positions,
-                                scale, interpret=False)
+                                    scale,
+                                    interpret=_platform_of(q) == "cpu")
+    return _xla_paged_verify_decode_attention(q, k_pages, v_pages, tables,
+                                              positions, scale)

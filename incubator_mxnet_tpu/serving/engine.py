@@ -505,8 +505,10 @@ class GenerationEngine:
     prefix hit prefills only the unshared suffix).  The program set stays
     closed: one miss-prefill per bucket, one suffix-prefill per bucket
     (prefix hits), and ONE paged decode.  Decode attention routes through
-    :func:`kernels.flash_attention.paged_decode_attention`, whose lax
-    gather reference keeps paged decode bit-identical to dense.
+    :func:`kernels.flash_attention.paged_decode_attention`: on a TPU the
+    Pallas kernel that reads the pool in place up to each slot's write
+    head, on the CPU the lax gather that keeps paged decode bit-identical
+    to dense (``program_inventory()["paged_attention"]`` says which).
     """
 
     def __init__(self, block, *, name: Optional[str] = None,
@@ -533,6 +535,10 @@ class GenerationEngine:
         self.block = block
         self.name = str(name or getattr(block, "name", "gpt"))
         self._ctx = ctx if ctx is not None else current_context()
+        #: what the paged attention entry points picked when a decode
+        #: program was last traced ("pallas" | "lax_gather"); None until
+        #: then, and in dense mode
+        self._paged_attention = None
         self.max_slots = int(max_slots
                              or getenv_int("MXNET_GEN_MAX_SLOTS", 8))
         if self.max_slots < 1:
@@ -1214,12 +1220,14 @@ class GenerationEngine:
         :func:`paged_decode_attention`.  ``tables`` (S, max_blocks) int32
         is an operand — join/leave never recompiles."""
         import jax.numpy as jnp
-        from ..kernels.flash_attention import paged_decode_attention
+        from ..kernels.flash_attention import (paged_attention_impl,
+                                               paged_decode_attention)
         L, H, D = self.num_layers, self.num_heads, self.head_dim
         S = last_tokens.shape[0]
         C = H * D
         bs = self.block_size
         caches = list(cache)
+        self._paged_attention = paged_attention_impl(tables, caches[0])
         rows = jnp.arange(S)
         blk = tables[rows, positions // bs]                    # (S,)
         off = positions % bs                                   # (S,)
@@ -1272,12 +1280,14 @@ class GenerationEngine:
         the burst composes with the BlockPool prefix cache unchanged."""
         import jax.numpy as jnp
         from jax import lax
-        from ..kernels.flash_attention import paged_decode_attention
+        from ..kernels.flash_attention import (paged_attention_impl,
+                                               paged_decode_attention)
         L, H, D = self.num_layers, self.num_heads, self.head_dim
         S = last_tokens.shape[0]
         C = H * D
         bs = self.block_size
         k = int(self.scan_steps)
+        self._paged_attention = paged_attention_impl(tables, cache[0])
         rows = jnp.arange(S)
 
         def run_scan():
@@ -1355,13 +1365,15 @@ class GenerationEngine:
         to the null block — overrun rows near the budget edge land in
         the sink, never in a neighbor's block."""
         import jax.numpy as jnp
-        from ..kernels.flash_attention import paged_verify_decode_attention
+        from ..kernels.flash_attention import (
+            paged_attention_impl, paged_verify_decode_attention)
         L, H, D = self.num_layers, self.num_heads, self.head_dim
         S, Q = tokens.shape
         C = H * D
         bs = self.block_size
         NB = self.max_blocks_per_slot
         caches = list(cache)
+        self._paged_attention = paged_attention_impl(tables, caches[0])
         rows = jnp.arange(S)
         pos_q = positions[:, None] \
             + jnp.arange(Q, dtype=jnp.int32)[None, :]          # (S, Q)
@@ -1969,6 +1981,7 @@ class GenerationEngine:
             "compiled_programs": self.compiled_programs(),
             "warm": self.warm,
             "paged": self.paged,
+            "paged_attention": self._paged_attention,
             "scan_steps": self.scan_steps,
             "spec_k": self.spec_k if self.draft is not None else 0,
             "programs": _telemetry.dispatch_ledger(prefix=prefix),

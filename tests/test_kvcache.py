@@ -429,7 +429,7 @@ def test_watchdog_restart_rewipes_pool():
 # ------------------------------------------- paged Pallas gather parity
 def test_paged_pallas_kernel_interpret_parity(monkeypatch):
     from incubator_mxnet_tpu.kernels.flash_attention import (
-        _paged_decode_pallas, _xla_paged_decode_attention,
+        _paged_verify_pallas, _xla_paged_decode_attention,
         paged_decode_attention)
     import jax.numpy as jnp
     rng = np.random.RandomState(0)
@@ -440,8 +440,9 @@ def test_paged_pallas_kernel_interpret_parity(monkeypatch):
     tables = jnp.asarray(rng.randint(0, NBLK, (S, NB)).astype(np.int32))
     positions = jnp.asarray(np.array([5, 30, 63], np.int32))
     ref = _xla_paged_decode_attention(q, kp, vp, tables, positions, 0.25)
-    out = _paged_decode_pallas(q, kp, vp, tables, positions, 0.25,
-                               interpret=True)
+    # single-query decode is the verify kernel at query width 1
+    out = _paged_verify_pallas(q[:, :, None, :], kp, vp, tables, positions,
+                               0.25, interpret=True)[:, :, 0, :]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
     # the dispatch honors the force knob (interpret mode on CPU)

@@ -1,0 +1,295 @@
+"""Paged decode attention: the Pallas kernel that reads the pool in place,
+live blocks only (``kernels/flash_attention.py``), against the lax gather.
+
+Interpret-mode parity over the cases a dense grid never told apart, the
+trace-time selection, the engine's inventory field, and — in the fixtures
+at the bottom — compiles for a v5e that is not attached (the chip's own
+numerics and speed are ``tests_tpu/test_kernels_tpu.py``'s).
+"""
+import importlib
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import incubator_mxnet_tpu as mx
+from incubator_mxnet_tpu.models.gpt import GPTModel
+from incubator_mxnet_tpu.serving import GenerationEngine
+
+fa = importlib.import_module("incubator_mxnet_tpu.kernels.flash_attention")
+
+H, D, BS = 2, 8, 16
+SCALE = 0.3
+
+
+def _pool(rng, n_blocks):
+    kp = rng.standard_normal((n_blocks, H, BS, D)).astype(np.float32)
+    vp = rng.standard_normal((n_blocks, H, BS, D)).astype(np.float32)
+    return kp, vp
+
+
+def _case(name, n_q):
+    """``(k_pages, v_pages, tables, positions)`` of one scenario.  Tables
+    reserve past the write head, as the engine's do (prompt + budget)."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    n_cols = 64                                   # 1,024 keys a slot
+    kp, vp = _pool(rng, 200)
+
+    def table(*rows):
+        t = np.zeros((len(rows), n_cols), np.int32)
+        for i, r in enumerate(rows):
+            t[i, :len(r)] = r
+        return t
+
+    fresh = iter(range(1, 200))
+    take = lambda n: [next(fresh) for _ in range(n)]       # noqa: E731
+    if name == "position_0":
+        tables, pos = table(take(3), take(1), take(2)), [0, 0, 0]
+    elif name == "block_edge":                    # 15 / 16 / 17
+        tables, pos = table(take(4), take(4), take(4)), [15, 16, 17]
+    elif name == "last_key":                      # 1,023 less the rows
+        tables = table(take(64), take(2), take(64))
+        pos = [1024 - n_q, 3, 1024 - n_q]
+    elif name == "null_table":                    # a free slot between two
+        tables, pos = table(take(9), [], take(20)), [130, 0, 300]
+    elif name == "shared_prefix":                 # prefix-cache hit
+        lead = take(4)
+        tables = table(lead + take(3), lead + take(5))
+        pos = [70, 100]
+    elif name == "frozen_slot":
+        # a slot frozen mid-burst wrote its replayed steps to block 0:
+        # the null block holds junk, and nobody may see it
+        kp[0], vp[0] = 1e3, -1e3
+        tables, pos = table(take(6), take(3), []), [40, 33, 0]
+    elif name == "odd_slots":                     # S = 5, ragged
+        tables = table(take(2), take(30), take(11), take(1), take(17))
+        pos = [20, 470, 128, 7, 255]
+    elif name == "short_table":                   # 12 columns: 1.5 groups
+        n_cols = 12
+        tables = table(take(12), take(5), take(12))
+        pos = [192 - n_q, 64, 127]
+    else:
+        raise KeyError(name)
+    return kp, vp, tables, np.asarray(pos, np.int32)
+
+
+CASES = ["position_0", "block_edge", "last_key", "null_table",
+         "shared_prefix", "frozen_slot", "odd_slots", "short_table"]
+
+
+@pytest.mark.parametrize("n_q", [1, 5])
+@pytest.mark.parametrize("case", CASES)
+def test_kernel_matches_lax_gather(case, n_q):
+    kp, vp, tables, pos = _case(case, n_q)
+    rng = np.random.default_rng(7)
+    q = rng.standard_normal((len(pos), H, n_q, D)).astype(np.float32)
+    got = fa._paged_verify_pallas(q, kp, vp, tables, pos, SCALE,
+                                  interpret=True)
+    ref = fa._xla_paged_verify_decode_attention(q, kp, vp, tables, pos,
+                                                SCALE)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=2e-6, rtol=2e-6)
+    if n_q == 1:
+        ref1 = fa._xla_paged_decode_attention(q[:, :, 0], kp, vp, tables,
+                                              pos, SCALE)
+        np.testing.assert_allclose(np.asarray(got)[:, :, 0],
+                                   np.asarray(ref1), atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("group_keys", [16, 64, 256])
+def test_kernel_group_size_is_free(group_keys, monkeypatch):
+    """Any group width gives the same answer (the width is a tuning
+    constant, 128 keys as timed on the chip)."""
+    monkeypatch.setattr(fa, "_PAGED_GROUP_KEYS", group_keys)
+    kp, vp, tables, pos = _case("odd_slots", 1)
+    q = np.random.default_rng(1).standard_normal(
+        (len(pos), H, 2, D)).astype(np.float32)
+    got = fa._paged_verify_pallas(q, kp, vp, tables, pos, SCALE,
+                                  interpret=True)
+    ref = fa._xla_paged_verify_decode_attention(q, kp, vp, tables, pos,
+                                                SCALE)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               atol=2e-6, rtol=2e-6)
+
+
+def test_work_list_visits_live_groups_only():
+    """One step per (slot, group) up to the write head, slot-major; a
+    dead column repeats the page its operand read a step earlier."""
+    tables = np.zeros((4, 16), np.int32)
+    tables[0, :16] = np.arange(1, 17)
+    tables[2, :5] = np.arange(20, 25)
+    tables[3, :9] = np.arange(30, 39)
+    pos = np.asarray([255, 0, 40, 130], np.int32)     # pages 16, 1, 3, 9
+    n_steps, slot, group, page = fa._paged_work_list(
+        jnp.asarray(tables), jnp.asarray(pos), 1, BS, 4)
+    n = int(n_steps)
+    assert n == 4 + 1 + 1 + 3
+    assert np.asarray(slot)[:n].tolist() == [0] * 4 + [1, 2] + [3] * 3
+    assert np.asarray(group)[:n].tolist() == [0, 1, 2, 3, 0, 0, 0, 1, 2]
+    page = np.asarray(page).reshape(-1, 4)
+    assert page[:4].reshape(-1).tolist() == list(range(1, 17))
+    # the free slot reads the null block once, then keeps what was there
+    assert page[4].tolist() == [0, 14, 15, 16]
+    assert page[5].tolist() == [20, 21, 22, 16]
+    assert page[6].tolist() == [30, 31, 32, 33]
+    assert page[8].tolist() == [38, 35, 36, 37]
+    # the padding repeats the last live step: nothing moves, nothing runs
+    assert (np.asarray(slot)[n:] == 3).all()
+    assert (page[n:] == page[n - 1]).all()
+
+
+def test_selection_is_by_platform_and_shape_never_by_flag(monkeypatch):
+    monkeypatch.delenv("MXNET_FA_DECODE_FORCE_PALLAS", raising=False)
+    q = jnp.zeros((2, H, D), jnp.float32)
+    pool = jnp.zeros((4, H, BS, D), jnp.float32)
+    monkeypatch.setenv("MXNET_USE_FUSION", "1")     # no longer read here
+    assert fa.paged_attention_impl(q, pool) == "lax_gather"
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+    monkeypatch.delenv("MXNET_USE_FUSION")
+    assert fa.paged_attention_impl(q, pool) == "pallas"
+    assert fa.paged_attention_impl(
+        q, jnp.zeros((4, H, 12, D), jnp.float32)) == "lax_gather"
+    assert fa.paged_attention_impl(
+        q, pool.astype(jnp.bfloat16)) == "lax_gather"
+    src = open(fa.__file__).read()
+    paged = src[src.index("def paged_attention_impl"):]
+    assert "MXNET_USE_FUSION" not in paged
+
+
+def _gpt():
+    mx.random.seed(3)
+    net = GPTModel(vocab_size=50, units=32, hidden_size=64, num_layers=2,
+                   num_heads=2, max_length=64, dropout=0.0)
+    net.initialize(init=mx.init.Normal(0.6))
+    net(mx.nd.array(np.zeros((1, 2), np.int32)))
+    return net
+
+
+@pytest.mark.parametrize("force,want", [(False, "lax_gather"),
+                                        (True, "pallas")])
+def test_inventory_reports_what_the_trace_picked(force, want, monkeypatch):
+    if force:
+        monkeypatch.setenv("MXNET_FA_DECODE_FORCE_PALLAS", "1")
+    else:
+        monkeypatch.delenv("MXNET_FA_DECODE_FORCE_PALLAS", raising=False)
+    eng = GenerationEngine(_gpt(), name="inv%d" % force, max_slots=2,
+                           max_len=64, scan_steps=2)
+    assert eng.program_inventory()["paged_attention"] is None
+    out = eng.generate([3, 7, 11], max_new_tokens=6)
+    assert eng.program_inventory()["paged_attention"] == want
+    dense = GenerationEngine(_gpt(), name="invd%d" % force, max_slots=2,
+                             max_len=64, paged=False)
+    assert dense.generate([3, 7, 11], max_new_tokens=6) == out
+    assert dense.program_inventory()["paged_attention"] is None
+
+
+# --- compiled for a v5e that is not attached --------------------------------
+# (on-chip-measurement guide, section 2: the topology is described inside a
+# fixture, in this one file, so only the worker that runs it loads libtpu)
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                        # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A described-device compile can be written to the persistent cache
+    but not read back: keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+
+
+@pytest.mark.parametrize("n_q", [1, 5])
+def test_kernel_compiles_for_v5e_at_the_serve_cell_shapes(
+        n_q, one_chip, no_compile_cache):
+    """S 36, H 16, bs 16, D 64, 64 table entries, 1,217 blocks, f32: with
+    the pool as the decode programs keep it (H and D minor-most) the call
+    needs no copy of it."""
+    from jax.experimental.layout import Format, Layout
+    S, Hc, Dc, bs, n_cols, N = 36, 16, 64, 16, 64, 1217
+
+    def sds(shape, dtype=jnp.float32, order=None):
+        order = order or tuple(range(len(shape)))
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=Format(
+            Layout(major_to_minor=order), one_chip))
+
+    pool = sds((N, Hc, bs, Dc), order=(0, 2, 1, 3))
+    compiled = _compile(
+        lambda q, k, v, t, p: fa._paged_verify_pallas(q, k, v, t, p,
+                                                      0.125, False),
+        sds((S, Hc, n_q, Dc)), pool, pool, sds((S, n_cols), jnp.int32),
+        sds((S,), jnp.int32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"= f32\[1217,16,16,64\]\{[^}]*\} copy\(", text)
+
+
+def test_burst_program_reads_the_pool_in_place_on_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """The decode-burst program at GPT-2-medium's widths (2 layers): the
+    kernel is in it, no dense gather is, and inside the scan the pools
+    are not copied — the carry keeps the layout the kernel reads."""
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+    from incubator_mxnet_tpu import random as mx_random
+    mx.random.seed(0)
+    net = GPTModel(vocab_size=512, units=1024, hidden_size=4096,
+                   num_layers=2, num_heads=16, max_length=1024, dropout=0.0)
+    net.initialize(mx.init.Zero())
+    with mx.autograd.pause():
+        net(mx.nd.array(np.zeros((1, 2), np.int32)))
+    S, N = 36, 1217
+    eng = GenerationEngine(net, name="aot", max_slots=S, max_len=1024,
+                           prefill_buckets=[256], paged=True, block_size=16,
+                           num_blocks=65, scan_steps=8)
+
+    def sds(x, shape=None):
+        x = jnp.asarray(x) if not hasattr(x, "dtype") else x
+        return jax.ShapeDtypeStruct(shape or x.shape, x.dtype,
+                                    sharding=one_chip)
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(                # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    Hc, bs, Dc = eng.num_heads, eng.block_size, eng.head_dim
+    cache = tuple(sds(c, (N, Hc, bs, Dc)) for c in eng._cache)
+    params, aux = eng._param_fn()
+    args = (cache, i32(S, 1), i32(S), i32(S), i32(S),
+            jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip),
+            i32(S, eng.max_blocks_per_slot),
+            tuple(sds(a) for a in eng._samp_tuple()),
+            tuple(sds(p) for p in params), tuple(sds(a) for a in aux),
+            sds(mx_random.new_key(eng._ctx)))
+    text = _compile(eng._decode_burst_paged_pure, *args).as_text()
+    assert eng.program_inventory()["paged_attention"] == "pallas"
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert f"f32[{S * 64},16,16,64]" not in text          # the dense gather
+    comps = re.split(r"\n(?=(?:ENTRY )?%?[\w.\-]+ \([^\n]*\) -> [^\n]*\{\n)",
+                     text)
+    pool_copy = re.compile(r"= f32\[1217,16,16,64\]\{[^}]*\} copy\(")
+    for comp in comps:
+        if not comp.lstrip().startswith("ENTRY"):
+            assert not pool_copy.search(comp), comp[:200]

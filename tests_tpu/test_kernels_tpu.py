@@ -2,14 +2,20 @@
 shapes the served models use, against its lax reference.
 
 gpt2_124m decode: H 12, D 64, block 16, T 1024 (8 slots; verify width
-spec_k + 1 = 5).  BERT-large attention: H 16, D 64, T 128 and 512.
+spec_k + 1 = 5).  The benchmark's serve cell (GPT-2-medium): 36 slots, H 16,
+D 64, block 16, 64 table entries, 1,217 blocks, ragged positions — with one
+timing line a call (``-s`` shows them; PERF.md section 6 keeps them).
+BERT-large attention: H 16, D 64, T 128 and 512.
 
 Precision: the kernels run as served — Mosaic's default f32 matmul, like
 XLA's on the chip, is ONE bf16 pass (operands rounded to 8 mantissa bits;
 measured on the v5e: a single-key decode returns exactly bf16(v)).  The
 lax references run at ``highest``, so the comparison is against the math
-and the tolerance is that rounding: 2e-2 on O(1) values."""
+and the tolerance is that rounding: 2e-2 on O(1) values.  The paged kernel
+has no matmul — float32 products and sums on the VPU — and is held to
+1e-5."""
 import importlib
+import time
 
 import jax
 import jax.numpy as jnp
@@ -66,10 +72,12 @@ def test_decode_paged_matches_lax():
     q = _rand(rng, (S, H, D))
     kp, vp, tables = _paged(rng)
     pos = _positions()
-    got = jax.jit(lambda *a: fa._paged_decode_pallas(*a, SCALE, False))(
-        q, kp, vp, tables, pos)
     ref = _ref(fa._xla_paged_decode_attention, q, kp, vp, tables, pos, SCALE)
-    onp.testing.assert_allclose(onp.asarray(got), ref, **TOL)
+    # on the chip the public paged entry point takes the kernel by default
+    assert fa.paged_attention_impl(q, kp) == "pallas"
+    pub = jax.jit(lambda *a: fa.paged_decode_attention(*a, scale=SCALE))(
+        q, kp, vp, tables, pos)
+    onp.testing.assert_allclose(onp.asarray(pub), ref, rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("n_q", [5])
@@ -90,11 +98,108 @@ def test_verify_paged_matches_lax(n_q):
     q = _rand(rng, (S, H, n_q, D))
     kp, vp, tables = _paged(rng)
     pos = jnp.minimum(_positions(), T - n_q)
-    got = jax.jit(lambda *a: fa._paged_verify_pallas(*a, SCALE, False))(
-        q, kp, vp, tables, pos)
+    got = jax.jit(lambda *a: fa.paged_verify_decode_attention(
+        *a, scale=SCALE))(q, kp, vp, tables, pos)
     ref = _ref(fa._xla_paged_verify_decode_attention, q, kp, vp, tables,
                pos, SCALE)
-    onp.testing.assert_allclose(onp.asarray(got), ref, **TOL)
+    onp.testing.assert_allclose(onp.asarray(got), ref, rtol=1e-5, atol=1e-5)
+
+
+# --- the benchmark's serve cell: GPT-2-medium, 36 slots, 1,217 blocks
+CELL = dict(S=36, H=16, D=64, bs=16, n_cols=64, N=1217)
+
+
+def _cell_case(fill):
+    """Tables and positions as a serve cell holds them: every stream
+    reserves past its write head; ``chat`` leaves half the slots free."""
+    c = CELL
+    r = onp.random.default_rng(11)
+    pos = r.integers(32, 640, c["S"])
+    live = onp.ones(c["S"], bool) if fill == "closed" \
+        else r.random(c["S"]) < 0.5
+    pos = onp.where(live, pos, 0)
+    perm = 1 + r.permutation(c["N"] - 1)
+    tables = onp.zeros((c["S"], c["n_cols"]), onp.int32)
+    used = 0
+    for s in range(c["S"]):
+        if live[s]:
+            n = min(c["n_cols"], (pos[s] + 96) // c["bs"] + 1)
+            tables[s, :n] = perm[used:used + n]
+            used += n
+    assert used < c["N"], used
+    return jnp.asarray(tables), jnp.asarray(pos, jnp.int32), \
+        int((pos[live] + 1).sum())
+
+
+def _ms_per_call(step, q, k, v, t, p):
+    """Device time of one call inside a program: the slope of a
+    ``fori_loop`` over the call between 10 and 60 trips (what the
+    program pays once — dispatch, the pools' relayout — drops out)."""
+    def loop(n):
+        def run(q, k, v, t, p):
+            def body(_, c):
+                qq, tt = c
+                o = step(qq, k, v, tt, p)
+                z = (o.reshape(-1)[0] * 0).astype(jnp.int32)
+                return qq + o * 0, tt + z      # each trip needs the last
+            return jax.lax.fori_loop(0, n, body, (q, t))[0]
+        return jax.jit(run)
+    took = {}
+    for n in (10, 60):
+        f = loop(n)
+        f(q, k, v, t, p).block_until_ready()
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            f(q, k, v, t, p).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
+        took[n] = best
+    return (took[60] - took[10]) / 50 * 1e3
+
+
+@pytest.mark.parametrize("n_q", [1, 5])
+@pytest.mark.parametrize("fill", ["closed", "chat"])
+def test_paged_at_the_serve_cell_shapes(fill, n_q):
+    c = CELL
+    rng = onp.random.default_rng(5)
+    q = _rand(rng, (c["S"], c["H"], n_q, D))
+    kp = _rand(rng, (c["N"], c["H"], c["bs"], c["D"]))
+    vp = _rand(rng, (c["N"], c["H"], c["bs"], c["D"]))
+    tables, pos, live_keys = _cell_case(fill)
+    kernel = lambda *a: fa._paged_verify_pallas(*a, SCALE, False)  # noqa: E731
+    gather = lambda *a: fa._xla_paged_verify_decode_attention(     # noqa: E731
+        *a, SCALE)
+    got = jax.jit(kernel)(q, kp, vp, tables, pos)
+    ref = _ref(gather, q, kp, vp, tables, pos)
+    onp.testing.assert_allclose(onp.asarray(got), ref, rtol=1e-5, atol=1e-5)
+    line = {name: round(_ms_per_call(step, q, kp, vp, tables, pos), 4)
+            for name, step in (("pallas", kernel), ("lax_gather", gather))}
+    print(f"\npaged attention, serve cell {fill}, n_q {n_q}, "
+          f"{live_keys} live keys: ms a call {line}", flush=True)
+    assert line["pallas"] < line["lax_gather"]
+
+
+def test_engine_traces_the_kernel_on_the_chip():
+    """A paged engine on the chip decodes (per step and in bursts) through
+    the kernel and says so in its inventory; the per-step and the scanned
+    program give the same greedy stream."""
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu.models.gpt import GPTModel
+    from incubator_mxnet_tpu.serving import GenerationEngine
+    mx.random.seed(3)
+    net = GPTModel(vocab_size=64, units=768, hidden_size=1024, num_layers=2,
+                   num_heads=12, max_length=256, dropout=0.0)
+    net.initialize(init=mx.init.Normal(0.05))
+    net(mx.nd.array(onp.zeros((1, 2), onp.int32)))
+    prompt = [3, 7, 11, 5, 9]
+    got = {}
+    for steps in (0, 4):
+        eng = GenerationEngine(net, name=f"chip{steps}", max_slots=4,
+                               max_len=256, prefill_buckets=[8],
+                               scan_steps=steps)
+        got[steps] = eng.generate(prompt, max_new_tokens=40)
+        assert eng.program_inventory()["paged_attention"] == "pallas"
+    assert len(got[0]) == 40 and got[0] == got[4]
 
 
 # --- flash forward + both backward kernels, BERT-large attention shapes
