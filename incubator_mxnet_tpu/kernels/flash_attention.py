@@ -42,7 +42,8 @@ import jax.numpy as jnp
 
 __all__ = ["flash_attention", "flash_attention_lse", "decode_attention",
            "paged_decode_attention", "verify_decode_attention",
-           "paged_verify_decode_attention", "paged_attention_impl"]
+           "paged_verify_decode_attention", "paged_attention_impl",
+           "prefill_attention", "paged_prefix_attention"]
 
 _BLOCK_Q = 128
 _BLOCK_K = 128
@@ -547,6 +548,50 @@ def flash_attention(q, k, v, scale=None, causal=False, mask=None):
     return _dispatch(q, k, v, scale, causal, mask, with_lse=False)
 
 
+def prefill_attention(q, k, v, window=None, scale=None):
+    """Causal attention of a whole prompt in the layout a served layer
+    holds it: ``q`` (B, T, Hq, D), ``k``/``v`` (B, T, Hkv, D) with
+    ``Hq`` a multiple of ``Hkv`` (each group of ``Hq // Hkv`` query heads
+    reads one KV head), keys ``j`` with ``i - window < j <= i`` (all
+    ``j <= i`` without a window).  Returns (B, T, Hq, D) in ``q``'s type.
+
+    The lax path: scores and softmax in float32, the products with the
+    operands' own type accumulated in float32, queries taken 512 at a
+    time so that a long prompt's (T, T) scores never exist at once.  The
+    Pallas flash kernels above take neither groups nor a window."""
+    B, T, Hq, D = q.shape
+    Hkv = k.shape[2]
+    G = Hq // Hkv
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
+    kidx = jnp.arange(T, dtype=jnp.int32)[None, :]
+
+    def rows(q_rows, i0):
+        """q_rows (B, Tq, Hq, D), whose first row is query ``i0``."""
+        Tq = q_rows.shape[1]
+        qg = q_rows.reshape(B, Tq, Hkv, G, D)
+        s = jnp.einsum("bqkgd,btkd->bkgqt", qg, k,
+                       preferred_element_type=jnp.float32) * scale
+        qidx = (i0 + jnp.arange(Tq, dtype=jnp.int32))[:, None]
+        live = kidx <= qidx
+        if window is not None:
+            live = live & (kidx > qidx - int(window))
+        s = jnp.where(live[None, None, None], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bkgqt,btkd->bqkgd", p.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32)
+        return o.reshape(B, Tq, Hq, D).astype(q.dtype)
+
+    chunk = 512
+    if T <= chunk or T % chunk:
+        return rows(q, 0)
+    n = T // chunk
+    qs = jnp.moveaxis(q.reshape(B, n, chunk, Hq, D), 1, 0)
+    out = jax.lax.map(lambda a: rows(a[0], a[1]),
+                      (qs, jnp.arange(n, dtype=jnp.int32) * chunk))
+    return jnp.moveaxis(out, 0, 1).reshape(B, T, Hq, D)
+
+
 # ---------------------------------------------------------------------------
 # decode-shaped attention: one query position per slot over a
 # preallocated KV cache (the GenerationEngine's per-step attention).
@@ -622,51 +667,104 @@ def decode_attention(q, k, v, positions, scale=None):
 # each slot reads through an int32 block table instead of a dense strip.
 # ---------------------------------------------------------------------------
 
+def _dense_view(pages, tables):
+    """Each slot's blocks gathered into a dense strip: ``pages``
+    (num_blocks, H, block_size, D) through ``tables`` (S, max_blocks) ->
+    (S, H, max_blocks * block_size, D)."""
+    S, nb = tables.shape
+    _, H, bs, D = pages.shape
+    return jnp.moveaxis(pages[tables], 2, 1).reshape(S, H, nb * bs, D)
+
+
 def _xla_paged_decode_attention(q, k_pages, v_pages, tables, positions,
-                                scale):
+                                scale, window=None):
     """Gather each slot's blocks into a dense (S, H, T, D) view and reuse
     :func:`_xla_decode_attention` verbatim.  Masked (stale / null-block)
     positions contribute exact-zero softmax weight, so the result is
-    bit-identical to dense decode over the same valid entries."""
-    S, nb = tables.shape
+    bit-identical to dense decode over the same valid entries.  Grouped
+    heads or a window take :func:`_xla_grouped_decode_attention` over the
+    same view."""
+    H = k_pages.shape[1]
+    k, v = _dense_view(k_pages, tables), _dense_view(v_pages, tables)
+    if window is None and q.shape[1] == H:
+        return _xla_decode_attention(q, k, v, positions, scale)
+    return _xla_grouped_decode_attention(
+        q[:, :, None, :], k, v, positions, scale, window)[:, :, 0, :]
+
+
+def _paged_kernel_kind(q, k_pages, q_heads, window):
+    """``"mha"`` (:func:`_paged_kernel`), ``"gqa"``
+    (:func:`_paged_gqa_kernel`) or None (the lax gather) for a paged call
+    with ``q_heads`` query heads and a ``window`` (or None) over the pool
+    ``k_pages``; ``q`` names the platform."""
+    from ..base import getenv_bool
+    if _platform_of(q) != "tpu" \
+            and not getenv_bool("MXNET_FA_DECODE_FORCE_PALLAS"):
+        return None
     _, H, bs, D = k_pages.shape
-    k = jnp.moveaxis(k_pages[tables], 2, 1).reshape(S, H, nb * bs, D)
-    v = jnp.moveaxis(v_pages[tables], 2, 1).reshape(S, H, nb * bs, D)
-    return _xla_decode_attention(q, k, v, positions, scale)
+    f32 = k_pages.dtype == jnp.float32
+    if int(q_heads) == H and window is None:
+        return "mha" if f32 and bs % 8 == 0 and D % 8 == 0 else None
+    if H == 1 and D % 128 == 0 and (
+            (f32 and bs % 8 == 0)
+            or (k_pages.dtype == jnp.bfloat16 and bs % 16 == 0)):
+        return "gqa"
+    return None
 
 
-def paged_attention_impl(q, k_pages):
+def paged_attention_impl(q, k_pages, q_heads=None, window=None):
     """Which implementation the two paged entry points trace for a call
-    with operand ``q`` (any of them: it names the platform) over the pool
-    ``k_pages`` (num_blocks, H, block_size, D):
-    ``"pallas"`` — the kernel that reads the pool in place, live blocks
-    only — on a TPU for a float32 pool (the one the engine allocates, and
-    the one timed on the chip) with ``block_size`` and ``D`` multiples of
-    8, else ``"lax_gather"``, the dense gather (the CPU path, and the
-    reference the kernel is tested against).  Decided from what is
+    with operand ``q`` (any of them: it names the platform), ``q_heads``
+    query heads (default: as many as the pool has) and a ``window`` over
+    the pool ``k_pages`` (num_blocks, H, block_size, D):
+    ``"pallas"`` — a kernel that reads the pool in place, live blocks
+    only — on a TPU, else ``"lax_gather"``, the dense gather (the CPU
+    path, and the reference the kernels are tested against).  Two kernels
+    exist: one query head a KV head, no window, over a float32 pool with
+    ``block_size`` and ``D`` multiples of 8 (:func:`_paged_kernel`, the
+    VPU); and any number of query heads on ONE KV head — a chip's share
+    of a grouped-query layer — with or without a window, over a float32
+    or bfloat16 pool whose page is whole tiles (``D`` a multiple of 128,
+    ``block_size`` of 8, of 16 for bfloat16; :func:`_paged_gqa_kernel`,
+    the MXU).  Anything else takes the gather.  Decided from what is
     visible at trace time, never from the environment;
     ``MXNET_FA_DECODE_FORCE_PALLAS=1`` is the test hook that takes the
     kernel (interpreted on a CPU) wherever the shapes allow it.
     ``GenerationEngine.program_inventory()`` reports it."""
-    from ..base import getenv_bool
-    _, _, bs, D = k_pages.shape
-    fits = k_pages.dtype == jnp.float32 and bs % 8 == 0 and D % 8 == 0
-    if fits and (_platform_of(q) == "tpu"
-                 or getenv_bool("MXNET_FA_DECODE_FORCE_PALLAS")):
-        return "pallas"
-    return "lax_gather"
+    kind = _paged_kernel_kind(
+        q, k_pages, k_pages.shape[1] if q_heads is None else q_heads, window)
+    return "pallas" if kind else "lax_gather"
+
+
+def _paged_pallas(q, k_pages, v_pages, tables, positions, scale, window):
+    """``q`` (S, Hq, Q, D) through the kernel that takes the call, or None
+    where none does."""
+    kind = _paged_kernel_kind(q, k_pages, q.shape[1], window)
+    if kind is None:
+        return None
+    interpret = _platform_of(q) == "cpu"
+    if kind == "mha":
+        return _paged_verify_pallas(q, k_pages, v_pages, tables, positions,
+                                    scale, interpret=interpret)
+    return _paged_gqa_pallas(q, k_pages, v_pages, tables, positions, scale,
+                             None if window is None else int(window),
+                             interpret)
 
 
 def paged_decode_attention(q, k_pages, v_pages, tables, positions,
-                           scale=None):
+                           scale=None, window=None):
     """Per-slot single-position attention over a PAGED KV cache.
 
-    ``q`` (S, H, D): this step's query; ``k_pages``/``v_pages``
+    ``q`` (S, Hq, D): this step's query; ``k_pages``/``v_pages``
     (num_blocks, H, block_size, D): the block pool, already holding this
-    position's K/V; ``tables`` (S, max_blocks) int32: each slot's block
-    table, padded with the null block 0; ``positions`` (S,) int32: each
-    slot's current write head in logical token coordinates.  Attends over
-    logical positions ``<= positions[s]`` and returns (S, H, D).
+    position's K/V (``Hq`` a multiple of ``H``: each group of query heads
+    reads one KV head); ``tables`` (S, max_blocks) int32: each slot's
+    block table, padded with the null block 0; ``positions`` (S,) int32:
+    each slot's current write head in logical token coordinates.  Attends
+    over logical positions ``<= positions[s]`` — with a ``window`` (a
+    static int), over ``positions[s] - window < t <= positions[s]`` only,
+    and the kernel's work list starts at the window's first block — and
+    returns (S, Hq, D).
 
     Same scanned-burst contract as :func:`decode_attention`:
     ``positions`` (and the write head it masks) may be carry-traced
@@ -675,16 +773,16 @@ def paged_decode_attention(q, k_pages, v_pages, tables, positions,
     prefix while its redirected null-block writes stay invisible.
 
     :func:`paged_attention_impl` picks the implementation at trace time:
-    the Pallas kernel (single-query decode IS verify at query width 1)
+    a Pallas kernel (single-query decode IS verify at query width 1)
     or the lax gather."""
     if scale is None:
         scale = 1.0 / math.sqrt(k_pages.shape[-1])
-    if paged_attention_impl(q, k_pages) == "pallas":
-        return _paged_verify_pallas(
-            q[:, :, None, :], k_pages, v_pages, tables, positions, scale,
-            interpret=_platform_of(q) == "cpu")[:, :, 0, :]
+    out = _paged_pallas(q[:, :, None, :], k_pages, v_pages, tables,
+                        positions, scale, window)
+    if out is not None:
+        return out[:, :, 0, :]
     return _xla_paged_decode_attention(q, k_pages, v_pages, tables,
-                                       positions, scale)
+                                       positions, scale, window)
 
 
 # ---------------------------------------------------------------------------
@@ -713,6 +811,34 @@ def _xla_verify_decode_attention(q, k, v, positions, scale):
     p = p / jnp.sum(p, axis=-1, keepdims=True)
     return jnp.einsum("shqt,shtd->shqd", p,
                       v.astype(jnp.float32)).astype(q.dtype)
+
+
+def _xla_grouped_decode_attention(q, k, v, positions, scale, window):
+    """:func:`_xla_verify_decode_attention` for grouped heads and a
+    window: ``q`` (S, Hq, Q, D) over ``k``/``v`` (S, Hkv, T, D), each
+    group of ``Hq // Hkv`` query heads on one KV head; row j attends keys
+    ``t`` with ``head - window < t <= head``, ``head = positions[s] + j``
+    (no lower bound without a window).  Scores and softmax in float32;
+    the products take the cache in its own type (a bfloat16 pool is not
+    upcast whole) and accumulate in float32."""
+    S, Hq, Q, D = q.shape
+    Hkv, T = k.shape[1], k.shape[2]
+    qg = q.reshape(S, Hkv, Hq // Hkv, Q, D).astype(k.dtype)
+    s = jnp.einsum("skgqd,sktd->skgqt", qg, k,
+                   preferred_element_type=jnp.float32) * scale
+    key_idx = jnp.arange(T, dtype=jnp.int32)
+    head = positions[:, None].astype(jnp.int32) \
+        + jnp.arange(Q, dtype=jnp.int32)[None, :]              # (S, Q)
+    live = key_idx[None, None, :] <= head[:, :, None]          # (S, Q, T)
+    if window is not None:
+        live = live & (key_idx[None, None, :] > head[:, :, None]
+                       - int(window))
+    s = jnp.where(live[:, None, None], s, -1e30)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    o = jnp.einsum("skgqt,sktd->skgqd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(S, Hq, Q, D).astype(q.dtype)
 
 
 def _verify_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
@@ -826,15 +952,16 @@ def verify_decode_attention(q, k, v, positions, scale=None):
 
 
 def _xla_paged_verify_decode_attention(q, k_pages, v_pages, tables,
-                                       positions, scale):
+                                       positions, scale, window=None):
     """Gather each slot's blocks into a dense (S, H, T, D) view and reuse
     :func:`_xla_verify_decode_attention` verbatim (same bit-identity
-    argument as the single-query paged gather)."""
-    S, nb = tables.shape
-    _, H, bs, D = k_pages.shape
-    k = jnp.moveaxis(k_pages[tables], 2, 1).reshape(S, H, nb * bs, D)
-    v = jnp.moveaxis(v_pages[tables], 2, 1).reshape(S, H, nb * bs, D)
-    return _xla_verify_decode_attention(q, k, v, positions, scale)
+    argument as the single-query paged gather); grouped heads or a window
+    take :func:`_xla_grouped_decode_attention`."""
+    H = k_pages.shape[1]
+    k, v = _dense_view(k_pages, tables), _dense_view(v_pages, tables)
+    if window is None and q.shape[1] == H:
+        return _xla_verify_decode_attention(q, k, v, positions, scale)
+    return _xla_grouped_decode_attention(q, k, v, positions, scale, window)
 
 
 def _paged_kernel(slot_ref, group_ref, page_ref, pos_ref, q_ref, *refs,
@@ -889,10 +1016,12 @@ def _paged_kernel(slot_ref, group_ref, page_ref, pos_ref, q_ref, *refs,
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def _paged_work_list(tables, positions, n_q, bs, n_pages):
+def _paged_work_list(tables, positions, n_q, bs, n_pages, window=None):
     """The (slot, group) steps that hold a live key, slot-major, and the
     pool page each of a step's ``n_pages`` operands reads: every slot's
-    groups up to its write head (at least its first), none past it.
+    groups up to its write head (at least its first), none past it —
+    and, with a ``window``, none before the group that holds the first
+    key the slot's first query row may read (``positions - window + 1``).
     Returns ``(n_steps, slot, group, page)``; the arrays are padded to
     the static bound ``S * n_groups`` by repeating the last live step.
 
@@ -903,6 +1032,10 @@ def _paged_work_list(tables, positions, n_q, bs, n_pages):
     n_groups = -(-n_cols // n_pages)
     last = jnp.minimum((positions + n_q - 1) // bs, n_cols - 1)    # (S,)
     n_live = last // n_pages + 1                     # live groups a slot
+    if window is not None:
+        first = jnp.maximum(positions - int(window) + 1, 0) \
+            // (bs * n_pages)                        # a slot's first group
+        n_live = n_live - first
     ends = jnp.cumsum(n_live)
     n_steps = ends[-1]
     step = jnp.minimum(jnp.arange(S * n_groups, dtype=jnp.int32),
@@ -910,6 +1043,8 @@ def _paged_work_list(tables, positions, n_q, bs, n_pages):
     slot = jnp.sum(step[:, None] >= ends[None, :], axis=1,
                    dtype=jnp.int32)                  # the slot of a step
     group = step - (ends - n_live)[slot]
+    if window is not None:
+        group = group + first[slot]
     col = group[:, None] * n_pages \
         + jnp.arange(n_pages, dtype=jnp.int32)[None, :]   # (steps, pages)
     live = col <= last[slot][:, None]
@@ -920,6 +1055,122 @@ def _paged_work_list(tables, positions, n_q, bs, n_pages):
     page = jnp.where(src >= 0, jnp.take_along_axis(
         page, jnp.maximum(src, 0), axis=0), 0)
     return n_steps, slot, group, page.reshape(-1)
+
+
+def _paged_gqa_kernel(slot_ref, group_ref, page_ref, pos_ref, q_ref, *refs,
+                      scale, n_pages, n_cols, n_q, q_heads, window):
+    """:func:`_paged_kernel` for a chip's share of a grouped-query layer:
+    every query head of slot ``slot_ref[i]`` against the ONE KV head's
+    ``group_ref[i]``-th group of pages.  A page is a (bs, D) tile of the
+    pool in the pool's own type; the query rows — ``n_q`` positions times
+    ``q_heads`` heads, position-major, padded to whole tiles — meet it on
+    the MXU: scores ``q k^T`` and ``p v`` with operands of the pool's
+    type, accumulated in float32; the softmax runs in float32.  With a
+    ``window`` row j reads keys ``head - window < t <= head`` only, and
+    the slot's first step is the group that holds the first of them."""
+    from jax.experimental import pallas as pl
+    del page_ref
+    k_refs, v_refs = refs[:n_pages], refs[n_pages:2 * n_pages]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * n_pages:]
+    i = pl.program_id(0)
+    g = group_ref[i]
+    pos = pos_ref[slot_ref[i]]
+    R = q_ref.shape[1]
+    bs = k_refs[0].shape[1]
+    T = n_pages * bs
+    n_keys = n_cols * bs
+    first = 0 if window is None \
+        else jnp.maximum(pos - window + 1, 0) // T
+
+    @pl.when(g == first)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    k = jnp.concatenate([r[0] for r in k_refs], axis=0)           # (T, D)
+    v = jnp.concatenate([r[0] for r in v_refs], axis=0)
+    s = jax.lax.dot_general(
+        q_ref[0].astype(k.dtype), k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale               # (R, T)
+    idx = g * T + jax.lax.broadcasted_iota(jnp.int32, (R, T), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (R, T), 0)
+    head = pos
+    for j in range(1, n_q):         # row r is query position r // q_heads
+        head = head + (row >= j * q_heads).astype(jnp.int32)
+    live = idx <= jnp.minimum(head, n_keys - 1)
+    if window is not None:
+        live = live & (idx > head - window)
+    s = jnp.where(live, s, -1e30)
+    m_prev = m_ref[...]                                           # (R, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    # a row whose window starts in a later group has no key here
+    p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                       # (R, D)
+    m_ref[...] = m_new
+
+    @pl.when((g + 1) * T > jnp.minimum(pos + n_q - 1, n_keys - 1))
+    def _fin():
+        o_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window",
+                                             "interpret"))
+def _paged_gqa_pallas(q, k_pages, v_pages, tables, positions, scale, window,
+                      interpret):
+    """:func:`_paged_verify_pallas` for ``q`` (S, Hq, n_q, D) over a pool
+    of ONE KV head ``[N, 1, bs, D]`` — taken as ``[N, bs, D]``, the same
+    bytes — with the work list bounded from below by ``window``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    S, Hq, n_q, D = q.shape
+    N, _, bs, _ = k_pages.shape
+    n_cols = tables.shape[1]
+    n_pages = min(max(1, _PAGED_GROUP_KEYS // bs), n_cols)
+    positions = positions.astype(jnp.int32)
+    n_steps, slot, group, page = _paged_work_list(
+        tables.astype(jnp.int32), positions, n_q, bs, n_pages, window)
+    R = n_q * Hq
+    Rp = -(-R // 16) * 16               # whole tiles of either type
+    rows = jnp.swapaxes(q, 1, 2).reshape(S, R, D).astype(jnp.float32)
+    rows = jnp.pad(rows, ((0, 0), (0, Rp - R), (0, 0)))
+    spec_q = pl.BlockSpec((1, Rp, D), lambda i, slot, *_: (slot[i], 0, 0))
+    spec_pages = [
+        pl.BlockSpec((1, bs, D),
+                     lambda i, slot, group, page, pos, j=j:
+                     (page[i * n_pages + j], 0, 0))
+        for j in range(n_pages)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(n_steps,),
+        in_specs=[spec_q] + spec_pages + spec_pages,
+        out_specs=spec_q,
+        scratch_shapes=[
+            pltpu.VMEM((Rp, D), jnp.float32),
+            pltpu.VMEM((Rp, 1), jnp.float32),
+            pltpu.VMEM((Rp, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _paged_gqa_kernel, scale=scale, n_pages=n_pages, n_cols=n_cols,
+        n_q=n_q, q_heads=Hq, window=window)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, Rp, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=32 * 2 ** 20),
+        interpret=interpret,
+    )(slot, group, page, positions, rows,
+      *([k_pages.reshape(N, bs, D)] * n_pages),
+      *([v_pages.reshape(N, bs, D)] * n_pages))
+    return jnp.swapaxes(out[:, :R].reshape(S, n_q, Hq, D), 1, 2).astype(
+        q.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -991,20 +1242,56 @@ def _paged_verify_pallas(q, k_pages, v_pages, tables, positions, scale,
 
 
 def paged_verify_decode_attention(q, k_pages, v_pages, tables, positions,
-                                  scale=None):
+                                  scale=None, window=None):
     """Per-slot k+1-wide attention over a PAGED KV cache.
 
-    ``q`` (S, H, Q, D): query block, row j at logical position
+    ``q`` (S, Hq, Q, D): query block, row j at logical position
     ``positions[s] + j``; ``k_pages``/``v_pages`` (num_blocks, H,
-    block_size, D); ``tables`` (S, max_blocks) int32 padded with null
-    block 0; ``positions`` (S,) int32 base positions.  Returns
-    (S, H, Q, D).  :func:`paged_attention_impl` picks the Pallas kernel
-    or the lax gather at trace time."""
+    block_size, D), ``Hq`` a multiple of ``H``; ``tables`` (S, max_blocks)
+    int32 padded with null block 0; ``positions`` (S,) int32 base
+    positions; ``window`` as in :func:`paged_decode_attention`.  Returns
+    (S, Hq, Q, D).  :func:`paged_attention_impl` picks a Pallas kernel or
+    the lax gather at trace time."""
     if scale is None:
         scale = 1.0 / math.sqrt(k_pages.shape[-1])
-    if paged_attention_impl(q, k_pages) == "pallas":
-        return _paged_verify_pallas(q, k_pages, v_pages, tables, positions,
-                                    scale,
-                                    interpret=_platform_of(q) == "cpu")
+    out = _paged_pallas(q, k_pages, v_pages, tables, positions, scale,
+                        window)
+    if out is not None:
+        return out
     return _xla_paged_verify_decode_attention(q, k_pages, v_pages, tables,
-                                              positions, scale)
+                                              positions, scale, window)
+
+
+def paged_prefix_attention(q, k_pages, v_pages, table, ctx, window=None,
+                           scale=None):
+    """A prompt's SUFFIX over ONE slot's paged strip (the prefix-hit
+    prefill): ``q`` (1, Hq, Tb, D), row j at logical position ``ctx + j``;
+    the pool already holds the suffix's own K/V; ``table`` (max_blocks,)
+    int32; ``ctx`` an int32 scalar.  Row j attends keys ``<= ctx + j``
+    (and ``> ctx + j - window``).  Returns (1, Hq, Tb, D).
+
+    Always the lax gather of the slot's whole strip: the kernels' work
+    lists are built for a few query rows a slot, not for hundreds.  One
+    query head a KV head without a window keeps the arithmetic of the
+    model's own fused attention (stable softmax, probabilities in the
+    cache's type), which is what keeps a prefix hit's tokens those of a
+    miss."""
+    H, D = k_pages.shape[1], k_pages.shape[3]
+    Hq, Tb = q.shape[1], q.shape[2]
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
+    ck = _dense_view(k_pages, table[None])                 # (1, H, T, D)
+    cv = _dense_view(v_pages, table[None])
+    T = ck.shape[2]
+    if Hq != H or window is not None:
+        return _xla_grouped_decode_attention(
+            q, ck, cv, jnp.reshape(ctx, (1,)), scale, window)
+    q_idx = jnp.arange(Tb, dtype=jnp.int32)
+    key_idx = jnp.arange(T, dtype=jnp.int32)
+    live = key_idx[None, :] <= (ctx + q_idx)[:, None]          # (Tb, T)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, ck) * scale
+    s = jnp.where(live[None, None], s, -1e30)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.exp(s - m)
+    lsum = jnp.sum(p, axis=-1, keepdims=True)
+    return jnp.einsum("bhqk,bhkd->bhqd", (p / lsum).astype(cv.dtype), cv)

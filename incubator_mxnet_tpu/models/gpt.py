@@ -87,6 +87,40 @@ class GPTCell(HybridBlock):
             return self.ffn(h)[0]
         return self.ffn(h)
 
+    # -- the serving seam (docs/serving.md "The layer interface") -------
+    def serve_prefill(self, h, positions, live=None):
+        """A whole prompt with nothing cached, over jax arrays: h
+        (B, T, C) -> ``(h', k, v)``, k and v (B, T, heads, D).  The body
+        is :meth:`prime`; learned positions were added at the embedding,
+        and padding costs nothing to mask here."""
+        del positions, live
+        out, k, v = self.prime(NDArray(h))
+        B, T, _ = h.shape
+        H = self.attention._num_heads
+        return (out._data, k._data.reshape(B, T, H, -1),
+                v._data.reshape(B, T, H, -1))
+
+    def serve_cached(self, h, positions, attend, live=None):
+        """Positions that attend through the engine's cache:
+        ``attend(q, k, v)`` (each (B, T, heads, D)) writes k and v where
+        the engine's program says and returns q's attention over what
+        the cache then holds.  Returns ``(h', counts)``; this layer
+        counts nothing."""
+        del positions, live
+        at = self.attention
+        x = NDArray(h)
+        hn = self.ln1(x)
+        q, kn, vn = at.query(hn), at.key(hn), at.value(hn)
+        B, T, C = h.shape
+        H = at._num_heads
+        attn = attend(q._data.reshape(B, T, H, -1),
+                      kn._data.reshape(B, T, H, -1),
+                      vn._data.reshape(B, T, H, -1))
+        out_nd = NDArray(attn.reshape(B, T, C).astype(h.dtype))
+        x = x + at.dropout(at.proj(out_nd))
+        x = x + self._ffn_out(self.ln2(x))
+        return x._data, {}
+
     def step(self, x, cache_k, cache_v, t):
         """One-position incremental step: x (B, 1, C) at position ``t``,
         cache_k/v (B, Tmax, C) holding positions < t.  Returns
@@ -170,6 +204,34 @@ class GPTModel(HybridBlock):
             # aux_weight * aux once, regardless of depth
             return logits, aux_total
         return logits
+
+    # -- the serving seam (docs/serving.md "The layer interface") -------
+    #: integer counters the layers return from ``serve_cached``: none
+    serve_counters = ()
+
+    def kv_layout(self):
+        """What the layers cache per position (``serving.kvcache.KVLayout``):
+        every head its own K/V, float32 pools, no window."""
+        cells = list(self.cells._children.values())
+        heads = cells[0].attention._num_heads
+        return {"num_layers": len(cells), "kv_heads": heads,
+                "head_dim": self._units // heads, "dtype": "float32",
+                "windows": (None,) * len(cells),
+                "max_length": self._max_length}
+
+    def serve_layers(self):
+        return list(self.cells._children.values())
+
+    def serve_embed(self, tokens, positions):
+        """tokens, positions (B, T) int32 jax arrays -> h (B, T, C): the
+        token embedding plus the LEARNED position's."""
+        x = self.embed(NDArray(tokens)) + self.pos_embed(NDArray(positions))
+        return self.drop(x)._data
+
+    def serve_head(self, h):
+        """h (B, T, C) -> logits (B, T, vocab): final LayerNorm, tied
+        head."""
+        return self._project(self.ln_f(NDArray(h)))._data
 
     # -- pipeline parallelism ------------------------------------------
     def pipeline_split(self):

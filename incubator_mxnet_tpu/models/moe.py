@@ -31,7 +31,116 @@ from ..gluon import nn
 from ..gluon.block import HybridBlock
 from ..ndarray.ndarray import _invoke
 
-__all__ = ["MoEFFN", "MoELoss", "ep_rules"]
+__all__ = ["MoEFFN", "MoELoss", "ep_rules", "route_token_choice",
+           "held_experts_ffn"]
+
+
+# ---------------------------------------------------------------------------
+# token-choice routing over the PUBLISHED expert count, for a layer that
+# holds a share of the experts (one chip of an expert-parallel group).
+# Nothing is dropped and nothing depends on how many tokens arrive, so
+# prefill and decode route alike.  The capacity path below stays for
+# training until ROADMAP D9 retires it.
+# ---------------------------------------------------------------------------
+
+def route_token_choice(logits, bias, k, route_norm=True, route_scale=1.0):
+    """Sigmoid token-choice routing: ``logits`` (T, E) float32 over ALL the
+    published experts, ``bias`` (E,) added for the choice only.  Returns
+    ``(idx (T, k) int32, w (T, k) float32)``: each token's ``k`` experts
+    and the weights their outputs are summed with — the chosen scores
+    themselves, normalised to sum to 1 when ``route_norm``, times
+    ``route_scale``."""
+    import jax
+    import jax.numpy as jnp
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32)[None], k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if route_norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * route_scale
+
+
+def _swiglu(x, w_gate, w_up, w_down):
+    """``W_down(silu(W_gate x) * W_up x)`` with ``(in, out)`` matrices:
+    products accumulate in float32, the activation is float32, operands
+    of the second product are of ``x``'s type.  Returns float32."""
+    import jax
+    import jax.numpy as jnp
+    g = jnp.dot(x, w_gate, preferred_element_type=jnp.float32)
+    u = jnp.dot(x, w_up, preferred_element_type=jnp.float32)
+    mid = (jax.nn.silu(g) * u).astype(x.dtype)
+    return jnp.dot(mid, w_down, preferred_element_type=jnp.float32)
+
+
+def held_experts_ffn(x, idx, w, held, w_gate, w_up, w_down, live=None,
+                     tile=None):
+    """The part of ``sum_k w_k * Expert_k(x)`` that the experts HELD here
+    give: ``held = (first, count)`` names the published experts
+    ``first .. first + count - 1``, whose SwiGLU matrices are the
+    stacked ``w_gate``/``w_up`` (count, d, f) and ``w_down`` (count, f, d).
+    ``x`` (T, d), ``idx``/``w`` (T, k) from :func:`route_token_choice`,
+    ``live`` (T,) bool marks real tokens (padding and free slots route
+    nowhere).  Returns ``(y (T, d) float32, (pairs, pairs_held,
+    experts_touched))``, the three counts int32 scalars.
+
+    One grouped product, static shapes, no token dropped: the T*k
+    (token, expert) pairs are counting-sorted by expert (pairs of experts
+    held elsewhere last), and a loop whose trip count is a runtime value
+    walks the held pairs ``tile`` rows at a time — one step per
+    (expert, tile of its rows), each reading that expert's matrices once.
+    An expert no token chose is not visited and its weights are not
+    read; work follows the pairs held, never the published count."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    T, k = idx.shape
+    d = x.shape[-1]
+    P = T * k
+    first, count = int(held[0]), int(held[1])
+    tile = min(int(tile or (128 if P >= 1024 else 32)), P)
+    local = idx.reshape(P) - first
+    is_held = (local >= 0) & (local < count)
+    n_live = jnp.asarray(T, jnp.int32)
+    if live is not None:
+        is_held = is_held & jnp.repeat(live, k)
+        n_live = jnp.sum(live, dtype=jnp.int32)
+    key = jnp.where(is_held, local, count)                       # (P,)
+    onehot = (key[:, None] == jnp.arange(count + 1, dtype=jnp.int32)[None]
+              ).astype(jnp.int32)                                # (P, c+1)
+    n = jnp.sum(onehot, axis=0)                # pairs of each expert held
+    starts = jnp.cumsum(n) - n
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
+    dest = starts[key] + rank                  # a pair's row once sorted
+    src = jnp.zeros(P, jnp.int32).at[dest].set(
+        jnp.arange(P, dtype=jnp.int32))
+    xs = x[src // k]                                             # (P, d)
+    n_e = n[:count]
+    chunks = (n_e + tile - 1) // tile
+    ends = jnp.cumsum(chunks)
+    row_ids = jnp.arange(tile, dtype=jnp.int32)
+
+    def step(i, ys):
+        e = jnp.sum(i >= ends, dtype=jnp.int32)
+        row0 = starts[e] + (i - (ends[e] - chunks[e])) * tile
+        r0 = jnp.minimum(row0, P - tile)
+        rows = lax.dynamic_slice(xs, (r0, 0), (tile, d))
+        out = _swiglu(rows,
+                      lax.dynamic_index_in_dim(w_gate, e, 0, False),
+                      lax.dynamic_index_in_dim(w_up, e, 0, False),
+                      lax.dynamic_index_in_dim(w_down, e, 0, False))
+        at = r0 + row_ids
+        mine = (at >= row0) & (at < jnp.minimum(row0 + tile,
+                                                starts[e] + n_e[e]))
+        old = lax.dynamic_slice(ys, (r0, 0), (tile, d))
+        return lax.dynamic_update_slice(
+            ys, jnp.where(mine[:, None], out, old), (r0, 0))
+
+    ys = lax.fori_loop(0, ends[-1], step, jnp.zeros((P, d), jnp.float32))
+    wk = jnp.where(is_held.reshape(T, k), w, 0.0)
+    y = jnp.sum(ys[dest].reshape(T, k, d) * wk[..., None], axis=1)
+    counts = (n_live * k, jnp.sum(n_e, dtype=jnp.int32),
+              jnp.sum(n_e > 0, dtype=jnp.int32))
+    return y, counts
 
 
 def _moe_dispatch(logits, k, capacity, valid=None):

@@ -1904,6 +1904,9 @@ class ContinuousBatcher(DynamicBatcher):
             ks = getattr(self.engine, "kv_stats", None)
             if ks is not None:
                 out.update(ks())
+            dc = getattr(self.engine, "decode_counters", None)
+            if dc is not None:
+                out.update(dc())
             if self._decode_health_last is not None:
                 out["decode_health"] = dict(self._decode_health_last)
                 out["nonfinite_generations"] = \

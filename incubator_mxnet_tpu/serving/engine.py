@@ -493,6 +493,16 @@ class GenerationEngine:
     Decoding is greedy (argmax) — the serving contract is determinism:
     cached decode must match the full re-forward token-for-token.
 
+    **The model behind it** supplies the serving layer interface
+    (``kv_layout`` / ``serve_embed`` / ``serve_layers`` / ``serve_head``,
+    and ``serve_prefill`` / ``serve_cached`` on each layer;
+    docs/serving.md "The layer interface").  The paged programs call
+    that and nothing of a layer's insides, and allocate the pools from
+    the model's :class:`~.kvcache.KVLayout` — KV heads, head size, type,
+    window per layer.  ``models.gpt.GPTModel`` and
+    ``models.afmoe.AFMoEModel`` implement it; the dense-mode bodies
+    below are GPT-only and refuse another block.
+
     **Paged mode** (default; ``MXNET_KV_PAGED=0`` falls back to the dense
     layout above): the cache becomes per-layer block pools ``[num_blocks,
     heads, block_size, head_dim]`` managed by a
@@ -525,13 +535,14 @@ class GenerationEngine:
         import jax
         from ..base import getenv_int, getenv_bool
         ensure_compile_cache()
-        for attr in ("embed", "pos_embed", "cells", "ln_f", "_units",
-                     "_max_length"):
+        for attr in ("kv_layout", "serve_embed", "serve_layers",
+                     "serve_head"):
             if not hasattr(block, attr):
                 raise MXNetError(
-                    "GenerationEngine needs a GPT-style block (embed/"
-                    f"pos_embed/cells/ln_f); {type(block).__name__} has "
-                    f"no {attr!r}")
+                    "GenerationEngine needs a block with the serving "
+                    "layer interface (kv_layout/serve_embed/serve_layers/"
+                    "serve_head, docs/serving.md); "
+                    f"{type(block).__name__} has no {attr!r}")
         self.block = block
         self.name = str(name or getattr(block, "name", "gpt"))
         self._ctx = ctx if ctx is not None else current_context()
@@ -539,21 +550,41 @@ class GenerationEngine:
         #: program was last traced ("pallas" | "lax_gather"); None until
         #: then, and in dense mode
         self._paged_attention = None
+        self._paged_impls = set()
         self.max_slots = int(max_slots
                              or getenv_int("MXNET_GEN_MAX_SLOTS", 8))
         if self.max_slots < 1:
             raise MXNetError(f"max_slots must be >= 1: {self.max_slots}")
-        blk_len = int(block._max_length)
+        #: the model's own statement of what its layers cache
+        #: (:class:`~.kvcache.KVLayout`): pools are allocated from it
+        from .kvcache import KVLayout
+        self.layout = KVLayout.of(block.kv_layout())
+        blk_len = self.layout.max_length
         self.max_len = min(int(max_len
                                or getenv_int("MXNET_GEN_MAX_LEN", blk_len)),
                            blk_len)
         if self.max_len < 2:
             raise MXNetError(f"max_len must be >= 2: {self.max_len}")
-        self._cells = list(block.cells._children.values())
-        self.num_layers = len(self._cells)
-        at = self._cells[0].attention
-        self.num_heads = int(at._num_heads)
-        self.head_dim = int(block._units) // self.num_heads
+        self._layers = list(block.serve_layers())
+        self.num_layers = self.layout.num_layers
+        if len(self._layers) != self.num_layers:
+            raise MXNetError(
+                f"{self.name}: kv_layout() states {self.num_layers} "
+                f"layers, serve_layers() gives {len(self._layers)}")
+        #: KV heads and their size — what a pool's blocks hold (a model
+        #: with grouped heads has more query heads than these)
+        self.num_heads = self.layout.kv_heads
+        self.head_dim = self.layout.head_dim
+        #: integer counters the model's layers return from the decode
+        #: programs, added on the host to ``metrics.MODEL_COUNTERS``
+        self._counters = tuple(getattr(block, "serve_counters", ()))
+        for n in self._counters:
+            if n not in _m.MODEL_COUNTERS:
+                raise MXNetError(
+                    f"{self.name}: the model counts {n!r}, which "
+                    "serving/metrics.py MODEL_COUNTERS does not register")
+        self._decode_counts = dict.fromkeys(
+            self._counters + ("decode_context_tokens",), 0)
         if prefill_buckets:
             self.prefill_buckets = tuple(sorted(
                 {int(b) for b in prefill_buckets}))
@@ -575,6 +606,17 @@ class GenerationEngine:
         self.prefix_cache_enabled = self.paged and bool(
             getenv_bool("MXNET_KV_PREFIX_CACHE", True)
             if prefix_cache is None else prefix_cache)
+        if not self.paged:
+            # the dense bodies reach into a GPT cell (ROADMAP D2 retires
+            # them); every other model is served paged
+            for attr in ("embed", "pos_embed", "cells", "ln_f"):
+                if not hasattr(block, attr):
+                    raise MXNetError(
+                        "the dense KV mode serves a GPT-style block only "
+                        "(embed/pos_embed/cells/ln_f); "
+                        f"{type(block).__name__} has no {attr!r}: serve "
+                        "it paged (paged=True, MXNET_KV_PAGED=1)")
+            self._cells = list(block.cells._children.values())
         if self.paged:
             from .kvcache import BlockPool
             self.max_blocks_per_slot = -(-self.max_len // self.block_size)
@@ -619,9 +661,7 @@ class GenerationEngine:
         # construction like the health plane: it changes every
         # program's output arity, so it must never vary per request
         # (per-request N is a host-side slice up to this cap).
-        vs = getattr(block, "_vocab_size", None)
-        self.vocab_size = int(vs if vs is not None
-                              else self.block.embed.weight.shape[0])
+        self.vocab_size = int(block._vocab_size)
         self.logprobs_topn = max(0, min(
             int(logprobs_topn if logprobs_topn is not None
                 else getenv_int("MXNET_SAMPLING_LOGPROBS_TOPN", 5)),
@@ -1088,6 +1128,10 @@ class GenerationEngine:
         return tuple(caches), nxt
 
     # -- pure programs, paged layout ------------------------------------
+    # The five bodies below know nothing of a model's insides: they call
+    # the layer interface (docs/serving.md "The layer interface") — the
+    # model embeds, its layers project and mix, and attention comes back
+    # here through ``attend``, where the cache is written and read.
     def _scatter_block(self, pool, hslice, table, idx, traced_idx):
         """Write an (H, w, D) strip into block ``table[idx]`` of a
         (num_blocks, H, block_size, D) pool.  ``idx`` may be traced
@@ -1104,33 +1148,58 @@ class GenerationEngine:
         return lax.dynamic_update_slice(
             pool, hslice[None].astype(pool.dtype), (blk, 0, 0, 0))
 
+    def _note_paged_attention(self, tables, pool, q_heads, window):
+        """Record what the paged attention entry points pick for a layer
+        of the program being traced (``program_inventory``)."""
+        from ..kernels.flash_attention import paged_attention_impl
+        self._paged_impls.add(
+            paged_attention_impl(tables, pool, q_heads, window))
+        self._paged_attention = "+".join(sorted(self._paged_impls))
+
+    def _zero_counts(self):
+        import jax.numpy as jnp
+        return {n: jnp.zeros((), jnp.int32) for n in self._counters}
+
+    def _sum_counts(self, total, counts):
+        return {n: total[n] + counts.get(n, 0) for n in self._counters}
+
+    def _cached_layers(self, tokens, pos, attend_for, live):
+        """Embed, run every layer through its ``serve_cached`` with the
+        program's ``attend_for(l)``, project: ``(logits, counts)``."""
+        h = self.block.serve_embed(tokens, pos)
+        counts = self._zero_counts()
+        for l, layer in enumerate(self._layers):
+            h, c = layer.serve_cached(h, pos, attend_for(l), live)
+            counts = self._sum_counts(counts, c)
+        return self.block.serve_head(h), counts
+
     def _prefill_paged_pure(self, cache, tokens, n_valid, table, samp,
                             param_vals, aux_vals, key):
-        """Prefix-cache MISS prefill: the exact dense prefill body (so
-        paged == dense bit-for-bit), with the slot's K/V scattered into
-        the blocks named by ``table`` (max_blocks,) int32 instead of a
-        dense row.  Positions past the table's reservation redirect to
-        the null block."""
+        """Prefix-cache MISS prefill: the model's own whole-prompt layers
+        (``serve_prefill``, which return each layer's K/V), with the
+        slot's K/V scattered into the blocks named by ``table``
+        (max_blocks,) int32.  Positions past the table's reservation
+        redirect to the null block."""
         import jax.numpy as jnp
-        L, H, D = self.num_layers, self.num_heads, self.head_dim
+        L = self.num_layers
         Tb = tokens.shape[1]
         bs = self.block_size
 
         def body():
-            x = self.block._embed_at(NDArray(tokens))
+            pos = jnp.arange(Tb, dtype=jnp.int32)[None]
+            h = self.block.serve_embed(tokens, pos)
             ks, vs = [], []
-            for cell in self._cells:
-                x, k, v = cell.prime(x)
-                ks.append(k._data)
-                vs.append(v._data)
-            logits = self.block._project(self.block.ln_f(x))
-            return logits._data, ks, vs
+            for layer in self._layers:
+                h, k, v = layer.serve_prefill(h, pos, pos < n_valid)
+                ks.append(k)
+                vs.append(v)
+            return self.block.serve_head(h), ks, vs
 
         logits, ks, vs = self._with_params(param_vals, aux_vals, key, body)
         out = list(cache)
         for l in range(L):
-            kh = ks[l].reshape(Tb, H, D).transpose(1, 0, 2)
-            vh = vs[l].reshape(Tb, H, D).transpose(1, 0, 2)
+            kh = ks[l][0].transpose(1, 0, 2)               # (H, Tb, D)
+            vh = vs[l][0].transpose(1, 0, 2)
             for j in range(-(-Tb // bs)):
                 out[l] = self._scatter_block(
                     out[l], kh[:, j * bs:(j + 1) * bs], table, j, False)
@@ -1146,38 +1215,23 @@ class GenerationEngine:
                           param_vals, aux_vals, key):
         """Prefix-cache HIT prefill: ``ctx`` leading positions (always a
         multiple of block_size) already hold valid K/V in shared blocks;
-        run the transformer over only the SUFFIX ``tokens`` (1, Tb),
-        appending K/V at positions [ctx, ctx+Tb) and attending through
-        the block table — same manual body as decode, widened to Tb query
-        rows.  ``ctx`` is an int32 operand, so one program per suffix
-        bucket serves every hit length."""
+        run the layers over only the SUFFIX ``tokens`` (1, Tb), appending
+        K/V at positions [ctx, ctx+Tb) and attending through the block
+        table (:func:`paged_prefix_attention`).  ``ctx`` is an int32
+        operand, so one program per suffix bucket serves every hit
+        length."""
         import jax.numpy as jnp
-        import math as _math
-        L, H, D = self.num_layers, self.num_heads, self.head_dim
+        from ..kernels.flash_attention import paged_prefix_attention
+        L = self.num_layers
         Tb = tokens.shape[1]
         bs = self.block_size
-        T = self.max_blocks_per_slot * bs
-        C = H * D
-        scale = 1.0 / _math.sqrt(D)
         caches = list(cache)
+        j0 = ctx // bs
 
-        def body():
-            pos = jnp.minimum(ctx + jnp.arange(Tb, dtype=jnp.int32),
-                              self.max_len - 1)[None]          # (1, Tb)
-            x = self.block.embed(NDArray(tokens)) \
-                + self.block.pos_embed(NDArray(pos))
-            h = self.block.drop(x)
-            q_idx = jnp.arange(Tb, dtype=jnp.int32)
-            key_idx = jnp.arange(T, dtype=jnp.int32)
-            live = key_idx[None, :] <= (ctx + q_idx)[:, None]  # (Tb, T)
-            for l, cell in enumerate(self._cells):
-                at = cell.attention
-                hn = cell.ln1(h)
-                q, kn, vn = at.query(hn), at.key(hn), at.value(hn)
-                qh = q._data.reshape(Tb, H, D).transpose(1, 0, 2)[None]
-                knh = kn._data.reshape(Tb, H, D).transpose(1, 0, 2)
-                vnh = vn._data.reshape(Tb, H, D).transpose(1, 0, 2)
-                j0 = ctx // bs
+        def attend_for(l):
+            def attend(q, k, v):             # (1, Tb, heads, D) each
+                knh = k[0].transpose(1, 0, 2)
+                vnh = v[0].transpose(1, 0, 2)
                 for j in range(-(-Tb // bs)):
                     caches[l] = self._scatter_block(
                         caches[l], knh[:, j * bs:(j + 1) * bs],
@@ -1185,25 +1239,17 @@ class GenerationEngine:
                     caches[L + l] = self._scatter_block(
                         caches[L + l], vnh[:, j * bs:(j + 1) * bs],
                         table, j0 + j, True)
-                # gather this slot's whole logical strip and attend
-                # (mirrors _sdpa's stable-softmax arithmetic)
-                ck = jnp.moveaxis(caches[l][table], 1, 0).reshape(
-                    1, H, T, D)
-                cv = jnp.moveaxis(caches[L + l][table], 1, 0).reshape(
-                    1, H, T, D)
-                s = jnp.einsum("bhqd,bhkd->bhqk", qh, ck) * scale
-                s = jnp.where(live[None, None], s, -1e30)
-                m = jnp.max(s, axis=-1, keepdims=True)
-                p = jnp.exp(s - m)
-                lsum = jnp.sum(p, axis=-1, keepdims=True)
-                attn = jnp.einsum("bhqk,bhkd->bhqd",
-                                  (p / lsum).astype(cv.dtype), cv)
-                out_nd = NDArray(attn.transpose(0, 2, 1, 3).reshape(
-                    1, Tb, C).astype(h._data.dtype))
-                h = h + at.dropout(at.proj(out_nd))
-                h = h + cell._ffn_out(cell.ln2(h))
-            logits = self.block._project(self.block.ln_f(h))
-            return logits._data
+                attn = paged_prefix_attention(
+                    q.transpose(0, 2, 1, 3), caches[l], caches[L + l],
+                    table, ctx, self.layout.windows[l])
+                return attn.transpose(0, 2, 1, 3)
+            return attend
+
+        def body():
+            q_idx = jnp.arange(Tb, dtype=jnp.int32)
+            pos = jnp.minimum(ctx + q_idx, self.max_len - 1)[None]  # (1, Tb)
+            return self._cached_layers(tokens, pos, attend_for,
+                                       (q_idx < n_valid)[None])[0]
 
         logits = self._with_params(param_vals, aux_vals, key, body)
         last = jnp.take(logits[0], n_valid - 1, axis=0)
@@ -1212,51 +1258,56 @@ class GenerationEngine:
             return tuple(caches), first, lp
         return tuple(caches), first
 
+    def _decode_attend_for(self, caches, blk, off, tables, positions):
+        """``attend_for(l)`` of the two decode programs: one position a
+        slot, K/V written to block ``blk`` at offset ``off``, attention
+        through :func:`paged_decode_attention` bounded by the layer's
+        window.  ``caches`` is the program's list, updated in place."""
+        from ..kernels.flash_attention import paged_decode_attention
+        L = self.num_layers
+
+        def attend_for(l):
+            window = self.layout.windows[l]
+
+            def attend(q, k, v):             # (S, 1, heads, D) each
+                ck = caches[l].at[blk, :, off].set(
+                    k[:, 0].astype(caches[l].dtype))
+                cv = caches[L + l].at[blk, :, off].set(
+                    v[:, 0].astype(caches[L + l].dtype))
+                caches[l], caches[L + l] = ck, cv
+                self._note_paged_attention(tables, ck, q.shape[2], window)
+                return paged_decode_attention(
+                    q[:, 0], ck, cv, tables, positions,
+                    window=window)[:, None]
+            return attend
+        return attend_for
+
     def _decode_paged_pure(self, cache, last_tokens, positions, tables,
                            samp, param_vals, aux_vals, key):
-        """The decode program, paged: identical to :meth:`_decode_pure`
-        except each slot's K/V write lands in block ``tables[s, pos//bs]``
-        at offset ``pos % bs`` and attention reads through
+        """The decode program, paged: one token for EVERY slot, each
+        slot's K/V write landing in block ``tables[s, pos//bs]`` at
+        offset ``pos % bs`` and attention reading through
         :func:`paged_decode_attention`.  ``tables`` (S, max_blocks) int32
-        is an operand — join/leave never recompiles."""
+        is an operand — join/leave never recompiles.  A free slot's
+        table is all null block: it rides along, and the layers are told
+        it is not live."""
         import jax.numpy as jnp
-        from ..kernels.flash_attention import (paged_attention_impl,
-                                               paged_decode_attention)
-        L, H, D = self.num_layers, self.num_heads, self.head_dim
         S = last_tokens.shape[0]
-        C = H * D
         bs = self.block_size
         caches = list(cache)
-        self._paged_attention = paged_attention_impl(tables, caches[0])
+        self._paged_impls = set()
         rows = jnp.arange(S)
         blk = tables[rows, positions // bs]                    # (S,)
         off = positions % bs                                   # (S,)
 
         def body():
-            pos_nd = NDArray(positions.reshape(S, 1))
-            x = self.block.embed(NDArray(last_tokens)) \
-                + self.block.pos_embed(pos_nd)
-            h = self.block.drop(x)
-            for l, cell in enumerate(self._cells):
-                at = cell.attention
-                hn = cell.ln1(h)
-                q, kn, vn = at.query(hn), at.key(hn), at.value(hn)
-                qh = q._data.reshape(S, H, D)
-                knh = kn._data.reshape(S, H, D)
-                vnh = vn._data.reshape(S, H, D)
-                ck = caches[l].at[blk, :, off].set(
-                    knh.astype(caches[l].dtype))
-                cv = caches[L + l].at[blk, :, off].set(
-                    vnh.astype(caches[L + l].dtype))
-                caches[l], caches[L + l] = ck, cv
-                attn = paged_decode_attention(qh, ck, cv, tables, positions)
-                out_nd = NDArray(attn.reshape(S, 1, C).astype(h._data.dtype))
-                h = h + at.dropout(at.proj(out_nd))
-                h = h + cell._ffn_out(cell.ln2(h))
-            logits = self.block._project(self.block.ln_f(h))
-            return logits._data
+            return self._cached_layers(
+                last_tokens, positions.reshape(S, 1),
+                self._decode_attend_for(caches, blk, off, tables,
+                                        positions),
+                (tables[:, 0] != 0)[:, None])
 
-        logits = self._with_params(param_vals, aux_vals, key, body)
+        logits, counts = self._with_params(param_vals, aux_vals, key, body)
         lg = logits[:, 0, :]
         nxt = self._sample_step(lg, positions + 1, samp)
         out = (tuple(caches), nxt)
@@ -1265,60 +1316,43 @@ class GenerationEngine:
         if self.logprobs_topn:
             from .sampling import topn_logprobs
             out = out + (topn_logprobs(lg, samp[3], self.logprobs_topn),)
+        if self._counters:
+            out = out + (tuple(counts[n] for n in self._counters),)
         return out
 
     def _decode_burst_paged_pure(self, cache, last_tokens, positions,
                                  budgets, eos_ids, done0, tables, samp,
                                  param_vals, aux_vals, key):
         """:meth:`_decode_burst_pure` over the paged layout: the scanned
-        step is the exact :meth:`_decode_paged_pure` cell body, and a
-        frozen (done) slot's K/V writes are redirected to the null block
-        0 — belt on top of the idempotent-rewrite argument, so a
-        finished slot's replayed steps can never touch a live block, its
-        own or (through any future sharing scheme) anyone else's.
-        Decode positions sit strictly past the shared prompt blocks, so
-        the burst composes with the BlockPool prefix cache unchanged."""
+        step is the exact :meth:`_decode_paged_pure` body, and a frozen
+        (done) slot's K/V writes are redirected to the null block 0 —
+        belt on top of the idempotent-rewrite argument, so a finished
+        slot's replayed steps can never touch a live block, its own or
+        (through any future sharing scheme) anyone else's.  Decode
+        positions sit strictly past the shared prompt blocks, so the
+        burst composes with the BlockPool prefix cache unchanged.  A
+        model's counters ride the carry and come back summed over the
+        steps."""
         import jax.numpy as jnp
         from jax import lax
-        from ..kernels.flash_attention import (paged_attention_impl,
-                                               paged_decode_attention)
-        L, H, D = self.num_layers, self.num_heads, self.head_dim
         S = last_tokens.shape[0]
-        C = H * D
         bs = self.block_size
         k = int(self.scan_steps)
-        self._paged_attention = paged_attention_impl(tables, cache[0])
+        self._paged_impls = set()
         rows = jnp.arange(S)
 
         def run_scan():
             def step(carry, _):
-                caches, lt, pos, done, emitted = carry
+                caches, lt, pos, done, emitted, counts = carry
                 caches = list(caches)
                 blk = jnp.where(done, 0, tables[rows, pos // bs])  # (S,)
                 off = pos % bs                                     # (S,)
-                pos_nd = NDArray(pos.reshape(S, 1))
-                x = self.block.embed(NDArray(lt)) \
-                    + self.block.pos_embed(pos_nd)
-                h = self.block.drop(x)
-                for l, cell in enumerate(self._cells):
-                    at = cell.attention
-                    hn = cell.ln1(h)
-                    q, kn, vn = at.query(hn), at.key(hn), at.value(hn)
-                    qh = q._data.reshape(S, H, D)
-                    knh = kn._data.reshape(S, H, D)
-                    vnh = vn._data.reshape(S, H, D)
-                    ck = caches[l].at[blk, :, off].set(
-                        knh.astype(caches[l].dtype))
-                    cv = caches[L + l].at[blk, :, off].set(
-                        vnh.astype(caches[L + l].dtype))
-                    caches[l], caches[L + l] = ck, cv
-                    attn = paged_decode_attention(qh, ck, cv, tables, pos)
-                    out_nd = NDArray(attn.reshape(S, 1, C).astype(
-                        h._data.dtype))
-                    h = h + at.dropout(at.proj(out_nd))
-                    h = h + cell._ffn_out(cell.ln2(h))
-                logits = self.block._project(self.block.ln_f(h))
-                lg = logits._data[:, 0, :]
+                logits, c = self._cached_layers(
+                    lt, pos.reshape(S, 1),
+                    self._decode_attend_for(caches, blk, off, tables, pos),
+                    (~done)[:, None])
+                counts = self._sum_counts(counts, c)
+                lg = logits[:, 0, :]
                 # keyed at pos + 1 (the position this token will
                 # occupy): the carry IS the per-step key split
                 nxt = self._sample_step(lg, pos + 1, samp)
@@ -1334,13 +1368,14 @@ class GenerationEngine:
                     from .sampling import topn_logprobs
                     ys = ys + topn_logprobs(lg, samp[3],
                                             self.logprobs_topn)
-                return (tuple(caches), lt2, pos2, done2, emitted2), ys
+                return (tuple(caches), lt2, pos2, done2, emitted2,
+                        counts), ys
 
             carry0 = (cache, last_tokens, positions, done0,
-                      jnp.zeros(S, jnp.int32))
+                      jnp.zeros(S, jnp.int32), self._zero_counts())
             return lax.scan(step, carry0, None, length=k)
 
-        (caches, _, _, _, emitted), ys = self._with_params(
+        (caches, _, _, _, emitted, counts), ys = self._with_params(
             param_vals, aux_vals, key, run_scan)
         ys = list(ys)
         if self.logprobs_topn:
@@ -1355,25 +1390,27 @@ class GenerationEngine:
             out = (caches, toks, emitted)
         if self.logprobs_topn:
             out = out + ((lpv, lpi),)
+        if self._counters:
+            out = out + (tuple(counts[n] for n in self._counters),)
         return out
 
     def _verify_paged_pure(self, cache, tokens, positions, tables, samp,
                            param_vals, aux_vals, key):
-        """The verify program, paged: :meth:`_verify_pure` with each
-        slot's Q writes routed through its block table.  Positions past a
-        slot's reservation (table padding) or past ``max_len`` redirect
-        to the null block — overrun rows near the budget edge land in
-        the sink, never in a neighbor's block."""
+        """The verify program, paged: ``tokens`` (S, Q) — column 0 each
+        slot's last accepted token, the rest the draft's proposals — at
+        positions ``positions + j``, each slot's Q writes routed through
+        its block table.  Positions past a slot's reservation (table
+        padding) or past ``max_len`` redirect to the null block — overrun
+        rows near the budget edge land in the sink, never in a neighbor's
+        block.  With Q == 1 this is exactly decode."""
         import jax.numpy as jnp
-        from ..kernels.flash_attention import (
-            paged_attention_impl, paged_verify_decode_attention)
-        L, H, D = self.num_layers, self.num_heads, self.head_dim
+        from ..kernels.flash_attention import paged_verify_decode_attention
+        L = self.num_layers
         S, Q = tokens.shape
-        C = H * D
         bs = self.block_size
         NB = self.max_blocks_per_slot
         caches = list(cache)
-        self._paged_attention = paged_attention_impl(tables, caches[0])
+        self._paged_impls = set()
         rows = jnp.arange(S)
         pos_q = positions[:, None] \
             + jnp.arange(Q, dtype=jnp.int32)[None, :]          # (S, Q)
@@ -1383,31 +1420,26 @@ class GenerationEngine:
                                    jnp.minimum(col, NB - 1)], 0)  # (S, Q)
         off = pos_q % bs                                          # (S, Q)
 
-        def body():
-            pos_nd = NDArray(jnp.minimum(pos_q, self.max_len - 1))
-            x = self.block.embed(NDArray(tokens)) \
-                + self.block.pos_embed(pos_nd)
-            h = self.block.drop(x)
-            for l, cell in enumerate(self._cells):
-                at = cell.attention
-                hn = cell.ln1(h)
-                q, kn, vn = at.query(hn), at.key(hn), at.value(hn)
-                qh = q._data.reshape(S, Q, H, D).transpose(0, 2, 1, 3)
-                knh = kn._data.reshape(S, Q, H, D)
-                vnh = vn._data.reshape(S, Q, H, D)
+        def attend_for(l):
+            window = self.layout.windows[l]
+
+            def attend(q, k, v):             # (S, Q, heads, D) each
                 ck = caches[l].at[blk, :, off].set(
-                    knh.astype(caches[l].dtype))
+                    k.astype(caches[l].dtype))
                 cv = caches[L + l].at[blk, :, off].set(
-                    vnh.astype(caches[L + l].dtype))
+                    v.astype(caches[L + l].dtype))
                 caches[l], caches[L + l] = ck, cv
-                attn = paged_verify_decode_attention(qh, ck, cv, tables,
-                                                     positions)
-                out_nd = NDArray(attn.transpose(0, 2, 1, 3).reshape(
-                    S, Q, C).astype(h._data.dtype))
-                h = h + at.dropout(at.proj(out_nd))
-                h = h + cell._ffn_out(cell.ln2(h))
-            logits = self.block._project(self.block.ln_f(h))
-            return logits._data
+                self._note_paged_attention(tables, ck, q.shape[2], window)
+                attn = paged_verify_decode_attention(
+                    q.transpose(0, 2, 1, 3), ck, cv, tables, positions,
+                    window=window)
+                return attn.transpose(0, 2, 1, 3)
+            return attend
+
+        def body():
+            return self._cached_layers(
+                tokens, jnp.minimum(pos_q, self.max_len - 1), attend_for,
+                jnp.broadcast_to((tables[:, 0] != 0)[:, None], (S, Q)))[0]
 
         logits = self._with_params(param_vals, aux_vals, key, body)
         nxt = self._sample_verify(logits, pos_q, samp)
@@ -1437,12 +1469,13 @@ class GenerationEngine:
             N, H, bs, D = (self.num_blocks, self.num_heads,
                            self.block_size, self.head_dim)
             self._cache = tuple(
-                jnp.zeros((N, H, bs, D), jnp.float32, device=dev)
+                jnp.zeros((N, H, bs, D), jnp.dtype(self.layout.dtype),
+                          device=dev)
                 for _ in range(2 * self.num_layers))
             self.pool.reset()
             # bytes behind one block across all layers — lets the pool
             # report occupancy in bytes (device-memory attribution)
-            self.pool.block_bytes = self.cache_bytes // self.num_blocks
+            self.pool.block_bytes = self.layout.block_bytes(bs)
             self._slot_blocks = [[] for _ in range(self.max_slots)]
             self._tables = _np.zeros(
                 (self.max_slots, self.max_blocks_per_slot), _np.int32)
@@ -1653,6 +1686,7 @@ class GenerationEngine:
             out = self._guarded(self._decode, lt, pos,
                                 self._samp_tuple())
         out = self._enqueued(out)
+        counts = out.pop() if self.paged and self._counters else ()
         if self.logprobs_topn:
             self._last_logprobs = tuple(_np.asarray(a)
                                         for a in out.pop())
@@ -1660,7 +1694,12 @@ class GenerationEngine:
             self._last_decode_health = out.pop()
         cache, nxt = out
         self._cache = cache
-        return _np.asarray(nxt)
+        nxt = _np.asarray(nxt)
+        if self.paged:
+            held = _np.asarray([bool(b) for b in self._slot_blocks])
+            self._count_decode(counts, _np.asarray(positions, _np.int64)
+                               .reshape(-1), held.astype(_np.int64))
+        return nxt
 
     def decode_burst(self, last_tokens, positions, budgets, eos_ids,
                      active):
@@ -1697,6 +1736,7 @@ class GenerationEngine:
             out = self._guarded(self._decode_burst, lt, pos, bud, eos,
                                 done0, self._samp_tuple())
         out = self._enqueued(out)
+        counts = out.pop() if self.paged and self._counters else ()
         if self.logprobs_topn:          # (k, S, N) per burst step
             self._last_logprobs = tuple(_np.asarray(a)
                                         for a in out.pop())
@@ -1704,7 +1744,36 @@ class GenerationEngine:
             self._last_decode_health = out.pop()
         cache, toks, emitted = out
         self._cache = cache
-        return _np.asarray(toks), _np.asarray(emitted)
+        toks, emitted = _np.asarray(toks), _np.asarray(emitted)
+        if self.paged:
+            self._count_decode(counts, _np.asarray(positions, _np.int64)
+                               .reshape(-1), emitted.astype(_np.int64))
+        return toks, emitted
+
+    def _count_decode(self, counts, positions, steps) -> None:
+        """After a decode or burst dispatch, with its results already on
+        the host: add what the model's layers counted in the program to
+        their series, and the context the steps had behind them to
+        ``mxtpu_decode_context_tokens`` — slot ``s`` was live for
+        ``steps[s]`` steps from write head ``positions[s]``, so its
+        written positions sum to ``steps * (pos + 1) + steps * (steps -
+        1) / 2`` (nothing is pulled from the device for this one).
+        Warm-up traffic is not counted."""
+        if self._warming:
+            return
+        ctx = int(_np.sum(steps * (positions + 1)
+                          + steps * (steps - 1) // 2))
+        _m.DECODE_CONTEXT_TOKENS.inc(ctx, model=self.name)
+        self._decode_counts["decode_context_tokens"] += ctx
+        for n, v in zip(self._counters, counts):
+            v = int(v)
+            _m.MODEL_COUNTERS[n].inc(v, model=self.name)
+            self._decode_counts[n] += v
+
+    def decode_counters(self) -> dict:
+        """Lifetime totals of :meth:`_count_decode`'s series, for
+        ``GET /v1/models``."""
+        return dict(self._decode_counts)
 
     def last_decode_health(self):
         """Device arrays from the most recent decode dispatch when the

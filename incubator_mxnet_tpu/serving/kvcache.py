@@ -52,14 +52,53 @@ from __future__ import annotations
 import hashlib
 import threading
 from collections import OrderedDict, deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 from ..base import MXNetError
 from . import metrics as _m
 
-__all__ = ["BlockPool", "blocks_for", "NULL_BLOCK"]
+__all__ = ["BlockPool", "KVLayout", "blocks_for", "NULL_BLOCK"]
 
 NULL_BLOCK = 0
+
+
+class KVLayout(NamedTuple):
+    """What a served model's layers keep per cached position, as the model
+    states it (``block.kv_layout()``): the pools are allocated from this
+    and the pool's bytes counted from it, never from a model's insides.
+    ``windows`` has one entry a layer: None where a layer reads every
+    earlier position, else the number of latest positions it reads (a
+    mask and a lower bound on the attention's work; no block is freed
+    behind a window yet, ROADMAP M3)."""
+    num_layers: int
+    kv_heads: int
+    head_dim: int
+    dtype: str
+    windows: Tuple[Optional[int], ...]
+    max_length: int
+
+    @classmethod
+    def of(cls, stated: dict) -> "KVLayout":
+        try:
+            lay = cls(int(stated["num_layers"]), int(stated["kv_heads"]),
+                      int(stated["head_dim"]), str(stated["dtype"]),
+                      tuple(None if w is None else int(w)
+                            for w in stated["windows"]),
+                      int(stated["max_length"]))
+        except (KeyError, TypeError) as e:
+            raise MXNetError(f"kv_layout() must give {cls._fields}: {e!r}")
+        if len(lay.windows) != lay.num_layers:
+            raise MXNetError(
+                f"kv_layout(): {len(lay.windows)} windows for "
+                f"{lay.num_layers} layers")
+        return lay
+
+    def block_bytes(self, block_size: int) -> int:
+        """Bytes behind one block of ``block_size`` positions: K and V of
+        every layer."""
+        import jax.numpy as jnp
+        return (2 * self.num_layers * self.kv_heads * int(block_size)
+                * self.head_dim * jnp.dtype(self.dtype).itemsize)
 
 
 def blocks_for(tokens: int, block_size: int) -> int:

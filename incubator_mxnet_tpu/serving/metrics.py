@@ -45,6 +45,28 @@ BURST_GATE = _telemetry.registry.counter(
     "mxtpu_serve_burst_gate",
     "decode dispatches that were not a burst, by the reason the gate "
     "said no (reason=queue|cancel|deadline|constrained|disabled)")
+MOE_PAIRS_TOTAL = _telemetry.registry.counter(
+    "mxtpu_moe_pairs_total",
+    "(token, expert) pairs the decode programs' expert layers routed, "
+    "over all published experts: live slots x experts per token, summed "
+    "over steps and expert layers")
+MOE_PAIRS_HELD = _telemetry.registry.counter(
+    "mxtpu_moe_pairs_held",
+    "of mxtpu_moe_pairs_total, the pairs whose expert this replica "
+    "holds: the rows its grouped expert product computed")
+MOE_EXPERTS_TOUCHED = _telemetry.registry.counter(
+    "mxtpu_moe_experts_touched",
+    "experts held here that got at least one token, summed over decode "
+    "steps and expert layers: the expert weights a step had to read")
+DECODE_CONTEXT_TOKENS = _telemetry.registry.counter(
+    "mxtpu_decode_context_tokens",
+    "written positions of the live slots (write head + 1), summed over "
+    "decode steps: the context the step's attention had behind it")
+#: counters a served model's layers return from the decode programs
+#: (``block.serve_counters``), by the model's name for each
+MODEL_COUNTERS = {"moe_pairs_total": MOE_PAIRS_TOTAL,
+                  "moe_pairs_held": MOE_PAIRS_HELD,
+                  "moe_experts_touched": MOE_EXPERTS_TOUCHED}
 CANCELLED = _telemetry.registry.counter(
     "mxtpu_serve_cancelled",
     "generation requests cancelled mid-decode (client disconnect); the "

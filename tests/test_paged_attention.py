@@ -141,6 +141,93 @@ def test_work_list_visits_live_groups_only():
     assert (page[n:] == page[n - 1]).all()
 
 
+# -- a chip's share of a grouped-query layer: query heads on ONE KV head,
+# -- a window's lower bound, a float32 or bfloat16 pool ----------------------
+GQ, GD = 6, 128
+
+
+def _gqa_case(dtype, n_q, seed=0):
+    """4 slots over a pool of one KV head: a free slot, a head inside its
+    first window, one far past it, one at the table's end."""
+    rng = np.random.default_rng(seed)
+    n_cols, n_blocks = 32, 100                     # 512 keys a slot
+    kp = jnp.asarray(rng.standard_normal((n_blocks, 1, BS, GD)), dtype)
+    vp = jnp.asarray(rng.standard_normal((n_blocks, 1, BS, GD)), dtype)
+    tables = np.zeros((4, n_cols), np.int32)
+    tables[1, :3] = [7, 8, 9]
+    tables[2, :] = np.arange(20, 52)
+    tables[3, :] = np.arange(60, 92)
+    pos = np.asarray([0, 37, 300, 512 - n_q], np.int32)
+    q = jnp.asarray(rng.standard_normal((4, GQ, n_q, GD)), jnp.float32)
+    return q, kp, vp, jnp.asarray(tables), jnp.asarray(pos)
+
+
+@pytest.mark.parametrize("window", [None, 40, 200])
+@pytest.mark.parametrize("n_q", [1, 3])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_gqa_kernel_matches_lax_gather(dtype, n_q, window):
+    """The MXU kernel against the lax twin: float32 pools agree to
+    float32 rounding; on a bfloat16 pool both round the softmax weights
+    to bfloat16 before the product with V (the twin by design, the same
+    way), so they agree to bfloat16's 2**-8 on O(1) values."""
+    q, kp, vp, tables, pos = _gqa_case(jnp.dtype(dtype), n_q)
+    got = fa._paged_gqa_pallas(q, kp, vp, tables, pos, SCALE, window,
+                               interpret=True)
+    ref = fa._xla_paged_verify_decode_attention(q, kp, vp, tables, pos,
+                                                SCALE, window)
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got)[1:], np.asarray(ref)[1:],
+                               atol=tol, rtol=tol)
+    # and the twin against plain numpy over the keys the window leaves
+    k = np.asarray(kp, np.float32)[np.asarray(tables)].reshape(4, -1, GD)
+    v = np.asarray(vp, np.float32)[np.asarray(tables)].reshape(4, -1, GD)
+    for s in (1, 2, 3):
+        for j in range(n_q):
+            head = int(pos[s]) + j
+            lo = 0 if window is None else max(0, head - window + 1)
+            sc = np.asarray(q)[s, :, j] @ k[s, lo:head + 1].T * SCALE
+            w = np.exp(sc - sc.max(-1, keepdims=True))
+            want = (w / w.sum(-1, keepdims=True)) @ v[s, lo:head + 1]
+            np.testing.assert_allclose(np.asarray(ref)[s, :, j], want,
+                                       atol=3e-2 if dtype == "bfloat16"
+                                       else 1e-4)
+
+
+def test_work_list_starts_at_the_window(monkeypatch):
+    """With a window a slot's first step is the group that holds the
+    first key its first row reads; groups before it are not visited."""
+    tables = np.zeros((3, 16), np.int32)
+    tables[0, :16] = np.arange(1, 17)
+    tables[2, :16] = np.arange(20, 36)
+    pos = np.asarray([255, 0, 130], np.int32)
+    n_steps, slot, group, _ = fa._paged_work_list(
+        jnp.asarray(tables), jnp.asarray(pos), 1, BS, 4, window=100)
+    n = int(n_steps)
+    # slot 0: keys 156..255 -> groups 2, 3; slot 2: keys 31..130 -> 0..2
+    assert np.asarray(slot)[:n].tolist() == [0, 0, 1, 2, 2, 2]
+    assert np.asarray(group)[:n].tolist() == [2, 3, 0, 0, 1, 2]
+    full = fa._paged_work_list(jnp.asarray(tables), jnp.asarray(pos), 1,
+                               BS, 4)
+    assert int(full[0]) == 4 + 1 + 3
+
+
+def test_gqa_selection(monkeypatch):
+    """Grouped heads take a kernel only as one KV head with whole-tile
+    pages; every other grouping takes the gather."""
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+    q = jnp.zeros((2, 6, 128))
+    pool = lambda h, bs, d, dt: jnp.zeros((4, h, bs, d), dt)   # noqa: E731
+    impl = fa.paged_attention_impl
+    assert impl(q, pool(1, 16, 128, jnp.bfloat16), 6, 4096) == "pallas"
+    assert impl(q, pool(1, 16, 128, jnp.bfloat16), 6) == "pallas"
+    assert impl(q, pool(1, 8, 128, jnp.float32), 6) == "pallas"
+    assert impl(q, pool(1, 8, 128, jnp.bfloat16), 6) == "lax_gather"
+    assert impl(q, pool(2, 16, 128, jnp.bfloat16), 6) == "lax_gather"
+    assert impl(q, pool(1, 16, 64, jnp.float32), 6) == "lax_gather"
+    assert impl(q, pool(16, 16, 64, jnp.float32)) == "pallas"
+    assert impl(q, pool(16, 16, 64, jnp.float32), 16, 128) == "lax_gather"
+
+
 def test_selection_is_by_platform_and_shape_never_by_flag(monkeypatch):
     monkeypatch.delenv("MXNET_FA_DECODE_FORCE_PALLAS", raising=False)
     q = jnp.zeros((2, H, D), jnp.float32)
@@ -247,6 +334,28 @@ def test_kernel_compiles_for_v5e_at_the_serve_cell_shapes(
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert not re.search(r"= f32\[1217,16,16,64\]\{[^}]*\} copy\(", text)
+
+
+@pytest.mark.parametrize("window", [None, 4096])
+def test_gqa_kernel_compiles_for_v5e_at_the_agent_cell_shapes(
+        window, one_chip, no_compile_cache):
+    """S 64, 6 query heads on 1 KV head of 128, bs 16, 512 table entries,
+    a bfloat16 pool of 32,769 blocks: the kernel takes the pool as it
+    rests, without a copy."""
+    S, n_cols, N = 64, 512, 32769
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((N, 1, 16, 128), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, k, v, t, p: fa._paged_gqa_pallas(
+            q, k, v, t, p, 0.088, window, False),
+        sds((S, 6, 1, 128), jnp.bfloat16), pool, pool,
+        sds((S, n_cols), jnp.int32), sds((S,), jnp.int32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"= bf16\[32769,[^\]]*\]\{[^}]*\} copy\(", text)
 
 
 def test_burst_program_reads_the_pool_in_place_on_v5e(

@@ -15,6 +15,7 @@ and the tolerance is that rounding: 2e-2 on O(1) values.  The paged kernel
 has no matmul — float32 products and sums on the VPU — and is held to
 1e-5."""
 import importlib
+import math
 import time
 
 import jax
@@ -177,6 +178,48 @@ def test_paged_at_the_serve_cell_shapes(fill, n_q):
     print(f"\npaged attention, serve cell {fill}, n_q {n_q}, "
           f"{live_keys} live keys: ms a call {line}", flush=True)
     assert line["pallas"] < line["lax_gather"]
+
+
+@pytest.mark.parametrize("window", [None, 4096])
+def test_gqa_paged_at_the_agent_cell_shapes(window):
+    """The grouped-query kernel compiled for the chip at the
+    ``trinity-ep8-agent-closed`` cell's shapes — 64 slots, 6 query heads
+    on 1 KV head of 128, block 16, 512 table entries, a bfloat16 pool,
+    contexts 4,300-5,300 — against the lax gather at ``highest``, with
+    and without the window's lower bound.  Both round K, V and the
+    softmax weights to bfloat16, so they agree to 2e-2.  The timing line
+    is a record, not a claim: at these shapes (59% of every table live)
+    the gather, whose cost does not depend on what is live, was the
+    faster on the v5e — 1.44 / 1.69 ms a call against the kernel's 2.74 /
+    2.47 (my chip run 1, PR 26): the kernel pays ~0.07 us a 4 KB page
+    (PERF.md, PR 26)."""
+    rng = onp.random.default_rng(11)
+    S, Hq, Dh, bs, n_cols = 64, 6, 128, 16, 512
+    N = 1 + S * 336
+    kp = _rand(rng, (N, 1, bs, Dh), jnp.bfloat16)
+    vp = _rand(rng, (N, 1, bs, Dh), jnp.bfloat16)
+    tables = onp.zeros((S, n_cols), onp.int32)
+    tables[:, :336] = 1 + rng.permutation(S * 336).reshape(S, 336)
+    tables = jnp.asarray(tables)
+    pos = jnp.asarray(rng.integers(4300, 5300, S), jnp.int32)
+    q = _rand(rng, (S, Hq, Dh), jnp.bfloat16)
+    scale = 1.0 / math.sqrt(Dh)
+
+    def kernel(q, k, v, t, p):
+        return fa._paged_gqa_pallas(q[:, :, None, :], k, v, t, p, scale,
+                                    window, False)[:, :, 0, :]
+
+    def gather(q, k, v, t, p):
+        return fa._xla_paged_decode_attention(q, k, v, t, p, scale, window)
+
+    got = onp.asarray(jax.jit(kernel)(q, kp, vp, tables, pos), onp.float32)
+    ref = _ref(gather, q, kp, vp, tables, pos)
+    onp.testing.assert_allclose(got, ref, **TOL)
+    line = {name: round(_ms_per_call(step, q, kp, vp, tables, pos), 4)
+            for name, step in (("pallas", kernel), ("lax_gather", gather))}
+    keys = int(onp.sum(onp.minimum(onp.asarray(pos) + 1, window or 10**9)))
+    print(f"\ngqa paged attention, agent cell, window {window}, {keys} "
+          f"keys read: ms a call {line}", flush=True)
 
 
 def test_engine_traces_the_kernel_on_the_chip():
