@@ -667,32 +667,47 @@ def decode_attention(q, k, v, positions, scale=None):
 # each slot reads through an int32 block table instead of a dense strip.
 # ---------------------------------------------------------------------------
 
-def _dense_view(pages, tables):
+def _pool_dims(pages, position_major):
+    """``(num_blocks, H, block_size, stored features)`` of a pool stored
+    as stated, ``[N, H, bs, D]``, or position-major, ``[N, bs, H, Dp]``
+    (``serving.kvcache.KVLayout.pool_shape``)."""
+    n, a, b, d = pages.shape
+    return (n, b, a, d) if position_major else (n, a, b, d)
+
+
+def _dense_view(pages, tables, position_major=False, head_dim=None):
     """Each slot's blocks gathered into a dense strip: ``pages``
     (num_blocks, H, block_size, D) through ``tables`` (S, max_blocks) ->
-    (S, H, max_blocks * block_size, D)."""
+    (S, H, max_blocks * block_size, D); of a position-major pool
+    (num_blocks, block_size, H, Dp), its first ``head_dim`` features."""
     S, nb = tables.shape
+    if position_major:
+        _, bs, H, Dp = pages.shape
+        return jax.lax.slice_in_dim(
+            jnp.moveaxis(pages[tables].reshape(S, nb * bs, H, Dp), 2, 1),
+            0, head_dim, axis=3)
     _, H, bs, D = pages.shape
     return jnp.moveaxis(pages[tables], 2, 1).reshape(S, H, nb * bs, D)
 
 
 def _xla_paged_decode_attention(q, k_pages, v_pages, tables, positions,
-                                scale, window=None):
+                                scale, window=None, position_major=False):
     """Gather each slot's blocks into a dense (S, H, T, D) view and reuse
     :func:`_xla_decode_attention` verbatim.  Masked (stale / null-block)
     positions contribute exact-zero softmax weight, so the result is
     bit-identical to dense decode over the same valid entries.  Grouped
     heads or a window take :func:`_xla_grouped_decode_attention` over the
     same view."""
-    H = k_pages.shape[1]
-    k, v = _dense_view(k_pages, tables), _dense_view(v_pages, tables)
+    H = _pool_dims(k_pages, position_major)[1]
+    k = _dense_view(k_pages, tables, position_major, q.shape[-1])
+    v = _dense_view(v_pages, tables, position_major, q.shape[-1])
     if window is None and q.shape[1] == H:
         return _xla_decode_attention(q, k, v, positions, scale)
     return _xla_grouped_decode_attention(
         q[:, :, None, :], k, v, positions, scale, window)[:, :, 0, :]
 
 
-def _paged_kernel_kind(q, k_pages, q_heads, window):
+def _paged_kernel_kind(q, k_pages, q_heads, window, position_major=False):
     """``"mha"`` (:func:`_paged_kernel`), ``"gqa"``
     (:func:`_paged_gqa_kernel`) or None (the lax gather) for a paged call
     with ``q_heads`` query heads and a ``window`` (or None) over the pool
@@ -701,10 +716,12 @@ def _paged_kernel_kind(q, k_pages, q_heads, window):
     if _platform_of(q) != "tpu" \
             and not getenv_bool("MXNET_FA_DECODE_FORCE_PALLAS"):
         return None
-    _, H, bs, D = k_pages.shape
+    _, H, bs, D = _pool_dims(k_pages, position_major)
     f32 = k_pages.dtype == jnp.float32
     if int(q_heads) == H and window is None:
         return "mha" if f32 and bs % 8 == 0 and D % 8 == 0 else None
+    if position_major:          # the grouped kernel takes the stated shape
+        return None
     if H == 1 and D % 128 == 0 and (
             (f32 and bs % 8 == 0)
             or (k_pages.dtype == jnp.bfloat16 and bs % 16 == 0)):
@@ -712,7 +729,8 @@ def _paged_kernel_kind(q, k_pages, q_heads, window):
     return None
 
 
-def paged_attention_impl(q, k_pages, q_heads=None, window=None):
+def paged_attention_impl(q, k_pages, q_heads=None, window=None,
+                         position_major=False):
     """Which implementation the two paged entry points trace for a call
     with operand ``q`` (any of them: it names the platform), ``q_heads``
     query heads (default: as many as the pool has) and a ``window`` over
@@ -730,29 +748,36 @@ def paged_attention_impl(q, k_pages, q_heads=None, window=None):
     visible at trace time, never from the environment;
     ``MXNET_FA_DECODE_FORCE_PALLAS=1`` is the test hook that takes the
     kernel (interpreted on a CPU) wherever the shapes allow it.
-    ``GenerationEngine.program_inventory()`` reports it."""
+    ``GenerationEngine.program_inventory()`` reports it.  A pool stored
+    ``position_major`` (num_blocks, block_size, H, Dp: features padded to
+    whole lanes, ``KVLayout.pool_shape``) is read by the same kernel, its
+    pages as they lie, or by the same gather."""
     kind = _paged_kernel_kind(
-        q, k_pages, k_pages.shape[1] if q_heads is None else q_heads, window)
+        q, k_pages, _pool_dims(k_pages, position_major)[1]
+        if q_heads is None else q_heads, window, position_major)
     return "pallas" if kind else "lax_gather"
 
 
-def _paged_pallas(q, k_pages, v_pages, tables, positions, scale, window):
+def _paged_pallas(q, k_pages, v_pages, tables, positions, scale, window,
+                  position_major=False):
     """``q`` (S, Hq, Q, D) through the kernel that takes the call, or None
     where none does."""
-    kind = _paged_kernel_kind(q, k_pages, q.shape[1], window)
+    kind = _paged_kernel_kind(q, k_pages, q.shape[1], window,
+                              position_major)
     if kind is None:
         return None
     interpret = _platform_of(q) == "cpu"
     if kind == "mha":
         return _paged_verify_pallas(q, k_pages, v_pages, tables, positions,
-                                    scale, interpret=interpret)
+                                    scale, interpret=interpret,
+                                    position_major=position_major)
     return _paged_gqa_pallas(q, k_pages, v_pages, tables, positions, scale,
                              None if window is None else int(window),
                              interpret)
 
 
 def paged_decode_attention(q, k_pages, v_pages, tables, positions,
-                           scale=None, window=None):
+                           scale=None, window=None, position_major=False):
     """Per-slot single-position attention over a PAGED KV cache.
 
     ``q`` (S, Hq, D): this step's query; ``k_pages``/``v_pages``
@@ -774,15 +799,18 @@ def paged_decode_attention(q, k_pages, v_pages, tables, positions,
 
     :func:`paged_attention_impl` picks the implementation at trace time:
     a Pallas kernel (single-query decode IS verify at query width 1)
-    or the lax gather."""
+    or the lax gather.  ``position_major``: the pools are stored
+    (num_blocks, block_size, H, Dp), as ``KVLayout.pool_shape`` has them
+    where that is how they rest row-major."""
     if scale is None:
-        scale = 1.0 / math.sqrt(k_pages.shape[-1])
+        scale = 1.0 / math.sqrt(q.shape[-1])
     out = _paged_pallas(q[:, :, None, :], k_pages, v_pages, tables,
-                        positions, scale, window)
+                        positions, scale, window, position_major)
     if out is not None:
         return out[:, :, 0, :]
     return _xla_paged_decode_attention(q, k_pages, v_pages, tables,
-                                       positions, scale, window)
+                                       positions, scale, window,
+                                       position_major)
 
 
 # ---------------------------------------------------------------------------
@@ -952,13 +980,15 @@ def verify_decode_attention(q, k, v, positions, scale=None):
 
 
 def _xla_paged_verify_decode_attention(q, k_pages, v_pages, tables,
-                                       positions, scale, window=None):
+                                       positions, scale, window=None,
+                                       position_major=False):
     """Gather each slot's blocks into a dense (S, H, T, D) view and reuse
     :func:`_xla_verify_decode_attention` verbatim (same bit-identity
     argument as the single-query paged gather); grouped heads or a window
     take :func:`_xla_grouped_decode_attention`."""
-    H = k_pages.shape[1]
-    k, v = _dense_view(k_pages, tables), _dense_view(v_pages, tables)
+    H = _pool_dims(k_pages, position_major)[1]
+    k = _dense_view(k_pages, tables, position_major, q.shape[-1])
+    v = _dense_view(v_pages, tables, position_major, q.shape[-1])
     if window is None and q.shape[1] == H:
         return _xla_verify_decode_attention(q, k, v, positions, scale)
     return _xla_grouped_decode_attention(q, k, v, positions, scale, window)
@@ -1173,9 +1203,10 @@ def _paged_gqa_pallas(q, k_pages, v_pages, tables, positions, scale, window,
         q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret",
+                                             "position_major"))
 def _paged_verify_pallas(q, k_pages, v_pages, tables, positions, scale,
-                         interpret):
+                         interpret, position_major=False):
     """The paged cache's ``pallas_call``: K and V are read from the pool's
     own buffers, a page (one block, all H heads) at a time, and only up
     to each slot's write head.  No dense (S, H, T, D) view exists.
@@ -1191,16 +1222,24 @@ def _paged_verify_pallas(q, k_pages, v_pages, tables, positions, scale,
     into with H and D minor-most, so the ``swapaxes`` below is a bitcast
     there and a page is one contiguous run of (H, D) tiles
     (``tests/test_paged_attention.py`` compiles the burst program for the
-    chip and holds it to that).
+    chip and holds it to that).  A ``position_major`` pool IS
+    ``[N, bs, H, Dp]``: its pages are taken as they lie, the query padded
+    with zeros to ``Dp`` features (a zero feature adds nothing to a score,
+    and the pool's lanes past D hold zeros) and the result cut back to D.
 
     Jitted on its own so that a program's 24 layers trace and lower the
     kernel once: unrolled per layer it added 4 s to a decode program's
     trace, paid at every start-up whatever the compile cache holds."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    S, H, n_q, D = q.shape
+    S, H, n_q, head_dim = q.shape
     n_cols = tables.shape[1]
-    bs = k_pages.shape[2]
+    _, _, bs, D = _pool_dims(k_pages, position_major)
+    if position_major:
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, D - head_dim),))
+    else:
+        k_pages, v_pages = (jnp.swapaxes(k_pages, 1, 2),
+                            jnp.swapaxes(v_pages, 1, 2))
     n_pages = min(max(1, _PAGED_GROUP_KEYS // bs), n_cols)
     positions = positions.astype(jnp.int32)
     n_steps, slot, group, page = _paged_work_list(
@@ -1236,13 +1275,14 @@ def _paged_verify_pallas(q, k_pages, v_pages, tables, positions, scale,
             vmem_limit_bytes=32 * 2 ** 20),
         interpret=interpret,
     )(slot, group, page, positions, qt,
-      *([jnp.swapaxes(k_pages, 1, 2)] * n_pages),
-      *([jnp.swapaxes(v_pages, 1, 2)] * n_pages))
-    return jnp.swapaxes(out, 1, 2)
+      *([k_pages] * n_pages), *([v_pages] * n_pages))
+    out = jnp.swapaxes(out, 1, 2)
+    return out[..., :head_dim] if position_major else out
 
 
 def paged_verify_decode_attention(q, k_pages, v_pages, tables, positions,
-                                  scale=None, window=None):
+                                  scale=None, window=None,
+                                  position_major=False):
     """Per-slot k+1-wide attention over a PAGED KV cache.
 
     ``q`` (S, Hq, Q, D): query block, row j at logical position
@@ -1251,19 +1291,20 @@ def paged_verify_decode_attention(q, k_pages, v_pages, tables, positions,
     int32 padded with null block 0; ``positions`` (S,) int32 base
     positions; ``window`` as in :func:`paged_decode_attention`.  Returns
     (S, Hq, Q, D).  :func:`paged_attention_impl` picks a Pallas kernel or
-    the lax gather at trace time."""
+    the lax gather at trace time; ``position_major`` as there."""
     if scale is None:
-        scale = 1.0 / math.sqrt(k_pages.shape[-1])
+        scale = 1.0 / math.sqrt(q.shape[-1])
     out = _paged_pallas(q, k_pages, v_pages, tables, positions, scale,
-                        window)
+                        window, position_major)
     if out is not None:
         return out
-    return _xla_paged_verify_decode_attention(q, k_pages, v_pages, tables,
-                                              positions, scale, window)
+    return _xla_paged_verify_decode_attention(
+        q, k_pages, v_pages, tables, positions, scale, window,
+        position_major)
 
 
 def paged_prefix_attention(q, k_pages, v_pages, table, ctx, window=None,
-                           scale=None):
+                           scale=None, position_major=False):
     """A prompt's SUFFIX over ONE slot's paged strip (the prefix-hit
     prefill): ``q`` (1, Hq, Tb, D), row j at logical position ``ctx + j``;
     the pool already holds the suffix's own K/V; ``table`` (max_blocks,)
@@ -1276,12 +1317,12 @@ def paged_prefix_attention(q, k_pages, v_pages, table, ctx, window=None,
     model's own fused attention (stable softmax, probabilities in the
     cache's type), which is what keeps a prefix hit's tokens those of a
     miss."""
-    H, D = k_pages.shape[1], k_pages.shape[3]
+    H, D = _pool_dims(k_pages, position_major)[1], q.shape[3]
     Hq, Tb = q.shape[1], q.shape[2]
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    ck = _dense_view(k_pages, table[None])                 # (1, H, T, D)
-    cv = _dense_view(v_pages, table[None])
+    ck = _dense_view(k_pages, table[None], position_major, D)  # (1, H, T, D)
+    cv = _dense_view(v_pages, table[None], position_major, D)
     T = ck.shape[2]
     if Hq != H or window is not None:
         return _xla_grouped_decode_attention(

@@ -634,6 +634,13 @@ class GenerationEngine:
             self.max_blocks_per_slot = 0
             self.num_blocks = 0
             self.pool = None
+        #: the shape each pool is stored in on this device, and whether
+        #: that is position-major (:meth:`~.kvcache.KVLayout.pool_shape`:
+        #: where the stated [N, H, bs, D] would rest in a layout no
+        #: program keeps)
+        self._pool_shape, self._position_major = self.layout.pool_shape(
+            self.num_blocks, self.block_size, self._ctx.jax_device()) \
+            if self.paged else (None, False)
         self._warming = False
         # multi-token decode bursts (docs/serving.md): lax.scan
         # ``scan_steps`` decode steps into ONE dispatch with in-program
@@ -717,6 +724,7 @@ class GenerationEngine:
         self.draft: Optional["GenerationEngine"] = None
         self.spec_k = 0
         self._warmup_done = False
+        self._cache = ()
         self.reset()
         _register_device_observers(self)
 
@@ -1132,11 +1140,27 @@ class GenerationEngine:
     # the layer interface (docs/serving.md "The layer interface") — the
     # model embeds, its layers project and mix, and attention comes back
     # here through ``attend``, where the cache is written and read.
-    def _scatter_block(self, pool, hslice, table, idx, traced_idx):
-        """Write an (H, w, D) strip into block ``table[idx]`` of a
-        (num_blocks, H, block_size, D) pool.  ``idx`` may be traced
-        (``traced_idx``) — out-of-range indices redirect to the null
-        block 0, where padded-garbage writes are harmless."""
+    def _block_rows(self, kv, pool):
+        """A prompt's K or V ``kv`` (Tb, H, D) laid as ``pool`` holds a
+        block's positions, once a layer: (H, Tb, D) for a pool stored as
+        stated, (Tb, H, Dp) for a position-major one."""
+        if self._position_major:
+            return self._to_lanes(kv, pool)
+        return kv.transpose(1, 0, 2)
+
+    def _strip(self, rows, j):
+        """Block ``j``'s positions of :meth:`_block_rows`' ``rows``."""
+        bs = self.block_size
+        if self._position_major:
+            return rows[j * bs:(j + 1) * bs]
+        return rows[:, j * bs:(j + 1) * bs]
+
+    def _scatter_block(self, pool, strip, table, idx, traced_idx):
+        """Write a :meth:`_strip` — (H, w, D), or (w, H, Dp) of a
+        position-major pool — into block ``table[idx]`` of ``pool``.
+        ``idx`` may be traced (``traced_idx``) — out-of-range indices
+        redirect to the null block 0, where padded-garbage writes are
+        harmless."""
         import jax.numpy as jnp
         from jax import lax
         NB = self.max_blocks_per_slot
@@ -1146,14 +1170,33 @@ class GenerationEngine:
         else:
             blk = table[idx]
         return lax.dynamic_update_slice(
-            pool, hslice[None].astype(pool.dtype), (blk, 0, 0, 0))
+            pool, strip[None].astype(pool.dtype), (blk, 0, 0, 0))
+
+    @staticmethod
+    def _to_lanes(rows, pool):
+        """``rows`` (..., D) as a position-major pool ``[N, bs, H, Dp]``
+        holds them: zeros on the lanes past D."""
+        from jax import lax
+        pad = pool.shape[-1] - rows.shape[-1]
+        if not pad:
+            return rows
+        return lax.pad(rows, rows.dtype.type(0),
+                       ((0, 0, 0),) * (rows.ndim - 1) + ((0, pad, 0),))
+
+    def _write_rows(self, pool, blk, off, rows):
+        """Set position ``off`` of block ``blk`` — (S,) or (S, Q) each —
+        to ``rows`` (S, H, D) or (S, Q, H, D)."""
+        rows = rows.astype(pool.dtype)
+        if self._position_major:
+            return pool.at[blk, off].set(self._to_lanes(rows, pool))
+        return pool.at[blk, :, off].set(rows)
 
     def _note_paged_attention(self, tables, pool, q_heads, window):
         """Record what the paged attention entry points pick for a layer
         of the program being traced (``program_inventory``)."""
         from ..kernels.flash_attention import paged_attention_impl
-        self._paged_impls.add(
-            paged_attention_impl(tables, pool, q_heads, window))
+        self._paged_impls.add(paged_attention_impl(
+            tables, pool, q_heads, window, self._position_major))
         self._paged_attention = "+".join(sorted(self._paged_impls))
 
     def _zero_counts(self):
@@ -1198,13 +1241,13 @@ class GenerationEngine:
         logits, ks, vs = self._with_params(param_vals, aux_vals, key, body)
         out = list(cache)
         for l in range(L):
-            kh = ks[l][0].transpose(1, 0, 2)               # (H, Tb, D)
-            vh = vs[l][0].transpose(1, 0, 2)
+            kh = self._block_rows(ks[l][0], out[l])        # (H, Tb, D)
+            vh = self._block_rows(vs[l][0], out[L + l])
             for j in range(-(-Tb // bs)):
                 out[l] = self._scatter_block(
-                    out[l], kh[:, j * bs:(j + 1) * bs], table, j, False)
+                    out[l], self._strip(kh, j), table, j, False)
                 out[L + l] = self._scatter_block(
-                    out[L + l], vh[:, j * bs:(j + 1) * bs], table, j, False)
+                    out[L + l], self._strip(vh, j), table, j, False)
         last = jnp.take(logits[0], n_valid - 1, axis=0)
         first, lp = self._sample_prefill(last, n_valid, samp)
         if lp is not None:
@@ -1230,18 +1273,18 @@ class GenerationEngine:
 
         def attend_for(l):
             def attend(q, k, v):             # (1, Tb, heads, D) each
-                knh = k[0].transpose(1, 0, 2)
-                vnh = v[0].transpose(1, 0, 2)
+                knh = self._block_rows(k[0], caches[l])
+                vnh = self._block_rows(v[0], caches[L + l])
                 for j in range(-(-Tb // bs)):
                     caches[l] = self._scatter_block(
-                        caches[l], knh[:, j * bs:(j + 1) * bs],
-                        table, j0 + j, True)
+                        caches[l], self._strip(knh, j), table, j0 + j, True)
                     caches[L + l] = self._scatter_block(
-                        caches[L + l], vnh[:, j * bs:(j + 1) * bs],
-                        table, j0 + j, True)
+                        caches[L + l], self._strip(vnh, j), table, j0 + j,
+                        True)
                 attn = paged_prefix_attention(
                     q.transpose(0, 2, 1, 3), caches[l], caches[L + l],
-                    table, ctx, self.layout.windows[l])
+                    table, ctx, self.layout.windows[l],
+                    position_major=self._position_major)
                 return attn.transpose(0, 2, 1, 3)
             return attend
 
@@ -1270,15 +1313,13 @@ class GenerationEngine:
             window = self.layout.windows[l]
 
             def attend(q, k, v):             # (S, 1, heads, D) each
-                ck = caches[l].at[blk, :, off].set(
-                    k[:, 0].astype(caches[l].dtype))
-                cv = caches[L + l].at[blk, :, off].set(
-                    v[:, 0].astype(caches[L + l].dtype))
+                ck = self._write_rows(caches[l], blk, off, k[:, 0])
+                cv = self._write_rows(caches[L + l], blk, off, v[:, 0])
                 caches[l], caches[L + l] = ck, cv
                 self._note_paged_attention(tables, ck, q.shape[2], window)
                 return paged_decode_attention(
-                    q[:, 0], ck, cv, tables, positions,
-                    window=window)[:, None]
+                    q[:, 0], ck, cv, tables, positions, window=window,
+                    position_major=self._position_major)[:, None]
             return attend
         return attend_for
 
@@ -1424,15 +1465,13 @@ class GenerationEngine:
             window = self.layout.windows[l]
 
             def attend(q, k, v):             # (S, Q, heads, D) each
-                ck = caches[l].at[blk, :, off].set(
-                    k.astype(caches[l].dtype))
-                cv = caches[L + l].at[blk, :, off].set(
-                    v.astype(caches[L + l].dtype))
+                ck = self._write_rows(caches[l], blk, off, k)
+                cv = self._write_rows(caches[L + l], blk, off, v)
                 caches[l], caches[L + l] = ck, cv
                 self._note_paged_attention(tables, ck, q.shape[2], window)
                 attn = paged_verify_decode_attention(
                     q.transpose(0, 2, 1, 3), ck, cv, tables, positions,
-                    window=window)
+                    window=window, position_major=self._position_major)
                 return attn.transpose(0, 2, 1, 3)
             return attend
 
@@ -1466,16 +1505,20 @@ class GenerationEngine:
         # uncommitted pool would follow jax's default device instead
         dev = self._ctx.jax_device()
         if self.paged:
-            N, H, bs, D = (self.num_blocks, self.num_heads,
-                           self.block_size, self.head_dim)
+            # the old pools go first: two sets need not fit side by side
+            # (deleted, not unbound: a replaced worker's late dispatch
+            # still finds a cache of the programs' shape, and fails on it)
+            for c in self._cache:
+                c.delete()
             self._cache = tuple(
-                jnp.zeros((N, H, bs, D), jnp.dtype(self.layout.dtype),
+                jnp.zeros(self._pool_shape, jnp.dtype(self.layout.dtype),
                           device=dev)
                 for _ in range(2 * self.num_layers))
             self.pool.reset()
-            # bytes behind one block across all layers — lets the pool
-            # report occupancy in bytes (device-memory attribution)
-            self.pool.block_bytes = self.layout.block_bytes(bs)
+            # bytes behind one block across all layers, as stored — lets
+            # the pool report occupancy in bytes (device-memory
+            # attribution)
+            self.pool.block_bytes = self.cache_bytes // self.num_blocks
             self._slot_blocks = [[] for _ in range(self.max_slots)]
             self._tables = _np.zeros(
                 (self.max_slots, self.max_blocks_per_slot), _np.int32)
@@ -1486,6 +1529,20 @@ class GenerationEngine:
         self._cache = tuple(
             jnp.zeros((S, H, T, D), jnp.float32, device=dev)
             for _ in range(2 * self.num_layers))
+
+    @property
+    def pool_layout(self):
+        """How the pools are stored, for ``/programs``: ``"default"`` as
+        the model states them, ``[N, H, bs, D]`` (the CPU; a pool that
+        rests row-major as stated; dense mode), else the position-major
+        shape ``[N, bs, H, Dp]`` they are stored in so that the device's
+        default layout is the one the programs keep."""
+        if not self._position_major:
+            return "default"
+        return {"stored": "position_major",
+                "shape": list(self._pool_shape),
+                "stated": [self.num_blocks, self.num_heads,
+                           self.block_size, self.head_dim]}
 
     @property
     def cache_bytes(self) -> int:
@@ -2051,6 +2108,7 @@ class GenerationEngine:
             "warm": self.warm,
             "paged": self.paged,
             "paged_attention": self._paged_attention,
+            "pool_layout": self.pool_layout,
             "scan_steps": self.scan_steps,
             "spec_k": self.spec_k if self.draft is not None else 0,
             "programs": _telemetry.dispatch_ledger(prefix=prefix),

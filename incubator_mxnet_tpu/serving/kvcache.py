@@ -93,6 +93,60 @@ class KVLayout(NamedTuple):
                 f"{lay.num_layers} layers")
         return lay
 
+    #: a TPU's vector registers and the tiles its memory is laid in are this
+    #: many features wide
+    LANES = 128
+
+    def pool_shape(self, num_blocks: int, block_size: int, device):
+        """``(shape, position_major)``: the shape each K and V pool of this
+        model is stored in on ``device``, read off platform, shape and type
+        and nothing else.
+
+        A pool is stated as ``[N, H, bs, D]`` (block, head, position,
+        feature) and the paged programs keep it block, position, head,
+        feature: a position's ``(H, D)`` row is one piece — what a step's
+        row write sets — and a kernel page is ``[bs, H, D]``.  Every
+        program takes the pools and returns them, and an array crosses a
+        program's boundary in the layout its device gives any array of that
+        shape, so a pool whose default layout is another order is copied
+        whole on the way in and again on the way out.  On a TPU the
+        default of ``f32[N, 16, 16, 64]`` puts N on the lanes (features
+        under 128 would pad them), which no program keeps.  So where the
+        stated shape does not rest row-major the pool is stored
+        position-major, ``[N, bs, H, Dp]`` with ``Dp`` the features
+        rounded up to whole :attr:`LANES` (the lanes past ``D`` hold
+        zeros: the bytes the stated shape takes in the programs' layout
+        anyway), provided THAT rests row-major; then its default layout
+        is the programs' and nothing is copied.  Everywhere else — off
+        the TPU, one KV head of 128 features (``bf16[N, 1, 16, 128]``
+        rests row-major as stated), a head count the device would rather
+        not put on the sublanes — the stated shape stays, and the
+        programs are the ones they were.  (A pinned
+        ``jax.experimental.layout.Format`` would say the same, but an
+        executable read back from the persistent compile cache returns its
+        results in default layouts — jax 0.9.0 with this libtpu — so only
+        a default layout survives a warm start: docs/serving.md.)"""
+        import jax.numpy as jnp
+        stated = (int(num_blocks), self.kv_heads, int(block_size),
+                  self.head_dim)
+        if device.platform != "tpu":
+            return stated, False
+
+        def rests_row_major(shape):
+            from jax.experimental.layout import Layout
+            order = Layout.from_pjrt_layout(device.client.get_default_layout(
+                jnp.dtype(self.dtype), shape, device)).major_to_minor
+            kept = [a for a in order if shape[a] != 1]
+            return kept == sorted(kept)
+
+        if rests_row_major(stated):
+            return stated, False
+        by_position = (stated[0], stated[2], stated[1],
+                       -(-self.head_dim // self.LANES) * self.LANES)
+        if rests_row_major(by_position):
+            return by_position, True
+        return stated, False
+
     def block_bytes(self, block_size: int) -> int:
         """Bytes behind one block of ``block_size`` positions: K and V of
         every layer."""

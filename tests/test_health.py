@@ -153,6 +153,7 @@ def _spmd_params(prefix):
                               mesh=_mesh())
     for x, y in _batches(8):
         tr.step(x, y)
+    health.sync()       # while the trainer, and with it its monitor, lives
     return _params(tr)
 
 

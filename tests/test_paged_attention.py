@@ -358,12 +358,90 @@ def test_gqa_kernel_compiles_for_v5e_at_the_agent_cell_shapes(
     assert not re.search(r"= bf16\[32769,[^\]]*\]\{[^}]*\} copy\(", text)
 
 
-def test_burst_program_reads_the_pool_in_place_on_v5e(
-        one_chip, no_compile_cache, monkeypatch):
-    """The decode-burst program at GPT-2-medium's widths (2 layers): the
-    kernel is in it, no dense gather is, and inside the scan the pools
-    are not copied — the carry keeps the layout the kernel reads."""
-    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+def _position_major(pages, lanes=128):
+    """A stated pool (N, H, bs, D) as ``KVLayout.pool_shape`` stores it
+    position-major: (N, bs, H, Dp), zeros on the lanes past D."""
+    pad = -pages.shape[-1] % lanes
+    return jnp.pad(jnp.swapaxes(pages, 1, 2), ((0, 0),) * 3 + ((0, pad),))
+
+
+@pytest.mark.parametrize("force", [False, True], ids=["lax", "kernel"])
+@pytest.mark.parametrize("entry", ["decode", "verify", "prefix"])
+def test_position_major_pool_reads_as_the_stated_one(entry, force,
+                                                     monkeypatch):
+    """The three paged entry points over a pool stored position-major
+    give what they give over the stated pool: to the bit through the
+    gather, which only moves the same values, and to rounding through the
+    kernel (interpreted), whose padded features add zeros in another
+    order of summation."""
+    if force:
+        monkeypatch.setenv("MXNET_FA_DECODE_FORCE_PALLAS", "1")
+    else:
+        monkeypatch.delenv("MXNET_FA_DECODE_FORCE_PALLAS", raising=False)
+    rng = np.random.default_rng(11)
+    S, nb = 3, 4
+    kp = jnp.asarray(rng.standard_normal((1 + S * nb, H, BS, D)),
+                     jnp.float32)
+    vp = jnp.asarray(rng.standard_normal(kp.shape), jnp.float32)
+    tables = jnp.asarray(1 + rng.permutation(S * nb).reshape(S, nb),
+                         jnp.int32)
+    pos = jnp.asarray([0, BS + 3, nb * BS - 6], jnp.int32)
+    pools = {False: (kp, vp), True: (_position_major(kp),
+                                     _position_major(vp))}
+    q = jnp.asarray(rng.standard_normal(
+        {"decode": (S, H, D), "verify": (S, H, 5, D),
+         "prefix": (1, H, 6, D)}[entry]), jnp.float32)
+    got = {}
+    for pm, (k, v) in pools.items():
+        if entry == "decode":
+            got[pm] = fa.paged_decode_attention(
+                q, k, v, tables, pos, position_major=pm)
+        elif entry == "verify":
+            got[pm] = fa.paged_verify_decode_attention(
+                q, k, v, tables, pos, position_major=pm)
+        else:
+            got[pm] = fa.paged_prefix_attention(
+                q, k, v, tables[1], jnp.int32(BS), position_major=pm)
+    assert fa.paged_attention_impl(
+        q, pools[True][0], position_major=True) \
+        == ("pallas" if force else "lax_gather")
+    if force and entry != "prefix":     # a sum over 128 lanes, not over 8
+        np.testing.assert_allclose(np.asarray(got[True]),
+                                   np.asarray(got[False]), rtol=0, atol=2e-6)
+    else:
+        np.testing.assert_array_equal(np.asarray(got[True]),
+                                      np.asarray(got[False]))
+
+
+@pytest.mark.parametrize("heads,head_dim,dtype,want", [
+    (16, 64, "float32", ((1217, 16, 16, 128), True)),  # gpt2-medium-serve
+    (16, 64, "bfloat16", ((1217, 16, 16, 128), True)),
+    (12, 64, "float32", ((1217, 12, 16, 64), False)),  # chip_smoke's gpt2
+    (1, 128, "bfloat16", ((1217, 1, 16, 128), False)),  # the agent cell's
+    (8, 128, "float32", ((1217, 8, 16, 128), False)),
+])
+def test_pool_is_stored_so_that_it_rests_as_the_programs_keep_it(
+        heads, head_dim, dtype, want, topo):
+    """The rule, read off platform, shape and type: on a v5e position-major
+    with the features on whole lanes where the stated shape would rest in
+    another order than row-major AND the position-major one does rest
+    row-major; the stated shape where it rests row-major already (a head
+    of 128 features), where the device would not rest the other one
+    row-major either (12 heads: it puts the 16 positions on the sublanes),
+    and on the CPU always."""
+    from incubator_mxnet_tpu.serving.kvcache import KVLayout
+    lay = KVLayout(2, heads, head_dim, dtype, (None, None), 1024)
+    assert lay.pool_shape(1217, 16, topo.devices[0]) == want
+    assert lay.pool_shape(1217, 16, jax.devices("cpu")[0]) \
+        == ((1217, heads, 16, head_dim), False)
+
+
+@pytest.fixture(scope="module")
+def serve_cell_programs(topo, one_chip):
+    """The five paged programs of a GPT at GPT-2-medium's widths (2
+    layers), each with its operands as the serve cell dispatches them (S
+    36, N 1,217) on the described chip, the pools in the shape the rule
+    gives for that chip: ``(engine, {name: (the engine's jit, operands)})``."""
     from incubator_mxnet_tpu import random as mx_random
     mx.random.seed(0)
     net = GPTModel(vocab_size=512, units=1024, hidden_size=4096,
@@ -375,6 +453,10 @@ def test_burst_program_reads_the_pool_in_place_on_v5e(
     eng = GenerationEngine(net, name="aot", max_slots=S, max_len=1024,
                            prefill_buckets=[256], paged=True, block_size=16,
                            num_blocks=65, scan_steps=8)
+    # the engine lives on the CPU; its programs are traced for the chip
+    eng._pool_shape, eng._position_major = eng.layout.pool_shape(
+        N, eng.block_size, topo.devices[0])
+    assert eng._position_major
 
     def sds(x, shape=None):
         x = jnp.asarray(x) if not hasattr(x, "dtype") else x
@@ -383,22 +465,119 @@ def test_burst_program_reads_the_pool_in_place_on_v5e(
 
     i32 = lambda *shape: jax.ShapeDtypeStruct(                # noqa: E731
         shape, jnp.int32, sharding=one_chip)
-    Hc, bs, Dc = eng.num_heads, eng.block_size, eng.head_dim
-    cache = tuple(sds(c, (N, Hc, bs, Dc)) for c in eng._cache)
+    cache = tuple(sds(c, eng._pool_shape) for c in eng._cache)
     params, aux = eng._param_fn()
-    args = (cache, i32(S, 1), i32(S), i32(S), i32(S),
+    tail = (tuple(sds(p) for p in params), tuple(sds(a) for a in aux),
+            sds(mx_random.new_key(eng._ctx)))
+    samp = tuple(sds(a) for a in eng._samp_tuple())
+    slot_samp = tuple(sds(a) for a in eng._slot_samp(0))
+    tables, row = i32(S, eng.max_blocks_per_slot), i32(
+        eng.max_blocks_per_slot)
+    done = jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)
+    programs = {
+        "decode_burst": (eng._decode_burst_jit,
+                         (i32(S, 1), i32(S), i32(S), i32(S), done, tables,
+                          samp)),
+        "decode": (eng._decode_jit, (i32(S, 1), i32(S), tables, samp)),
+        "verify": (eng._verify_jit, (i32(S, 5), i32(S), tables, samp)),
+        "prefill": (eng._prefill_jit, (i32(1, 256), i32(), row, slot_samp)),
+        "prefill_ext": (eng._prefill_ext_jit,
+                        (i32(1, 256), i32(), i32(), row, slot_samp)),
+    }
+    return eng, {name: (jitted, (cache,) + operands + tail)
+                 for name, (jitted, operands) in programs.items()}
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "decode", "verify",
+                                     "prefill", "prefill_ext"])
+def test_paged_programs_keep_the_pool_in_place_on_v5e(
+        program, serve_cell_programs, no_compile_cache, monkeypatch):
+    """Each paged program at the serve cell's shapes, the engine's own
+    jit compiled for the v5e over pools stored as the rule has them there
+    (``f32[1217, 16, 16, 128]``, position-major): no pool is copied
+    anywhere in it, the ENTRY computation included — the pools come in and
+    go out in the device's default layout, row-major, and that is the
+    layout the program keeps them in.  The decode programs hold the
+    kernel and no dense gather."""
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+    eng, programs = serve_cell_programs
+    jitted, args = programs[program]
+    compiled = jitted.trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"= f32\[1217,16,16,128\]\{[^}]*\} copy\(", text)
+    assert "f32[1217,16,16,64]" not in text             # the stated shape
+    pools_in, pools_out = compiled.input_formats[0][0], \
+        compiled.output_formats[0]
+    assert {f.layout.major_to_minor for f in (*pools_in, *pools_out)} \
+        == {(0, 1, 2, 3)}
+    if program == "decode_burst":
+        assert eng.program_inventory()["paged_attention"] == "pallas"
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+    if program in ("decode_burst", "decode", "verify"):
+        assert "f32[2304,16,16," not in text            # the dense gather
+
+
+def test_agent_cell_pool_stays_as_stated_and_is_not_copied_on_v5e(
+        topo, one_chip, no_compile_cache, monkeypatch):
+    """The AFMoE cell's pool, ``bf16[32769, 1, 16, 128]``, rests row-major
+    as stated: the rule leaves it so, its programs are the ones they
+    were, and the burst program (2 of the configuration's layers, a
+    sliding and a full one, 4 experts held) takes the pools as they rest:
+    no copy of one in it."""
+    import json
+    import os
+    import sys
+    from incubator_mxnet_tpu import random as mx_random
+    chip = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "chip")
+    if chip not in sys.path:
+        sys.path.insert(0, chip)
+    from programs import afmoe_serve
+    from reference import afmoe as ref
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+    with open(os.path.join(chip, "configs",
+                           "trinity-large-serve-ep8.json")) as f:
+        cfg = json.load(f)
+    dep = cfg["deployment"]
+    cfg.update(num_hidden_layers=2, num_experts=4, vocab_size=512,
+               layer_types=["sliding_attention", "full_attention"])
+    dt, d, V = ref.param_dtype(cfg), cfg["hidden_size"], cfg["vocab_size"]
+    shape = lambda s: jax.ShapeDtypeStruct(s, dt)             # noqa: E731
+    net = afmoe_serve.build_net(cfg)
+    net.adopt_arrays({
+        "embed_tokens": shape((V, d)), "norm": shape((d,)),
+        "lm_head": shape((d, V)),
+        "layers": [{n: shape(s) for n, s in ref.layer_shapes(cfg, i).items()}
+                   for i in range(cfg["num_hidden_layers"])]})
+    S, N = dep["max_slots"], dep["num_blocks"]
+    eng = GenerationEngine(net, name="aot-agent", max_slots=S,
+                           max_len=dep["max_len"], prefill_buckets=[512],
+                           paged=True, block_size=dep["block_size"],
+                           num_blocks=1 + dep["max_len"] // dep["block_size"],
+                           scan_steps=dep["scan_steps"])
+    pool, position_major = eng.layout.pool_shape(N, eng.block_size,
+                                                 topo.devices[0])
+    assert (pool, position_major, eng.layout.dtype) \
+        == ((32769, 1, 16, 128), False, "bfloat16")
+    assert not eng._position_major
+
+    def sds(x, shape=None):
+        x = jnp.asarray(x) if not hasattr(x, "dtype") else x
+        return jax.ShapeDtypeStruct(shape or x.shape, x.dtype,
+                                    sharding=one_chip)
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(                # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    params, aux = eng._param_fn()
+    args = (tuple(sds(c, pool) for c in eng._cache),
+            i32(S, 1), i32(S), i32(S), i32(S),
             jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip),
             i32(S, eng.max_blocks_per_slot),
             tuple(sds(a) for a in eng._samp_tuple()),
             tuple(sds(p) for p in params), tuple(sds(a) for a in aux),
             sds(mx_random.new_key(eng._ctx)))
-    text = _compile(eng._decode_burst_paged_pure, *args).as_text()
+    text = eng._decode_burst_jit.trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
     assert eng.program_inventory()["paged_attention"] == "pallas"
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
-    assert f"f32[{S * 64},16,16,64]" not in text          # the dense gather
-    comps = re.split(r"\n(?=(?:ENTRY )?%?[\w.\-]+ \([^\n]*\) -> [^\n]*\{\n)",
-                     text)
-    pool_copy = re.compile(r"= f32\[1217,16,16,64\]\{[^}]*\} copy\(")
-    for comp in comps:
-        if not comp.lstrip().startswith("ENTRY"):
-            assert not pool_copy.search(comp), comp[:200]
+    assert not re.search(r"= bf16\[32769,[^\]]*\]\{[^}]*\} copy\(", text)

@@ -16,6 +16,7 @@ has no matmul — float32 products and sums on the VPU — and is held to
 1e-5."""
 import importlib
 import math
+import re
 import time
 
 import jax
@@ -243,6 +244,65 @@ def test_engine_traces_the_kernel_on_the_chip():
         got[steps] = eng.generate(prompt, max_new_tokens=40)
         assert eng.program_inventory()["paged_attention"] == "pallas"
     assert len(got[0]) == 40 and got[0] == got[4]
+
+
+def test_pool_rests_as_the_burst_program_takes_it():
+    """On the chip the pools of a GPT of 16 heads of 64 features (stated
+    ``[N, 16, 16, 64]``: N would lie on the lanes by default) are stored
+    position-major, ``[N, 16, 16, 128]``, which rests row-major: that is
+    the layout the burst program takes and returns them in — the engine's
+    own jit says so — and they stay in it through prefill, single steps,
+    bursts and `reset()`, in a second engine too, whose programs come out
+    of the persistent compile cache (an executable read back from it
+    gives its results in default layouts, whatever it was compiled for).
+    One KV head of 128 features rests row-major as stated."""
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import random as mx_random
+    from incubator_mxnet_tpu.models.gpt import GPTModel
+    from incubator_mxnet_tpu.serving import GenerationEngine
+    from incubator_mxnet_tpu.serving.kvcache import KVLayout
+    mx.random.seed(3)
+    net = GPTModel(vocab_size=64, units=1024, hidden_size=1024,
+                   num_layers=2, num_heads=16, max_length=256, dropout=0.0)
+    net.initialize(init=mx.init.Normal(0.05))
+    net(mx.nd.array(onp.zeros((1, 2), onp.int32)))
+    got = []
+    for name in ("rest-a", "rest-b"):
+        eng = GenerationEngine(net, name=name, max_slots=4, max_len=256,
+                               prefill_buckets=[8], scan_steps=4)
+        assert eng.program_inventory()["pool_layout"] == {
+            "stored": "position_major", "shape": [65, 16, 16, 128],
+            "stated": [65, 16, 16, 64]}
+
+        def resting():
+            return {(c.shape, c.format.layout) for c in eng._cache}
+
+        allocated = resting()
+        assert {(s, l.major_to_minor) for s, l in allocated} \
+            == {((65, 16, 16, 128), (0, 1, 2, 3))}
+        got.append(eng.generate([3, 7, 11, 5, 9], max_new_tokens=24))
+        assert resting() == allocated
+        assert eng.program_inventory()["paged_attention"] == "pallas"
+        S = eng.max_slots
+        i32 = lambda *s: jnp.zeros(s, jnp.int32)              # noqa: E731
+        params, aux = eng._param_fn()
+        compiled = eng._decode_burst_jit.lower(
+            eng._cache, i32(S, 1), i32(S), i32(S), i32(S),
+            jnp.zeros(S, bool), i32(S, eng.max_blocks_per_slot),
+            eng._samp_tuple(), params, aux,
+            mx_random.new_key(eng._ctx)).compile()
+        taken = {f.layout for f in compiled.input_formats[0][0]}
+        given = {f.layout for f in compiled.output_formats[0]}
+        assert taken == given == {l for _, l in allocated}
+        assert not re.search(r"= f32\[65,16,16,128\]\{[^}]*\} copy\(",
+                             compiled.as_text())
+        eng.reset()
+        assert resting() == allocated
+        assert eng.generate([3, 7, 11, 5, 9], max_new_tokens=24) == got[-1]
+    assert got[0] == got[1]
+    agent = KVLayout(5, 1, 128, "bfloat16", (None,) * 5, 8192)
+    assert agent.pool_shape(32769, 16, jax.devices()[0]) \
+        == ((32769, 1, 16, 128), False)
 
 
 # --- flash forward + both backward kernels, BERT-large attention shapes
