@@ -18,9 +18,7 @@ Judged metric (BASELINE.md): BERT pretraining samples/sec/chip, north star
 MFU is the honest efficiency metric.  The BERT step trains the FULL
 pretrain objective (MLM + NSP heads), matching the anchor workload.
 """
-import functools
 import json
-import math
 import os
 import subprocess
 import sys
@@ -630,15 +628,12 @@ def _bench_generate(on_accel, kind, dev):
     tokens/sec floor on the CPU config is the acceptance bar of
     docs/serving.md.
 
-    Two paged-KV axes ride along (docs/serving.md "Paged KV cache"):
-    ``concurrent_streams_per_gb`` pits the paged pool against the dense
-    per-slot cache under an EQUAL cache-byte budget — 16 shared-prefix
-    streaming clients, peak concurrent slots normalized per GB of
-    cache, floor >= 2x — and ``prefix_prefill_savings`` measures the
-    prefill FLOPs drop (XLA_COST plane) when a repeated prompt hits the
-    prefix cache and only its suffix is prefilled, floor >= 1.3x.
+    One paged-KV axis rides along (docs/serving.md "Paged KV cache"):
+    ``prefix_prefill_savings`` measures the prefill FLOPs drop (XLA_COST
+    plane) when a repeated prompt hits the prefix cache and only its
+    suffix is prefilled, floor >= 1.3x.
 
-    The third axis, ``speculative_decoding``, measures draft-verify
+    The second axis, ``speculative_decoding``, measures draft-verify
     decode: a 1-layer draft proposes k=4 tokens and the target scores
     all k+1 in one fixed-shape verify dispatch.  Greedy acceptance is
     exact (sequences asserted identical to plain decode); recorded are
@@ -653,7 +648,7 @@ def _bench_generate(on_accel, kind, dev):
     accept rate is recorded next to greedy's (accept rate vs
     temperature).
 
-    The fourth axis, ``decode_scan``, measures the whole-decode-loop
+    The third axis, ``decode_scan``, measures the whole-decode-loop
     capture (docs/serving.md "Multi-token decode bursts"): the same
     16-client steady-state load through the same net with scan_steps=0
     (one dispatch per token) vs the default k-step ``lax.scan`` burst
@@ -663,7 +658,7 @@ def _bench_generate(on_accel, kind, dev):
     speedup >= 1.2x and burst dispatches_per_token <= 0.2 (the
     docs/serving.md dispatch-economy bar for k=8).
 
-    The fifth axis, ``sampling``, runs the same steady-state load
+    The fourth axis, ``sampling``, runs the same steady-state load
     greedy vs stochastically sampled (temperature 0.8, top-p 0.9,
     fixed per-request seeds).  Sampling operands are traced inputs of
     the SAME compiled programs, so the recorded ``overhead_pct`` floor
@@ -782,54 +777,7 @@ def _bench_generate(on_accel, kind, dev):
     speedup = round(continuous["tokens_per_sec"]
                     / max(naive["tokens_per_sec"], 1e-9), 3)
 
-    # -- paged vs dense concurrency under an EQUAL cache-byte budget --
-    # dense buys 4 slots x max_len positions; the paged pool holds the
-    # same token-positions as 16-token blocks (plus the null block) and
-    # lets 16 shared-prefix clients fit in them
     system = [int(t) for t in rng.integers(1, V, size=32)]
-    shared_prompts = [system + [int(t) for t in rng.integers(1, V, size=4)]
-                      for _ in range(clients)]
-    shared_new = 12
-    dense_eng = GenerationEngine(net, name="bench-dense", max_slots=4,
-                                 max_len=max_len, paged=False)
-    paged_eng = GenerationEngine(net, name="bench-paged",
-                                 max_slots=clients, max_len=max_len,
-                                 paged=True, block_size=16,
-                                 num_blocks=1 + (4 * max_len) // 16)
-
-    def peak_streams(eng, tag):
-        bat = ContinuousBatcher(eng, name=f"bench-{tag}")
-        try:
-            reqs = [bat.submit_async(p, max_new_tokens=shared_new)
-                    for p in shared_prompts]
-            outs = [r.result(timeout=300) for r in reqs]
-            return outs, bat.stats()["peak_slots_in_use"]
-        finally:
-            bat.close()
-
-    dense_outs, dense_peak = peak_streams(dense_eng, "dense")
-    paged_outs, paged_peak = peak_streams(paged_eng, "paged")
-    if paged_outs != dense_outs:
-        raise RuntimeError(
-            "paged stream outputs != dense under the shared-prefix "
-            "load (greedy decode must be exact)")
-    gb = float(2 ** 30)
-    dense_spg = dense_peak / (dense_eng.cache_bytes / gb)
-    paged_spg = paged_peak / (paged_eng.cache_bytes / gb)
-    streams_ratio = round(paged_spg / max(dense_spg, 1e-9), 3)
-    streams_axis = {
-        "clients": clients,
-        "dense": {"peak_streams": int(dense_peak),
-                  "cache_mb": round(dense_eng.cache_bytes / 2**20, 3),
-                  "streams_per_gb": round(dense_spg, 1)},
-        "paged": {"peak_streams": int(paged_peak),
-                  "cache_mb": round(paged_eng.cache_bytes / 2**20, 3),
-                  "streams_per_gb": round(paged_spg, 1),
-                  "prefix_cache_hits": paged_eng.pool.hits},
-        "paged_vs_dense": streams_ratio,
-        "floor": "paged_vs_dense >= 2.0",
-        "floor_ok": bool(streams_ratio >= 2.0),
-    }
 
     # -- prefix-cache prefill savings: the same prompt twice; the hit
     # run prefills only the suffix bucket, measured on the XLA_COST
@@ -1110,116 +1058,16 @@ def _bench_generate(on_accel, kind, dev):
         "outputs_identical": True,
         "speedup": speedup,
         "speedup_floor": 3.0,
-        "concurrent_streams_per_gb": streams_axis,
         "prefix_prefill_savings": prefix_axis,
         "speculative_decoding": spec_axis,
         "decode_scan": scan_axis,
         "sampling": sampling_axis,
-        "floor_ok": bool(speedup >= 3.0 and streams_axis["floor_ok"]
+        "floor_ok": bool(speedup >= 3.0
                          and prefix_axis["floor_ok"]
                          and spec_axis["floor_ok"]
                          and scan_axis["floor_ok"]
                          and sampling_axis["floor_ok"]),
     }
-
-
-def _bench_decode_attn(on_accel, kind, dev):
-    """``decode_attention`` micro bench: the lax reference vs the Pallas
-    kernel (interpret-mode on CPU — a parity/emulation tool, so the
-    only floor on that ratio is that lax must not fall behind the
-    emulator), for both the single-query decode shape and the new
-    k+1-wide speculative ``verify`` shape.  Outputs are asserted
-    allclose between the two paths.
-
-    The recorded ``speedup_floor`` guards the verify kernel's scaling:
-    ONE k+1-wide dispatch vs k+1 single-query decode dispatches
-    (``verify_amortization`` = per-token throughput ratio).  Attention
-    compute scales with the query width on both sides, so parity
-    (1.0x) is the expectation and 0.8x the regression floor — the same
-    pattern as ``int8_conv``'s 0.8x (an accidentally quadratic mask or
-    a per-query cache re-read shows up here long before it drags the
-    end-to-end ``generate`` spec axis under ITS 1.3x floor)."""
-    import jax
-    import jax.numpy as jnp
-
-    fa = sys.modules.get("incubator_mxnet_tpu.kernels.flash_attention")
-    if fa is None:
-        import importlib
-        fa = importlib.import_module(
-            "incubator_mxnet_tpu.kernels.flash_attention")
-
-    S, H, T, D = (16, 8, 1024, 64) if on_accel else (8, 4, 512, 64)
-    Q = 5                                   # spec_k=4 drafted + 1 bonus
-    steps, warmup = (50, 5) if on_accel else (20, 3)
-    rng = np.random.default_rng(0)
-    q1 = jnp.asarray(rng.standard_normal((S, H, D)), jnp.float32)
-    qk = jnp.asarray(rng.standard_normal((S, H, Q, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((S, H, T, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((S, H, T, D)), jnp.float32)
-    positions = jnp.asarray(rng.integers(Q, T - Q, size=S), jnp.int32)
-    scale = 1.0 / math.sqrt(D)
-    interpret = not on_accel
-
-    lax_decode = jax.jit(functools.partial(
-        fa._xla_decode_attention, scale=scale))
-    pl_decode = jax.jit(functools.partial(
-        fa._decode_pallas, scale=scale, interpret=interpret))
-    lax_verify = jax.jit(functools.partial(
-        fa._xla_verify_decode_attention, scale=scale))
-    pl_verify = jax.jit(functools.partial(
-        fa._verify_pallas, scale=scale, interpret=interpret))
-
-    # parity first: the Pallas kernel must agree with the reference on
-    # both shapes before any of its timings mean anything
-    ref1 = np.asarray(lax_decode(q1, k, v, positions))
-    np.testing.assert_allclose(
-        np.asarray(pl_decode(q1, k, v, positions)), ref1,
-        atol=2e-3, rtol=2e-3)
-    refk = np.asarray(lax_verify(qk, k, v, positions))
-    np.testing.assert_allclose(
-        np.asarray(pl_verify(qk, k, v, positions)), refk,
-        atol=2e-3, rtol=2e-3)
-
-    def rate(fn, *args):
-        for _ in range(warmup):
-            fn(*args).block_until_ready()
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            fn(*args).block_until_ready()
-        return steps / (time.perf_counter() - t0)
-
-    lax_1 = rate(lax_decode, q1, k, v, positions)
-    pl_1 = rate(pl_decode, q1, k, v, positions)
-    lax_k = rate(lax_verify, qk, k, v, positions)
-    pl_k = rate(pl_verify, qk, k, v, positions)
-    # amortization: ONE k+1-wide verify dispatch vs k+1 single-query
-    # decode dispatches (per-token throughput ratio), on whichever
-    # path serves this platform
-    d_rate, v_rate = (pl_1, pl_k) if on_accel else (lax_1, lax_k)
-    amort = round(v_rate / (d_rate / Q), 3)
-    lax_vs_interp = round(lax_1 / max(pl_1, 1e-9), 3)
-    rec = {
-        "shape": {"slots": S, "heads": H, "cache_tokens": T,
-                  "head_dim": D, "verify_width": Q},
-        "pallas_mode": "compiled" if on_accel else "interpret",
-        "decode_lax_calls_per_sec": round(lax_1, 1),
-        "decode_pallas_calls_per_sec": round(pl_1, 1),
-        "verify_lax_calls_per_sec": round(lax_k, 1),
-        "verify_pallas_calls_per_sec": round(pl_k, 1),
-        "lax_vs_pallas": lax_vs_interp,
-        "parity_ok": True,
-        "verify_amortization": amort,
-        "speedup_floor": 0.8,
-        "floor": "verify_amortization >= 0.8"
-                 + ("" if on_accel else " and lax_vs_pallas >= 1.0"),
-        "floor_ok": bool(amort >= 0.8
-                         and (on_accel or lax_vs_interp >= 1.0)),
-    }
-    if not rec["floor_ok"]:
-        rec["regression"] = (
-            f"verify amortization {amort} < floor 0.8 or lax path "
-            f"fell behind the interpreter ({lax_vs_interp})")
-    return rec
 
 
 def _bench_train_loop(on_accel, kind, dev):
@@ -1805,8 +1653,6 @@ def _sub_main(name):
         rec = _bench_serve(on_accel, kind, dev)
     elif name == "generate":
         rec = _bench_generate(on_accel, kind, dev)
-    elif name == "decode_attn":
-        rec = _bench_decode_attn(on_accel, kind, dev)
     elif name == "train_loop":
         rec = _bench_train_loop(on_accel, kind, dev)
     else:
@@ -1861,7 +1707,6 @@ def _main(preset_fusion):
         optim = _run_sub("optim", timeout=1800)
         serve = _run_sub("serve", timeout=1800)
         serve["generate"] = _run_sub("generate", timeout=1800)
-        serve["decode_attn"] = _run_sub("decode_attn", timeout=1800)
         train_loop = _run_sub("train_loop", timeout=1800)
         scaling = _scaling_dryrun()
     else:
@@ -1883,8 +1728,6 @@ def _main(preset_fusion):
                            lambda: _bench_serve(False, kind, dev))
         serve["generate"] = _cpu_bench(
             "generate", lambda: _bench_generate(False, kind, dev))
-        serve["decode_attn"] = _cpu_bench(
-            "decode_attn", lambda: _bench_decode_attn(False, kind, dev))
         train_loop = _cpu_bench(
             "train_loop", lambda: _bench_train_loop(False, kind, dev))
         scaling = _scaling_dryrun()

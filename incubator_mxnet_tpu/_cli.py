@@ -217,8 +217,7 @@ def _fleet_stats(ns) -> int:
 
 
 def _load_generation_engine(name, cfg_path, max_slots=None, max_len=None,
-                            paged=None, block_size=None,
-                            scan_steps=None):
+                            block_size=None, scan_steps=None):
     """Build a :class:`serving.GenerationEngine` from a ``--gen-model``
     JSON config: architecture kwargs for ``models.gpt.GPTModel`` plus a
     ``"params"`` weights file (``Block.save_parameters`` format,
@@ -243,14 +242,12 @@ def _load_generation_engine(name, cfg_path, max_slots=None, max_len=None,
     params = cfg.pop("params", None)
     cfg_slots = cfg.pop("max_slots", None)
     cfg_len = cfg.pop("max_len", None)
-    cfg_paged = cfg.pop("paged", None)
     cfg_bs = cfg.pop("block_size", None)
     cfg_spec_k = cfg.pop("spec_k", None)    # draft configs only
     cfg_scan = cfg.pop("scan_steps", None)
     cfg_lp = cfg.pop("logprobs_topn", None)
     max_slots = cfg_slots if max_slots is None else max_slots
     max_len = cfg_len if max_len is None else max_len
-    paged = cfg_paged if paged is None else paged
     block_size = cfg_bs if block_size is None else block_size
     scan_steps = cfg_scan if scan_steps is None else scan_steps
     cfg.setdefault("dropout", 0.0)      # serving never trains
@@ -263,8 +260,7 @@ def _load_generation_engine(name, cfg_path, max_slots=None, max_len=None,
                 os.path.abspath(cfg_path)), params)
         net.load_parameters(params)
     engine = GenerationEngine(net, name=name, max_slots=max_slots,
-                              max_len=max_len, paged=paged,
-                              block_size=block_size,
+                              max_len=max_len, block_size=block_size,
                               scan_steps=scan_steps,
                               logprobs_topn=cfg_lp)
     # surfaced by serve_main when this config backs a --gen-draft
@@ -284,7 +280,7 @@ def serve_main():
                     [--queue N] [--input-names data]
                     [--input-specs 784] [--warmup] [--preload]
                     [--gen-slots N] [--gen-max-len N]
-                    [--gen-paged 0|1] [--gen-block-size N]
+                    [--gen-block-size N]
 
     Each ``--model`` is ``NAME=PREFIX[:EPOCH]`` naming a
     ``HybridBlock.export`` / ``model.save_checkpoint`` pair
@@ -305,10 +301,9 @@ def serve_main():
     ``/v1/models/<NAME>:generate`` behind continuous batching
     (docs/serving.md); ``--gen-slots`` / ``--gen-max-len`` override the
     config and the ``MXNET_GEN_MAX_SLOTS`` / ``MXNET_GEN_MAX_LEN``
-    env defaults.  The KV cache is paged by default (block pool +
-    prefix sharing); ``--gen-paged 0`` restores the dense layout and
-    ``--gen-block-size`` sets tokens per block (``MXNET_KV_PAGED`` /
-    ``MXNET_KV_BLOCK_SIZE``).
+    env defaults.  The KV cache is a block pool with prefix sharing;
+    ``--gen-block-size`` sets tokens per block
+    (``MXNET_KV_BLOCK_SIZE``).
 
     ``--gen-draft NAME=CONFIG.json`` attaches a small draft model to
     the generation model registered as ``NAME``, enabling speculative
@@ -364,10 +359,6 @@ def serve_main():
     ap.add_argument("--gen-max-len", type=int, default=None,
                     help="KV-cache sequence capacity (default config or "
                          "MXNET_GEN_MAX_LEN or the model's max_length)")
-    ap.add_argument("--gen-paged", type=int, choices=(0, 1), default=None,
-                    help="paged KV cache: 1 on (default; block pool + "
-                         "prefix sharing), 0 dense fallback (also "
-                         "MXNET_KV_PAGED)")
     ap.add_argument("--gen-block-size", type=int, default=None,
                     help="tokens per paged KV block (default "
                          "MXNET_KV_BLOCK_SIZE or 16)")
@@ -448,9 +439,7 @@ def serve_main():
             ap.error(f"--gen-model wants NAME=CONFIG.json, got {spec!r}")
         engine = _load_generation_engine(
             name, cfg_path, max_slots=ns.gen_slots,
-            max_len=ns.gen_max_len,
-            paged=None if ns.gen_paged is None else bool(ns.gen_paged),
-            block_size=ns.gen_block_size,
+            max_len=ns.gen_max_len, block_size=ns.gen_block_size,
             scan_steps=ns.gen_scan_steps)
         if name in drafts:
             # the draft mirrors the target's slot/sequence geometry so
@@ -458,16 +447,14 @@ def serve_main():
             draft = _load_generation_engine(
                 name + "-draft", drafts[name],
                 max_slots=engine.max_slots, max_len=engine.max_len,
-                paged=engine.paged,
-                block_size=engine.block_size if engine.paged else None)
+                block_size=engine.block_size)
             engine.attach_draft(
                 draft, spec_k=getattr(draft, "_cfg_spec_k", None))
             sys.stderr.write(
                 f"mxtpu-serve: attached draft to {name} from "
                 f"{drafts[name]} (spec_k {engine.spec_k})\n")
         srv.add_model(name, engine, warmup=ns.warmup)
-        kv = (f"paged blocks={engine.num_blocks - 1}x"
-              f"{engine.block_size}" if engine.paged else "dense")
+        kv = f"paged blocks={engine.num_blocks - 1}x{engine.block_size}"
         sys.stderr.write(
             f"mxtpu-serve: loaded generation model {name} from "
             f"{cfg_path} (slots {engine.max_slots}, max_len "

@@ -40,10 +40,10 @@ import math
 import jax
 import jax.numpy as jnp
 
-__all__ = ["flash_attention", "flash_attention_lse", "decode_attention",
-           "paged_decode_attention", "verify_decode_attention",
-           "paged_verify_decode_attention", "paged_attention_impl",
-           "prefill_attention", "paged_prefix_attention"]
+__all__ = ["flash_attention", "flash_attention_lse",
+           "paged_decode_attention", "paged_verify_decode_attention",
+           "paged_attention_impl", "prefill_attention",
+           "paged_prefix_attention"]
 
 _BLOCK_Q = 128
 _BLOCK_K = 128
@@ -594,7 +594,9 @@ def prefill_attention(q, k, v, window=None, scale=None):
 
 # ---------------------------------------------------------------------------
 # decode-shaped attention: one query position per slot over a
-# preallocated KV cache (the GenerationEngine's per-step attention).
+# contiguous (S, H, T, D) strip of keys — the body of the lax paged path
+# (which gathers each slot's blocks into such a strip) and the reference
+# the paged kernels are tested against.
 # ---------------------------------------------------------------------------
 
 def _xla_decode_attention(q, k, v, positions, scale):
@@ -613,58 +615,10 @@ def _xla_decode_attention(q, k, v, positions, scale):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _decode_pallas(q, k, v, positions, scale, interpret):
-    """Single-query decode IS verify at query width 1 (one kernel body,
-    :func:`_verify_kernel`, serves all four decode-shaped entry points)."""
-    return _verify_pallas(q[:, :, None, :], k, v, positions, scale,
-                          interpret)[:, :, 0, :]
-
-
-def decode_attention(q, k, v, positions, scale=None):
-    """Per-slot single-position attention over a preallocated KV cache.
-
-    ``q`` (S, H, D): this step's query, one position per slot; ``k``/``v``
-    (S, H, T, D): the cache, already holding this position's K/V at index
-    ``positions[s]``; ``positions`` (S,) int32: each slot's current write
-    head.  Attends over cache entries ``<= positions[s]`` (later entries
-    are stale garbage by the continuous-batching contract) and returns
-    (S, H, D).
-
-    The position mask is the load-bearing contract for **scanned decode
-    bursts** (``GenerationEngine.decode_burst``): ``positions`` may be a
-    traced value riding a ``lax.scan`` carry — per-slot, data-dependent,
-    frozen for finished slots — not just a host constant.  Every
-    implementation below masks strictly by comparison against
-    ``positions`` (never by python-level slicing on its value), so a
-    frozen slot keeps attending over exactly its old prefix and stale
-    bytes past the write head stay invisible at any scan step.
-
-    Dispatch mirrors :func:`flash_attention`: a Pallas online-softmax
-    kernel when T is tile-aligned and K+V fit the VMEM budget, otherwise
-    the lax fallback.  On CPU the lax path is the default — decode runs
-    once per generated token, and interpret-mode emulation is a parity
-    tool, not a serving path (``MXNET_FA_DECODE_FORCE_PALLAS=1`` forces
-    the interpreted kernel for tests)."""
-    from ..base import getenv_bool
-    S, H, T, D = k.shape
-    if scale is None:
-        scale = 1.0 / math.sqrt(D)
-    platform = _platform_of(q)
-    force = getenv_bool("MXNET_FA_DECODE_FORCE_PALLAS")
-    kv_bytes = 2 * T * D * q.dtype.itemsize
-    aligned = T % _BLOCK_K == 0 and kv_bytes <= 8 * 2 ** 20
-    if force and aligned:
-        return _decode_pallas(q, k, v, positions, scale,
-                              interpret=platform == "cpu")
-    if platform == "cpu" or not aligned:
-        return _xla_decode_attention(q, k, v, positions, scale)
-    return _decode_pallas(q, k, v, positions, scale, interpret=False)
-
-
 # ---------------------------------------------------------------------------
-# paged decode attention: the same single-query attention, but the KV
-# cache lives in fixed-size blocks (serving/kvcache.py BlockPool) and
-# each slot reads through an int32 block table instead of a dense strip.
+# paged decode attention (the GenerationEngine's per-step attention): the
+# KV cache lives in fixed-size blocks (serving/kvcache.py BlockPool) and
+# each slot reads through an int32 block table.
 # ---------------------------------------------------------------------------
 
 def _pool_dims(pages, position_major):
@@ -695,7 +649,7 @@ def _xla_paged_decode_attention(q, k_pages, v_pages, tables, positions,
     """Gather each slot's blocks into a dense (S, H, T, D) view and reuse
     :func:`_xla_decode_attention` verbatim.  Masked (stale / null-block)
     positions contribute exact-zero softmax weight, so the result is
-    bit-identical to dense decode over the same valid entries.  Grouped
+    bit-identical to attention over the valid entries alone.  Grouped
     heads or a window take :func:`_xla_grouped_decode_attention` over the
     same view."""
     H = _pool_dims(k_pages, position_major)[1]
@@ -791,11 +745,14 @@ def paged_decode_attention(q, k_pages, v_pages, tables, positions,
     and the kernel's work list starts at the window's first block — and
     returns (S, Hq, D).
 
-    Same scanned-burst contract as :func:`decode_attention`:
-    ``positions`` (and the write head it masks) may be carry-traced
-    inside ``lax.scan``, so all masking is comparison-based against the
-    traced value — a slot frozen mid-burst attends over exactly its old
-    prefix while its redirected null-block writes stay invisible.
+    The position mask is the load-bearing contract for **scanned decode
+    bursts** (``GenerationEngine.decode_burst``): ``positions`` may be a
+    traced value riding a ``lax.scan`` carry — per-slot, data-dependent,
+    frozen for finished slots — not just a host constant.  Every
+    implementation masks strictly by comparison against ``positions``
+    (never by python-level slicing on its value), so a slot frozen
+    mid-burst attends over exactly its old prefix while its redirected
+    null-block writes stay invisible.
 
     :func:`paged_attention_impl` picks the implementation at trace time:
     a Pallas kernel (single-query decode IS verify at query width 1)
@@ -815,8 +772,8 @@ def paged_decode_attention(q, k_pages, v_pages, tables, positions,
 
 # ---------------------------------------------------------------------------
 # verify-shaped attention: a k+1-wide query block per slot over the same
-# caches — the speculative-decode verify program scores every drafted
-# position in ONE dispatch.  Query row j of slot s sits at logical
+# strips and pools — the speculative-decode verify program scores every
+# drafted position in ONE dispatch.  Query row j of slot s sits at logical
 # position positions[s] + j, so the mask is causal-within-the-block on
 # top of the per-slot length mask the single-query kernels already use.
 # ---------------------------------------------------------------------------
@@ -867,116 +824,6 @@ def _xla_grouped_decode_attention(q, k, v, positions, scale, window):
     o = jnp.einsum("skgqt,sktd->skgqd", p.astype(v.dtype), v,
                    preferred_element_type=jnp.float32)
     return o.reshape(S, Hq, Q, D).astype(q.dtype)
-
-
-def _verify_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                   l_ref, *, scale, n_q, block_k, n_kb):
-    """Grid (S, H, n_kb) over a DENSE cache: a (Q, D) query block against
-    K/V blocks (block_k, D), online softmax across the kb axis with
-    per-row running max / denominator in scratch (persists along the
-    innermost grid dim)."""
-    from jax.experimental import pallas as pl
-    kb = pl.program_id(2)
-
-    @pl.when(kb == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -1e30)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    pos = pos_ref[pl.program_id(0)]
-    D = q_ref.shape[-1]
-    q = q_ref[...].reshape(n_q, D).astype(jnp.float32)
-    k = k_ref[...].reshape(block_k, D).astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # (Q, block_k)
-    idx = kb * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (n_q, block_k), 1)
-    head = pos + jax.lax.broadcasted_iota(jnp.int32, (n_q, block_k), 0)
-    s = jnp.where(idx <= head, s, -1e30)
-    m_prev, l_prev = m_ref[:], l_ref[:]               # (Q, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                            # (Q, block_k)
-    l_ref[:] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    m_ref[:] = m_new
-    v_blk = v_ref[...].reshape(block_k, D).astype(jnp.float32)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p, v_blk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # (Q, D)
-
-    @pl.when(kb == n_kb - 1)
-    def _fin():
-        o_ref[...] = (acc_ref[:] / l_ref[:]).reshape(
-            o_ref.shape).astype(o_ref.dtype)
-
-
-def _verify_pallas(q, k, v, positions, scale, interpret):
-    """The dense cache's ``pallas_call``.  The query/output blocks span
-    the array's whole last two dims (Q, D), and the per-slot position is
-    read from prefetched SMEM inside the kernel — both are what the TPU
-    lowering requires (a (1,)-blocked SMEM operand or a block whose
-    second-minor dim is 1 of H is refused)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    S, H, n_q, D = q.shape
-    T = k.shape[2]
-    block_k = min(_BLOCK_K, T)
-    n_kb = T // block_k
-    kernel = functools.partial(_verify_kernel, scale=scale, n_q=n_q,
-                               block_k=block_k, n_kb=n_kb)
-    spec_q = pl.BlockSpec((1, 1, n_q, D),
-                          lambda s, h, kb, pos: (s, h, 0, 0))
-    spec_kv = pl.BlockSpec((1, 1, block_k, D),
-                           lambda s, h, kb, pos: (s, h, kb, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(S, H, n_kb),
-        in_specs=[spec_q, spec_kv, spec_kv],
-        out_specs=spec_q,
-        scratch_shapes=[
-            pltpu.VMEM((n_q, D), jnp.float32),
-            pltpu.VMEM((n_q, 1), jnp.float32),
-            pltpu.VMEM((n_q, 1), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(positions.astype(jnp.int32), q, k, v)
-
-
-def verify_decode_attention(q, k, v, positions, scale=None):
-    """Per-slot k+1-wide attention over a preallocated KV cache.
-
-    ``q`` (S, H, Q, D): this step's query block — row j is the query of
-    the token at logical position ``positions[s] + j``; ``k``/``v``
-    (S, H, T, D): the cache, already holding all Q positions' K/V;
-    ``positions`` (S,) int32: the base position of row 0.  Row j attends
-    entries ``<= positions[s] + j`` and the call returns (S, H, Q, D).
-    With Q == 1 this is exactly :func:`decode_attention`.
-
-    Dispatch gates mirror :func:`decode_attention`: Pallas when T is
-    tile-aligned and K+V fit the VMEM budget, lax otherwise; on CPU the
-    lax path is the default and ``MXNET_FA_DECODE_FORCE_PALLAS=1`` forces
-    the interpreted kernel for parity tests."""
-    from ..base import getenv_bool
-    S, H, T, D = k.shape
-    if scale is None:
-        scale = 1.0 / math.sqrt(D)
-    platform = _platform_of(q)
-    force = getenv_bool("MXNET_FA_DECODE_FORCE_PALLAS")
-    kv_bytes = 2 * T * D * q.dtype.itemsize
-    aligned = T % _BLOCK_K == 0 and kv_bytes <= 8 * 2 ** 20
-    if force and aligned:
-        return _verify_pallas(q, k, v, positions, scale,
-                              interpret=platform == "cpu")
-    if platform == "cpu" or not aligned:
-        return _xla_verify_decode_attention(q, k, v, positions, scale)
-    return _verify_pallas(q, k, v, positions, scale, interpret=False)
 
 
 def _xla_paged_verify_decode_attention(q, k_pages, v_pages, tables,

@@ -37,8 +37,8 @@ signals through the pure :func:`scale_decision` policy
 
 Generation serving rides the same layers: :class:`GenerationEngine`
 (paged KV cache over a :class:`~.kvcache.BlockPool` — fixed-size
-blocks, per-slot block tables, refcounted prefix sharing; dense mode
-via ``MXNET_KV_PAGED=0`` — with a prefill/decode split) behind a
+blocks, per-slot block tables, refcounted prefix sharing — with a
+prefill/decode split) behind a
 :class:`ContinuousBatcher` (per-slot join/leave, one decode dispatch
 per step over all live requests, pool-capacity admission) behind
 ``POST /v1/models/<name>:generate`` with SSE streaming.  The sampling

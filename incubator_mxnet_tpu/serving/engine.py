@@ -464,61 +464,63 @@ def derive_prefill_buckets(max_len: int, smallest: int = 8):
 
 class GenerationEngine:
     """Autoregressive generation as a closed set of compiled programs
-    over a PREALLOCATED per-layer KV cache ``[slots, heads, max_len,
-    head_dim]``.
+    over a PREALLOCATED paged KV cache: per layer one K and one V pool of
+    ``num_blocks`` blocks of ``block_size`` positions, managed by a
+    :class:`~.kvcache.BlockPool`.
 
     The naive serving path re-runs prefill over the whole growing
     context every token — O(n^2) work and one fresh dispatch per request
     per token.  This engine splits the work once:
 
     * ``prefill(tokens, slot)`` — full-prefix forward at the request's
-      prompt-length bucket, writing the slot's K/V rows and returning
-      the first generated token.  One compiled program per prefill
-      bucket (:func:`derive_prefill_buckets`).
+      prompt-length bucket, writing the slot's K/V into its blocks and
+      returning the first generated token.  One compiled program per
+      prefill bucket (:func:`derive_prefill_buckets`), and one more per
+      bucket for prefix-cache hits, which prefill only the unshared
+      suffix.
     * ``decode(last_tokens, positions)`` — ONE fixed-shape dispatch
       advancing every slot one token: embeds each slot's last token at
       its own position, appends K/V at that position, and attends over
-      its live prefix via :func:`kernels.flash_attention.decode_attention`.
-      Exactly one compiled program, regardless of how many requests are
-      in flight or how long they run.
+      its live prefix via
+      :func:`kernels.flash_attention.paged_decode_attention`.  Exactly
+      one compiled program, regardless of how many requests are in
+      flight or how long they run; ``decode_burst`` scans
+      ``scan_steps`` of them into one dispatch, and ``verify`` scores a
+      draft's proposals ``spec_k + 1`` positions wide.
 
-    Both programs take the whole cache DONATED (the engine owns it and
+    Each slot addresses its K/V through an int32 *block table* operand —
+    an (S, max_blocks) array that enters the SAME compiled programs as
+    data, never as a shape.  A request reserves only ``ceil((prompt +
+    budget) / block_size)`` blocks, so a byte budget admits many more
+    concurrent streams than ``max_len`` rows would, and full prompt
+    blocks are shared across requests via the pool's prefix cache.
+
+    Every program takes the whole cache DONATED (the engine owns it and
     rebinds the returned buffers), so XLA updates the cache in place.
     The cache is single-writer by contract: only the continuous
     batcher's worker thread dispatches.  Free slots still flow through
-    ``decode`` (their writes land in their own rows at position 0 and
-    are overwritten by the next prefill), which is what keeps the
-    program count at one.
+    ``decode`` (their table is all null block, where writes are
+    harmless, and the layers are told they are not live), which is what
+    keeps the program count at one.
 
-    Decoding is greedy (argmax) — the serving contract is determinism:
-    cached decode must match the full re-forward token-for-token.
+    The serving contract is determinism: greedy (temperature 0) cached
+    decode matches the full re-forward token-for-token, and a seeded
+    sampled run replays bit-identically through per-step decode, bursts
+    and speculative verify.
 
     **The model behind it** supplies the serving layer interface
     (``kv_layout`` / ``serve_embed`` / ``serve_layers`` / ``serve_head``,
     and ``serve_prefill`` / ``serve_cached`` on each layer;
-    docs/serving.md "The layer interface").  The paged programs call
-    that and nothing of a layer's insides, and allocate the pools from
-    the model's :class:`~.kvcache.KVLayout` — KV heads, head size, type,
+    docs/serving.md "The layer interface").  The programs call that and
+    nothing of a layer's insides, and allocate the pools from the
+    model's :class:`~.kvcache.KVLayout` — KV heads, head size, type,
     window per layer.  ``models.gpt.GPTModel`` and
-    ``models.afmoe.AFMoEModel`` implement it; the dense-mode bodies
-    below are GPT-only and refuse another block.
+    ``models.afmoe.AFMoEModel`` implement it.
 
-    **Paged mode** (default; ``MXNET_KV_PAGED=0`` falls back to the dense
-    layout above): the cache becomes per-layer block pools ``[num_blocks,
-    heads, block_size, head_dim]`` managed by a
-    :class:`~.kvcache.BlockPool`, and each slot addresses its K/V through
-    an int32 *block table* operand — an (S, max_blocks) array that enters
-    the SAME compiled programs as data, never as a shape.  A request
-    reserves only ``ceil((prompt + budget) / block_size)`` blocks, so the
-    same byte budget admits many more concurrent streams, and full prompt
-    blocks are shared across requests via the pool's prefix cache (a
-    prefix hit prefills only the unshared suffix).  The program set stays
-    closed: one miss-prefill per bucket, one suffix-prefill per bucket
-    (prefix hits), and ONE paged decode.  Decode attention routes through
-    :func:`kernels.flash_attention.paged_decode_attention`: on a TPU the
-    Pallas kernel that reads the pool in place up to each slot's write
-    head, on the CPU the lax gather that keeps paged decode bit-identical
-    to dense (``program_inventory()["paged_attention"]`` says which).
+    Decode attention on a TPU is the Pallas kernel that reads the pool
+    in place up to each slot's write head, on the CPU the lax gather
+    that is its reference (``program_inventory()["paged_attention"]``
+    says which).
     """
 
     def __init__(self, block, *, name: Optional[str] = None,
@@ -548,7 +550,7 @@ class GenerationEngine:
         self._ctx = ctx if ctx is not None else current_context()
         #: what the paged attention entry points picked when a decode
         #: program was last traced ("pallas" | "lax_gather"); None until
-        #: then, and in dense mode
+        #: then
         self._paged_attention = None
         self._paged_impls = set()
         self.max_slots = int(max_slots
@@ -557,7 +559,7 @@ class GenerationEngine:
             raise MXNetError(f"max_slots must be >= 1: {self.max_slots}")
         #: the model's own statement of what its layers cache
         #: (:class:`~.kvcache.KVLayout`): pools are allocated from it
-        from .kvcache import KVLayout
+        from .kvcache import BlockPool, KVLayout
         self.layout = KVLayout.of(block.kv_layout())
         blk_len = self.layout.max_length
         self.max_len = min(int(max_len
@@ -595,52 +597,36 @@ class GenerationEngine:
                     f"{self.prefill_buckets}")
         else:
             self.prefill_buckets = derive_prefill_buckets(self.max_len)
-        # paged KV cache (serving/kvcache.py): on by default, dense stays
-        # available as the fallback and parity oracle
-        self.paged = bool(getenv_bool("MXNET_KV_PAGED", True)
-                          if paged is None else paged)
+        if paged is not None and not paged:
+            # the keyword outlives the mode only until benchmark/chip's
+            # callers stop passing it (ROADMAP D2)
+            raise MXNetError(
+                f"{self.name}: paged=False: the dense KV mode is gone; "
+                "the engine has one cache, the block pool")
         self.block_size = int(block_size
                               or getenv_int("MXNET_KV_BLOCK_SIZE", 16))
         if self.block_size < 1:
             raise MXNetError(f"block_size must be >= 1: {self.block_size}")
-        self.prefix_cache_enabled = self.paged and bool(
+        self.prefix_cache_enabled = bool(
             getenv_bool("MXNET_KV_PREFIX_CACHE", True)
             if prefix_cache is None else prefix_cache)
-        if not self.paged:
-            # the dense bodies reach into a GPT cell (ROADMAP D2 retires
-            # them); every other model is served paged
-            for attr in ("embed", "pos_embed", "cells", "ln_f"):
-                if not hasattr(block, attr):
-                    raise MXNetError(
-                        "the dense KV mode serves a GPT-style block only "
-                        "(embed/pos_embed/cells/ln_f); "
-                        f"{type(block).__name__} has no {attr!r}: serve "
-                        "it paged (paged=True, MXNET_KV_PAGED=1)")
-            self._cells = list(block.cells._children.values())
-        if self.paged:
-            from .kvcache import BlockPool
-            self.max_blocks_per_slot = -(-self.max_len // self.block_size)
-            nb = int(num_blocks or getenv_int("MXNET_KV_NUM_BLOCKS", 0)) \
-                or 1 + self.max_slots * self.max_blocks_per_slot
-            if nb < 1 + self.max_blocks_per_slot:
-                raise MXNetError(
-                    f"num_blocks {nb} cannot hold even one max_len slot "
-                    f"({self.max_blocks_per_slot} blocks + null block)")
-            self.num_blocks = nb
-            self.pool = BlockPool(nb, self.block_size,
-                                  prefix_cache=self.prefix_cache_enabled,
-                                  model=self.name)
-        else:
-            self.max_blocks_per_slot = 0
-            self.num_blocks = 0
-            self.pool = None
+        self.max_blocks_per_slot = -(-self.max_len // self.block_size)
+        nb = int(num_blocks or getenv_int("MXNET_KV_NUM_BLOCKS", 0)) \
+            or 1 + self.max_slots * self.max_blocks_per_slot
+        if nb < 1 + self.max_blocks_per_slot:
+            raise MXNetError(
+                f"num_blocks {nb} cannot hold even one max_len slot "
+                f"({self.max_blocks_per_slot} blocks + null block)")
+        self.num_blocks = nb
+        self.pool = BlockPool(nb, self.block_size,
+                              prefix_cache=self.prefix_cache_enabled,
+                              model=self.name)
         #: the shape each pool is stored in on this device, and whether
         #: that is position-major (:meth:`~.kvcache.KVLayout.pool_shape`:
         #: where the stated [N, H, bs, D] would rest in a layout no
         #: program keeps)
         self._pool_shape, self._position_major = self.layout.pool_shape(
-            self.num_blocks, self.block_size, self._ctx.jax_device()) \
-            if self.paged else (None, False)
+            self.num_blocks, self.block_size, self._ctx.jax_device())
         self._warming = False
         # multi-token decode bursts (docs/serving.md): lax.scan
         # ``scan_steps`` decode steps into ONE dispatch with in-program
@@ -683,33 +669,21 @@ class GenerationEngine:
         self._last_logprobs = None
         self._last_prefill_logprobs = None
         self._last_verify_logprobs = None
-        if self.paged:
-            self._prefill_jit = jax.jit(self._prefill_paged_pure,
+        self._prefill_jit = jax.jit(self._prefill_paged_pure,
+                                    donate_argnums=(0,))
+        self._prefill_ext_jit = jax.jit(self._prefill_ext_pure,
                                         donate_argnums=(0,))
-            self._prefill_ext_jit = jax.jit(self._prefill_ext_pure,
-                                            donate_argnums=(0,))
-            self._prefill_ext = _telemetry.instrument_jit(
-                "serving:" + self.name + ":prefill_ext",
-                self._prefill_ext_jit)
-            self._decode_jit = jax.jit(self._decode_paged_pure,
-                                       donate_argnums=(0,))
-            self._decode_burst_jit = jax.jit(self._decode_burst_paged_pure,
-                                             donate_argnums=(0,))
-            self._verify_jit = jax.jit(self._verify_paged_pure,
-                                       donate_argnums=(0,))
-        else:
-            self._prefill_jit = jax.jit(self._prefill_pure,
-                                        donate_argnums=(0,))
-            self._prefill_ext_jit = None
-            self._prefill_ext = None
-            self._decode_jit = jax.jit(self._decode_pure,
-                                       donate_argnums=(0,))
-            self._decode_burst_jit = jax.jit(self._decode_burst_pure,
-                                             donate_argnums=(0,))
-            self._verify_jit = jax.jit(self._verify_pure,
-                                       donate_argnums=(0,))
+        self._decode_jit = jax.jit(self._decode_paged_pure,
+                                   donate_argnums=(0,))
+        self._decode_burst_jit = jax.jit(self._decode_burst_paged_pure,
+                                         donate_argnums=(0,))
+        self._verify_jit = jax.jit(self._verify_paged_pure,
+                                   donate_argnums=(0,))
         self._prefill = _telemetry.instrument_jit(
             "serving:" + self.name + ":prefill", self._prefill_jit)
+        self._prefill_ext = _telemetry.instrument_jit(
+            "serving:" + self.name + ":prefill_ext",
+            self._prefill_ext_jit)
         self._decode = _telemetry.instrument_jit(
             "serving:" + self.name + ":decode", self._decode_jit)
         self._decode_burst = _telemetry.instrument_jit(
@@ -891,251 +865,6 @@ class GenerationEngine:
         return jax.vmap(row)(logits, temps, topks, topps, biases, keys)
 
     # -- pure programs --------------------------------------------------
-    def _prefill_pure(self, cache, tokens, n_valid, slot, samp,
-                      param_vals, aux_vals, key):
-        """tokens (1, Tb) int32 (zero-padded past ``n_valid``), scalar
-        ``slot``: run the full-prefix forward (causal, so the first
-        ``n_valid`` positions are exact regardless of padding), write the
-        slot's K/V rows for positions [0, Tb), return (cache', first
-        generated token)."""
-        import jax.numpy as jnp
-        from jax import lax
-        L, H, D = self.num_layers, self.num_heads, self.head_dim
-        Tb = tokens.shape[1]
-
-        def body():
-            x = self.block._embed_at(NDArray(tokens))
-            ks, vs = [], []
-            for cell in self._cells:
-                x, k, v = cell.prime(x)
-                ks.append(k._data)
-                vs.append(v._data)
-            logits = self.block._project(self.block.ln_f(x))
-            return logits._data, ks, vs
-
-        logits, ks, vs = self._with_params(param_vals, aux_vals, key, body)
-        out = list(cache)
-        for l in range(L):
-            kh = ks[l].reshape(Tb, H, D).transpose(1, 0, 2)[None]
-            vh = vs[l].reshape(Tb, H, D).transpose(1, 0, 2)[None]
-            out[l] = lax.dynamic_update_slice(
-                out[l], kh.astype(out[l].dtype), (slot, 0, 0, 0))
-            out[L + l] = lax.dynamic_update_slice(
-                out[L + l], vh.astype(out[L + l].dtype), (slot, 0, 0, 0))
-        last = jnp.take(logits[0], n_valid - 1, axis=0)
-        first, lp = self._sample_prefill(last, n_valid, samp)
-        if lp is not None:
-            return tuple(out), first, lp
-        return tuple(out), first
-
-    def _decode_pure(self, cache, last_tokens, positions, samp,
-                     param_vals, aux_vals, key):
-        """One token for EVERY slot: last_tokens (S, 1) int32, positions
-        (S,) int32 (the index each slot writes this step).  Free slots
-        ride along writing into their own row at position 0 — harmless,
-        the next prefill overwrites.  Returns (cache', next (S,))."""
-        import jax.numpy as jnp
-        from ..kernels.flash_attention import decode_attention
-        L, H, D = self.num_layers, self.num_heads, self.head_dim
-        S = last_tokens.shape[0]
-        C = H * D
-        caches = list(cache)
-        rows = jnp.arange(S)
-
-        def body():
-            pos_nd = NDArray(positions.reshape(S, 1))
-            x = self.block.embed(NDArray(last_tokens)) \
-                + self.block.pos_embed(pos_nd)
-            h = self.block.drop(x)
-            for l, cell in enumerate(self._cells):
-                at = cell.attention
-                hn = cell.ln1(h)
-                q, kn, vn = at.query(hn), at.key(hn), at.value(hn)
-                qh = q._data.reshape(S, H, D)
-                knh = kn._data.reshape(S, H, D)
-                vnh = vn._data.reshape(S, H, D)
-                ck = caches[l].at[rows, :, positions].set(
-                    knh.astype(caches[l].dtype))
-                cv = caches[L + l].at[rows, :, positions].set(
-                    vnh.astype(caches[L + l].dtype))
-                caches[l], caches[L + l] = ck, cv
-                attn = decode_attention(qh, ck, cv, positions)
-                out_nd = NDArray(attn.reshape(S, 1, C).astype(h._data.dtype))
-                h = h + at.dropout(at.proj(out_nd))
-                h = h + cell._ffn_out(cell.ln2(h))
-            logits = self.block._project(self.block.ln_f(h))
-            return logits._data
-
-        logits = self._with_params(param_vals, aux_vals, key, body)
-        lg = logits[:, 0, :]
-        nxt = self._sample_step(lg, positions + 1, samp)
-        out = (tuple(caches), nxt)
-        if self._health_on:
-            out = out + (_health.decode_health(lg),)
-        if self.logprobs_topn:
-            from .sampling import topn_logprobs
-            out = out + (topn_logprobs(lg, samp[3], self.logprobs_topn),)
-        return out
-
-    def _decode_burst_pure(self, cache, last_tokens, positions, budgets,
-                           eos_ids, done0, samp,
-                           param_vals, aux_vals, key):
-        """``scan_steps`` decode steps captured as ONE program
-        (:func:`jax.lax.scan` over the exact :meth:`_decode_pure` cell
-        body) with in-program termination riding the carry.
-
-        Per slot: ``budgets`` (S,) int32 caps the tokens this burst may
-        emit (the request's remaining budget), ``eos_ids`` (S,) int32 is
-        the stop token (-1: none), ``done0`` (S,) bool marks slots that
-        must not emit at all (free slots).  A slot whose step hits EOS or
-        exhausts its budget flips ``done``; from then on its
-        ``(last_token, position)`` carry is FROZEN, so every subsequent
-        step recomputes — and rewrites, bit-for-bit — the same K/V at
-        the same position (per-slot rows are independent, so the rewrite
-        is exactly idempotent and a mid-burst EOS cannot corrupt the
-        cache).  Live slots are untouched by their neighbors' freezes:
-        the token stream is bit-identical to ``scan_steps`` per-step
-        :meth:`_decode_pure` dispatches.
-
-        Returns ``(cache', tokens (k, S), emitted (S,))`` — row ``j`` of
-        ``tokens`` is step ``j``'s argmax; slot ``s``'s valid prefix is
-        ``tokens[:emitted[s], s]``.  With the health plane on, the
-        per-step logit stats are folded across the burst in-program
-        (max / mean / all) to the same (S,) triplet one decode returns."""
-        import jax.numpy as jnp
-        from jax import lax
-        from ..kernels.flash_attention import decode_attention
-        L, H, D = self.num_layers, self.num_heads, self.head_dim
-        S = last_tokens.shape[0]
-        C = H * D
-        k = int(self.scan_steps)
-        rows = jnp.arange(S)
-
-        def run_scan():
-            def step(carry, _):
-                caches, lt, pos, done, emitted = carry
-                caches = list(caches)
-                pos_nd = NDArray(pos.reshape(S, 1))
-                x = self.block.embed(NDArray(lt)) \
-                    + self.block.pos_embed(pos_nd)
-                h = self.block.drop(x)
-                for l, cell in enumerate(self._cells):
-                    at = cell.attention
-                    hn = cell.ln1(h)
-                    q, kn, vn = at.query(hn), at.key(hn), at.value(hn)
-                    qh = q._data.reshape(S, H, D)
-                    knh = kn._data.reshape(S, H, D)
-                    vnh = vn._data.reshape(S, H, D)
-                    ck = caches[l].at[rows, :, pos].set(
-                        knh.astype(caches[l].dtype))
-                    cv = caches[L + l].at[rows, :, pos].set(
-                        vnh.astype(caches[L + l].dtype))
-                    caches[l], caches[L + l] = ck, cv
-                    attn = decode_attention(qh, ck, cv, pos)
-                    out_nd = NDArray(attn.reshape(S, 1, C).astype(
-                        h._data.dtype))
-                    h = h + at.dropout(at.proj(out_nd))
-                    h = h + cell._ffn_out(cell.ln2(h))
-                logits = self.block._project(self.block.ln_f(h))
-                lg = logits._data[:, 0, :]
-                # keyed at pos + 1 (the position this token will
-                # occupy): the carry IS the per-step key split
-                nxt = self._sample_step(lg, pos + 1, samp)
-                emit = ~done
-                emitted2 = emitted + emit.astype(jnp.int32)
-                done2 = done | (emit & ((nxt == eos_ids)
-                                        | (emitted2 >= budgets)))
-                lt2 = jnp.where(done2[:, None], lt, nxt[:, None])
-                pos2 = jnp.where(done2, pos, pos + 1)
-                ys = (nxt,) if not self._health_on \
-                    else (nxt,) + _health.decode_health(lg)
-                if self.logprobs_topn:
-                    from .sampling import topn_logprobs
-                    ys = ys + topn_logprobs(lg, samp[3],
-                                            self.logprobs_topn)
-                return (tuple(caches), lt2, pos2, done2, emitted2), ys
-
-            carry0 = (cache, last_tokens, positions, done0,
-                      jnp.zeros(S, jnp.int32))
-            return lax.scan(step, carry0, None, length=k)
-
-        (caches, _, _, _, emitted), ys = self._with_params(
-            param_vals, aux_vals, key, run_scan)
-        ys = list(ys)
-        if self.logprobs_topn:            # stacked (k, S, N) per burst
-            lpi = ys.pop()
-            lpv = ys.pop()
-        if self._health_on:
-            toks, lmax, ent, fin = ys
-            # frozen steps replay their final live step's logits, so the
-            # fold is dominated by live emissions (max/all exact, mean
-            # slightly weighted toward the freeze value)
-            out = (caches, toks, emitted,
-                   (lmax.max(axis=0), ent.mean(axis=0), fin.all(axis=0)))
-        else:
-            (toks,) = ys
-            out = (caches, toks, emitted)
-        if self.logprobs_topn:
-            out = out + ((lpv, lpi),)
-        return out
-
-    def _verify_pure(self, cache, tokens, positions, samp,
-                     param_vals, aux_vals, key):
-        """The speculative-decode VERIFY program: a k+1-wide
-        generalization of :meth:`_decode_pure`.  ``tokens`` (S, Q) int32
-        — row 0 is each slot's last accepted token, rows 1..Q-1 the
-        draft's proposals; ``positions`` (S,) int32 the base write head.
-        Scatters Q K/V writes per slot per layer (positions past
-        ``max_len`` drop — overrun rows near the budget edge must not
-        stomp a live entry), attends via
-        :func:`kernels.flash_attention.verify_decode_attention`, and
-        returns (cache', argmax (S, Q)): the target's next token AFTER
-        each of the Q positions.  With Q == 1 this is exactly decode."""
-        import jax.numpy as jnp
-        from ..kernels.flash_attention import verify_decode_attention
-        L, H, D = self.num_layers, self.num_heads, self.head_dim
-        S, Q = tokens.shape
-        C = H * D
-        caches = list(cache)
-        rows = jnp.arange(S)
-        pos_q = positions[:, None] \
-            + jnp.arange(Q, dtype=jnp.int32)[None, :]          # (S, Q)
-
-        def body():
-            pos_nd = NDArray(jnp.minimum(pos_q, self.max_len - 1))
-            x = self.block.embed(NDArray(tokens)) \
-                + self.block.pos_embed(pos_nd)
-            h = self.block.drop(x)
-            for l, cell in enumerate(self._cells):
-                at = cell.attention
-                hn = cell.ln1(h)
-                q, kn, vn = at.query(hn), at.key(hn), at.value(hn)
-                qh = q._data.reshape(S, Q, H, D).transpose(0, 2, 1, 3)
-                knh = kn._data.reshape(S, Q, H, D)
-                vnh = vn._data.reshape(S, Q, H, D)
-                ck = caches[l].at[rows[:, None], :, pos_q].set(
-                    knh.astype(caches[l].dtype), mode="drop")
-                cv = caches[L + l].at[rows[:, None], :, pos_q].set(
-                    vnh.astype(caches[L + l].dtype), mode="drop")
-                caches[l], caches[L + l] = ck, cv
-                attn = verify_decode_attention(qh, ck, cv, positions)
-                out_nd = NDArray(attn.transpose(0, 2, 1, 3).reshape(
-                    S, Q, C).astype(h._data.dtype))
-                h = h + at.dropout(at.proj(out_nd))
-                h = h + cell._ffn_out(cell.ln2(h))
-            logits = self.block._project(self.block.ln_f(h))
-            return logits._data
-
-        logits = self._with_params(param_vals, aux_vals, key, body)
-        nxt = self._sample_verify(logits, pos_q, samp)
-        if self.logprobs_topn:
-            from .sampling import topn_logprobs
-            lp = topn_logprobs(logits, samp[3][:, None, :],
-                               self.logprobs_topn)
-            return tuple(caches), nxt, lp
-        return tuple(caches), nxt
-
-    # -- pure programs, paged layout ------------------------------------
     # The five bodies below know nothing of a model's insides: they call
     # the layer interface (docs/serving.md "The layer interface") — the
     # model embeds, its layers project and mix, and attention comes back
@@ -1364,16 +1093,32 @@ class GenerationEngine:
     def _decode_burst_paged_pure(self, cache, last_tokens, positions,
                                  budgets, eos_ids, done0, tables, samp,
                                  param_vals, aux_vals, key):
-        """:meth:`_decode_burst_pure` over the paged layout: the scanned
-        step is the exact :meth:`_decode_paged_pure` body, and a frozen
-        (done) slot's K/V writes are redirected to the null block 0 —
-        belt on top of the idempotent-rewrite argument, so a finished
-        slot's replayed steps can never touch a live block, its own or
-        (through any future sharing scheme) anyone else's.  Decode
-        positions sit strictly past the shared prompt blocks, so the
-        burst composes with the BlockPool prefix cache unchanged.  A
-        model's counters ride the carry and come back summed over the
-        steps."""
+        """``scan_steps`` decode steps captured as ONE program
+        (:func:`jax.lax.scan` over the exact :meth:`_decode_paged_pure`
+        body) with in-program termination riding the carry.
+
+        Per slot: ``budgets`` (S,) int32 caps the tokens this burst may
+        emit (the request's remaining budget), ``eos_ids`` (S,) int32 is
+        the stop token (-1: none), ``done0`` (S,) bool marks slots that
+        must not emit at all (free slots).  A slot whose step hits EOS or
+        exhausts its budget flips ``done``; from then on its
+        ``(last_token, position)`` carry is FROZEN and its K/V writes are
+        redirected to the null block 0, so a finished slot's replayed
+        steps can never touch a live block, its own or (through any
+        future sharing scheme) anyone else's.  Live slots are untouched
+        by their neighbors' freezes: the token stream is bit-identical
+        to ``scan_steps`` per-step :meth:`_decode_paged_pure` dispatches.
+        Decode positions sit strictly past the shared prompt blocks, so
+        the burst composes with the BlockPool prefix cache unchanged.
+
+        Returns ``(cache', tokens (k, S), emitted (S,))`` — row ``j`` of
+        ``tokens`` is step ``j``'s token; slot ``s``'s valid prefix is
+        ``tokens[:emitted[s], s]``.  With the health plane on, the
+        per-step logit stats are folded across the burst in-program
+        (max / mean / all) to the same (S,) triplet one decode returns
+        (frozen steps replay their final live step's logits, so the fold
+        is dominated by live emissions).  A model's counters ride the
+        carry and come back summed over the steps."""
         import jax.numpy as jnp
         from jax import lax
         S = last_tokens.shape[0]
@@ -1494,9 +1239,9 @@ class GenerationEngine:
         """(Re)allocate the cache: all slots free, all rows zero.  Called
         at construction and by the continuous batcher after a watchdog
         restart (a replaced worker must not trust donated buffers that a
-        dying dispatch may have consumed).  Paged mode also rewipes the
-        block pool, every block table, and the prefix cache — cached K/V
-        must never outlive the params that computed it."""
+        dying dispatch may have consumed).  Also rewipes the block pool,
+        every block table, and the prefix cache — cached K/V must never
+        outlive the params that computed it."""
         import jax.numpy as jnp
         if getattr(self, "draft", None) is not None:
             self.draft.reset()
@@ -1504,37 +1249,30 @@ class GenerationEngine:
         # committed to the engine's device, like the parameters: an
         # uncommitted pool would follow jax's default device instead
         dev = self._ctx.jax_device()
-        if self.paged:
-            # the old pools go first: two sets need not fit side by side
-            # (deleted, not unbound: a replaced worker's late dispatch
-            # still finds a cache of the programs' shape, and fails on it)
-            for c in self._cache:
-                c.delete()
-            self._cache = tuple(
-                jnp.zeros(self._pool_shape, jnp.dtype(self.layout.dtype),
-                          device=dev)
-                for _ in range(2 * self.num_layers))
-            self.pool.reset()
-            # bytes behind one block across all layers, as stored — lets
-            # the pool report occupancy in bytes (device-memory
-            # attribution)
-            self.pool.block_bytes = self.cache_bytes // self.num_blocks
-            self._slot_blocks = [[] for _ in range(self.max_slots)]
-            self._tables = _np.zeros(
-                (self.max_slots, self.max_blocks_per_slot), _np.int32)
-            self._tables_dev = None
-            return
-        S, H, T, D = (self.max_slots, self.num_heads, self.max_len,
-                      self.head_dim)
+        # the old pools go first: two sets need not fit side by side
+        # (deleted, not unbound: a replaced worker's late dispatch
+        # still finds a cache of the programs' shape, and fails on it)
+        for c in self._cache:
+            c.delete()
         self._cache = tuple(
-            jnp.zeros((S, H, T, D), jnp.float32, device=dev)
+            jnp.zeros(self._pool_shape, jnp.dtype(self.layout.dtype),
+                      device=dev)
             for _ in range(2 * self.num_layers))
+        self.pool.reset()
+        # bytes behind one block across all layers, as stored — lets
+        # the pool report occupancy in bytes (device-memory
+        # attribution)
+        self.pool.block_bytes = self.cache_bytes // self.num_blocks
+        self._slot_blocks = [[] for _ in range(self.max_slots)]
+        self._tables = _np.zeros(
+            (self.max_slots, self.max_blocks_per_slot), _np.int32)
+        self._tables_dev = None
 
     @property
     def pool_layout(self):
         """How the pools are stored, for ``/programs``: ``"default"`` as
         the model states them, ``[N, H, bs, D]`` (the CPU; a pool that
-        rests row-major as stated; dense mode), else the position-major
+        rests row-major as stated), else the position-major
         shape ``[N, bs, H, Dp]`` they are stored in so that the device's
         default layout is the one the programs keep."""
         if not self._position_major:
@@ -1585,6 +1323,14 @@ class GenerationEngine:
                                              model=self.name)
             raise
 
+    def _tables_device(self):
+        """Every slot's block table, (S, max_blocks) int32, device-cached
+        until a slot's table changes."""
+        import jax.numpy as jnp
+        if self._tables_dev is None:
+            self._tables_dev = jnp.asarray(self._tables)
+        return self._tables_dev
+
     def _enqueued(self, out) -> list:
         """A decode, burst or verify program is enqueued and ``out`` are
         its (future) results: what follows pulls them, so the worker
@@ -1616,9 +1362,9 @@ class GenerationEngine:
         token.  After this the slot's write head is at ``len(tokens)``
         (the returned token's K/V lands there on its first decode).
 
-        Paged mode allocates the slot's block table first —
-        ``reserve_tokens`` (default ``max_len``) is the worst-case total
-        positions (prompt + budget) the request may ever write, so decode
+        The slot's block table is allocated first — ``reserve_tokens``
+        (default ``max_len``) is the worst-case total positions
+        (prompt + budget) the request may ever write, so decode
         NEVER allocates and can never fail mid-flight.  A prefix-cache
         hit dispatches the suffix program instead, skipping the shared
         span's prefill work entirely.
@@ -1661,12 +1407,6 @@ class GenerationEngine:
                                reserve_tokens=int(
                                    reserve_tokens or self.max_len)
                                + self.spec_k)
-        if not self.paged:
-            padded = self._padded(toks, n, span)
-            return self._unpack_prefill(self._guarded(
-                self._prefill, jnp.asarray(padded),
-                jnp.asarray(n, jnp.int32), jnp.asarray(slot, jnp.int32),
-                self._slot_samp(slot)))
         if self._slot_blocks[slot]:
             self.release_slot(slot)
         reserve = int(reserve_tokens or self.max_len) \
@@ -1734,16 +1474,10 @@ class GenerationEngine:
             self.max_slots, 1))
         pos = jnp.asarray(_np.asarray(positions, _np.int32).reshape(
             self.max_slots))
-        if self.paged:
-            if self._tables_dev is None:
-                self._tables_dev = jnp.asarray(self._tables)
-            out = self._guarded(self._decode, lt, pos, self._tables_dev,
-                                self._samp_tuple())
-        else:
-            out = self._guarded(self._decode, lt, pos,
-                                self._samp_tuple())
-        out = self._enqueued(out)
-        counts = out.pop() if self.paged and self._counters else ()
+        out = self._enqueued(self._guarded(
+            self._decode, lt, pos, self._tables_device(),
+            self._samp_tuple()))
+        counts = out.pop() if self._counters else ()
         if self.logprobs_topn:
             self._last_logprobs = tuple(_np.asarray(a)
                                         for a in out.pop())
@@ -1752,10 +1486,9 @@ class GenerationEngine:
         cache, nxt = out
         self._cache = cache
         nxt = _np.asarray(nxt)
-        if self.paged:
-            held = _np.asarray([bool(b) for b in self._slot_blocks])
-            self._count_decode(counts, _np.asarray(positions, _np.int64)
-                               .reshape(-1), held.astype(_np.int64))
+        held = _np.asarray([bool(b) for b in self._slot_blocks])
+        self._count_decode(counts, _np.asarray(positions, _np.int64)
+                           .reshape(-1), held.astype(_np.int64))
         return nxt
 
     def decode_burst(self, last_tokens, positions, budgets, eos_ids,
@@ -1783,17 +1516,10 @@ class GenerationEngine:
         eos = jnp.asarray(_np.asarray(eos_ids, _np.int32).reshape(S))
         done0 = jnp.asarray(
             ~_np.asarray(active, bool).reshape(S))
-        if self.paged:
-            if self._tables_dev is None:
-                self._tables_dev = jnp.asarray(self._tables)
-            out = self._guarded(self._decode_burst, lt, pos, bud, eos,
-                                done0, self._tables_dev,
-                                self._samp_tuple())
-        else:
-            out = self._guarded(self._decode_burst, lt, pos, bud, eos,
-                                done0, self._samp_tuple())
-        out = self._enqueued(out)
-        counts = out.pop() if self.paged and self._counters else ()
+        out = self._enqueued(self._guarded(
+            self._decode_burst, lt, pos, bud, eos, done0,
+            self._tables_device(), self._samp_tuple()))
+        counts = out.pop() if self._counters else ()
         if self.logprobs_topn:          # (k, S, N) per burst step
             self._last_logprobs = tuple(_np.asarray(a)
                                         for a in out.pop())
@@ -1802,9 +1528,8 @@ class GenerationEngine:
         cache, toks, emitted = out
         self._cache = cache
         toks, emitted = _np.asarray(toks), _np.asarray(emitted)
-        if self.paged:
-            self._count_decode(counts, _np.asarray(positions, _np.int64)
-                               .reshape(-1), emitted.astype(_np.int64))
+        self._count_decode(counts, _np.asarray(positions, _np.int64)
+                           .reshape(-1), emitted.astype(_np.int64))
         return toks, emitted
 
     def _count_decode(self, counts, positions, steps) -> None:
@@ -1902,15 +1627,9 @@ class GenerationEngine:
         lt = jnp.asarray(toks)
         pos = jnp.asarray(_np.asarray(positions, _np.int32).reshape(
             self.max_slots))
-        if self.paged:
-            if self._tables_dev is None:
-                self._tables_dev = jnp.asarray(self._tables)
-            res = self._guarded(self._verify, lt, pos,
-                                self._tables_dev, self._samp_tuple())
-        else:
-            res = self._guarded(self._verify, lt, pos,
-                                self._samp_tuple())
-        res = self._enqueued(res)
+        res = self._enqueued(self._guarded(
+            self._verify, lt, pos, self._tables_device(),
+            self._samp_tuple()))
         if self.logprobs_topn:          # (S, Q, N) per verify
             cache, out, lp = res
             self._last_verify_logprobs = tuple(_np.asarray(a)
@@ -1952,9 +1671,9 @@ class GenerationEngine:
         ``[0, spec_k]`` counts the draft tokens accepted per slot.
         Rejected positions' K/V is rolled back: the cursor simply does
         not advance past them (stale entries are masked and then
-        overwritten by the next dispatch at the same position), and in
-        paged mode the pool's :meth:`~.kvcache.BlockPool.rewind` COW
-        guard keeps the overwrite out of any shared block."""
+        overwritten by the next dispatch at the same position), and the
+        pool's :meth:`~.kvcache.BlockPool.rewind` COW guard keeps the
+        overwrite out of any shared block."""
         if self.draft is None:
             raise MXNetError(f"{self.name}: no draft attached "
                              "(attach_draft first)")
@@ -1987,12 +1706,11 @@ class GenerationEngine:
         match = out[:, :k] == drafted                          # (S, k)
         accepted = _np.where(match.all(axis=1), k,
                              _np.argmin(match, axis=1))
-        if self.paged or self.draft.paged:
-            self._rollback_rejected(pos, accepted)
+        self._rollback_rejected(pos, accepted)
         return out, accepted
 
     def _rollback_rejected(self, base_positions, accepted) -> None:
-        """Paged rollback after a verify: for every slot that rejected
+        """Rollback after a verify: for every slot that rejected
         draft tokens, run the pool's COW guard over the dirty tail so
         the next dispatch's overwrites cannot touch a shared block.
         Block tables are per-slot operands, so a neighbor never observes
@@ -2002,7 +1720,7 @@ class GenerationEngine:
                 continue
             keep = int(base_positions[s]) + int(accepted[s]) + 1
             for eng in (self, self.draft):
-                if not eng.paged or not eng._slot_blocks[s]:
+                if not eng._slot_blocks[s]:
                     continue
                 blocks = eng._slot_blocks[s]
                 new = eng.pool.rewind(blocks, keep)
@@ -2013,15 +1731,13 @@ class GenerationEngine:
                     eng._tables[s] = row
                     eng._tables_dev = None
 
-    # -- paged-pool bookkeeping (no-ops in dense mode) -------------------
+    # -- block-pool bookkeeping ------------------------------------------
     def release_slot(self, slot: int) -> None:
         """Return ``slot``'s blocks to the pool (decref — shared prefix
         blocks stay live for their other readers / the prefix cache).
         Cascades to the draft engine's mirrored slot."""
         if self.draft is not None:
             self.draft.release_slot(slot)
-        if not self.paged:
-            return
         blocks = self._slot_blocks[int(slot)]
         if blocks:
             self.pool.release(blocks)
@@ -2033,16 +1749,13 @@ class GenerationEngine:
                   reserved_blocks: int = 0) -> bool:
         """Admission check: can the pool reserve worst-case capacity for
         this prompt right now?  ``reserved_blocks`` discounts capacity
-        promised to earlier admits in the same scheduling step.  Dense
-        mode always admits (capacity == slots there).  With a draft
-        attached both pools must fit the reservation (plus the spec_k
-        verify headroom)."""
+        promised to earlier admits in the same scheduling step.  With a
+        draft attached both pools must fit the reservation (plus the
+        spec_k verify headroom)."""
         if self.draft is not None and not self.draft.can_admit(
                 tokens, int(reserve_tokens) + self.spec_k,
                 reserved_blocks):
             return False
-        if not self.paged:
-            return True
         toks = _np.asarray(tokens, _np.int32).reshape(-1)
         n = int(toks.shape[0])
         reserve = int(reserve_tokens) \
@@ -2054,8 +1767,6 @@ class GenerationEngine:
         """Worst-case blocks a request reserving ``reserve_tokens``
         positions can take (no sharing assumed) — the scheduler's
         discount unit for multi-admit steps."""
-        if not self.paged:
-            return 0
         from .kvcache import blocks_for
         reserve = int(reserve_tokens) \
             + (self.spec_k if self.draft is not None else 0)
@@ -2064,27 +1775,19 @@ class GenerationEngine:
     def kv_capacity_tokens(self) -> int:
         """Total token positions the KV cache can hold across all
         requests — the backpressure unit for admission control."""
-        if self.paged:
-            return (self.num_blocks - 1) * self.block_size
-        return self.max_slots * self.max_len
+        return (self.num_blocks - 1) * self.block_size
 
     def kv_stats(self) -> dict:
         """Cache-utilization facts for ``GET /v1/models`` and
         ``stats()``."""
-        if not self.paged:
-            return {"kv_paged": False,
-                    "kv_capacity_tokens": self.kv_capacity_tokens()}
-        out = {"kv_paged": True,
-               "kv_capacity_tokens": self.kv_capacity_tokens()}
+        out = {"kv_capacity_tokens": self.kv_capacity_tokens()}
         out.update(self.pool.stats())
         return out
 
     def slot_occupancy(self) -> List[dict]:
-        """Per-slot KV occupancy (paged mode; ``[]`` dense): blocks held
-        and reserved token capacity per live slot — the flight-dump view
-        of who holds the pool when an OOM hits."""
-        if not self.paged:
-            return []
+        """Per-slot KV occupancy: blocks held and reserved token capacity
+        per live slot — the flight-dump view of who holds the pool when
+        an OOM hits."""
         out = []
         for slot, blocks in enumerate(self._slot_blocks):
             if blocks:
@@ -2106,7 +1809,6 @@ class GenerationEngine:
             "expected_programs": self.expected_programs,
             "compiled_programs": self.compiled_programs(),
             "warm": self.warm,
-            "paged": self.paged,
             "paged_attention": self._paged_attention,
             "pool_layout": self.pool_layout,
             "scan_steps": self.scan_steps,
@@ -2146,7 +1848,7 @@ class GenerationEngine:
                 self.prefill(_np.zeros(max(1, min(b, self.max_len - 1)),
                                        _np.int32), 0)
                 self.release_slot(0)
-            if self.paged and self.prefix_cache_enabled:
+            if self.prefix_cache_enabled:
                 # suffix programs take ctx/table as OPERANDS: one dummy
                 # dispatch per bucket (writes land in the null block)
                 row = jnp.zeros(self.max_blocks_per_slot, jnp.int32)
@@ -2195,13 +1897,11 @@ class GenerationEngine:
 
     def compiled_programs(self) -> int:
         try:
-            n = int(self._prefill_jit._cache_size()) \
+            return int(self._prefill_jit._cache_size()) \
+                + int(self._prefill_ext_jit._cache_size()) \
                 + int(self._decode_jit._cache_size()) \
                 + int(self._decode_burst_jit._cache_size()) \
                 + int(self._verify_jit._cache_size())
-            if self._prefill_ext_jit is not None:
-                n += int(self._prefill_ext_jit._cache_size())
-            return n
         except Exception:
             return 0
 

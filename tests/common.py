@@ -30,3 +30,16 @@ def with_seed(seed=None, retries=2):
             raise last
         return wrapper
     return deco
+
+
+def greedy_reference(net, prompt, max_new_tokens):
+    """The cache-free oracle of the serving tests: the greedy
+    continuation of ``prompt`` by ``net.generate(use_cache=False)``,
+    which re-runs the whole prefix at every step — no KV cache, no
+    engine, none of the serving programs."""
+    import incubator_mxnet_tpu as mx
+    ids = mx.nd.array(_np.asarray([prompt], _np.int32))
+    out = net.generate(ids, max_new_tokens=int(max_new_tokens),
+                       use_cache=False, temperature=0.0)
+    return [int(t) for t in
+            _np.asarray(out.asnumpy()).reshape(-1)[len(prompt):]]

@@ -253,11 +253,11 @@ def test_counters_reach_the_batcher_stats():
     assert eng.program_inventory()["paged_attention"] == "lax_gather"
 
 
-def test_dense_mode_refuses_what_is_not_a_gpt_block():
+def test_the_dense_mode_is_gone_for_this_block_too():
     cfg = _cfg()
     net = prog.build_net(cfg)
     prog.load_weights(net, ref.init_params(cfg, 1))
-    with pytest.raises(MXNetError, match="dense KV mode serves a GPT-style"):
+    with pytest.raises(MXNetError, match="dense KV mode is gone"):
         GenerationEngine(net, name="t", max_slots=2, max_len=64, paged=False)
 
 

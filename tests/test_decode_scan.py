@@ -1,6 +1,6 @@
 """Scanned decode-burst tests (docs/serving.md "Multi-token decode
 bursts"): greedy bit-parity of the k-step ``lax.scan`` burst against
-per-step decode across k x dense/paged x in-program termination
+the cache-free oracle across k x in-program termination
 (EOS-mid-burst, budget-cut-mid-burst), mid-flight join through the
 ``ContinuousBatcher``, the spec draft-scan, the closed-program-set
 contract, and a forced-Pallas parity run."""
@@ -8,6 +8,8 @@ import time
 
 import numpy as np
 import pytest
+
+from common import greedy_reference
 
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import fault, telemetry
@@ -42,35 +44,18 @@ def _gpt(max_length=64, seed=3):
 
 PROMPTS = ([9, 9, 4, 1], [3, 7, 11], [5, 2])
 
-# per-step continuations are deterministic per (seed, paged) — computed
-# once, shared by every k of the parity matrix to keep tier-1 cheap
-_REF_CACHE = {}
+# the continuations are deterministic per seed — computed once, shared
+# by every k of the parity matrix to keep tier-1 cheap
+_REF_CACHE = []
 
 
-def _per_step_reference(net, budget=24, max_len=64, paged=False):
-    """Ground truth: the per-step host loop, one decode dispatch per
-    token, no eos — each slot's full greedy continuation."""
-    if paged in _REF_CACHE:
-        return _REF_CACHE[paged]
-    kw = dict(paged=True, block_size=8) if paged else dict(paged=False)
-    eng = GenerationEngine(net, name="ref", max_slots=len(PROMPTS),
-                           max_len=max_len, scan_steps=0, **kw)
-    outs = [[] for _ in PROMPTS]
-    for s, p in enumerate(PROMPTS):
-        outs[s].append(eng.prefill(np.asarray(p, np.int32), s,
-                                   reserve_tokens=len(p) + budget))
-    S = eng.max_slots
-    for _ in range(budget - 1):
-        last = np.zeros(S, np.int32)
-        pos = np.zeros(S, np.int32)
-        for s, p in enumerate(PROMPTS):
-            last[s] = outs[s][-1]
-            pos[s] = len(p) + len(outs[s]) - 1
-        nxt = eng.decode(last, pos)
-        for s in range(S):
-            outs[s].append(int(nxt[s]))
-    _REF_CACHE[paged] = outs
-    return outs
+def _reference(net, budget=24):
+    """Ground truth: each prompt's full greedy continuation, no eos, by
+    the cache-free re-forward."""
+    if not _REF_CACHE:
+        _REF_CACHE.extend(greedy_reference(net, p, budget)
+                          for p in PROMPTS)
+    return _REF_CACHE
 
 
 def _truncate(ref, budget, eos_id):
@@ -134,13 +119,13 @@ def _eos_mid_burst(ref, k):
     return ref[1]
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
 @pytest.mark.parametrize("k", [1, 4, 8])
-def test_burst_parity_matrix(k, paged):
-    """k bursts x {dense, paged} x {budget-cut, EOS} mid-burst: every
-    emitted token bit-identical to the per-step loop."""
+def test_burst_parity_matrix(k):
+    """k bursts x {budget-cut, EOS} mid-burst: every emitted token
+    identical to the cache-free continuation, cut where the serving
+    contract cuts it."""
     net = _gpt()
-    ref = _per_step_reference(net, paged=paged)
+    ref = _reference(net)
     # slot 0: budget cut NOT on a burst boundary; slot 1: eos that
     # fires mid-burst; slot 2: plain short budget
     budgets = [k + 3 if k > 1 else 3, 24, 10]
@@ -148,9 +133,9 @@ def test_burst_parity_matrix(k, paged):
     expected = [_truncate(ref[s], budgets[s], eos_ids[s])
                 for s in range(len(PROMPTS))]
     assert len(expected[1]) < 24          # the eos really cut slot 1
-    kw = dict(paged=True, block_size=8) if paged else dict(paged=False)
     eng = GenerationEngine(net, name=f"scan{k}", scan_steps=k,
-                           max_slots=len(PROMPTS), max_len=64, **kw)
+                           max_slots=len(PROMPTS), max_len=64,
+                           block_size=8)
     got = _run_burst(eng, budgets, eos_ids)
     assert got == expected
     # lazy compilation stayed inside the closed AOT prediction
@@ -158,7 +143,6 @@ def test_burst_parity_matrix(k, paged):
     assert eng.compiled_programs() <= eng.expected_programs
 
 
-@pytest.mark.slow  # tier-1 budget rider: scan program-set closure stays in test_spec_draft_scan_parity_and_program_set
 def test_burst_program_joins_closed_set():
     # max_len=16 keeps the prefill bucket ladder (and so the warmup
     # compile bill) minimal — this test only counts programs

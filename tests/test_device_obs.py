@@ -1,6 +1,6 @@
 """Device-plane observability tests (docs/observability.md "Device
-plane"): the dispatch ledger's closed-program-set accounting for dense,
-paged, and speculative engines; the ``mxtpu_dispatches_per_token``
+plane"): the dispatch ledger's closed-program-set accounting for plain
+and speculative engines; the ``mxtpu_dispatches_per_token``
 dispatch-economy gauge (exactly 1.0 for plain decode, < 1.0 when a
 draft amortizes dispatches over accepted bursts); OOM forensics — an
 injected ``RESOURCE_EXHAUSTED`` dispatch failure produces exactly ONE
@@ -79,17 +79,18 @@ def _post(port, path, body=b"{}", timeout=30):
 
 
 # ------------------------------------------- closed-program-set ledger
-def test_closed_program_set_dense(monkeypatch):
-    eng = _engine(name="obsd", paged=False)
+def test_closed_program_set(monkeypatch):
+    eng = _engine(name="obsd")
     assert eng.warmup() == eng.expected_programs
     inv = eng.program_inventory()
-    assert inv["model"] == "obsd" and not inv["paged"]
+    assert inv["model"] == "obsd"
     assert inv["compiled_programs"] == inv["expected_programs"]
-    assert inv["slots"] == []                  # dense: no paged slots
+    assert inv["slots"] == []                  # warm-up left none live
     # every warmed program shows up as a ledger site; sites that never
     # dispatched (the verify wrapper on a draftless engine) sit at 0 —
     # that surplus-program visibility IS the inventory's point
     sites = telemetry.dispatch_ledger(prefix="serving:obsd:")
+    assert "serving:obsd:prefill_ext" in sites
     decode = sites["serving:obsd:decode"]
     assert decode["dispatches"] >= 1
     assert decode["last_dispatch_age_s"] is not None
@@ -101,7 +102,6 @@ def test_closed_program_set_dense(monkeypatch):
         eng.warmup()
 
 
-@pytest.mark.slow  # tier-1 budget rider: spec program-set closure stays in test_decode_scan, dpt contracts in device_obs_smoke + test_batcher_spec_stats_and_gauge
 def test_closed_program_set_spec_and_dispatches_per_token():
     tnet = _gpt()
     tgt = GenerationEngine(tnet, name="obst", max_slots=2, max_len=64)
@@ -109,7 +109,7 @@ def test_closed_program_set_spec_and_dispatches_per_token():
     tgt.attach_draft(drf, spec_k=4)            # draft IS the target:
     tgt.warmup()                               # accept rate 1
     inv = tgt.program_inventory()
-    assert inv["spec_k"] == 4 and inv["paged"]
+    assert inv["spec_k"] == 4
     assert inv["compiled_programs"] == inv["expected_programs"]
     assert inv["draft"]["model"] == "obsf"
     assert inv["draft"]["compiled_programs"] == \

@@ -1,5 +1,4 @@
-"""Generation serving tests: the decode-shaped attention entry point
-(lax fallback + interpret-mode Pallas parity), GenerationEngine's
+"""Generation serving tests: GenerationEngine's
 prefill/decode split against a full re-forward at every step, the
 ContinuousBatcher's per-slot join/leave machinery (mid-flight join,
 slot free on finish/cancel/deadline, watchdog restart mid-decode), the
@@ -13,10 +12,11 @@ import urllib.request
 import numpy as np
 import pytest
 
+from common import greedy_reference
+
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import fault, telemetry
 from incubator_mxnet_tpu.base import MXNetError
-from incubator_mxnet_tpu.kernels.flash_attention import decode_attention
 from incubator_mxnet_tpu.models.gpt import GPTModel
 from incubator_mxnet_tpu.serving import (Cancelled, ContinuousBatcher,
                                          DeadlineExceeded,
@@ -56,48 +56,6 @@ def _engine(max_slots=4, max_len=64, seed=3):
                                  max_len=max_len)
 
 
-def _ref_decode_attention(q, k, v, positions):
-    """numpy reference: per (slot, head) causal single-query attention
-    over cache rows <= position."""
-    S, H, D = q.shape
-    T = k.shape[2]
-    out = np.zeros((S, H, D), np.float32)
-    for s in range(S):
-        for h in range(H):
-            scores = (k[s, h] @ q[s, h]) / np.sqrt(D)      # (T,)
-            scores[np.arange(T) > positions[s]] = -np.inf
-            w = np.exp(scores - scores.max())
-            w /= w.sum()
-            out[s, h] = w @ v[s, h]
-    return out
-
-
-# ------------------------------------------------------ decode kernel
-def test_decode_attention_matches_reference():
-    rng = np.random.default_rng(0)
-    S, H, T, D = 4, 2, 128, 32
-    q = rng.standard_normal((S, H, D)).astype(np.float32)
-    k = rng.standard_normal((S, H, T, D)).astype(np.float32)
-    v = rng.standard_normal((S, H, T, D)).astype(np.float32)
-    pos = np.array([0, 5, 63, T - 1], np.int32)
-    got = np.asarray(decode_attention(q, k, v, pos))
-    ref = _ref_decode_attention(q, k, v, pos)
-    np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-5)
-
-
-def test_decode_attention_pallas_interpret(monkeypatch):
-    monkeypatch.setenv("MXNET_FA_DECODE_FORCE_PALLAS", "1")
-    rng = np.random.default_rng(1)
-    S, H, T, D = 2, 1, 128, 8
-    q = rng.standard_normal((S, H, D)).astype(np.float32)
-    k = rng.standard_normal((S, H, T, D)).astype(np.float32)
-    v = rng.standard_normal((S, H, T, D)).astype(np.float32)
-    pos = np.array([3, T - 1], np.int32)
-    got = np.asarray(decode_attention(q, k, v, pos))
-    ref = _ref_decode_attention(q, k, v, pos)
-    np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-5)
-
-
 def test_derive_prefill_buckets():
     assert derive_prefill_buckets(128) == (8, 16, 32, 64, 128)
     assert derive_prefill_buckets(48) == (8, 16, 32, 48)
@@ -107,7 +65,6 @@ def test_derive_prefill_buckets():
 
 
 # ------------------------------------------------------------- engine
-@pytest.mark.slow
 def test_prefill_decode_matches_full_reforward_every_step():
     """The cached path must reproduce a full re-forward of the growing
     context at EVERY decode step — one wrong K/V write or position
@@ -127,15 +84,10 @@ def test_prefill_decode_matches_full_reforward_every_step():
 def test_engine_generate_matches_net_generate():
     net, eng = _engine()
     for prompt in ([5, 2], [9, 9, 4, 1], [1]):
-        ref = net.generate(mx.nd.array(np.asarray([prompt], np.int32)),
-                           max_new_tokens=16, use_cache=False,
-                           temperature=0.0)
-        ref = [int(t) for t in
-               np.asarray(ref.asnumpy()).reshape(-1)[len(prompt):]]
-        assert eng.generate(prompt, max_new_tokens=16) == ref
+        assert eng.generate(prompt, max_new_tokens=16) \
+            == greedy_reference(net, prompt, 16)
 
 
-@pytest.mark.slow  # tier-1 budget rider: dense closed-set stays in test_device_obs::test_closed_program_set_dense
 def test_warmup_compiles_closed_program_set():
     _, eng = _engine()
     warmed = eng.warmup()

@@ -1,7 +1,7 @@
 """Paged KV-cache subsystem tests: BlockPool invariants (alloc / free /
-refcount / copy-on-write / LRU eviction), the paged GenerationEngine's
-token-for-token parity against the dense oracle (solo, prefix-hit, and
-mid-flight join through the ContinuousBatcher), prefix-cache FLOPs
+refcount / copy-on-write / LRU eviction), the GenerationEngine's
+token-for-token parity against the cache-free oracle (solo, prefix-hit,
+and mid-flight join through the ContinuousBatcher), prefix-cache FLOPs
 savings measured on the ``XLA_COST`` plane, the closed compiled-program
 set, pool-rewipe on ``reset()``, the paged Pallas gather's
 interpret-mode parity, and capacity backpressure on the HTTP surface
@@ -14,6 +14,8 @@ import urllib.request
 
 import numpy as np
 import pytest
+
+from common import greedy_reference
 
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import fault, telemetry
@@ -49,13 +51,12 @@ def _gpt(max_length=64, seed=3):
 
 
 def _pair(max_slots=4, max_len=64, seed=3, **paged_kw):
-    """One model, two engines: dense oracle + paged under test."""
+    """A model — whose cache-free ``greedy_reference`` is the oracle —
+    and the engine under test over it."""
     net = _gpt(max_length=max_len, seed=seed)
-    dense = GenerationEngine(net, name="dense", max_slots=max_slots,
-                             max_len=max_len, paged=False)
     paged = GenerationEngine(net, name="paged", max_slots=max_slots,
-                             max_len=max_len, paged=True, **paged_kw)
-    return net, dense, paged
+                             max_len=max_len, **paged_kw)
+    return net, paged
 
 
 # ------------------------------------------------------ pool invariants
@@ -229,22 +230,21 @@ def test_prefix_keys_are_collision_resistant():
     pool.release(t2)
 
 
-# ------------------------------------------------- paged vs dense parity
+# ------------------------------------------- paged vs cache-free parity
 def test_paged_solo_parity_token_for_token():
-    _, dense, paged = _pair()
+    net, paged = _pair()
     for prompt in ([9, 9, 4, 1], [3, 7, 11], list(range(1, 20)),
                    [2] * 33, [5] * 40):
-        want = dense.generate(prompt, max_new_tokens=20)
+        want = greedy_reference(net, prompt, 20)
         got = paged.generate(prompt, max_new_tokens=20)
         assert got == want, prompt
-        dense.reset()
         paged.reset()
 
 
 def test_paged_prefix_hit_parity_and_sharing():
-    _, dense, paged = _pair()
+    net, paged = _pair()
     prompt = [5] * 40
-    want = dense.generate(prompt, max_new_tokens=12)
+    want = greedy_reference(net, prompt, 12)
     first = paged.generate(prompt, max_new_tokens=12)
     hits0 = paged.pool.hits
     second = paged.generate(prompt, max_new_tokens=12)  # through the cache
@@ -254,11 +254,9 @@ def test_paged_prefix_hit_parity_and_sharing():
 
 
 def test_paged_midflight_join_parity():
-    _, dense, paged = _pair()
-    solo_a = dense.generate([9, 9, 4, 1], max_new_tokens=30)
-    dense.reset()
-    solo_b = dense.generate([3, 7, 11], max_new_tokens=8)
-    dense.reset()
+    net, paged = _pair()
+    solo_a = greedy_reference(net, [9, 9, 4, 1], 30)
+    solo_b = greedy_reference(net, [3, 7, 11], 8)
     bat = ContinuousBatcher(paged, name="paged")
     try:
         ra = bat.submit_async([9, 9, 4, 1], max_new_tokens=30)
@@ -273,12 +271,12 @@ def test_paged_midflight_join_parity():
         bat.close()
 
 
-@pytest.mark.slow
 def test_closed_program_set_survives_hits_and_joins():
-    _, _, paged = _pair()
+    _, paged = _pair()
     warmed = paged.warmup()
+    # a miss and a hit prefill per bucket, decode, burst
     assert warmed == paged.expected_programs \
-        == 2 * len(paged.prefill_buckets) + 1
+        == 2 * len(paged.prefill_buckets) + 2
     n = paged.compiled_programs()
     paged.generate([4, 4, 4], max_new_tokens=8)
     paged.generate([2] * 17, max_new_tokens=8)
@@ -299,9 +297,9 @@ def test_failed_prefill_does_not_poison_prefix_cache(monkeypatch):
     # prefill dispatch runs; if that dispatch fails, the never-written
     # blocks must be unregistered or a later same-prefix request would
     # "hit" blocks holding garbage K/V.
-    _, dense, paged = _pair()
+    net, paged = _pair()
     prompt = [5] * 40
-    want = dense.generate(prompt, max_new_tokens=8)
+    want = greedy_reference(net, prompt, 8)
 
     def boom(*a, **kw):
         raise RuntimeError("injected prefill failure")
@@ -318,9 +316,8 @@ def test_failed_prefill_does_not_poison_prefix_cache(monkeypatch):
 
 
 # -------------------------------------------- prefix cache saves prefill
-@pytest.mark.slow
 def test_prefix_hit_cuts_prefill_flops():
-    _, _, paged = _pair()
+    _, paged = _pair()
     events = []
 
     def on_cost(**kw):
@@ -349,24 +346,19 @@ def test_prefix_hit_cuts_prefill_flops():
 
 # ------------------------------------------------- engine-level eviction
 def test_engine_eviction_under_pressure_stays_correct():
-    net = _gpt()
-    dense = GenerationEngine(net, name="dense", paged=False,
-                             max_slots=2, max_len=64)
     # 5 blocks = 80 tokens: one 40-token request + cached leftovers
     # force LRU eviction on the next distinct prompt
-    paged = GenerationEngine(net, name="paged", paged=True,
-                             max_slots=2, max_len=64, num_blocks=6)
+    net, paged = _pair(max_slots=2, num_blocks=6)
     prompts = [[5] * 40, [9] * 40, [3] * 40, [5] * 40]
     for p in prompts:
-        want = dense.generate(p, max_new_tokens=8)
-        dense.reset()
-        assert paged.generate(p, max_new_tokens=8) == want, p
+        assert paged.generate(p, max_new_tokens=8) \
+            == greedy_reference(net, p, 8), p
     assert paged.pool.evictions > 0
 
 
 # ----------------------------------------------------- reset rewipes all
 def test_reset_rewipes_tables_pool_and_prefix_cache():
-    _, _, paged = _pair()
+    _, paged = _pair()
     paged.generate([5] * 40, max_new_tokens=8)
     paged.generate([5] * 40, max_new_tokens=8)
     assert paged.pool.hits > 0
@@ -386,7 +378,7 @@ def test_reset_rewipes_tables_pool_and_prefix_cache():
 
 def test_watchdog_restart_rewipes_pool():
     from incubator_mxnet_tpu.serving import CircuitBreaker
-    _, _, paged = _pair(max_slots=2, max_len=128)
+    _, paged = _pair(max_slots=2, max_len=128)
     # short breaker cooldown so the post-restart probe is admitted
     bat = ContinuousBatcher(paged, name="paged",
                             breaker=CircuitBreaker("paged",
@@ -454,14 +446,10 @@ def test_paged_pallas_kernel_interpret_parity(monkeypatch):
 
 def test_paged_engine_parity_with_forced_pallas_decode(monkeypatch):
     monkeypatch.setenv("MXNET_FA_DECODE_FORCE_PALLAS", "1")
-    net = _gpt()
-    paged = GenerationEngine(net, name="paged", paged=True,
-                             max_slots=2, max_len=64)
-    monkeypatch.delenv("MXNET_FA_DECODE_FORCE_PALLAS")
-    dense = GenerationEngine(net, name="dense", paged=False,
-                             max_slots=2, max_len=64)
-    want = dense.generate([3, 7, 11], max_new_tokens=8)
+    net, paged = _pair(max_slots=2)
+    want = greedy_reference(net, [3, 7, 11], 8)
     got = paged.generate([3, 7, 11], max_new_tokens=8)
+    assert paged.program_inventory()["paged_attention"] == "pallas"
     # interpreted-kernel fp differs from lax at the ulp level; greedy
     # argmax must still agree token-for-token
     assert got == want
@@ -515,7 +503,6 @@ def test_http_429_retry_after_on_pool_exhaustion():
         stats = json.load(urllib.request.urlopen(
             f"http://127.0.0.1:{srv.port}/v1/models", timeout=10))
         g = stats["models"]["g"]
-        assert g["kv_paged"] is True
         assert g["kv_blocks_total"] == 4
         assert "kv_utilization" in g
     finally:
@@ -523,11 +510,14 @@ def test_http_429_retry_after_on_pool_exhaustion():
         srv.stop()
 
 
-def test_dense_fallback_env(monkeypatch):
-    monkeypatch.setenv("MXNET_KV_PAGED", "0")
+def test_the_dense_mode_is_gone():
+    """The constructor keeps ``paged`` only until benchmark/chip stops
+    passing it: True and None mean the one mode there is, False is
+    refused."""
     net = _gpt()
-    eng = GenerationEngine(net, name="g", max_slots=2, max_len=64)
-    assert eng.paged is False
-    assert eng.pool is None
-    out = eng.generate([3, 7, 11], max_new_tokens=5)
-    assert len(out) == 5
+    with pytest.raises(MXNetError, match="dense KV mode is gone"):
+        GenerationEngine(net, name="g", max_slots=2, max_len=64,
+                         paged=False)
+    eng = GenerationEngine(net, name="g", max_slots=2, max_len=64,
+                           paged=True)
+    assert eng.pool is not None and "kv_blocks_total" in eng.kv_stats()

@@ -181,6 +181,3 @@ def test_inventory_reports_the_default_on_the_cpu():
     inv = eng.program_inventory()
     assert inv["pool_layout"] == "default"
     assert inv["draft"]["pool_layout"] == "default"
-    dense = GenerationEngine(_gpt(3), name="inv-dense", max_slots=2,
-                             max_len=64, paged=False)
-    assert dense.program_inventory()["pool_layout"] == "default"

@@ -1,6 +1,6 @@
 """Sampling-plane tests (docs/serving.md "Sampling"): seeded
-bit-identity across temperature/top-k/top-p x dense/paged x
-per-step/burst x spec-on/off, temperature->0 greedy parity,
+bit-identity across temperature/top-k/top-p x per-step/burst x
+spec-on/off, temperature->0 greedy parity with the cache-free oracle,
 Gumbel-coupled speculative sampling preserving the no-draft sampled
 stream bit-for-bit, per-token logprobs, multi-token stop sequences,
 JSON-mode constrained output, n>1 candidate fan-out, and the seed
@@ -11,6 +11,8 @@ import urllib.request
 
 import numpy as np
 import pytest
+
+from common import greedy_reference
 
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import fault, telemetry
@@ -56,16 +58,9 @@ def _net():
 
 
 @pytest.fixture(scope="module")
-def dense_eng(_net):
-    return GenerationEngine(_net, name="smp-d", max_slots=2, max_len=64,
-                            paged=False, prefix_cache=False,
-                            scan_steps=4, logprobs_topn=3)
-
-
-@pytest.fixture(scope="module")
 def paged_eng(_net):
     return GenerationEngine(_net, name="smp-p", max_slots=2, max_len=64,
-                            paged=True, block_size=8, prefix_cache=False,
+                            block_size=8, prefix_cache=False,
                             scan_steps=4, logprobs_topn=3)
 
 
@@ -177,11 +172,9 @@ def _burst_run(eng, prompt, budget, sp):
     return out
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_seeded_bit_identity_and_burst_parity(paged, dense_eng,
-                                              paged_eng):
-    eng = paged_eng if paged else dense_eng
-    greedy = eng.generate(PROMPT, 12)
+def test_seeded_bit_identity_and_burst_parity(paged_eng, _net):
+    eng = paged_eng
+    greedy = greedy_reference(_net, PROMPT, 12)
     assert eng.generate(PROMPT, 12) == greedy
     for sp in MATRIX:
         s1 = eng.generate(PROMPT, 12, sampling=sp)
@@ -203,27 +196,18 @@ def test_seeded_bit_identity_and_burst_parity(paged, dense_eng,
     assert eng.compiled_programs() <= eng.expected_programs
 
 
-def test_dense_paged_same_key_stream(dense_eng, paged_eng):
-    """The keyed Gumbel stream depends on (seed, position) only — the
-    cache layout must not leak into sampled output."""
-    sp = SamplingParams(temperature=0.8, top_k=10, seed=21)
-    assert dense_eng.generate(PROMPT, 10, sampling=sp) \
-        == paged_eng.generate(PROMPT, 10, sampling=sp)
-
-
 def test_spec_bit_identical_to_solo_sampled(_net):
     """Distribution preservation, in its strongest form: with the
     draft sampling the SAME keyed stream, every spec-emitted token
     equals the no-draft sampled run's token at any accept rate."""
     tgt = GenerationEngine(_net, name="smp-st", max_slots=2, max_len=64,
-                           paged=True, block_size=8, prefix_cache=False,
-                           scan_steps=0)
+                           block_size=8, prefix_cache=False, scan_steps=0)
     dr = GenerationEngine(_gpt(seed=5), name="smp-sd", max_slots=2,
-                          max_len=64, paged=True, block_size=8,
+                          max_len=64, block_size=8,
                           prefix_cache=False, scan_steps=0)
     tgt.attach_draft(dr, spec_k=3)
     solo = GenerationEngine(_net, name="smp-ss", max_slots=2,
-                            max_len=64, paged=True, block_size=8,
+                            max_len=64, block_size=8,
                             prefix_cache=False, scan_steps=0)
     for sp in (SamplingParams(temperature=0.9, top_p=0.95, seed=1234),
                SamplingParams(temperature=0.7, seed=7),
@@ -234,7 +218,7 @@ def test_spec_bit_identical_to_solo_sampled(_net):
     assert tgt.generate(PROMPT, 12) == solo.generate(PROMPT, 12)
 
 
-def test_first_token_frequency_matches_model(dense_eng, _net):
+def test_first_token_frequency_matches_model(paged_eng, _net):
     """Seed-averaged frequency test: the sampled first token's
     empirical distribution tracks the model's temperature-1 softmax."""
     logits = _net(mx.nd.array(np.asarray([PROMPT], np.int32)))
@@ -244,7 +228,7 @@ def test_first_token_frequency_matches_model(dense_eng, _net):
     n = 48
     counts = np.zeros(p.size)
     for seed in range(n):
-        tok = dense_eng.generate(PROMPT, 1, sampling=SamplingParams(
+        tok = paged_eng.generate(PROMPT, 1, sampling=SamplingParams(
             temperature=1.0, seed=seed))[0]
         counts[tok] += 1
     emp = counts / n
@@ -301,11 +285,16 @@ def test_batcher_stop_sequence_trims_burst(paged_eng):
         sp = SamplingParams(temperature=0.8, seed=11)
         base = b.submit(PROMPT, 16, sampling=sp)
         stop = tuple(base[2:4])
+        # where the stop is first complete in the sampled stream (the
+        # pair may already occur before index 2)
+        end = next(i for i in range(2, len(base) + 1)
+                   if tuple(base[i - 2:i]) == stop)
+        assert end <= 4
         got = b.submit(PROMPT, 16, sampling=SamplingParams(
             temperature=0.8, seed=11, stop=(stop,)))
         # stop sequence itself stays; the over-generated tail (the
         # burst ran past it) is discarded host-side
-        assert got == base[:4]
+        assert got == base[:end]
         st = b.stats()
         assert st["stop_hits"] >= 1
         assert st["slots_in_use"] == 0
@@ -334,7 +323,7 @@ def test_batcher_n_fanout_slot_accounting(paged_eng):
 
 def test_json_mode_output_parses():
     eng = GenerationEngine(_gpt(vocab=128, seed=7), name="smp-j",
-                           max_slots=2, max_len=64, paged=False,
+                           max_slots=2, max_len=64,
                            prefix_cache=False, scan_steps=4)
     b = ContinuousBatcher(eng, name="smp-j")
     try:

@@ -1,18 +1,19 @@
 """Speculative decoding tests: exact greedy draft-verify acceptance
-(bit-identical to plain decode at accept-rate 1, accept-rate 0, and in
-between), the spec x paged x prefix-cache x mid-flight-join matrix
+(bit-identical to the cache-free oracle at accept-rate 1, accept-rate
+0, and in between), the spec x prefix-cache x mid-flight-join matrix
 through the ContinuousBatcher (a joining stream must not observe a
 neighbor's rejected-token rollback), ``BlockPool.rewind``'s
 refcount/COW safety, the closed compiled-program set (verify adds
-exactly ONE program), the k-wide verify kernel's forced-Pallas
-interpret parity, per-request accepted/draft token accounting on the
-HTTP surface, and ``ModelServer.preload``."""
+exactly ONE program), the k-wide verify kernel interpreted under the
+engine, per-request accepted/draft token accounting on the HTTP
+surface, and ``ModelServer.preload``."""
 import json
-import sys
 import urllib.request
 
 import numpy as np
 import pytest
+
+from common import greedy_reference
 
 import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import fault, telemetry
@@ -46,8 +47,7 @@ def _gpt(max_length=64, seed=3, units=32, hidden=64, layers=2, heads=2):
     return net
 
 
-def _spec_pair(paged, max_slots=2, max_len=64, spec_k=4,
-               draft_seed=3, **kw):
+def _spec_pair(max_slots=2, max_len=64, spec_k=4, draft_seed=3, **kw):
     """Target + attached draft over the same slot geometry.  With
     ``draft_seed=3`` the draft IS the target (accept rate 1); any other
     seed gives an honest independent draft."""
@@ -55,17 +55,39 @@ def _spec_pair(paged, max_slots=2, max_len=64, spec_k=4,
     dnet = tnet if draft_seed == 3 else _gpt(max_length=max_len,
                                              seed=draft_seed)
     tgt = GenerationEngine(tnet, name="tgt", max_slots=max_slots,
-                           max_len=max_len, paged=paged, **kw)
+                           max_len=max_len, **kw)
     drf = GenerationEngine(dnet, name="drf", max_slots=max_slots,
-                           max_len=max_len, paged=paged, **kw)
+                           max_len=max_len, **kw)
     tgt.attach_draft(drf, spec_k=spec_k)
     return tgt
 
 
 def _golden(prompts, max_new=12, max_len=64):
-    eng = GenerationEngine(_gpt(max_length=max_len), name="golden",
-                           max_slots=1, max_len=max_len, paged=False)
-    return [eng.generate(p, max_new_tokens=max_new) for p in prompts]
+    """What the target must emit whatever the draft proposes: the
+    cache-free greedy continuation of the target's own weights."""
+    net = _gpt(max_length=max_len)
+    return [greedy_reference(net, p, max_new) for p in prompts]
+
+
+def _contrarian_draft(eng):
+    """Make ``eng``'s draft always propose a token the target will NOT
+    pick next (its own sample, perturbed) -> accept rate 0, every step
+    emits exactly the target's bonus token and rolls the rest back.
+    Returns the list the perturbed dispatches are counted in."""
+    calls = []
+    real_decode, real_burst = eng.draft.decode, eng.draft.decode_burst
+
+    def decode(last, pos):
+        calls.append("decode")
+        return (np.asarray(real_decode(last, pos)) + 1) % 50
+
+    def decode_burst(*a, **kw):
+        calls.append("burst")
+        toks, emitted = real_burst(*a, **kw)
+        return (toks + 1) % 50, emitted
+
+    eng.draft.decode, eng.draft.decode_burst = decode, decode_burst
+    return calls
 
 
 PROMPTS = [[3, 7, 11, 2], [5, 5, 9], [1, 2, 3, 4, 5, 6]]
@@ -129,77 +151,61 @@ def test_rewind_refuses_cow_of_kept_positions():
 
 
 # ============================================ exact acceptance, engine
-@pytest.mark.slow  # tier-1 budget rider: spec bitwise parity stays covered by test_sampling (greedy is the T=0 row of its spec matrix) + test_decode_scan's spec parity
-@pytest.mark.parametrize("paged", [False, True])
-def test_accept_rate_one_bitwise_identical(paged):
+def test_accept_rate_one_bitwise_identical():
     golden = _golden(PROMPTS)
-    eng = _spec_pair(paged, max_slots=2)    # draft == target weights
+    eng = _spec_pair(max_slots=2)           # draft == target weights
     for p, g in zip(PROMPTS, golden):
         assert eng.generate(p, max_new_tokens=12, speculative=True) == g
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("paged", [False, True])
-def test_adversarial_draft_still_bitwise_identical(paged):
+def test_adversarial_draft_still_bitwise_identical():
     golden = _golden(PROMPTS)
-    eng = _spec_pair(paged, max_slots=2)
-    # adversarial draft: always proposes a token the target will NOT
-    # pick next (perturb the real argmax) -> accept rate 0, every step
-    # emits exactly the target's bonus token
-    real_decode = eng.draft.decode
-
-    def contrarian(last, pos):
-        out = np.asarray(real_decode(last, pos))
-        return (out + 1) % 50
-
-    eng.draft.decode = contrarian
+    eng = _spec_pair(max_slots=2)
+    calls = _contrarian_draft(eng)
     for p, g in zip(PROMPTS, golden):
         assert eng.generate(p, max_new_tokens=12, speculative=True) == g
+    # one draft dispatch per emitted token after the first: nothing the
+    # draft proposed was ever accepted
+    assert len(calls) == len(PROMPTS) * 11
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("paged", [False, True])
-def test_independent_draft_bitwise_identical(paged):
+def test_independent_draft_bitwise_identical():
     golden = _golden(PROMPTS)
-    eng = _spec_pair(paged, max_slots=2, draft_seed=7)
+    eng = _spec_pair(max_slots=2, draft_seed=7)
     for p, g in zip(PROMPTS, golden):
         assert eng.generate(p, max_new_tokens=12, speculative=True) == g
 
 
 def test_attach_draft_validations():
-    tgt = GenerationEngine(_gpt(), name="t", max_slots=2, max_len=64,
-                           paged=False)
+    tgt = GenerationEngine(_gpt(), name="t", max_slots=2, max_len=64)
     with pytest.raises(MXNetError):
         tgt.attach_draft(tgt)               # cannot draft itself
     small = GenerationEngine(_gpt(seed=5), name="d", max_slots=1,
-                             max_len=64, paged=False)
+                             max_len=64)
     with pytest.raises(MXNetError):
         tgt.attach_draft(small)             # slot mismatch
     short = GenerationEngine(_gpt(max_length=32, seed=5), name="d2",
-                             max_slots=2, max_len=32, paged=False)
+                             max_slots=2, max_len=32)
     with pytest.raises(MXNetError):
         tgt.attach_draft(short)             # draft max_len too small
     ok = GenerationEngine(_gpt(seed=5), name="d3", max_slots=2,
-                          max_len=64, paged=False)
+                          max_len=64)
     with pytest.raises(MXNetError):
         tgt.attach_draft(ok, spec_k=0)      # k must be >= 1
 
 
 def test_spec_k_env_default(monkeypatch):
     monkeypatch.setenv("MXNET_SPEC_K", "2")
-    tgt = GenerationEngine(_gpt(), name="t", max_slots=2, max_len=64,
-                           paged=False)
+    tgt = GenerationEngine(_gpt(), name="t", max_slots=2, max_len=64)
     drf = GenerationEngine(_gpt(seed=5), name="d", max_slots=2,
-                           max_len=64, paged=False)
+                           max_len=64)
     tgt.attach_draft(drf)
     assert tgt.spec_k == 2
 
 
 # ====================================== closed compiled-program set
-@pytest.mark.slow  # program-set closure stays tier-1 via test_decode_scan::test_spec_draft_scan_parity_and_program_set
-@pytest.mark.parametrize("paged", [False, True])
-def test_verify_adds_exactly_one_program(paged):
-    eng = _spec_pair(paged, max_slots=2)
+def test_verify_adds_exactly_one_program():
+    eng = _spec_pair(max_slots=2)
     eng.warmup()
     assert eng.compiled_programs() == eng.expected_programs
     before = eng.compiled_programs()
@@ -209,20 +215,18 @@ def test_verify_adds_exactly_one_program(paged):
     assert eng.compiled_programs() == before    # no per-accept recompile
     # detaching nothing: a plain engine's expectation is one fewer
     plain = GenerationEngine(_gpt(), name="plain", max_slots=2,
-                             max_len=64, paged=paged)
+                             max_len=64)
     assert eng.expected_programs == plain.expected_programs + 1
 
 
-# ============================= batcher matrix: spec x paged x prefix x join
-@pytest.mark.parametrize("paged", [
-    False, pytest.param(True, marks=pytest.mark.slow)])
-def test_spec_batcher_matrix_mid_flight_joins(paged):
+# ===================================== batcher matrix: spec x prefix x join
+def test_spec_batcher_matrix_mid_flight_joins():
     import threading
     import time as _time
     system = list(range(1, 33))             # 32-token shared prefix
     prompts = [system + [40 + i] for i in range(4)]
     golden = _golden(prompts, max_new=10)
-    eng = _spec_pair(paged, max_slots=2)    # 2 slots, 4 requests: the
+    eng = _spec_pair(max_slots=2)           # 2 slots, 4 requests: the
     bat = ContinuousBatcher(eng, name="t")  # later two join mid-flight
     outs = [None] * 4
     errs = []
@@ -250,26 +254,18 @@ def test_spec_batcher_matrix_mid_flight_joins(paged):
         toks, acc, drafted = outs[i]
         assert toks == golden[i], (i, toks, golden[i])
         assert drafted >= acc >= 0
-    st = eng.pool.stats() if paged else {}
-    if paged:
-        assert st["prefix_cache_hits"] > 0  # matrix includes prefix hits
+    # the matrix includes prefix hits
+    assert eng.pool.stats()["prefix_cache_hits"] > 0
 
 
-@pytest.mark.slow  # join-under-rollback stays tier-1 via test_spec_batcher_matrix_mid_flight_joins
 def test_joining_stream_unaffected_by_neighbor_rollback():
     """Slot A runs an adversarial draft (rollback EVERY step) while B
     joins mid-flight; B's stream must equal the plain golden."""
     import threading
     import time as _time
     golden = _golden(PROMPTS, max_new=12)
-    eng = _spec_pair(True, max_slots=2)
-    real_decode = eng.draft.decode
-
-    def contrarian(last, pos):
-        out = np.asarray(real_decode(last, pos))
-        return (out + 1) % 50
-
-    eng.draft.decode = contrarian           # accept rate 0 everywhere
+    eng = _spec_pair(max_slots=2)
+    calls = _contrarian_draft(eng)          # accept rate 0 everywhere
     bat = ContinuousBatcher(eng, name="t")
     outs = [None, None]
     errs = []
@@ -294,12 +290,12 @@ def test_joining_stream_unaffected_by_neighbor_rollback():
     assert not errs, errs
     assert outs[0] == golden[0]
     assert outs[1] == golden[1]
-    assert eng.pool.rewinds >= 0            # rollback path exercised
+    assert calls                            # rollback path exercised
 
 
 def test_batcher_spec_stats_and_gauge():
     from incubator_mxnet_tpu.serving import metrics as _m
-    eng = _spec_pair(True, max_slots=2)
+    eng = _spec_pair(max_slots=2)
     bat = ContinuousBatcher(eng, name="t")
     try:
         req = bat.submit_async(PROMPTS[0], max_new_tokens=12)
@@ -316,42 +312,21 @@ def test_batcher_spec_stats_and_gauge():
 
 
 # ==================================== k-wide verify kernel, forced Pallas
-def test_verify_kernel_forced_pallas_interpret_parity(monkeypatch):
-    import importlib
-    fa = sys.modules.get(
-        "incubator_mxnet_tpu.kernels.flash_attention") \
-        or importlib.import_module(
-            "incubator_mxnet_tpu.kernels.flash_attention")
-    import jax.numpy as jnp
-    rng = np.random.default_rng(0)
-    S, H, T, D, Q = 2, 2, 128, 32, 5
-    q = jnp.asarray(rng.standard_normal((S, H, Q, D)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((S, H, T, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((S, H, T, D)), jnp.float32)
-    pos = jnp.asarray([7, 60], jnp.int32)
-    ref = np.asarray(fa._xla_verify_decode_attention(
-        q, k, v, pos, scale=0.25))
-    monkeypatch.setenv("MXNET_FA_DECODE_FORCE_PALLAS", "1")
-    out = np.asarray(fa.verify_decode_attention(q, k, v, pos,
-                                                scale=0.25))
-    np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
-
-
-@pytest.mark.slow  # op-level verify kernel parity stays tier-1 in test_flash_attention
 def test_paged_engine_parity_with_forced_pallas_verify(monkeypatch):
     golden = _golden(PROMPTS)
     monkeypatch.setenv("MXNET_FA_DECODE_FORCE_PALLAS", "1")
     # block_size 8 (divisible by 8) keeps the paged kernel's alignment
     # gate open so the interpreted Pallas path actually runs
-    eng = _spec_pair(True, max_slots=2, block_size=8)
+    eng = _spec_pair(max_slots=2, block_size=8)
     for p, g in zip(PROMPTS, golden):
         assert eng.generate(p, max_new_tokens=12, speculative=True) == g
+    assert eng.program_inventory()["paged_attention"] == "pallas"
 
 
 # =========================================== HTTP surface + preload
-@pytest.mark.slow
+@pytest.mark.slow  # 64 s alone: preload compiles both whole program sets
 def test_http_spec_fields_and_preload():
-    eng = _spec_pair(True, max_slots=2)
+    eng = _spec_pair(max_slots=2)
     srv = ModelServer(port=0)
     srv.add_model("g", eng)
     srv.preload()                           # warm BEFORE binding

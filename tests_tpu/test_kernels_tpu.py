@@ -55,20 +55,6 @@ def _paged(rng):
     return kp, vp, tables
 
 
-def test_decode_dense_matches_lax():
-    rng = onp.random.default_rng(0)
-    q, k, v = _rand(rng, (S, H, D)), _rand(rng, (S, H, T, D)), \
-        _rand(rng, (S, H, T, D))
-    pos = _positions()
-    got = jax.jit(lambda *a: fa._decode_pallas(*a, SCALE, False))(
-        q, k, v, pos)
-    ref = _ref(fa._xla_decode_attention, q, k, v, pos, SCALE)
-    onp.testing.assert_allclose(onp.asarray(got), ref, **TOL)
-    # on the chip the public dense entry point takes this kernel by default
-    pub = fa.decode_attention(q, k, v, pos, scale=SCALE)
-    onp.testing.assert_allclose(onp.asarray(pub), ref, **TOL)
-
-
 def test_decode_paged_matches_lax():
     rng = onp.random.default_rng(1)
     q = _rand(rng, (S, H, D))
@@ -80,18 +66,6 @@ def test_decode_paged_matches_lax():
     pub = jax.jit(lambda *a: fa.paged_decode_attention(*a, scale=SCALE))(
         q, kp, vp, tables, pos)
     onp.testing.assert_allclose(onp.asarray(pub), ref, rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.parametrize("n_q", [5])
-def test_verify_dense_matches_lax(n_q):
-    rng = onp.random.default_rng(2)
-    q, k, v = _rand(rng, (S, H, n_q, D)), _rand(rng, (S, H, T, D)), \
-        _rand(rng, (S, H, T, D))
-    pos = jnp.minimum(_positions(), T - n_q)
-    got = jax.jit(lambda *a: fa._verify_pallas(*a, SCALE, False))(
-        q, k, v, pos)
-    ref = _ref(fa._xla_verify_decode_attention, q, k, v, pos, SCALE)
-    onp.testing.assert_allclose(onp.asarray(got), ref, **TOL)
 
 
 @pytest.mark.parametrize("n_q", [5])
