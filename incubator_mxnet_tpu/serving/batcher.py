@@ -1907,6 +1907,9 @@ class ContinuousBatcher(DynamicBatcher):
             dc = getattr(self.engine, "decode_counters", None)
             if dc is not None:
                 out.update(dc())
+            ops = getattr(self.engine, "operand_sources", None)
+            if ops is not None:
+                out["operands"] = ops()
             if self._decode_health_last is not None:
                 out["decode_health"] = dict(self._decode_health_last)
                 out["nonfinite_generations"] = \

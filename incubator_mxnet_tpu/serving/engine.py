@@ -26,6 +26,7 @@ The jit is wrapped in :func:`telemetry.instrument_jit` under
 """
 from __future__ import annotations
 
+import contextlib
 import warnings
 import weakref
 from typing import Callable, List, Optional, Sequence, Tuple
@@ -43,6 +44,16 @@ from . import metrics as _m
 
 __all__ = ["InferenceEngine", "GenerationEngine", "derive_buckets",
            "derive_prefill_buckets"]
+
+
+@contextlib.contextmanager
+def _donating():
+    """Around a call that donates buffers: donation is advisory on the
+    CPU, which says so on every call."""
+    with warnings.catch_warnings():
+        warnings.filterwarnings(
+            "ignore", message="Some donated buffers were not usable")
+        yield
 
 
 def derive_buckets(max_batch_size: int) -> Tuple[int, ...]:
@@ -215,11 +226,7 @@ class InferenceEngine:
             with _telemetry.trace_span("serve.infer", cat="serving",
                                        model=self.name,
                                        batch=int(in_vals[0].shape[0])):
-                # donation is advisory on CPU; silence the per-call notice
-                with warnings.catch_warnings():
-                    warnings.filterwarnings(
-                        "ignore",
-                        message="Some donated buffers were not usable")
+                with _donating():
                     return self._call(in_vals, tuple(param_vals),
                                       tuple(aux_vals), key)
         except Exception as e:
@@ -462,6 +469,21 @@ def derive_prefill_buckets(max_len: int, smallest: int = 8):
     return tuple(out)
 
 
+# Columns of a slot's row in the slot state (GenerationEngine, "the slot
+# state"); the slot's block table follows from _TABLE on.  Temperature
+# and top-p ride as their float32 bit patterns, the root key as its two
+# uint32 words, so that a row is ONE int32 vector.
+_LAST, _POS, _BUDGET, _EOS, _DONE, _TOPK, _TEMP, _TOPP, _KEY0, _KEY1 = \
+    range(10)
+_TABLE = 10
+#: what the dispatch columns (_LAST.._DONE) of a free slot hold: the
+#: values the batcher passes for a slot without a request
+_FREE = (0, 0, 1, -1, 1)
+#: slot rows one dispatch of the row edit writes (fewer are padded by
+#: repeating the first)
+_EDIT_ROWS = 4
+
+
 class GenerationEngine:
     """Autoregressive generation as a closed set of compiled programs
     over a PREALLOCATED paged KV cache: per layer one K and one V pool of
@@ -494,6 +516,20 @@ class GenerationEngine:
     budget) / block_size)`` blocks, so a byte budget admits many more
     concurrent streams than ``max_len`` rows would, and full prompt
     blocks are shared across requests via the pool's prefix cache.
+
+    **The slot state.**  What a program reads per slot — last token,
+    position, remaining budget, stop id, ``done``, block table row,
+    temperature, top-k, top-p, logit-bias row, root key — and the
+    dispatch key live on the device in ONE structure (``rows`` (S,
+    10 + max_blocks) int32, ``bias`` (S, vocab) float32, ``key``).  The
+    decode, burst and verify programs take it donated, like the cache,
+    and return it advanced: a burst's carry ends holding each slot's
+    next last token, position and ``done``, so a dispatch that follows
+    no change uploads nothing.  The host keeps the same rows as numpy
+    (the authority for :meth:`reset`, a failed dispatch and
+    introspection), advances them by the same arithmetic from the tokens
+    it pulls, and edits the device's copy BY ROW where a join, a leave or
+    the batcher's own arrays say otherwise (:meth:`_slot_state`).
 
     Every program takes the whole cache DONATED (the engine owns it and
     rebinds the returned buffers), so XLA updates the cache in place.
@@ -659,13 +695,29 @@ class GenerationEngine:
             int(logprobs_topn if logprobs_topn is not None
                 else getenv_int("MXNET_SAMPLING_LOGPROBS_TOPN", 5)),
             self.vocab_size))
-        self._samp_temp = _np.zeros(self.max_slots, _np.float32)
-        self._samp_topk = _np.zeros(self.max_slots, _np.int32)
-        self._samp_topp = _np.ones(self.max_slots, _np.float32)
+        # the slot state's host side: one int32 row a slot (columns
+        # _LAST.._KEY1, then its block table) and one bias row; the
+        # named mirrors below are views of it
+        self._rows = _np.zeros(
+            (self.max_slots, _TABLE + self.max_blocks_per_slot), _np.int32)
+        self._tables = self._rows[:, _TABLE:]
+        self._samp_temp = self._rows[:, _TEMP].view(_np.float32)
+        self._samp_topk = self._rows[:, _TOPK]
+        self._samp_topp = self._rows[:, _TOPP].view(_np.float32)
+        self._samp_keys = self._rows[:, _KEY0:_KEY1 + 1].view(_np.uint32)
+        self._samp_topp[:] = 1.0
         self._samp_bias = _np.zeros((self.max_slots, self.vocab_size),
                                     _np.float32)
-        self._samp_keys = _np.zeros((self.max_slots, 2), _np.uint32)
-        self._samp_dev = None
+        #: the slot state on the device (None: the next dispatch builds
+        #: it whole from the rows), the slots whose row / bias row there
+        #: is behind the host's, and what brought it up to date since the
+        #: last dispatch (``mxtpu_serve_operands``)
+        self._state = None
+        self._bias_unset = None     # the row edit's operand for "no bias"
+        self._dirty, self._dirty_bias = set(), set()
+        self._rebuilt, self._edited = False, 0
+        self._operand_sources = dict.fromkeys(
+            ("carried", "patched", "rebuilt", "rows"), 0)
         self._last_logprobs = None
         self._last_prefill_logprobs = None
         self._last_verify_logprobs = None
@@ -674,11 +726,13 @@ class GenerationEngine:
         self._prefill_ext_jit = jax.jit(self._prefill_ext_pure,
                                         donate_argnums=(0,))
         self._decode_jit = jax.jit(self._decode_paged_pure,
-                                   donate_argnums=(0,))
+                                   donate_argnums=(0, 1))
         self._decode_burst_jit = jax.jit(self._decode_burst_paged_pure,
-                                         donate_argnums=(0,))
+                                         donate_argnums=(0, 1))
         self._verify_jit = jax.jit(self._verify_paged_pure,
-                                   donate_argnums=(0,))
+                                   donate_argnums=(0, 1))
+        self._slot_edit_jit = jax.jit(self._slot_edit_pure,
+                                      donate_argnums=(0, 1))
         self._prefill = _telemetry.instrument_jit(
             "serving:" + self.name + ":prefill", self._prefill_jit)
         self._prefill_ext = _telemetry.instrument_jit(
@@ -691,6 +745,8 @@ class GenerationEngine:
             self._decode_burst_jit)
         self._verify = _telemetry.instrument_jit(
             "serving:" + self.name + ":verify", self._verify_jit)
+        self._slot_edit = _telemetry.instrument_jit(
+            "serving:" + self.name + ":slot_edit", self._slot_edit_jit)
         # speculative decoding: a draft engine attached via attach_draft
         # proposes spec_k tokens per slot; THE verify program scores all
         # spec_k + 1 positions in one dispatch (exactly one extra
@@ -739,8 +795,8 @@ class GenerationEngine:
                 p._data._set_data(v)
 
     # -- sampling plane --------------------------------------------------
-    # Host side: per-slot numpy arrays mirrored to ONE cached device
-    # tuple (like _tables_dev), invalidated on any slot update.  Traced
+    # Host side: a slot's parameters are columns of its row of the slot
+    # state (and its bias row), edited on the device by row.  Traced
     # side: the token at sequence position t is sampled with
     # ``step_keys(root, t)`` — the key depends only on (root, position),
     # never on which program produced the logits, which is what makes
@@ -764,14 +820,14 @@ class GenerationEngine:
         self._samp_temp[s] = float(p.temperature)
         self._samp_topk[s] = int(p.top_k)
         self._samp_topp[s] = float(p.top_p)
+        self._samp_keys[s] = root_key(p.seed or 0)
+        self._dirty.add(s)
         row = _np.zeros(self.vocab_size, _np.float32)
         if p.logit_bias:
             for t, b in p.logit_bias.items():
                 if 0 <= int(t) < self.vocab_size:
                     row[int(t)] = float(b)
-        self._samp_bias[s] = row
-        self._samp_keys[s] = root_key(p.seed or 0)
-        self._samp_dev = None
+        self._set_bias_row(s, row)
         if self.draft is not None:
             self.draft.set_slot_sampling(slot, params)
 
@@ -781,12 +837,15 @@ class GenerationEngine:
         grammar machine's mask at each emit boundary; the new row is a
         traced operand of the NEXT dispatch).  Cascades to the draft so
         constrained slots never propose illegal tokens."""
-        s = int(slot)
-        self._samp_bias[s] = _np.asarray(row, _np.float32).reshape(
-            self.vocab_size)
-        self._samp_dev = None
+        self._set_bias_row(int(slot), _np.asarray(row, _np.float32).reshape(
+            self.vocab_size))
         if self.draft is not None:
             self.draft.update_slot_bias(slot, row)
+
+    def _set_bias_row(self, s: int, row) -> None:
+        if not _np.array_equal(self._samp_bias[s], row):
+            self._samp_bias[s] = row
+            self._dirty_bias.add(s)
 
     def last_logprobs(self):
         """Device arrays from the most recent decode/burst dispatch when
@@ -806,27 +865,166 @@ class GenerationEngine:
         verify dispatch; None when disabled."""
         return self._last_verify_logprobs
 
-    def _samp_tuple(self):
-        """The (S,)-wide sampling operand tuple, device-cached."""
-        import jax.numpy as jnp
-        if self._samp_dev is None:
-            self._samp_dev = (jnp.asarray(self._samp_temp),
-                              jnp.asarray(self._samp_topk),
-                              jnp.asarray(self._samp_topp),
-                              jnp.asarray(self._samp_bias),
-                              jnp.asarray(self._samp_keys))
-        return self._samp_dev
+    # -- the slot state ----------------------------------------------------
+    def rebuild_slot_state(self) -> None:
+        """Forget the device's copy of the slot state: the next dispatch
+        builds it whole from the host's rows (after :meth:`reset` and a
+        failed dispatch, whose donated state is gone; a test calls it
+        before every dispatch to hold the carried state against)."""
+        self._state = None
 
-    def _slot_samp(self, slot: int):
-        """Per-slot scalar sampling operands for the prefill programs
-        (temp (), top_k (), top_p (), bias (V,), root (2,))."""
+    def _put(self, array):
+        """``array`` on the engine's device, committed like the pools:
+        for what STAYS there.  A dispatch's own operands (a prompt, its
+        integers, edited rows) go to the program as numpy arrays, which
+        the call itself uploads — one trip into the runtime, not two."""
+        import jax
+        return jax.device_put(array, self._ctx.jax_device())
+
+    def _slot_state(self):
+        """The slot state on the device, brought up to the host's rows:
+        built whole where there is none, else edited by row — ONE
+        dispatch of a small program (:meth:`_slot_edit_pure`) for up to
+        ``_EDIT_ROWS`` changed slots, its operand their int32 rows, and
+        one more for each slot whose bias row changed, which carries
+        that row.  More changed rows than that (a draft's burst advances
+        every slot past what the target accepts) go as the whole
+        ``rows`` matrix in one upload; the bias matrix never does."""
+        from .. import random as _random
+        if self._state is None:
+            self._state = {"rows": self._put(self._rows),
+                           "bias": self._put(self._samp_bias),
+                           "key": self._put(_random.new_key(self._ctx))}
+            if self._bias_unset is None:
+                self._bias_unset = self._put(
+                    _np.zeros(self.vocab_size, _np.float32))
+            self._dirty.clear()
+            self._dirty_bias.clear()
+            self._rebuilt = True
+            return self._state
+        if not (self._dirty or self._dirty_bias):
+            return self._state
+        self._edited += len(self._dirty | self._dirty_bias)
+        if len(self._dirty) > _EDIT_ROWS:
+            self._state["rows"] = self._put(self._rows)
+            self._dirty.clear()
+        # a slot whose bias row changed leads an edit of its own; the
+        # others ride with the first where they fit
+        edits = [[s] for s in sorted(self._dirty_bias)]
+        plain = sorted(self._dirty - self._dirty_bias)
+        if plain and edits and len(plain) < _EDIT_ROWS:
+            edits[0] += plain
+        elif plain:
+            edits.append(plain)
+        try:
+            for slots in edits:
+                self._edit_slots(slots, slots[0] in self._dirty_bias)
+        except Exception:
+            self.rebuild_slot_state()   # an edit donates what it edits
+            raise
+        self._dirty.clear()
+        self._dirty_bias.clear()
+        return self._state
+
+    def _edit_slots(self, slots, with_bias: bool) -> None:
+        """Write the host's rows of ``slots`` (at most ``_EDIT_ROWS``)
+        into the device's slot state, and with them the first one's
+        bias row.  The rows go to the program as they are, a numpy
+        array: the call uploads them."""
+        state = self._state
+        slots = list(slots) + [slots[0]] * (_EDIT_ROWS - len(slots))
+        edits = _np.empty((_EDIT_ROWS, 2 + self._rows.shape[1]), _np.int32)
+        edits[:, 0] = slots
+        edits[:, 1] = with_bias
+        edits[:, 2:] = self._rows[slots]
+        with _donating():
+            state["rows"], state["bias"] = self._slot_edit(
+                state["rows"], state["bias"], edits,
+                self._put(self._samp_bias[slots[0]]) if with_bias
+                else self._bias_unset)
+
+    def _slot_edit_pure(self, rows, bias, edits, bias_row):
+        """The row edit: each of ``edits`` int32 (_EDIT_ROWS, 2 + W) is
+        ``[slot, set_bias, *row]``; ``bias_row`` (V,) replaces the FIRST
+        one's bias row where its ``set_bias``."""
         import jax.numpy as jnp
-        s = int(slot)
-        return (jnp.asarray(self._samp_temp[s]),
-                jnp.asarray(self._samp_topk[s]),
-                jnp.asarray(self._samp_topp[s]),
-                jnp.asarray(self._samp_bias[s]),
-                jnp.asarray(self._samp_keys[s]))
+        from jax import lax
+        for i in range(_EDIT_ROWS):
+            rows = lax.dynamic_update_slice(rows, edits[i:i + 1, 2:],
+                                            (edits[i, 0], 0))
+        slot = edits[0, 0]
+        kept = lax.dynamic_slice(bias, (slot, 0), (1, bias.shape[1]))
+        bias = lax.dynamic_update_slice(
+            bias, jnp.where(edits[0, 1] != 0, bias_row[None], kept),
+            (slot, 0))
+        return rows, bias
+
+    def _carry(self, want) -> None:
+        """Hold what the batcher derived from its requests for this
+        dispatch (``want``: column → (S,) array) against what the device
+        carries from the last one, and mark the slots where they differ
+        (a join, a leave, a host-side stop or cancel, a change of path
+        between step and burst) for a row edit."""
+        for col, arr in want.items():
+            held = self._rows[:, col]
+            diff = _np.flatnonzero(held != arr)
+            if diff.size:
+                held[diff] = arr[diff]
+                self._dirty.update(diff.tolist())
+
+    def _count_operands(self) -> None:
+        """One decode, burst or verify dispatch: where its slot state
+        came from (``mxtpu_serve_operands{source}``).  Warm-up traffic
+        is not counted."""
+        source = "rebuilt" if self._rebuilt else \
+            "patched" if self._edited else "carried"
+        rows, self._rebuilt, self._edited = self._edited, False, 0
+        if self._warming:
+            return
+        _m.SERVE_OPERANDS.inc(model=self.name, source=source)
+        self._operand_sources[source] += 1
+        if rows:
+            _m.SERVE_OPERAND_ROWS.inc(rows, model=self.name)
+            self._operand_sources["rows"] += rows
+
+    def operand_sources(self) -> dict:
+        """Lifetime counts of :meth:`_count_operands`, and the rows
+        edited, for ``GET /v1/models``."""
+        return dict(self._operand_sources)
+
+    def _slot_operands(self, state):
+        """Traced: the slot state's columns as the programs use them —
+        ``(last (S, 1), pos, budget, eos, done, tables, samp)``."""
+        import jax.numpy as jnp
+        from jax import lax
+        rows = state["rows"]
+        f32 = lambda c: lax.bitcast_convert_type(rows[:, c], jnp.float32)
+        samp = (f32(_TEMP), rows[:, _TOPK], f32(_TOPP), state["bias"],
+                lax.bitcast_convert_type(rows[:, _KEY0:_KEY1 + 1],
+                                         jnp.uint32))
+        return (rows[:, _LAST:_LAST + 1], rows[:, _POS], rows[:, _BUDGET],
+                rows[:, _EOS], rows[:, _DONE] != 0, rows[:, _TABLE:], samp)
+
+    def _slot_row(self, state, slot):
+        """Traced: slot ``slot``'s table row and scalar sampling operands
+        for the prefill programs (temp (), top_k (), top_p (), bias (V,),
+        root (2,)), sliced out of the slot state."""
+        from jax import lax
+        one = {k: lax.dynamic_slice_in_dim(state[k], slot, 1)
+               for k in ("rows", "bias")}
+        *_, tables, samp = self._slot_operands(one)
+        return tables[0], tuple(a[0] for a in samp)
+
+    @staticmethod
+    def _advanced(state, key, *cols):
+        """Traced: the slot state with its leading columns (``_LAST``
+        on) set to ``cols`` and the dispatch key moved on."""
+        import jax.numpy as jnp
+        from jax import lax
+        block = jnp.stack([c.astype(jnp.int32) for c in cols], axis=1)
+        return {"rows": lax.dynamic_update_slice(state["rows"], block,
+                                                 (0, 0)),
+                "bias": state["bias"], "key": key}
 
     # traced helpers (called from inside the pure programs)
     def _sample_prefill(self, last, first_pos, samp):
@@ -945,17 +1143,22 @@ class GenerationEngine:
             counts = self._sum_counts(counts, c)
         return self.block.serve_head(h), counts
 
-    def _prefill_paged_pure(self, cache, tokens, n_valid, table, samp,
-                            param_vals, aux_vals, key):
+    def _prefill_paged_pure(self, cache, state, tokens, at, param_vals,
+                            aux_vals):
         """Prefix-cache MISS prefill: the model's own whole-prompt layers
         (``serve_prefill``, which return each layer's K/V), with the
-        slot's K/V scattered into the blocks named by ``table``
-        (max_blocks,) int32.  Positions past the table's reservation
-        redirect to the null block."""
+        slot's K/V scattered into the blocks its table row names.
+        ``at`` int32 is ``[n_valid, slot]``: the table and the sampling
+        operands are the slot's row of ``state``, which is read and not
+        donated.  Positions past the table's reservation redirect to the
+        null block."""
         import jax.numpy as jnp
         L = self.num_layers
         Tb = tokens.shape[1]
         bs = self.block_size
+        n_valid = at[0]
+        table, samp = self._slot_row(state, at[1])
+        key = state["key"]
 
         def body():
             pos = jnp.arange(Tb, dtype=jnp.int32)[None]
@@ -983,21 +1186,24 @@ class GenerationEngine:
             return tuple(out), first, lp
         return tuple(out), first
 
-    def _prefill_ext_pure(self, cache, tokens, n_valid, ctx, table, samp,
-                          param_vals, aux_vals, key):
+    def _prefill_ext_pure(self, cache, state, tokens, at, param_vals,
+                          aux_vals):
         """Prefix-cache HIT prefill: ``ctx`` leading positions (always a
         multiple of block_size) already hold valid K/V in shared blocks;
         run the layers over only the SUFFIX ``tokens`` (1, Tb), appending
         K/V at positions [ctx, ctx+Tb) and attending through the block
-        table (:func:`paged_prefix_attention`).  ``ctx`` is an int32
-        operand, so one program per suffix bucket serves every hit
-        length."""
+        table (:func:`paged_prefix_attention`).  ``at`` int32 is
+        ``[n_valid, slot, ctx]`` — operands, so one program per suffix
+        bucket serves every hit length and every slot."""
         import jax.numpy as jnp
         from ..kernels.flash_attention import paged_prefix_attention
         L = self.num_layers
         Tb = tokens.shape[1]
         bs = self.block_size
         caches = list(cache)
+        n_valid, ctx = at[0], at[2]
+        table, samp = self._slot_row(state, at[1])
+        key = state["key"]
         j0 = ctx // bs
 
         def attend_for(l):
@@ -1052,16 +1258,22 @@ class GenerationEngine:
             return attend
         return attend_for
 
-    def _decode_paged_pure(self, cache, last_tokens, positions, tables,
-                           samp, param_vals, aux_vals, key):
+    def _decode_paged_pure(self, cache, state, param_vals, aux_vals):
         """The decode program, paged: one token for EVERY slot, each
         slot's K/V write landing in block ``tables[s, pos//bs]`` at
         offset ``pos % bs`` and attention reading through
-        :func:`paged_decode_attention`.  ``tables`` (S, max_blocks) int32
-        is an operand — join/leave never recompiles.  A free slot's
-        table is all null block: it rides along, and the layers are told
-        it is not live."""
+        :func:`paged_decode_attention`.  Last tokens, positions, tables
+        (S, max_blocks) and the sampling operands are the slot
+        ``state``'s — join/leave never recompiles.  A free slot's table
+        is all null block: it rides along, and the layers are told it is
+        not live.  The state comes back advanced for the slots that hold
+        a table: the sampled token as their last, position + 1, budget
+        - 1 (what the next dispatch would be handed)."""
+        import jax
         import jax.numpy as jnp
+        last_tokens, positions, budgets, _, _, tables, samp = \
+            self._slot_operands(state)
+        key_next, key = jax.random.split(state["key"])
         S = last_tokens.shape[0]
         bs = self.block_size
         caches = list(cache)
@@ -1080,7 +1292,12 @@ class GenerationEngine:
         logits, counts = self._with_params(param_vals, aux_vals, key, body)
         lg = logits[:, 0, :]
         nxt = self._sample_step(lg, positions + 1, samp)
-        out = (tuple(caches), nxt)
+        live = tables[:, 0] != 0
+        out = (tuple(caches),
+               self._advanced(state, key_next,
+                              jnp.where(live, nxt, last_tokens[:, 0]),
+                              positions + live, budgets - live),
+               nxt)
         if self._health_on:
             out = out + (_health.decode_health(lg),)
         if self.logprobs_topn:
@@ -1090,17 +1307,15 @@ class GenerationEngine:
             out = out + (tuple(counts[n] for n in self._counters),)
         return out
 
-    def _decode_burst_paged_pure(self, cache, last_tokens, positions,
-                                 budgets, eos_ids, done0, tables, samp,
-                                 param_vals, aux_vals, key):
+    def _decode_burst_paged_pure(self, cache, state, param_vals, aux_vals):
         """``scan_steps`` decode steps captured as ONE program
         (:func:`jax.lax.scan` over the exact :meth:`_decode_paged_pure`
         body) with in-program termination riding the carry.
 
-        Per slot: ``budgets`` (S,) int32 caps the tokens this burst may
-        emit (the request's remaining budget), ``eos_ids`` (S,) int32 is
-        the stop token (-1: none), ``done0`` (S,) bool marks slots that
-        must not emit at all (free slots).  A slot whose step hits EOS or
+        Per slot, from the slot ``state``: the budget caps the tokens
+        this burst may emit (the request's remaining budget), the stop
+        id is the stop token (-1: none), ``done`` marks slots that must
+        not emit at all (free slots).  A slot whose step hits EOS or
         exhausts its budget flips ``done``; from then on its
         ``(last_token, position)`` carry is FROZEN and its K/V writes are
         redirected to the null block 0, so a finished slot's replayed
@@ -1111,16 +1326,23 @@ class GenerationEngine:
         Decode positions sit strictly past the shared prompt blocks, so
         the burst composes with the BlockPool prefix cache unchanged.
 
-        Returns ``(cache', tokens (k, S), emitted (S,))`` — row ``j`` of
-        ``tokens`` is step ``j``'s token; slot ``s``'s valid prefix is
-        ``tokens[:emitted[s], s]``.  With the health plane on, the
+        Returns ``(cache', state', tokens (k, S), emitted (S,))`` — row
+        ``j`` of ``tokens`` is step ``j``'s token; slot ``s``'s valid
+        prefix is ``tokens[:emitted[s], s]``; ``state'`` holds the
+        carry's end (last token, position, ``done``) and budget -
+        emitted, which is what the next burst starts from.  With the
+        health plane on, the
         per-step logit stats are folded across the burst in-program
         (max / mean / all) to the same (S,) triplet one decode returns
         (frozen steps replay their final live step's logits, so the fold
         is dominated by live emissions).  A model's counters ride the
         carry and come back summed over the steps."""
+        import jax
         import jax.numpy as jnp
         from jax import lax
+        last_tokens, positions, budgets, eos_ids, done0, tables, samp = \
+            self._slot_operands(state)
+        key_next, key = jax.random.split(state["key"])
         S = last_tokens.shape[0]
         bs = self.block_size
         k = int(self.scan_steps)
@@ -1161,36 +1383,45 @@ class GenerationEngine:
                       jnp.zeros(S, jnp.int32), self._zero_counts())
             return lax.scan(step, carry0, None, length=k)
 
-        (caches, _, _, _, emitted, counts), ys = self._with_params(
+        (caches, lt, pos, done, emitted, counts), ys = self._with_params(
             param_vals, aux_vals, key, run_scan)
+        state = self._advanced(state, key_next, lt[:, 0], pos,
+                               budgets - emitted, eos_ids, done)
         ys = list(ys)
         if self.logprobs_topn:
             lpi = ys.pop()
             lpv = ys.pop()
         if self._health_on:
             toks, lmax, ent, fin = ys
-            out = (caches, toks, emitted,
+            out = (caches, state, toks, emitted,
                    (lmax.max(axis=0), ent.mean(axis=0), fin.all(axis=0)))
         else:
             (toks,) = ys
-            out = (caches, toks, emitted)
+            out = (caches, state, toks, emitted)
         if self.logprobs_topn:
             out = out + ((lpv, lpi),)
         if self._counters:
             out = out + (tuple(counts[n] for n in self._counters),)
         return out
 
-    def _verify_paged_pure(self, cache, tokens, positions, tables, samp,
-                           param_vals, aux_vals, key):
+    def _verify_paged_pure(self, cache, state, tokens, positions,
+                           param_vals, aux_vals):
         """The verify program, paged: ``tokens`` (S, Q) — column 0 each
         slot's last accepted token, the rest the draft's proposals — at
         positions ``positions + j``, each slot's Q writes routed through
-        its block table.  Positions past a slot's reservation (table
+        its block table (tables and sampling operands are the slot
+        ``state``'s; it comes back with only the dispatch key moved on:
+        what was accepted is decided on the host).  Positions past a
+        slot's reservation (table
         padding) or past ``max_len`` redirect to the null block — overrun
         rows near the budget edge land in the sink, never in a neighbor's
         block.  With Q == 1 this is exactly decode."""
+        import jax
         import jax.numpy as jnp
         from ..kernels.flash_attention import paged_verify_decode_attention
+        tables, samp = self._slot_operands(state)[5:]
+        key_next, key = jax.random.split(state["key"])
+        state = dict(state, key=key_next)
         L = self.num_layers
         S, Q = tokens.shape
         bs = self.block_size
@@ -1231,8 +1462,8 @@ class GenerationEngine:
             from .sampling import topn_logprobs
             lp = topn_logprobs(logits, samp[3][:, None, :],
                                self.logprobs_topn)
-            return tuple(caches), nxt, lp
-        return tuple(caches), nxt
+            return tuple(caches), state, nxt, lp
+        return tuple(caches), state, nxt
 
     # -- cache lifecycle ------------------------------------------------
     def reset(self):
@@ -1245,7 +1476,6 @@ class GenerationEngine:
         import jax.numpy as jnp
         if getattr(self, "draft", None) is not None:
             self.draft.reset()
-        self._samp_dev = None
         # committed to the engine's device, like the parameters: an
         # uncommitted pool would follow jax's default device instead
         dev = self._ctx.jax_device()
@@ -1264,9 +1494,9 @@ class GenerationEngine:
         # attribution)
         self.pool.block_bytes = self.cache_bytes // self.num_blocks
         self._slot_blocks = [[] for _ in range(self.max_slots)]
-        self._tables = _np.zeros(
-            (self.max_slots, self.max_blocks_per_slot), _np.int32)
-        self._tables_dev = None
+        self._tables[:] = 0
+        self._rows[:, :_TOPK] = _FREE
+        self.rebuild_slot_state()
 
     @property
     def pool_layout(self):
@@ -1304,15 +1534,14 @@ class GenerationEngine:
 
     # -- host-side dispatch ---------------------------------------------
     def _guarded(self, call, *args):
+        """Enqueue ``call`` over the cache, the slot state brought up to
+        the host's rows, ``args`` and the current parameters."""
         param_vals, aux_vals = self._param_fn()
-        from .. import random as _random
-        key = _random.new_key(self._ctx)
+        state = self._slot_state()
         try:
-            with warnings.catch_warnings():
-                warnings.filterwarnings(
-                    "ignore",
-                    message="Some donated buffers were not usable")
-                return call(self._cache, *args, param_vals, aux_vals, key)
+            with _donating():
+                return call(self._cache, state, *args, param_vals,
+                            aux_vals)
         except Exception as e:
             # RESOURCE_EXHAUSTED here is the device running out of HBM
             # mid-dispatch: publish the oom FAULT so the flight recorder
@@ -1323,20 +1552,37 @@ class GenerationEngine:
                                              model=self.name)
             raise
 
-    def _tables_device(self):
-        """Every slot's block table, (S, max_blocks) int32, device-cached
-        until a slot's table changes."""
-        import jax.numpy as jnp
-        if self._tables_dev is None:
-            self._tables_dev = jnp.asarray(self._tables)
-        return self._tables_dev
+    @contextlib.contextmanager
+    def _advancing(self, call, *args):
+        """Enqueue a decode, burst or verify program, which takes the
+        slot state donated, and yield its (future) results without the
+        cache and the state, which are rebound here.  What follows pulls
+        them, so the worker loop's ``operands`` phase ends at the enqueue
+        and ``decode_wait`` begins.  If the dispatch or a pull fails the
+        donated state is gone with it: the next dispatch rebuilds it
+        from the host's rows, which advance only after the pulls."""
+        try:
+            out = list(self._guarded(call, *args))
+            self._count_operands()
+            _m.loop_phase_switch("decode_wait", "serve.decode.wait")
+            self._cache, self._state = out[:2]
+            yield out[2:]
+        except Exception:
+            self.rebuild_slot_state()
+            raise
 
-    def _enqueued(self, out) -> list:
-        """A decode, burst or verify program is enqueued and ``out`` are
-        its (future) results: what follows pulls them, so the worker
-        loop's ``operands`` phase ends here and ``decode_wait`` begins."""
-        _m.loop_phase_switch("decode_wait", "serve.decode.wait")
-        return list(out)
+    def _pop_extras(self, out: list):
+        """Take what a decode or burst program returns past its tokens
+        off the end of ``out`` — the model's counters (returned), the
+        logprobs ((S, N), or (k, S, N) of a burst) and the health
+        triplet (stashed) — each there by what the engine was built
+        with."""
+        counts = out.pop() if self._counters else ()
+        if self.logprobs_topn:
+            self._last_logprobs = tuple(_np.asarray(a) for a in out.pop())
+        if self._health_on:
+            self._last_decode_health = out.pop()
+        return counts
 
     def _unpack_prefill(self, out) -> int:
         """Rebind the cache, stash the prefill logprobs (arity is baked
@@ -1415,12 +1661,9 @@ class GenerationEngine:
         table, m = self.pool.allocate(toks, n, reserve,
                                       share=not self._warming)
         self._slot_blocks[slot] = table
-        row = _np.zeros(self.max_blocks_per_slot, _np.int32)
-        row[:len(table)] = table
-        self._tables[slot] = row
-        self._tables_dev = None
+        self._set_table(slot, table)
         try:
-            return self._prefill_paged_dispatch(toks, n, m, row, slot, span)
+            return self._prefill_paged_dispatch(toks, n, m, slot, span)
         except Exception:
             # The fresh (non-shared) blocks never got their K/V written;
             # allocate() already registered the full ones in the prefix
@@ -1431,23 +1674,30 @@ class GenerationEngine:
             self.release_slot(slot)
             raise
 
-    def _prefill_paged_dispatch(self, toks, n: int, m: int, row,
-                                slot: int, span) -> int:
-        import jax.numpy as jnp
-        ss = self._slot_samp(slot)
+    def _set_table(self, slot: int, blocks) -> None:
+        """``blocks`` as ``slot``'s block table row (null block past
+        them)."""
+        row = self._tables[slot]
+        row[:len(blocks)] = blocks
+        row[len(blocks):] = 0
+        self._dirty.add(slot)
+
+    def _prefill_paged_dispatch(self, toks, n: int, m: int, slot: int,
+                                span) -> int:
+        """The slot's table and sampling operands reach the program as
+        its row of the slot state (edited just before, by
+        :meth:`_guarded`): what the call uploads is the padded prompt
+        and three integers."""
         if m == 0:
-            padded = self._padded(toks, n, span)
             out = self._guarded(
-                self._prefill, jnp.asarray(padded),
-                jnp.asarray(n, jnp.int32), jnp.asarray(row), ss)
+                self._prefill, self._padded(toks, n, span),
+                _np.asarray([n, slot], _np.int32))
         else:
             if span is not None:
                 span.attrs["prefix_hit_tokens"] = m
-            padded = self._padded(toks[m:], n - m, span)
             out = self._guarded(
-                self._prefill_ext, jnp.asarray(padded),
-                jnp.asarray(n - m, jnp.int32), jnp.asarray(m, jnp.int32),
-                jnp.asarray(row), ss)
+                self._prefill_ext, self._padded(toks[m:], n - m, span),
+                _np.asarray([n - m, slot, m], _np.int32))
         return self._unpack_prefill(out)
 
     def _padded(self, toks, n: int, span):
@@ -1465,30 +1715,26 @@ class GenerationEngine:
         Returns the next token per slot as a host int32 array.
 
         On a generation worker's thread the call spans two phases of its
-        loop: ``operands`` (uploads, sampling and parameter operands, the
-        key, the program's enqueue) and, from :meth:`_enqueued` on,
-        ``decode_wait`` — the host blocked on the device's result."""
-        import jax.numpy as jnp
+        loop: ``operands`` (the arrays held against what the device
+        carries, a row edit where they differ, the parameter operands,
+        the program's enqueue) and, from the enqueue on
+        (:meth:`_advancing`), ``decode_wait`` — the host blocked on the
+        device's result."""
+        S = self.max_slots
         _m.loop_phase_switch("operands", "serve.operands")
-        lt = jnp.asarray(_np.asarray(last_tokens, _np.int32).reshape(
-            self.max_slots, 1))
-        pos = jnp.asarray(_np.asarray(positions, _np.int32).reshape(
-            self.max_slots))
-        out = self._enqueued(self._guarded(
-            self._decode, lt, pos, self._tables_device(),
-            self._samp_tuple()))
-        counts = out.pop() if self._counters else ()
-        if self.logprobs_topn:
-            self._last_logprobs = tuple(_np.asarray(a)
-                                        for a in out.pop())
-        if self._health_on:
-            self._last_decode_health = out.pop()
-        cache, nxt = out
-        self._cache = cache
-        nxt = _np.asarray(nxt)
-        held = _np.asarray([bool(b) for b in self._slot_blocks])
+        self._carry({_LAST: _np.asarray(last_tokens, _np.int32).reshape(S),
+                     _POS: _np.asarray(positions, _np.int32).reshape(S)})
+        with self._advancing(self._decode) as out:
+            counts = self._pop_extras(out)
+            nxt = _np.asarray(out[0])
+        # the rows follow the program: a slot that holds a table moved on
+        rows = self._rows
+        live = self._tables[:, 0] != 0
+        rows[live, _LAST] = nxt[live]
+        rows[:, _POS] += live
+        rows[:, _BUDGET] -= live
         self._count_decode(counts, _np.asarray(positions, _np.int64)
-                           .reshape(-1), held.astype(_np.int64))
+                           .reshape(-1), live.astype(_np.int64))
         return nxt
 
     def decode_burst(self, last_tokens, positions, budgets, eos_ids,
@@ -1502,7 +1748,6 @@ class GenerationEngine:
         ``(tokens (k, S) int32, emitted (S,) int32)``; slot ``s``'s
         emitted tokens are ``tokens[:emitted[s], s]``, bit-identical to
         the same number of per-step :meth:`decode` calls."""
-        import jax.numpy as jnp
         k = int(self.scan_steps)
         if k < 1:
             raise MXNetError(
@@ -1510,27 +1755,36 @@ class GenerationEngine:
                 f"{self.scan_steps}; set MXNET_DECODE_SCAN_STEPS >= 1)")
         S = self.max_slots
         _m.loop_phase_switch("operands", "serve.operands")
-        lt = jnp.asarray(_np.asarray(last_tokens, _np.int32).reshape(S, 1))
-        pos = jnp.asarray(_np.asarray(positions, _np.int32).reshape(S))
-        bud = jnp.asarray(_np.asarray(budgets, _np.int32).reshape(S))
-        eos = jnp.asarray(_np.asarray(eos_ids, _np.int32).reshape(S))
-        done0 = jnp.asarray(
-            ~_np.asarray(active, bool).reshape(S))
-        out = self._enqueued(self._guarded(
-            self._decode_burst, lt, pos, bud, eos, done0,
-            self._tables_device(), self._samp_tuple()))
-        counts = out.pop() if self._counters else ()
-        if self.logprobs_topn:          # (k, S, N) per burst step
-            self._last_logprobs = tuple(_np.asarray(a)
-                                        for a in out.pop())
-        if self._health_on:
-            self._last_decode_health = out.pop()
-        cache, toks, emitted = out
-        self._cache = cache
-        toks, emitted = _np.asarray(toks), _np.asarray(emitted)
+        self._carry({_LAST: _np.asarray(last_tokens, _np.int32).reshape(S),
+                     _POS: _np.asarray(positions, _np.int32).reshape(S),
+                     _BUDGET: _np.asarray(budgets, _np.int32).reshape(S),
+                     _EOS: _np.asarray(eos_ids, _np.int32).reshape(S),
+                     _DONE: ~_np.asarray(active, bool).reshape(S)})
+        with self._advancing(self._decode_burst) as out:
+            counts = self._pop_extras(out)
+            toks, emitted = _np.asarray(out[0]), _np.asarray(out[1])
+        self._follow_burst(toks, emitted)
         self._count_decode(counts, _np.asarray(positions, _np.int64)
                            .reshape(-1), emitted.astype(_np.int64))
         return toks, emitted
+
+    def _follow_burst(self, toks, emitted) -> None:
+        """Move the host's rows to where the burst program's carry
+        ended, from the tokens it returned: a slot that met its stop id
+        or used its budget froze BEFORE consuming its last token (that
+        token is not its last token, nor its position counted), any
+        other consumed all it emitted."""
+        rows, cols = self._rows, _np.arange(self.max_slots)
+        final = toks[_np.maximum(emitted - 1, 0), cols]
+        ended = (emitted > 0) & ((final == rows[:, _EOS])
+                                 | (emitted >= rows[:, _BUDGET]))
+        moved = emitted - ended
+        rows[:, _LAST] = _np.where(
+            moved > 0, toks[_np.maximum(moved - 1, 0), cols],
+            rows[:, _LAST])
+        rows[:, _POS] += moved
+        rows[:, _BUDGET] -= emitted
+        rows[:, _DONE] |= ended
 
     def _count_decode(self, counts, positions, steps) -> None:
         """After a decode or burst dispatch, with its results already on
@@ -1621,24 +1875,14 @@ class GenerationEngine:
         int32 base write heads.  Returns the target's argmax (S, Q) as a
         host array: ``out[s, j]`` is the next token after consuming
         ``tokens[s, :j + 1]``."""
-        import jax.numpy as jnp
         _m.loop_phase_switch("operands", "serve.operands")
         toks = _np.asarray(tokens, _np.int32).reshape(self.max_slots, -1)
-        lt = jnp.asarray(toks)
-        pos = jnp.asarray(_np.asarray(positions, _np.int32).reshape(
-            self.max_slots))
-        res = self._enqueued(self._guarded(
-            self._verify, lt, pos, self._tables_device(),
-            self._samp_tuple()))
-        if self.logprobs_topn:          # (S, Q, N) per verify
-            cache, out, lp = res
-            self._last_verify_logprobs = tuple(_np.asarray(a)
-                                               for a in lp)
-        else:
-            cache, out = res
-            self._last_verify_logprobs = None
-        self._cache = cache
-        return _np.asarray(out)
+        pos = _np.asarray(positions, _np.int32).reshape(self.max_slots)
+        with self._advancing(self._verify, toks, pos) as res:
+            self._last_verify_logprobs = tuple(
+                _np.asarray(a) for a in res[1]) \
+                if self.logprobs_topn else None     # (S, Q, N)
+            return _np.asarray(res[0])
 
     def spec_step(self, last_tokens, positions):
         """One speculative step for EVERY slot: the draft proposes
@@ -1726,10 +1970,7 @@ class GenerationEngine:
                 new = eng.pool.rewind(blocks, keep)
                 if new != blocks:
                     eng._slot_blocks[s] = new
-                    row = _np.zeros(eng.max_blocks_per_slot, _np.int32)
-                    row[:len(new)] = new
-                    eng._tables[s] = row
-                    eng._tables_dev = None
+                    eng._set_table(s, new)
 
     # -- block-pool bookkeeping ------------------------------------------
     def release_slot(self, slot: int) -> None:
@@ -1742,8 +1983,8 @@ class GenerationEngine:
         if blocks:
             self.pool.release(blocks)
         self._slot_blocks[int(slot)] = []
-        self._tables[int(slot)] = 0
-        self._tables_dev = None
+        self._set_table(int(slot), ())
+        self._rows[int(slot), :_TOPK] = _FREE
 
     def can_admit(self, tokens, reserve_tokens: int,
                   reserved_blocks: int = 0) -> bool:
@@ -1827,11 +2068,12 @@ class GenerationEngine:
         one suffix-prefill per bucket when the prefix cache can hit),
         ONE decode, ONE decode burst (when ``scan_steps >= 1`` — the
         scan length is baked, budgets/eos/done are operands, so one
-        program serves every k-step burst), and — with a draft attached
-        — ONE verify (the query width is baked from ``spec_k``, so no
+        program serves every k-step burst), ONE row edit of the slot
+        state (the slot is an operand), and — with a draft attached —
+        ONE verify (the query width is baked from ``spec_k``, so no
         per-accept-length programs exist)."""
         per_bucket = 2 if self.prefix_cache_enabled else 1
-        return per_bucket * len(self.prefill_buckets) + 1 \
+        return per_bucket * len(self.prefill_buckets) + 2 \
             + (1 if self.scan_steps >= 1 else 0) \
             + (1 if self.draft is not None else 0)
 
@@ -1841,25 +2083,24 @@ class GenerationEngine:
         plus THE decode program — then reset the cache (warmup traffic
         must not look like live slots or poison the prefix cache).
         Returns the number of programs warmed."""
-        import jax.numpy as jnp
         self._warming = True
         try:
             for b in self.prefill_buckets:
                 self.prefill(_np.zeros(max(1, min(b, self.max_len - 1)),
                                        _np.int32), 0)
                 self.release_slot(0)
+            # the row edit, whatever the joins above happened to send
+            self._slot_state()
+            self._edit_slots([0], True)
             if self.prefix_cache_enabled:
-                # suffix programs take ctx/table as OPERANDS: one dummy
-                # dispatch per bucket (writes land in the null block)
-                row = jnp.zeros(self.max_blocks_per_slot, jnp.int32)
+                # suffix programs take ctx and the slot as OPERANDS: one
+                # dummy dispatch per bucket (slot 0 is released: its
+                # table is all null block, where the writes land)
                 for b in self.prefill_buckets:
                     sn = max(1, min(b, self.max_len - 1))
                     self._unpack_prefill(self._guarded(
-                        self._prefill_ext,
-                        jnp.zeros((1, b), jnp.int32),
-                        jnp.asarray(sn, jnp.int32),
-                        jnp.asarray(0, jnp.int32), row,
-                        self._slot_samp(0)))
+                        self._prefill_ext, _np.zeros((1, b), _np.int32),
+                        _np.asarray([sn, 0, 0], _np.int32)))
             self.decode(_np.zeros(self.max_slots, _np.int32),
                         _np.zeros(self.max_slots, _np.int32))
             if self.scan_steps >= 1:
@@ -1901,7 +2142,8 @@ class GenerationEngine:
                 + int(self._prefill_ext_jit._cache_size()) \
                 + int(self._decode_jit._cache_size()) \
                 + int(self._decode_burst_jit._cache_size()) \
-                + int(self._verify_jit._cache_size())
+                + int(self._verify_jit._cache_size()) \
+                + int(self._slot_edit_jit._cache_size())
         except Exception:
             return 0
 

@@ -45,6 +45,18 @@ BURST_GATE = _telemetry.registry.counter(
     "mxtpu_serve_burst_gate",
     "decode dispatches that were not a burst, by the reason the gate "
     "said no (reason=queue|cancel|deadline|constrained|disabled)")
+SERVE_OPERANDS = _telemetry.registry.counter(
+    "mxtpu_serve_operands",
+    "decode, burst and verify dispatches by where their per-slot "
+    "operands (the engine's slot state on the device) came from: "
+    "source=carried (as the last dispatch returned them: nothing "
+    "uploaded), patched (rows edited since: a join, a leave, a bias "
+    "row, a host-side stop), rebuilt (whole, from the host's rows: "
+    "start, reset, after a failed dispatch)")
+SERVE_OPERAND_ROWS = _telemetry.registry.counter(
+    "mxtpu_serve_operand_rows",
+    "slot rows of the slot state edited on the device ahead of the "
+    "dispatches mxtpu_serve_operands counts as patched")
 MOE_PAIRS_TOTAL = _telemetry.registry.counter(
     "mxtpu_moe_pairs_total",
     "(token, expert) pairs the decode programs' expert layers routed, "

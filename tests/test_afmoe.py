@@ -235,7 +235,7 @@ def test_counters_reach_the_batcher_stats():
     4 expert layers x 4 experts a token a live slot-step."""
     cfg = _cfg()
     eng, _ = _engine(cfg, logprobs_topn=0)
-    assert eng.warmup() == eng.expected_programs == 6
+    assert eng.warmup() == eng.expected_programs == 7
     assert eng.decode_counters()["moe_pairs_total"] == 0    # not warm-up's
     bat = ContinuousBatcher(eng, name="tiny")
     try:

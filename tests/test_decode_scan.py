@@ -225,6 +225,10 @@ def test_spec_draft_scan_parity_and_program_set():
     scanned = tgt1.generate([3, 7, 11], max_new_tokens=20,
                             speculative=True)
     assert scanned == host_loop
+    # the second run is the first to edit a row of a slot state that is
+    # there (the first built it whole): from then on nothing compiles
+    assert tgt1.generate([3, 7, 11], max_new_tokens=20,
+                         speculative=True) == scanned
     n_t, n_d = tgt1.compiled_programs(), dr1.compiled_programs()
     assert n_t <= tgt1.expected_programs
     assert n_d <= dr1.expected_programs

@@ -274,9 +274,9 @@ def test_paged_midflight_join_parity():
 def test_closed_program_set_survives_hits_and_joins():
     _, paged = _pair()
     warmed = paged.warmup()
-    # a miss and a hit prefill per bucket, decode, burst
+    # a miss and a hit prefill per bucket, decode, burst, the row edit
     assert warmed == paged.expected_programs \
-        == 2 * len(paged.prefill_buckets) + 2
+        == 2 * len(paged.prefill_buckets) + 3
     n = paged.compiled_programs()
     paged.generate([4, 4, 4], max_new_tokens=8)
     paged.generate([2] * 17, max_new_tokens=8)

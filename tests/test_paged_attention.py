@@ -441,7 +441,6 @@ def serve_cell_programs(topo, one_chip):
     layers), each with its operands as the serve cell dispatches them (S
     36, N 1,217) on the described chip, the pools in the shape the rule
     gives for that chip: ``(engine, {name: (the engine's jit, operands)})``."""
-    from incubator_mxnet_tpu import random as mx_random
     mx.random.seed(0)
     net = GPTModel(vocab_size=512, units=1024, hidden_size=4096,
                    num_layers=2, num_heads=16, max_length=1024, dropout=0.0)
@@ -466,24 +465,17 @@ def serve_cell_programs(topo, one_chip):
         shape, jnp.int32, sharding=one_chip)
     cache = tuple(sds(c, eng._pool_shape) for c in eng._cache)
     params, aux = eng._param_fn()
-    tail = (tuple(sds(p) for p in params), tuple(sds(a) for a in aux),
-            sds(mx_random.new_key(eng._ctx)))
-    samp = tuple(sds(a) for a in eng._samp_tuple())
-    slot_samp = tuple(sds(a) for a in eng._slot_samp(0))
-    tables, row = i32(S, eng.max_blocks_per_slot), i32(
-        eng.max_blocks_per_slot)
-    done = jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip)
+    tail = (tuple(sds(p) for p in params), tuple(sds(a) for a in aux))
+    # the per-slot operands are the engine's slot state, as it builds it
+    state = jax.tree.map(sds, eng._slot_state())
     programs = {
-        "decode_burst": (eng._decode_burst_jit,
-                         (i32(S, 1), i32(S), i32(S), i32(S), done, tables,
-                          samp)),
-        "decode": (eng._decode_jit, (i32(S, 1), i32(S), tables, samp)),
-        "verify": (eng._verify_jit, (i32(S, 5), i32(S), tables, samp)),
-        "prefill": (eng._prefill_jit, (i32(1, 256), i32(), row, slot_samp)),
-        "prefill_ext": (eng._prefill_ext_jit,
-                        (i32(1, 256), i32(), i32(), row, slot_samp)),
+        "decode_burst": (eng._decode_burst_jit, ()),
+        "decode": (eng._decode_jit, ()),
+        "verify": (eng._verify_jit, (i32(S, 5), i32(S))),
+        "prefill": (eng._prefill_jit, (i32(1, 256), i32(2))),
+        "prefill_ext": (eng._prefill_ext_jit, (i32(1, 256), i32(3))),
     }
-    return eng, {name: (jitted, (cache,) + operands + tail)
+    return eng, {name: (jitted, (cache, state) + operands + tail)
                  for name, (jitted, operands) in programs.items()}
 
 
@@ -527,7 +519,6 @@ def test_agent_cell_pool_stays_as_stated_and_is_not_copied_on_v5e(
     import json
     import os
     import sys
-    from incubator_mxnet_tpu import random as mx_random
     chip = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark", "chip")
     if chip not in sys.path:
@@ -570,12 +561,8 @@ def test_agent_cell_pool_stays_as_stated_and_is_not_copied_on_v5e(
         shape, jnp.int32, sharding=one_chip)
     params, aux = eng._param_fn()
     args = (tuple(sds(c, pool) for c in eng._cache),
-            i32(S, 1), i32(S), i32(S), i32(S),
-            jax.ShapeDtypeStruct((S,), jnp.bool_, sharding=one_chip),
-            i32(S, eng.max_blocks_per_slot),
-            tuple(sds(a) for a in eng._samp_tuple()),
-            tuple(sds(p) for p in params), tuple(sds(a) for a in aux),
-            sds(mx_random.new_key(eng._ctx)))
+            jax.tree.map(sds, eng._slot_state()),
+            tuple(sds(p) for p in params), tuple(sds(a) for a in aux))
     text = eng._decode_burst_jit.trace(*args).lower(
         lowering_platforms=("tpu",)).compile().as_text()
     assert eng.program_inventory()["paged_attention"] == "pallas"

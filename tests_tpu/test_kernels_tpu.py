@@ -231,7 +231,6 @@ def test_pool_rests_as_the_burst_program_takes_it():
     gives its results in default layouts, whatever it was compiled for).
     One KV head of 128 features rests row-major as stated."""
     import incubator_mxnet_tpu as mx
-    from incubator_mxnet_tpu import random as mx_random
     from incubator_mxnet_tpu.models.gpt import GPTModel
     from incubator_mxnet_tpu.serving import GenerationEngine
     from incubator_mxnet_tpu.serving.kvcache import KVLayout
@@ -257,14 +256,9 @@ def test_pool_rests_as_the_burst_program_takes_it():
         got.append(eng.generate([3, 7, 11, 5, 9], max_new_tokens=24))
         assert resting() == allocated
         assert eng.program_inventory()["paged_attention"] == "pallas"
-        S = eng.max_slots
-        i32 = lambda *s: jnp.zeros(s, jnp.int32)              # noqa: E731
         params, aux = eng._param_fn()
         compiled = eng._decode_burst_jit.lower(
-            eng._cache, i32(S, 1), i32(S), i32(S), i32(S),
-            jnp.zeros(S, bool), i32(S, eng.max_blocks_per_slot),
-            eng._samp_tuple(), params, aux,
-            mx_random.new_key(eng._ctx)).compile()
+            eng._cache, eng._slot_state(), params, aux).compile()
         taken = {f.layout for f in compiled.input_formats[0][0]}
         given = {f.layout for f in compiled.output_formats[0]}
         assert taken == given == {l for _, l in allocated}
