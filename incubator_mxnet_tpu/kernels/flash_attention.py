@@ -548,6 +548,21 @@ def flash_attention(q, k, v, scale=None, causal=False, mask=None):
     return _dispatch(q, k, v, scale, causal, mask, with_lse=False)
 
 
+def _by_query_chunks(rows, q, axis, chunk=512):
+    """``rows(q's queries i0 .. i0 + chunk, i0)`` over ``q``'s query
+    ``axis`` 512 at a time, so that a long prompt's (T, T) scores never
+    exist at once; all at once where they are few or do not divide."""
+    T = q.shape[axis]
+    if T <= chunk or T % chunk:
+        return rows(q, 0)
+    n = T // chunk
+    qs = jnp.moveaxis(q.reshape(q.shape[:axis] + (n, chunk)
+                                + q.shape[axis + 1:]), axis, 0)
+    out = jax.lax.map(lambda a: rows(a[0], a[1]),
+                      (qs, jnp.arange(n, dtype=jnp.int32) * chunk))
+    return jnp.moveaxis(out, 0, axis).reshape(q.shape)
+
+
 def prefill_attention(q, k, v, window=None, scale=None):
     """Causal attention of a whole prompt in the layout a served layer
     holds it: ``q`` (B, T, Hq, D), ``k``/``v`` (B, T, Hkv, D) with
@@ -557,7 +572,7 @@ def prefill_attention(q, k, v, window=None, scale=None):
 
     The lax path: scores and softmax in float32, the products with the
     operands' own type accumulated in float32, queries taken 512 at a
-    time so that a long prompt's (T, T) scores never exist at once.  The
+    time (:func:`_by_query_chunks`).  The
     Pallas flash kernels above take neither groups nor a window."""
     B, T, Hq, D = q.shape
     Hkv = k.shape[2]
@@ -582,14 +597,7 @@ def prefill_attention(q, k, v, window=None, scale=None):
                        preferred_element_type=jnp.float32)
         return o.reshape(B, Tq, Hq, D).astype(q.dtype)
 
-    chunk = 512
-    if T <= chunk or T % chunk:
-        return rows(q, 0)
-    n = T // chunk
-    qs = jnp.moveaxis(q.reshape(B, n, chunk, Hq, D), 1, 0)
-    out = jax.lax.map(lambda a: rows(a[0], a[1]),
-                      (qs, jnp.arange(n, dtype=jnp.int32) * chunk))
-    return jnp.moveaxis(out, 0, 1).reshape(B, T, Hq, D)
+    return _by_query_chunks(rows, q, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -676,7 +684,7 @@ def _paged_kernel_kind(q, k_pages, q_heads, window, position_major=False):
         return "mha" if f32 and bs % 8 == 0 and D % 8 == 0 else None
     if position_major:          # the grouped kernel takes the stated shape
         return None
-    if H == 1 and D % 128 == 0 and (
+    if int(q_heads) % H == 0 and D % 128 == 0 and (
             (f32 and bs % 8 == 0)
             or (k_pages.dtype == jnp.bfloat16 and bs % 16 == 0)):
         return "gqa"
@@ -694,11 +702,13 @@ def paged_attention_impl(q, k_pages, q_heads=None, window=None,
     path, and the reference the kernels are tested against).  Two kernels
     exist: one query head a KV head, no window, over a float32 pool with
     ``block_size`` and ``D`` multiples of 8 (:func:`_paged_kernel`, the
-    VPU); and any number of query heads on ONE KV head — a chip's share
-    of a grouped-query layer — with or without a window, over a float32
-    or bfloat16 pool whose page is whole tiles (``D`` a multiple of 128,
-    ``block_size`` of 8, of 16 for bfloat16; :func:`_paged_gqa_kernel`,
-    the MXU).  Anything else takes the gather.  Decided from what is
+    VPU); and ``Hq / H`` query heads on each of ``H`` KV heads — a
+    grouped-query layer, or a chip's share of one — with or without a
+    window, over a float32 or bfloat16 pool stored as stated whose page
+    is whole tiles (``D`` a multiple of 128, ``block_size`` of 8, of 16
+    for bfloat16; :func:`_paged_gqa_kernel`, the MXU; one query head a KV
+    head with a window, or over a bfloat16 pool, takes it too).  Anything
+    else takes the gather.  Decided from what is
     visible at trace time, never from the environment;
     ``MXNET_FA_DECODE_FORCE_PALLAS=1`` is the test hook that takes the
     kernel (interpreted on a CPU) wherever the shapes allow it.
@@ -935,16 +945,19 @@ def _paged_work_list(tables, positions, n_q, bs, n_pages, window=None):
 
 
 def _paged_gqa_kernel(slot_ref, group_ref, page_ref, pos_ref, q_ref, *refs,
-                      scale, n_pages, n_cols, n_q, q_heads, window):
-    """:func:`_paged_kernel` for a chip's share of a grouped-query layer:
-    every query head of slot ``slot_ref[i]`` against the ONE KV head's
-    ``group_ref[i]``-th group of pages.  A page is a (bs, D) tile of the
-    pool in the pool's own type; the query rows — ``n_q`` positions times
-    ``q_heads`` heads, position-major, padded to whole tiles — meet it on
-    the MXU: scores ``q k^T`` and ``p v`` with operands of the pool's
-    type, accumulated in float32; the softmax runs in float32.  With a
-    ``window`` row j reads keys ``head - window < t <= head`` only, and
-    the slot's first step is the group that holds the first of them."""
+                      scale, n_pages, n_cols, n_q, q_heads, kv_heads, window):
+    """:func:`_paged_kernel` for a grouped-query layer: the ``q_heads``
+    query heads of each of the ``kv_heads`` KV heads of slot
+    ``slot_ref[i]`` against that head's part of the ``group_ref[i]``-th
+    group of pages.  A page is one block of the pool, all its KV heads —
+    ``kv_heads`` (bs, D) tiles in the pool's own type, one run of memory,
+    ONE fetch whatever the head count.  A KV head's query rows —
+    ``n_q`` positions times ``q_heads`` heads, position-major, padded to
+    whole tiles — meet its tiles on the MXU: scores ``q k^T`` and ``p v``
+    with operands of the pool's type, accumulated in float32; the softmax
+    runs in float32.  With a ``window`` row j reads keys ``head - window <
+    t <= head`` only, and the slot's first step is the group that holds
+    the first of them."""
     from jax.experimental import pallas as pl
     del page_ref
     k_refs, v_refs = refs[:n_pages], refs[n_pages:2 * n_pages]
@@ -952,8 +965,8 @@ def _paged_gqa_kernel(slot_ref, group_ref, page_ref, pos_ref, q_ref, *refs,
     i = pl.program_id(0)
     g = group_ref[i]
     pos = pos_ref[slot_ref[i]]
-    R = q_ref.shape[1]
-    bs = k_refs[0].shape[1]
+    R = q_ref.shape[1] // kv_heads      # a KV head's rows, whole tiles
+    bs = k_refs[0].shape[1] // kv_heads
     T = n_pages * bs
     n_keys = n_cols * bs
     first = 0 if window is None \
@@ -965,11 +978,6 @@ def _paged_gqa_kernel(slot_ref, group_ref, page_ref, pos_ref, q_ref, *refs,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    k = jnp.concatenate([r[0] for r in k_refs], axis=0)           # (T, D)
-    v = jnp.concatenate([r[0] for r in v_refs], axis=0)
-    s = jax.lax.dot_general(
-        q_ref[0].astype(k.dtype), k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale               # (R, T)
     idx = g * T + jax.lax.broadcasted_iota(jnp.int32, (R, T), 1)
     row = jax.lax.broadcasted_iota(jnp.int32, (R, T), 0)
     head = pos
@@ -978,17 +986,25 @@ def _paged_gqa_kernel(slot_ref, group_ref, page_ref, pos_ref, q_ref, *refs,
     live = idx <= jnp.minimum(head, n_keys - 1)
     if window is not None:
         live = live & (idx > head - window)
-    s = jnp.where(live, s, -1e30)
-    m_prev = m_ref[...]                                           # (R, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    # a row whose window starts in a later group has no key here
-    p = jnp.where(live, jnp.exp(s - m_new), 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                       # (R, D)
-    m_ref[...] = m_new
+    for h in range(kv_heads):
+        rows, keys = pl.ds(h * R, R), pl.ds(h * bs, bs)
+        k = jnp.concatenate([r[0, keys] for r in k_refs], axis=0)   # (T, D)
+        v = jnp.concatenate([r[0, keys] for r in v_refs], axis=0)
+        s = jax.lax.dot_general(
+            q_ref[0, rows].astype(k.dtype), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale           # (R, T)
+        s = jnp.where(live, s, -1e30)
+        m_prev = m_ref[rows]                                      # (R, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a row whose window starts in a later group has no key here
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+        l_ref[rows] = l_ref[rows] * alpha \
+            + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                   # (R, D)
+        m_ref[rows] = m_new
 
     @pl.when((g + 1) * T > jnp.minimum(pos + n_q - 1, n_keys - 1))
     def _fin():
@@ -1000,24 +1016,30 @@ def _paged_gqa_kernel(slot_ref, group_ref, page_ref, pos_ref, q_ref, *refs,
 def _paged_gqa_pallas(q, k_pages, v_pages, tables, positions, scale, window,
                       interpret):
     """:func:`_paged_verify_pallas` for ``q`` (S, Hq, n_q, D) over a pool
-    of ONE KV head ``[N, 1, bs, D]`` — taken as ``[N, bs, D]``, the same
-    bytes — with the work list bounded from below by ``window``."""
+    of ``H`` KV heads ``[N, H, bs, D]``, ``Hq // H`` query heads a KV
+    head — taken as ``[N, H * bs, D]``, the same bytes: a block with all
+    its heads is one page operand, so a step's fetches do not grow with
+    ``H`` — with the work list bounded from below by ``window``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     S, Hq, n_q, D = q.shape
-    N, _, bs, _ = k_pages.shape
+    N, H, bs, _ = k_pages.shape
+    G = Hq // H
     n_cols = tables.shape[1]
     n_pages = min(max(1, _PAGED_GROUP_KEYS // bs), n_cols)
     positions = positions.astype(jnp.int32)
     n_steps, slot, group, page = _paged_work_list(
         tables.astype(jnp.int32), positions, n_q, bs, n_pages, window)
-    R = n_q * Hq
+    R = n_q * G
     Rp = -(-R // 16) * 16               # whole tiles of either type
-    rows = jnp.swapaxes(q, 1, 2).reshape(S, R, D).astype(jnp.float32)
-    rows = jnp.pad(rows, ((0, 0), (0, Rp - R), (0, 0)))
-    spec_q = pl.BlockSpec((1, Rp, D), lambda i, slot, *_: (slot[i], 0, 0))
+    rows = jnp.swapaxes(q.reshape(S, H, G, n_q, D), 2, 3).reshape(
+        S, H, R, D).astype(jnp.float32)
+    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, Rp - R), (0, 0))).reshape(
+        S, H * Rp, D)
+    spec_q = pl.BlockSpec((1, H * Rp, D),
+                          lambda i, slot, *_: (slot[i], 0, 0))
     spec_pages = [
-        pl.BlockSpec((1, bs, D),
+        pl.BlockSpec((1, H * bs, D),
                      lambda i, slot, group, page, pos, j=j:
                      (page[i * n_pages + j], 0, 0))
         for j in range(n_pages)]
@@ -1027,27 +1049,27 @@ def _paged_gqa_pallas(q, k_pages, v_pages, tables, positions, scale, window,
         in_specs=[spec_q] + spec_pages + spec_pages,
         out_specs=spec_q,
         scratch_shapes=[
-            pltpu.VMEM((Rp, D), jnp.float32),
-            pltpu.VMEM((Rp, 1), jnp.float32),
-            pltpu.VMEM((Rp, 1), jnp.float32),
+            pltpu.VMEM((H * Rp, D), jnp.float32),
+            pltpu.VMEM((H * Rp, 1), jnp.float32),
+            pltpu.VMEM((H * Rp, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _paged_gqa_kernel, scale=scale, n_pages=n_pages, n_cols=n_cols,
-        n_q=n_q, q_heads=Hq, window=window)
+        n_q=n_q, q_heads=G, kv_heads=H, window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, Rp, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((S, H * Rp, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=32 * 2 ** 20),
         interpret=interpret,
     )(slot, group, page, positions, rows,
-      *([k_pages.reshape(N, bs, D)] * n_pages),
-      *([v_pages.reshape(N, bs, D)] * n_pages))
-    return jnp.swapaxes(out[:, :R].reshape(S, n_q, Hq, D), 1, 2).astype(
-        q.dtype)
+      *([k_pages.reshape(N, H * bs, D)] * n_pages),
+      *([v_pages.reshape(N, H * bs, D)] * n_pages))
+    out = out.reshape(S, H, Rp, D)[:, :, :R].reshape(S, H, n_q, G, D)
+    return jnp.swapaxes(out, 2, 3).reshape(S, Hq, n_q, D).astype(q.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret",
@@ -1172,8 +1194,11 @@ def paged_prefix_attention(q, k_pages, v_pages, table, ctx, window=None,
     cv = _dense_view(v_pages, table[None], position_major, D)
     T = ck.shape[2]
     if Hq != H or window is not None:
-        return _xla_grouped_decode_attention(
-            q, ck, cv, jnp.reshape(ctx, (1,)), scale, window)
+        def rows(q_rows, i0):       # whose first row sits at ctx + i0
+            return _xla_grouped_decode_attention(
+                q_rows, ck, cv, jnp.reshape(ctx + i0, (1,)), scale, window)
+
+        return _by_query_chunks(rows, q, 2)
     q_idx = jnp.arange(Tb, dtype=jnp.int32)
     key_idx = jnp.arange(T, dtype=jnp.int32)
     live = key_idx[None, :] <= (ctx + q_idx)[:, None]          # (Tb, T)

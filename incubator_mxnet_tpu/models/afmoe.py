@@ -26,65 +26,17 @@ programs call.
 """
 from __future__ import annotations
 
+import functools
 import math
 
-import numpy as _np
-
 from ..base import MXNetError
-from ..gluon.block import HybridBlock
-from ..ndarray.ndarray import NDArray, _invoke
-from .moe import _swiglu, held_experts_ffn, route_token_choice
+from .decoder import ServedDecoder, ServedLayer, rms_norm, rotary
+from .moe import _glu, held_experts_ffn, route_token_choice
 
 __all__ = ["AFMoELayer", "AFMoEModel", "rms_norm", "rotary"]
 
-#: the integer counters an expert layer returns from ``serve_cached``
-#: (summed over layers and steps by the engine, added on the host to
-#: ``mxtpu_moe_pairs_total`` / ``_pairs_held`` / ``_experts_touched``)
-MOE_COUNTERS = ("moe_pairs_total", "moe_pairs_held", "moe_experts_touched")
 
-
-def rms_norm(x, w, eps):
-    """``x * rsqrt(mean(x^2) + eps) * w`` over the last axis, computed in
-    float32, returned in ``x``'s type."""
-    import jax.numpy as jnp
-    from jax import lax
-    xf = x.astype(jnp.float32)
-    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def rotary(x, positions, theta):
-    """Rotary embedding, half-rotation form, no scaling: ``x`` (B, T, H, D),
-    ``positions`` (B, T) int32.  Float32 inside, ``x``'s type out."""
-    import jax.numpy as jnp
-    D = x.shape[-1]
-    inv = 1.0 / (float(theta) ** (jnp.arange(0, D, 2, dtype=jnp.float32)
-                                  / D))                        # (D/2,)
-    ang = positions.astype(jnp.float32)[..., None] * inv        # (B, T, D/2)
-    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, :, None, :]
-    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, :, None, :]
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :D // 2], xf[..., D // 2:]
-    rot = jnp.concatenate([-x2, x1], -1)
-    return (xf * cos + rot * sin).astype(x.dtype)
-
-
-def _adopt(param, value):
-    """Make ``value`` (a jax array already on its device, of the
-    parameter's shape and type) the parameter's data: no copy, no trip
-    through the host, no initial allocation — 8 GB of weights cannot be
-    held twice."""
-    if tuple(value.shape) != tuple(param.shape):
-        raise MXNetError(f"{param.name}: parameter has {param.shape}, "
-                         f"given {tuple(value.shape)}")
-    if _np.dtype(value.dtype) != _np.dtype(param.dtype):
-        raise MXNetError(f"{param.name}: parameter is {param.dtype}, "
-                         f"given {value.dtype}")
-    param._data = NDArray(value)
-    param._deferred_init = None
-
-
-class AFMoELayer(HybridBlock):
+class AFMoELayer(ServedLayer):
     """One sandwich block: ``h += post_attn_norm(Attn(input_norm(h)))``,
     ``h += post_mlp_norm(FFN(pre_mlp_norm(h)))``.  ``sliding`` layers
     carry the rotary embedding and a causal window; full layers carry no
@@ -93,11 +45,9 @@ class AFMoELayer(HybridBlock):
     the shared expert."""
 
     def __init__(self, cfg, sliding, dense, **kwargs):
-        super().__init__(**kwargs)
-        self._c = cfg
+        self._c = c = cfg
         self._sliding = bool(sliding)
         self._dense = bool(dense)
-        c = cfg
         d, D = c["hidden_size"], c["head_dim"]
         hq, hkv = c["num_attention_heads"], c["num_key_value_heads"]
         shapes = {
@@ -119,17 +69,10 @@ class AFMoELayer(HybridBlock):
                 experts_gate=(E, d, f), experts_up=(E, d, f),
                 experts_down=(E, f, d),
                 shared_gate=(d, fs), shared_up=(d, fs), shared_down=(fs, d))
-        self._names = tuple(shapes)
-        with self.name_scope():
-            for name, shape in shapes.items():
-                setattr(self, name, self.params.get(
-                    name, shape=shape, dtype=c["dtype"],
-                    grad_req=c["grad_req"],
-                    init="ones" if len(shape) == 1
-                    and name != "expert_bias" else None))
-
-    def _w(self, name):
-        return getattr(self, name).data()._data
+        super().__init__(
+            shapes, c["dtype"], c["grad_req"],
+            c["sliding_window"] if self._sliding else None,
+            random=("expert_bias",), **kwargs)
 
     # -- the block, over jax arrays -------------------------------------
     def _attention_inputs(self, h, positions):
@@ -161,8 +104,8 @@ class AFMoELayer(HybridBlock):
         B, T, d = x.shape
         xt = x.reshape(B * T, d)
         if self._dense:
-            y = _swiglu(xt, self._w("mlp_gate"), self._w("mlp_up"),
-                        self._w("mlp_down"))
+            y = _glu(xt, self._w("mlp_gate"), self._w("mlp_up"),
+                     self._w("mlp_down"))
             return y.astype(x.dtype).reshape(B, T, d), ()
         with jax.named_scope("moe.route"):
             # the router's scores in float32, over all published experts
@@ -179,8 +122,8 @@ class AFMoELayer(HybridBlock):
                 self._w("experts_down"),
                 None if live is None else live.reshape(B * T))
         with jax.named_scope("moe.shared"):
-            y = y + _swiglu(xt, self._w("shared_gate"), self._w("shared_up"),
-                            self._w("shared_down"))
+            y = y + _glu(xt, self._w("shared_gate"), self._w("shared_up"),
+                         self._w("shared_down"))
         return y.astype(x.dtype).reshape(B, T, d), counts
 
     def _block(self, h, positions, attend, live):
@@ -202,46 +145,8 @@ class AFMoELayer(HybridBlock):
             rms_norm(h, self._w("pre_mlp_layernorm"), eps), live)
         return h + rms_norm(m, self._w("post_mlp_layernorm"), eps), counts
 
-    @property
-    def window(self):
-        return int(self._c["sliding_window"]) if self._sliding else None
 
-    # -- the serving seam -------------------------------------------------
-    def serve_prefill(self, h, positions, live=None):
-        """A whole prompt with nothing cached: h (B, T, d), positions
-        (B, T).  Returns ``(h', k, v)``, k and v (B, T, kv_heads, D) as
-        the cache is to hold them."""
-        from ..kernels.flash_attention import prefill_attention
-        kept = []
-
-        def attend(q, k, v):
-            kept.extend((k, v))
-            return prefill_attention(q, k, v, window=self.window)
-
-        h, _ = self._block(h, positions, attend, live)
-        return h, kept[0], kept[1]
-
-    def serve_cached(self, h, positions, attend, live=None):
-        """Positions that attend through the cache: ``attend(q, k, v)``
-        is the engine's — it writes k and v (B, T, kv_heads, D) where
-        ``positions`` say and returns the attention of q (B, T, heads, D)
-        over what the cache then holds.  Returns ``(h', counts)``, counts
-        a dict of int32 scalars keyed by ``AFMoEModel.serve_counters``
-        (empty for a dense layer)."""
-        h, counts = self._block(h, positions, attend, live)
-        return h, dict(zip(MOE_COUNTERS, counts))
-
-    def hybrid_forward(self, F, x, **params):
-        def run(xv):
-            import jax.numpy as jnp
-            B, T, _ = xv.shape
-            pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None],
-                                   (B, T))
-            return self.serve_prefill(xv, pos)[0]
-        return _invoke(run, [x], name="afmoe_layer")
-
-
-class AFMoEModel(HybridBlock):
+class AFMoEModel(ServedDecoder):
     """Embedding (times ``sqrt(hidden_size)`` when ``mup_enabled``) ->
     layers -> final RMSNorm -> untied head without bias.
 
@@ -263,7 +168,6 @@ class AFMoEModel(HybridBlock):
                  rms_norm_eps=1e-5, route_norm=True, route_scale=1.0,
                  mup_enabled=True, max_position_embeddings=262144,
                  dtype="float32", grad_req="null", **kwargs):
-        super().__init__(**kwargs)
         layer_types = list(layer_types)
         if len(layer_types) != num_hidden_layers:
             raise MXNetError(
@@ -279,7 +183,7 @@ class AFMoEModel(HybridBlock):
                 f"experts {first_expert}..{first_expert + num_experts - 1} "
                 f"are not among the {published} published")
         import jax.numpy as jnp
-        self._cfg = dict(
+        cfg = dict(
             hidden_size=int(hidden_size), head_dim=int(head_dim),
             intermediate_size=int(intermediate_size),
             moe_intermediate_size=int(moe_intermediate_size),
@@ -293,93 +197,21 @@ class AFMoEModel(HybridBlock):
             rope_theta=float(rope_theta), rms_norm_eps=float(rms_norm_eps),
             route_norm=bool(route_norm), route_scale=float(route_scale),
             dtype=jnp.dtype(dtype), grad_req=grad_req)
-        self._vocab_size = int(vocab_size)
-        self._units = int(hidden_size)
-        self._max_length = int(max_position_embeddings)
         self._mup = bool(mup_enabled)
-        with self.name_scope():
-            self.embed_tokens = self.params.get(
-                "embed_tokens", shape=(vocab_size, hidden_size),
-                dtype=self._cfg["dtype"], grad_req=grad_req)
-            self.layers = []
-            for i, kind in enumerate(layer_types):
-                if kind not in ("sliding_attention", "full_attention"):
-                    raise MXNetError(f"layer {i}: no such layer type "
-                                     f"{kind!r}")
-                layer = AFMoELayer(self._cfg, kind == "sliding_attention",
-                                   i < num_dense_layers,
-                                   prefix=f"layers{i}_")
-                self.register_child(layer)
-                self.layers.append(layer)
-            self.norm = self.params.get(
-                "norm", shape=(hidden_size,), dtype=self._cfg["dtype"],
-                grad_req=grad_req, init="ones")
-            self.lm_head = self.params.get(
-                "lm_head", shape=(hidden_size, vocab_size),
-                dtype=self._cfg["dtype"], grad_req=grad_req)
-
-    # -- weights ----------------------------------------------------------
-    def adopt_arrays(self, tree):
-        """Take device arrays as the parameters, without a copy:
-        ``tree = {"embed_tokens", "norm", "lm_head", "layers": [{name:
-        array}]}`` with a layer's names as ``AFMoELayer`` registers them
-        (``benchmark/chip/reference/afmoe.py`` makes exactly this)."""
-        _adopt(self.embed_tokens, tree["embed_tokens"])
-        _adopt(self.norm, tree["norm"])
-        _adopt(self.lm_head, tree["lm_head"])
-        if len(tree["layers"]) != len(self.layers):
-            raise MXNetError(f"{len(tree['layers'])} layers given, the "
-                             f"model has {len(self.layers)}")
-        for layer, arrays in zip(self.layers, tree["layers"]):
-            if set(arrays) != set(layer._names):
-                raise MXNetError(
-                    f"{layer.name}: given {sorted(arrays)}, the layer has "
-                    f"{sorted(layer._names)}")
-            for name in layer._names:
-                _adopt(getattr(layer, name), arrays[name])
-
-    # -- the serving seam -------------------------------------------------
-    #: counters the expert layers return from ``serve_cached``
-    serve_counters = MOE_COUNTERS
-
-    def kv_layout(self):
-        c = self._cfg
-        return {"num_layers": len(self.layers),
-                "kv_heads": c["num_key_value_heads"],
-                "head_dim": c["head_dim"], "dtype": str(c["dtype"]),
-                "windows": tuple(l.window for l in self.layers),
-                "max_length": self._max_length}
-
-    def serve_layers(self):
-        return list(self.layers)
+        for i, kind in enumerate(layer_types):
+            if kind not in ("sliding_attention", "full_attention"):
+                raise MXNetError(f"layer {i}: no such layer type {kind!r}")
+        super().__init__(
+            vocab_size, hidden_size, max_position_embeddings, cfg,
+            [functools.partial(AFMoELayer, cfg, kind == "sliding_attention",
+                               i < num_dense_layers)
+             for i, kind in enumerate(layer_types)], grad_req, **kwargs)
 
     def serve_embed(self, tokens, positions):
-        """tokens, positions (B, T) int32 -> h (B, T, d).  Positions are
-        the layers' business (rotary, in sliding layers only)."""
-        del positions
-        h = self.embed_tokens.data()._data[tokens]
+        """tokens, positions (B, T) int32 -> h (B, T, d), times
+        ``sqrt(hidden_size)`` when ``mup_enabled``."""
+        h = super().serve_embed(tokens, positions)
         if self._mup:
             h = (h.astype("float32") * math.sqrt(self._units)
                  ).astype(h.dtype)
         return h
-
-    def serve_head(self, h):
-        """h (B, T, d) -> float32 logits (B, T, vocab)."""
-        import jax.numpy as jnp
-        x = rms_norm(h, self.norm.data()._data, self._cfg["rms_norm_eps"])
-        return jnp.dot(x, self.lm_head.data()._data,
-                       preferred_element_type=jnp.float32)
-
-    def hybrid_forward(self, F, ids, **params):
-        """Full causal forward, no cache: ids (B, T) -> logits (B, T, V)."""
-        def run(iv):
-            import jax.numpy as jnp
-            B, T = iv.shape
-            pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None],
-                                   (B, T))
-            h = self.serve_embed(iv, pos)
-            for layer in self.layers:
-                h = layer.serve_prefill(h, pos)[0]
-            return self.serve_head(h)
-        return _invoke(run, [ids], name="afmoe_forward",
-                       differentiable=False)

@@ -43,16 +43,31 @@ __all__ = ["MoEFFN", "MoELoss", "ep_rules", "route_token_choice",
 # training until ROADMAP D9 retires it.
 # ---------------------------------------------------------------------------
 
-def route_token_choice(logits, bias, k, route_norm=True, route_scale=1.0):
-    """Sigmoid token-choice routing: ``logits`` (T, E) float32 over ALL the
-    published experts, ``bias`` (E,) added for the choice only.  Returns
-    ``(idx (T, k) int32, w (T, k) float32)``: each token's ``k`` experts
-    and the weights their outputs are summed with — the chosen scores
-    themselves, normalised to sum to 1 when ``route_norm``, times
-    ``route_scale``."""
+def route_token_choice(logits, bias, k, route_norm=True, route_scale=1.0,
+                       score="sigmoid"):
+    """Token-choice routing: ``logits`` (T, E) float32 over ALL the
+    published experts.  Returns ``(idx (T, k) int32, w (T, k) float32)``:
+    each token's ``k`` experts and the weights their outputs are summed
+    with.
+
+    ``score="sigmoid"``: the scores are the logits' sigmoids, ``bias``
+    (E,) is added for the choice only, and the weights are the chosen
+    scores themselves, normalised to sum to 1 when ``route_norm``, times
+    ``route_scale``.  ``score="softmax"``: the ``k`` largest logits are
+    chosen (no bias: pass None) and the weights are the softmax over the
+    CHOSEN logits — which is the softmax over all E, top ``k``,
+    renormalised — times ``route_scale``."""
     import jax
     import jax.numpy as jnp
-    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    logits = logits.astype(jnp.float32)
+    if score == "softmax":
+        if bias is not None:
+            raise MXNetError("softmax-scored routing takes no choice bias")
+        top, idx = jax.lax.top_k(logits, k)
+        return idx.astype(jnp.int32), jax.nn.softmax(top, -1) * route_scale
+    if score != "sigmoid":
+        raise MXNetError(f"no such routing score: {score!r}")
+    s = jax.nn.sigmoid(logits)
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32)[None], k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if route_norm:
@@ -60,24 +75,31 @@ def route_token_choice(logits, bias, k, route_norm=True, route_scale=1.0):
     return idx.astype(jnp.int32), w * route_scale
 
 
-def _swiglu(x, w_gate, w_up, w_down):
-    """``W_down(silu(W_gate x) * W_up x)`` with ``(in, out)`` matrices:
-    products accumulate in float32, the activation is float32, operands
-    of the second product are of ``x``'s type.  Returns float32."""
+def _glu(x, w_gate, w_up, w_down, act="silu"):
+    """``W_down(act(W_gate x) * W_up x)`` with ``(in, out)`` matrices,
+    ``act`` ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU): products accumulate
+    in float32, the activation is float32, operands of the second product
+    are of ``x``'s type.  Returns float32."""
     import jax
     import jax.numpy as jnp
+    gate = {"silu": jax.nn.silu, "relu": jax.nn.relu}[act]
     g = jnp.dot(x, w_gate, preferred_element_type=jnp.float32)
     u = jnp.dot(x, w_up, preferred_element_type=jnp.float32)
-    mid = (jax.nn.silu(g) * u).astype(x.dtype)
+    mid = (gate(g) * u).astype(x.dtype)
     return jnp.dot(mid, w_down, preferred_element_type=jnp.float32)
 
 
+def _swiglu(x, w_gate, w_up, w_down):
+    return _glu(x, w_gate, w_up, w_down, "silu")
+
+
 def held_experts_ffn(x, idx, w, held, w_gate, w_up, w_down, live=None,
-                     tile=None):
+                     tile=None, act="silu"):
     """The part of ``sum_k w_k * Expert_k(x)`` that the experts HELD here
     give: ``held = (first, count)`` names the published experts
-    ``first .. first + count - 1``, whose SwiGLU matrices are the
-    stacked ``w_gate``/``w_up`` (count, d, f) and ``w_down`` (count, f, d).
+    ``first .. first + count - 1``, whose gated-unit matrices are the
+    stacked ``w_gate``/``w_up`` (count, d, f) and ``w_down`` (count, f, d),
+    the gate's activation ``act`` (``"silu"`` or ``"relu"``).
     ``x`` (T, d), ``idx``/``w`` (T, k) from :func:`route_token_choice`,
     ``live`` (T,) bool marks real tokens (padding and free slots route
     nowhere).  Returns ``(y (T, d) float32, (pairs, pairs_held,
@@ -124,10 +146,10 @@ def held_experts_ffn(x, idx, w, held, w_gate, w_up, w_down, live=None,
         row0 = starts[e] + (i - (ends[e] - chunks[e])) * tile
         r0 = jnp.minimum(row0, P - tile)
         rows = lax.dynamic_slice(xs, (r0, 0), (tile, d))
-        out = _swiglu(rows,
-                      lax.dynamic_index_in_dim(w_gate, e, 0, False),
-                      lax.dynamic_index_in_dim(w_up, e, 0, False),
-                      lax.dynamic_index_in_dim(w_down, e, 0, False))
+        out = _glu(rows,
+                   lax.dynamic_index_in_dim(w_gate, e, 0, False),
+                   lax.dynamic_index_in_dim(w_up, e, 0, False),
+                   lax.dynamic_index_in_dim(w_down, e, 0, False), act)
         at = r0 + row_ids
         mine = (at >= row0) & (at < jnp.minimum(row0 + tile,
                                                 starts[e] + n_e[e]))

@@ -621,8 +621,13 @@ class GenerationEngine:
                 raise MXNetError(
                     f"{self.name}: the model counts {n!r}, which "
                     "serving/metrics.py MODEL_COUNTERS does not register")
+        #: the window of the model's windowed layers (the narrowest, were
+        #: they to differ), None where every layer reads every position
+        self._window = min(
+            (w for w in self.layout.windows if w is not None), default=None)
         self._decode_counts = dict.fromkeys(
-            self._counters + ("decode_context_tokens",), 0)
+            self._counters + ("decode_context_tokens",)
+            + (("decode_window_tokens",) if self._window else ()), 0)
         if prefill_buckets:
             self.prefill_buckets = tuple(sorted(
                 {int(b) for b in prefill_buckets}))
@@ -1113,10 +1118,23 @@ class GenerationEngine:
     def _write_rows(self, pool, blk, off, rows):
         """Set position ``off`` of block ``blk`` — (S,) or (S, Q) each —
         to ``rows`` (S, H, D) or (S, Q, H, D)."""
+        import jax.numpy as jnp
         rows = rows.astype(pool.dtype)
         if self._position_major:
             return pool.at[blk, off].set(self._to_lanes(rows, pool))
-        return pool.at[blk, :, off].set(rows)
+        N, H, bs, D = pool.shape
+        if H == 1 or D % self.layout.LANES:
+            return pool.at[blk, :, off].set(rows)
+        # several heads of whole lanes: the grouped kernel reads such a
+        # pool as [N, H * bs, D] (the same bytes), and a write through
+        # that view leaves the compiler no other order to keep the pool in
+        # than the one it rests in — written as [N, H, bs, D] it keeps
+        # positions before heads inside the program and copies every pool
+        # on the way in, for the kernel and on the way out
+        # (tests/test_paged_attention.py)
+        col = jnp.arange(H, dtype=off.dtype) * bs + off[..., None]
+        return pool.reshape(N, H * bs, D).at[blk[..., None], col].set(
+            rows).reshape(pool.shape)
 
     def _note_paged_attention(self, tables, pool, q_heads, window):
         """Record what the paged attention entry points pick for a layer
@@ -1133,15 +1151,27 @@ class GenerationEngine:
     def _sum_counts(self, total, counts):
         return {n: total[n] + counts.get(n, 0) for n in self._counters}
 
-    def _cached_layers(self, tokens, pos, attend_for, live):
+    def _cached_layers(self, tokens, pos, attend_for, live, last=None):
         """Embed, run every layer through its ``serve_cached`` with the
-        program's ``attend_for(l)``, project: ``(logits, counts)``."""
+        program's ``attend_for(l)``, project: ``(logits, counts)`` — of
+        every position, or of position ``last`` alone (a prefill samples
+        from one row: a head over the whole vocabulary for every position
+        of a prompt is gigabytes that nothing reads)."""
         h = self.block.serve_embed(tokens, pos)
         counts = self._zero_counts()
         for l, layer in enumerate(self._layers):
             h, c = layer.serve_cached(h, pos, attend_for(l), live)
             counts = self._sum_counts(counts, c)
-        return self.block.serve_head(h), counts
+        return self.block.serve_head(self._row(h, last)), counts
+
+    @staticmethod
+    def _row(h, last):
+        """h (B, T, d) -> its position ``last`` alone, (B, 1, d); all of
+        it for None."""
+        import jax.numpy as jnp
+        if last is None:
+            return h
+        return jnp.take(h, last, axis=1)[:, None]
 
     def _prefill_paged_pure(self, cache, state, tokens, at, param_vals,
                             aux_vals):
@@ -1168,7 +1198,7 @@ class GenerationEngine:
                 h, k, v = layer.serve_prefill(h, pos, pos < n_valid)
                 ks.append(k)
                 vs.append(v)
-            return self.block.serve_head(h), ks, vs
+            return self.block.serve_head(self._row(h, n_valid - 1)), ks, vs
 
         logits, ks, vs = self._with_params(param_vals, aux_vals, key, body)
         out = list(cache)
@@ -1180,8 +1210,7 @@ class GenerationEngine:
                     out[l], self._strip(kh, j), table, j, False)
                 out[L + l] = self._scatter_block(
                     out[L + l], self._strip(vh, j), table, j, False)
-        last = jnp.take(logits[0], n_valid - 1, axis=0)
-        first, lp = self._sample_prefill(last, n_valid, samp)
+        first, lp = self._sample_prefill(logits[0, 0], n_valid, samp)
         if lp is not None:
             return tuple(out), first, lp
         return tuple(out), first
@@ -1227,11 +1256,11 @@ class GenerationEngine:
             q_idx = jnp.arange(Tb, dtype=jnp.int32)
             pos = jnp.minimum(ctx + q_idx, self.max_len - 1)[None]  # (1, Tb)
             return self._cached_layers(tokens, pos, attend_for,
-                                       (q_idx < n_valid)[None])[0]
+                                       (q_idx < n_valid)[None],
+                                       n_valid - 1)[0]
 
         logits = self._with_params(param_vals, aux_vals, key, body)
-        last = jnp.take(logits[0], n_valid - 1, axis=0)
-        first, lp = self._sample_prefill(last, ctx + n_valid, samp)
+        first, lp = self._sample_prefill(logits[0, 0], ctx + n_valid, samp)
         if lp is not None:
             return tuple(caches), first, lp
         return tuple(caches), first
@@ -1793,14 +1822,29 @@ class GenerationEngine:
         ``mxtpu_decode_context_tokens`` — slot ``s`` was live for
         ``steps[s]`` steps from write head ``positions[s]``, so its
         written positions sum to ``steps * (pos + 1) + steps * (steps -
-        1) / 2`` (nothing is pulled from the device for this one).
-        Warm-up traffic is not counted."""
+        1) / 2`` (nothing is pulled from the device for this one).  For
+        a model with windowed layers the same sum with each step's
+        written positions capped at the window goes to
+        ``mxtpu_decode_window_tokens``: a batch holds contexts on both
+        sides of the window, so min(mean context, window) would
+        overstate what a windowed layer reads.  Warm-up traffic is not
+        counted."""
         if self._warming:
             return
-        ctx = int(_np.sum(steps * (positions + 1)
-                          + steps * (steps - 1) // 2))
+
+        def ramp(first, n):         # first + (first + 1) + ... n terms
+            return int(_np.sum(n * first + n * (n - 1) // 2))
+
+        ctx = ramp(positions + 1, steps)
         _m.DECODE_CONTEXT_TOKENS.inc(ctx, model=self.name)
         self._decode_counts["decode_context_tokens"] += ctx
+        if self._window:
+            # the steps whose written positions are still inside the window
+            under = _np.clip(self._window - positions, 0, steps)
+            win = ramp(positions + 1, under) \
+                + int(_np.sum((steps - under) * self._window))
+            _m.DECODE_WINDOW_TOKENS.inc(win, model=self.name)
+            self._decode_counts["decode_window_tokens"] += win
         for n, v in zip(self._counters, counts):
             v = int(v)
             _m.MODEL_COUNTERS[n].inc(v, model=self.name)

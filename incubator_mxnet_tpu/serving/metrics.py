@@ -74,6 +74,11 @@ DECODE_CONTEXT_TOKENS = _telemetry.registry.counter(
     "mxtpu_decode_context_tokens",
     "written positions of the live slots (write head + 1), summed over "
     "decode steps: the context the step's attention had behind it")
+DECODE_WINDOW_TOKENS = _telemetry.registry.counter(
+    "mxtpu_decode_window_tokens",
+    "of mxtpu_decode_context_tokens, the positions a windowed layer "
+    "reads: min(written positions, window) of each live slot, summed "
+    "over decode steps; only for a model with windowed layers")
 #: counters a served model's layers return from the decode programs
 #: (``block.serve_counters``), by the model's name for each
 MODEL_COUNTERS = {"moe_pairs_total": MOE_PAIRS_TOTAL,

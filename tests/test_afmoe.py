@@ -153,10 +153,10 @@ def _layer_weights(rng, d, f, E):
 
 def _dense_layer(x, w, idx, wt):
     """The uncut layer, every expert over every token, masked."""
-    y = moe._swiglu(x, w["sg"], w["su"], w["sd"])
+    y = moe._glu(x, w["sg"], w["su"], w["sd"])
     for e in range(w["gate"].shape[0]):
         share = jnp.sum(jnp.where(idx == e, wt, 0.0), -1)
-        y = y + moe._swiglu(x, w["gate"][e], w["up"][e], w["down"][e]) \
+        y = y + moe._glu(x, w["gate"][e], w["up"][e], w["down"][e]) \
             * share[:, None]
     return y
 
@@ -172,7 +172,7 @@ def test_the_shares_add_up(tokens):
     x = jnp.asarray(rng.standard_normal((tokens, d)), jnp.float32)
     idx, wt = moe.route_token_choice(x @ w["router"], w["bias"], k, True,
                                      2.448)
-    total = moe._swiglu(x, w["sg"], w["su"], w["sd"])
+    total = moe._glu(x, w["sg"], w["su"], w["sd"])
     held_pairs = 0
     for first in range(0, E, 2):
         y, (pairs, held, touched) = moe.held_experts_ffn(
@@ -202,7 +202,7 @@ def test_no_token_is_dropped_at_a_skewed_router(tokens):
     y, (pairs, held, touched) = moe.held_experts_ffn(
         x, idx, wt, (0, E), w["gate"], w["up"], w["down"])
     assert int(held) == int(pairs) == tokens * k
-    want = _dense_layer(x, w, idx, wt) - moe._swiglu(x, w["sg"], w["su"],
+    want = _dense_layer(x, w, idx, wt) - moe._glu(x, w["sg"], w["su"],
                                                      w["sd"])
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5,
                                rtol=2e-5)

@@ -268,6 +268,28 @@ def test_profiler_capture_roundtrip_and_guard(tmp_path):
     assert done.is_set() and not telemetry_device.capture_active()
 
 
+def test_profiler_capture_writes_the_xplane_alone(tmp_path):
+    """The capture's device trace is one ``.xplane.pb`` that
+    ``ProfileData`` reads, where jax's export puts it, and no
+    ``trace.json.gz``."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    path = telemetry_device.capture_profile(0.05, out_dir=str(tmp_path))
+    files = [os.path.relpath(f, path) for f in glob.glob(
+        os.path.join(path, "**", "*"), recursive=True) if os.path.isfile(f)]
+    xplane = [f for f in files if f.endswith(".xplane.pb")]
+    assert len(xplane) == 1
+    assert xplane[0].split(os.sep)[:2] == ["plugins", "profile"]
+    assert sorted(files) == sorted(xplane + ["spans.json"])
+    assert [p.name for p in
+            ProfileData.from_file(os.path.join(path, xplane[0])).planes]
+    # the first capture left no session behind: a second one starts
+    import jax._src.profiler as jp
+    assert jp._profile_state.profile_session is None
+    telemetry_device.capture_profile(0.05, out_dir=str(tmp_path))
+
+
 # ------------- HTTP surface: server routes + router federation/fan-out
 def test_http_device_routes_and_router_federation(monkeypatch,
                                                   tmp_path):

@@ -195,6 +195,59 @@ def test_gqa_kernel_matches_lax_gather(dtype, n_q, window):
                                        else 1e-4)
 
 
+def _gqa_heads_case(dtype, kv_heads, group, n_q, seed=0):
+    """:func:`_gqa_case` over ``kv_heads`` KV heads with ``group`` query
+    heads each."""
+    rng = np.random.default_rng(seed)
+    n_cols, n_blocks = 32, 100                     # 512 keys a slot
+    kp = jnp.asarray(rng.standard_normal((n_blocks, kv_heads, BS, GD)), dtype)
+    vp = jnp.asarray(rng.standard_normal((n_blocks, kv_heads, BS, GD)), dtype)
+    tables = np.zeros((4, n_cols), np.int32)
+    tables[1, :3] = [7, 8, 9]
+    tables[2, :] = np.arange(20, 52)
+    tables[3, :] = np.arange(60, 92)
+    pos = np.asarray([0, 37, 300, 512 - n_q], np.int32)
+    q = jnp.asarray(rng.standard_normal((4, kv_heads * group, n_q, GD)),
+                    jnp.float32)
+    return q, kp, vp, jnp.asarray(tables), jnp.asarray(pos)
+
+
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("group", [1, 7])
+@pytest.mark.parametrize("kv_heads", [1, 2, 4])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_gqa_kernel_over_kv_heads_matches_lax_gather(dtype, kv_heads, group,
+                                                     window):
+    """The grouped kernel's KV-head axis: ``group`` query heads on each of
+    ``kv_heads`` KV heads, a page all the heads of a block, against the
+    gather (single-query decode and a 3-wide verify block), tolerances as
+    in :func:`test_gqa_kernel_matches_lax_gather`; query head n must read
+    KV head n // group (checked against plain numpy on one slot)."""
+    for n_q in (1, 3):
+        q, kp, vp, tables, pos = _gqa_heads_case(jnp.dtype(dtype), kv_heads,
+                                                 group, n_q)
+        got = fa._paged_gqa_pallas(q, kp, vp, tables, pos, SCALE, window,
+                                   interpret=True)
+        ref = fa._xla_paged_verify_decode_attention(q, kp, vp, tables, pos,
+                                                    SCALE, window)
+        tol = 2e-5 if dtype == "float32" else 2e-2
+        np.testing.assert_allclose(np.asarray(got)[1:], np.asarray(ref)[1:],
+                                   atol=tol, rtol=tol)
+    s, j = 2, 0                                    # slot 2, its first row
+    k = np.asarray(kp, np.float32)[np.asarray(tables)[s]]   # (cols, H, bs, D)
+    v = np.asarray(vp, np.float32)[np.asarray(tables)[s]]
+    head = int(pos[s]) + j
+    lo = 0 if window is None else max(0, head - window + 1)
+    for n in range(kv_heads * group):
+        kh = k[:, n // group].reshape(-1, GD)[lo:head + 1]
+        vh = v[:, n // group].reshape(-1, GD)[lo:head + 1]
+        sc = np.asarray(q)[s, n, j] @ kh.T * SCALE
+        w = np.exp(sc - sc.max())
+        np.testing.assert_allclose(
+            np.asarray(got)[s, n, j], (w / w.sum()) @ vh,
+            atol=3e-2 if dtype == "bfloat16" else 1e-4)
+
+
 def test_work_list_starts_at_the_window(monkeypatch):
     """With a window a slot's first step is the group that holds the
     first key its first row reads; groups before it are not visited."""
@@ -214,8 +267,9 @@ def test_work_list_starts_at_the_window(monkeypatch):
 
 
 def test_gqa_selection(monkeypatch):
-    """Grouped heads take a kernel only as one KV head with whole-tile
-    pages; every other grouping takes the gather."""
+    """Grouped heads take a kernel where a page is whole tiles (128
+    features, 8 positions of float32 or 16 of bfloat16) and the query
+    heads divide over the KV heads; everything else takes the gather."""
     monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
     q = jnp.zeros((2, 6, 128))
     pool = lambda h, bs, d, dt: jnp.zeros((4, h, bs, d), dt)   # noqa: E731
@@ -224,7 +278,20 @@ def test_gqa_selection(monkeypatch):
     assert impl(q, pool(1, 16, 128, jnp.bfloat16), 6) == "pallas"
     assert impl(q, pool(1, 8, 128, jnp.float32), 6) == "pallas"
     assert impl(q, pool(1, 8, 128, jnp.bfloat16), 6) == "lax_gather"
-    assert impl(q, pool(2, 16, 128, jnp.bfloat16), 6) == "lax_gather"
+    # more than one KV head: the same kernel, a page all heads of a block
+    assert impl(q, pool(2, 16, 128, jnp.bfloat16), 6) == "pallas"
+    assert impl(q, pool(4, 16, 128, jnp.bfloat16), 28, 4096) == "pallas"
+    assert impl(q, pool(4, 16, 128, jnp.bfloat16), 28) == "pallas"
+    assert impl(q, pool(4, 16, 128, jnp.bfloat16), 4, 4096) == "pallas"
+    assert impl(q, pool(4, 16, 128, jnp.bfloat16), 6) == "lax_gather"
+    assert impl(q, pool(4, 16, 64, jnp.bfloat16), 28) == "lax_gather"
+    assert impl(q, jnp.zeros((4, 16, 4, 128), jnp.bfloat16), 28,
+                position_major=True) == "lax_gather"
+    # and ONE KV head selects what it selected
+    assert fa._paged_kernel_kind(q, pool(1, 16, 128, jnp.bfloat16), 6,
+                                 4096) == "gqa"
+    assert fa._paged_kernel_kind(q, pool(16, 16, 64, jnp.float32), 16,
+                                 None) == "mha"
     assert impl(q, pool(1, 16, 64, jnp.float32), 6) == "lax_gather"
     assert impl(q, pool(16, 16, 64, jnp.float32)) == "pallas"
     assert impl(q, pool(16, 16, 64, jnp.float32), 16, 128) == "lax_gather"
@@ -357,6 +424,28 @@ def test_gqa_kernel_compiles_for_v5e_at_the_agent_cell_shapes(
     assert not re.search(r"= bf16\[32769,[^\]]*\]\{[^}]*\} copy\(", text)
 
 
+@pytest.mark.parametrize("window", [None, 4096])
+def test_gqa_kernel_compiles_for_v5e_at_the_docqa_cell_shapes(
+        window, one_chip, no_compile_cache):
+    """S 32, 28 query heads on 4 KV heads of 128, bs 16, 512 table
+    entries, a bfloat16 pool of 16,385 blocks: the kernel takes the pool
+    as it rests (``[N, H * bs, D]`` is the same bytes), without a copy."""
+    S, n_cols, N = 32, 512, 16385
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((N, 4, 16, 128), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, k, v, t, p: fa._paged_gqa_pallas(
+            q, k, v, t, p, 0.088, window, False),
+        sds((S, 28, 1, 128), jnp.bfloat16), pool, pool,
+        sds((S, n_cols), jnp.int32), sds((S,), jnp.int32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"= bf16\[16385,[^\]]*\]\{[^}]*\} copy\(", text)
+
+
 def _position_major(pages, lanes=128):
     """A stated pool (N, H, bs, D) as ``KVLayout.pool_shape`` stores it
     position-major: (N, bs, H, Dp), zeros on the lanes past D."""
@@ -418,6 +507,7 @@ def test_position_major_pool_reads_as_the_stated_one(entry, force,
     (12, 64, "float32", ((1217, 12, 16, 64), False)),  # chip_smoke's gpt2
     (1, 128, "bfloat16", ((1217, 1, 16, 128), False)),  # the agent cell's
     (8, 128, "float32", ((1217, 8, 16, 128), False)),
+    (4, 128, "bfloat16", ((1217, 4, 16, 128), False)),  # the docqa cell's
 ])
 def test_pool_is_stored_so_that_it_rests_as_the_programs_keep_it(
         heads, head_dim, dtype, want, topo):
@@ -567,3 +657,75 @@ def test_agent_cell_pool_stays_as_stated_and_is_not_copied_on_v5e(
         lowering_platforms=("tpu",)).compile().as_text()
     assert eng.program_inventory()["paged_attention"] == "pallas"
     assert not re.search(r"= bf16\[32769,[^\]]*\]\{[^}]*\} copy\(", text)
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "decode"])
+def test_docqa_cell_pool_stays_as_stated_and_is_not_copied_on_v5e(
+        program, topo, one_chip, no_compile_cache, monkeypatch):
+    """The SmallThinker cell's pool, ``bf16[16385, 4, 16, 128]``, rests
+    row-major as stated and the rule leaves it so.  Its decode and burst
+    programs (2 of the configuration's layers, a full and a windowed one,
+    8 experts held) must keep it in that order: the rows are written
+    through the ``[N, H * bs, D]`` view the grouped kernel reads, so no
+    pool is copied on the way in, for the kernel or on the way out (written
+    as ``[N, H, bs, D]`` the compiler kept positions before heads inside
+    the program: 48 whole-pool copies a burst, PERF.md section 6, PR 31),
+    and both layers take the kernel."""
+    import json
+    import os
+    import sys
+    chip = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "chip")
+    if chip not in sys.path:
+        sys.path.insert(0, chip)
+    from programs import smallthinker_serve
+    from reference import smallthinker as ref
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+    with open(os.path.join(chip, "configs",
+                           "smallthinker-21b-serve-l8.json")) as f:
+        cfg = json.load(f)
+    dep = cfg["deployment"]
+    cfg.update(num_hidden_layers=2, moe_num_primary_experts=8,
+               moe_num_primary_experts_published=8, vocab_size=512,
+               rope_layout=[0, 1], sliding_window_layout=[0, 1])
+    dt, d, V = jnp.dtype(dep["param_dtype"]), cfg["hidden_size"], 512
+    shape = lambda s: jax.ShapeDtypeStruct(s, dt)             # noqa: E731
+    net = smallthinker_serve.build_net(cfg)
+    net.adopt_arrays({
+        "embed_tokens": shape((V, d)), "norm": shape((d,)),
+        "lm_head": shape((d, V)),
+        "layers": [{n: shape(s) for n, s in ref.layer_shapes(cfg).items()}
+                   for _ in range(2)]})
+    S, N = dep["max_slots"], dep["num_blocks"]
+    eng = GenerationEngine(net, name="aot-docqa", max_slots=S,
+                           max_len=dep["max_len"], prefill_buckets=[512],
+                           paged=True, block_size=dep["block_size"],
+                           num_blocks=1 + dep["max_len"] // dep["block_size"],
+                           scan_steps=dep["scan_steps"])
+    pool, position_major = eng.layout.pool_shape(N, eng.block_size,
+                                                 topo.devices[0])
+    assert (pool, position_major, eng.layout.dtype) \
+        == ((16385, 4, 16, 128), False, "bfloat16")
+    assert eng.layout.windows == (None, 4096)
+
+    def sds(x, shape=None):
+        x = jnp.asarray(x) if not hasattr(x, "dtype") else x
+        return jax.ShapeDtypeStruct(shape or x.shape, x.dtype,
+                                    sharding=one_chip)
+
+    params, aux = eng._param_fn()
+    args = (tuple(sds(c, pool) for c in eng._cache),
+            jax.tree.map(sds, eng._slot_state()),
+            tuple(sds(p) for p in params), tuple(sds(a) for a in aux))
+    jitted = {"decode_burst": eng._decode_burst_jit,
+              "decode": eng._decode_jit}[program]
+    compiled = jitted.trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert eng.program_inventory()["paged_attention"] == "pallas"
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert not re.search(r"= bf16\[16385,[^\]]*\]\{[^}]*\} copy\(", text)
+    pools_in, pools_out = compiled.input_formats[0][0], \
+        compiled.output_formats[0]
+    assert {f.layout.major_to_minor for f in (*pools_in, *pools_out)} \
+        == {(0, 1, 2, 3)}

@@ -69,13 +69,19 @@ def bridge():
 
 # ------------------------------------------------------------ the bridge
 def test_bridge_enters_and_exits_lifo_per_thread(bridge):
+    # both threads alive at once: a thread that ended before the other
+    # began would hand it its id, and the log is read by thread id
+    both = threading.Barrier(2)
+
     def work(tag):
+        both.wait(10)
         with telemetry.trace_span(f"outer.{tag}"):
             with telemetry.trace_span(f"mid.{tag}"):
                 with telemetry.trace_span(f"inner.{tag}"):
                     time.sleep(0.002)
             with telemetry.trace_span(f"second.{tag}"):
                 pass
+        both.wait(10)
 
     threads = [threading.Thread(target=work, args=(t,)) for t in "ab"]
     for t in threads:

@@ -197,6 +197,46 @@ def test_gqa_paged_at_the_agent_cell_shapes(window):
           f"keys read: ms a call {line}", flush=True)
 
 
+@pytest.mark.parametrize("window", [None, 4096])
+def test_gqa_paged_at_the_docqa_cell_shapes(window):
+    """The grouped-query kernel over FOUR KV heads compiled for the chip at
+    the ``smallthinker-l8-docqa-closed`` cell's shapes — 32 slots, 28 query
+    heads on 4 KV heads of 128, block 16, 512 table entries, a bfloat16
+    pool, contexts 1,500-7,700 on both sides of the window — against the
+    lax gather at ``highest``, with and without the window's lower bound,
+    tolerances as at the agent cell's shapes.  A page is a block with all
+    its four heads (16 KB, one fetch).  The timing line is a record, not a
+    claim (PERF.md, PR 31, has what was read)."""
+    rng = onp.random.default_rng(13)
+    S, H, Hq, Dh, bs, n_cols = 32, 4, 28, 128, 16, 512
+    N = 1 + S * n_cols
+    kp = _rand(rng, (N, H, bs, Dh), jnp.bfloat16)
+    vp = _rand(rng, (N, H, bs, Dh), jnp.bfloat16)
+    tables = jnp.asarray(
+        1 + rng.permutation(S * n_cols).reshape(S, n_cols), jnp.int32)
+    pos = jnp.asarray(rng.integers(1500, 7700, S), jnp.int32)
+    q = _rand(rng, (S, Hq, Dh), jnp.bfloat16)
+    scale = 1.0 / math.sqrt(Dh)
+
+    def kernel(q, k, v, t, p):
+        return fa._paged_gqa_pallas(q[:, :, None, :], k, v, t, p, scale,
+                                    window, False)[:, :, 0, :]
+
+    def gather(q, k, v, t, p):
+        return fa._xla_paged_decode_attention(q, k, v, t, p, scale, window)
+
+    got = onp.asarray(jax.jit(kernel)(q, kp, vp, tables, pos), onp.float32)
+    ref = _ref(gather, q, kp, vp, tables, pos)
+    onp.testing.assert_allclose(got, ref, **TOL)
+    line = {name: round(_ms_per_call(step, q, kp, vp, tables, pos), 4)
+            for name, step in (("pallas", kernel), ("lax_gather", gather))}
+    keys = int(onp.sum(onp.minimum(onp.asarray(pos) + 1, window or 10**9)))
+    nbytes = 2 * H * Dh * 2 * keys
+    print(f"\ngqa paged attention, docqa cell, window {window}, {keys} "
+          f"keys read = {nbytes / 1e6:.1f} MB = {nbytes / 819e9 * 1e3:.3f} "
+          f"ms of bandwidth: ms a call {line}", flush=True)
+
+
 def test_engine_traces_the_kernel_on_the_chip():
     """A paged engine on the chip decodes (per step and in bursts) through
     the kernel and says so in its inventory; the per-step and the scanned
