@@ -1910,6 +1910,9 @@ class ContinuousBatcher(DynamicBatcher):
             ops = getattr(self.engine, "operand_sources", None)
             if ops is not None:
                 out["operands"] = ops()
+            sd = getattr(self.engine, "sample_dispatches", None)
+            if sd is not None:
+                out["sample_dispatches"] = sd()
             if self._decode_health_last is not None:
                 out["decode_health"] = dict(self._decode_health_last)
                 out["nonfinite_generations"] = \

@@ -723,6 +723,9 @@ class GenerationEngine:
         self._rebuilt, self._edited = False, 0
         self._operand_sources = dict.fromkeys(
             ("carried", "patched", "rebuilt", "rows"), 0)
+        #: decode, burst and verify dispatches by the branch their
+        #: sampling step starts on (``mxtpu_sample_dispatches``)
+        self._sample_branches = dict.fromkeys(("greedy", "full"), 0)
         self._last_logprobs = None
         self._last_prefill_logprobs = None
         self._last_verify_logprobs = None
@@ -997,6 +1000,28 @@ class GenerationEngine:
         edited, for ``GET /v1/models``."""
         return dict(self._operand_sources)
 
+    def _count_sample_branch(self, live) -> None:
+        """One decode, burst or verify dispatch over the slots that hold
+        a table (a burst: those of them not ``done``, ``live`` (S,)
+        bool): the branch its sampling step takes
+        (``mxtpu_sample_dispatches{branch}``), which is the program's own
+        predicate — a live slot with a temperature — evaluated on the
+        host's rows; nothing is pulled from the device.  A burst counts
+        as it starts: its later steps fall to ``greedy`` once its last
+        sampled slot is done.  Warm-up traffic is not counted."""
+        if self._warming:
+            return
+        live = (self._tables[:, 0] != 0) & live
+        branch = "full" if (self._samp_temp[live] > 0.0).any() \
+            else "greedy"
+        _m.SAMPLE_DISPATCHES.inc(model=self.name, branch=branch)
+        self._sample_branches[branch] += 1
+
+    def sample_dispatches(self) -> dict:
+        """Lifetime counts of :meth:`_count_sample_branch`, for ``GET
+        /v1/models``."""
+        return dict(self._sample_branches)
+
     def _slot_operands(self, state):
         """Traced: the slot state's columns as the programs use them —
         ``(last (S, 1), pos, budget, eos, done, tables, samp)``."""
@@ -1031,19 +1056,22 @@ class GenerationEngine:
                                                  (0, 0)),
                 "bias": state["bias"], "key": key}
 
-    # traced helpers (called from inside the pure programs)
+    # traced helpers (called from inside the pure programs): each is
+    # sampling.sample_tokens, whose one branch of the whole batch skips
+    # the sampler where no live slot samples (``live``: the slots whose
+    # token is read; a freed slot keeps its last request's temperature)
     def _sample_prefill(self, last, first_pos, samp):
         """First generated token from prefill logits ``last`` (V,);
         ``first_pos`` is the sequence position it will occupy."""
-        from .sampling import _sample_row, step_keys, topn_logprobs
+        from .sampling import sample_tokens, step_keys, topn_logprobs
         temp, topk, topp, bias, root = samp
-        skey = step_keys(root, first_pos)
-        first = _sample_row(last, temp, topk, topp, bias, skey)
+        first = sample_tokens(last, temp, topk, topp, bias,
+                              step_keys(root, first_pos))
         lp = topn_logprobs(last, bias, self.logprobs_topn) \
             if self.logprobs_topn else None
         return first, lp
 
-    def _sample_step(self, lg, key_idx, samp):
+    def _sample_step(self, lg, key_idx, samp, live):
         """Next token per slot from decode logits ``lg`` (S, V);
         ``key_idx`` (S,) the sequence positions the sampled tokens will
         occupy (write-head + 1 — the burst scan's position carry feeds
@@ -1051,21 +1079,18 @@ class GenerationEngine:
         from .sampling import step_keys, sample_tokens
         temps, topks, topps, biases, roots = samp
         return sample_tokens(lg, temps, topks, topps, biases,
-                             step_keys(roots, key_idx))
+                             step_keys(roots, key_idx), live)
 
-    def _sample_verify(self, logits, pos_q, samp):
+    def _sample_verify(self, logits, pos_q, samp, live):
         """Per-position sampled tokens for the verify program: logits
         (S, Q, V), ``pos_q`` (S, Q) the positions of the consumed
         tokens; output (S, Q) — column j is the token AFTER consuming
         position pos_q[:, j], keyed at pos_q + 1, so each column is
         bit-identical to what per-step decode would sample there."""
-        import jax
-        from .sampling import _sample_row, step_keys
+        from .sampling import step_keys, sample_tokens
         temps, topks, topps, biases, roots = samp
-        keys = step_keys(roots[:, None, :], pos_q + 1)
-        row = jax.vmap(_sample_row, in_axes=(0, None, None, None,
-                                             None, 0))
-        return jax.vmap(row)(logits, temps, topks, topps, biases, keys)
+        return sample_tokens(logits, temps, topks, topps, biases,
+                             step_keys(roots[:, None, :], pos_q + 1), live)
 
     # -- pure programs --------------------------------------------------
     # The five bodies below know nothing of a model's insides: they call
@@ -1320,8 +1345,8 @@ class GenerationEngine:
 
         logits, counts = self._with_params(param_vals, aux_vals, key, body)
         lg = logits[:, 0, :]
-        nxt = self._sample_step(lg, positions + 1, samp)
         live = tables[:, 0] != 0
+        nxt = self._sample_step(lg, positions + 1, samp, live)
         out = (tuple(caches),
                self._advanced(state, key_next,
                               jnp.where(live, nxt, last_tokens[:, 0]),
@@ -1377,6 +1402,9 @@ class GenerationEngine:
         k = int(self.scan_steps)
         self._paged_impls = set()
         rows = jnp.arange(S)
+        # a draft's burst runs every slot (spec_step): one that holds no
+        # table is still nobody's token
+        held = tables[:, 0] != 0
 
         def run_scan():
             def step(carry, _):
@@ -1392,8 +1420,8 @@ class GenerationEngine:
                 lg = logits[:, 0, :]
                 # keyed at pos + 1 (the position this token will
                 # occupy): the carry IS the per-step key split
-                nxt = self._sample_step(lg, pos + 1, samp)
                 emit = ~done
+                nxt = self._sample_step(lg, pos + 1, samp, emit & held)
                 emitted2 = emitted + emit.astype(jnp.int32)
                 done2 = done | (emit & ((nxt == eos_ids)
                                         | (emitted2 >= budgets)))
@@ -1486,7 +1514,7 @@ class GenerationEngine:
                 jnp.broadcast_to((tables[:, 0] != 0)[:, None], (S, Q)))[0]
 
         logits = self._with_params(param_vals, aux_vals, key, body)
-        nxt = self._sample_verify(logits, pos_q, samp)
+        nxt = self._sample_verify(logits, pos_q, samp, tables[:, 0] != 0)
         if self.logprobs_topn:
             from .sampling import topn_logprobs
             lp = topn_logprobs(logits, samp[3][:, None, :],
@@ -1582,10 +1610,11 @@ class GenerationEngine:
             raise
 
     @contextlib.contextmanager
-    def _advancing(self, call, *args):
+    def _advancing(self, call, *args, live=True):
         """Enqueue a decode, burst or verify program, which takes the
         slot state donated, and yield its (future) results without the
-        cache and the state, which are rebound here.  What follows pulls
+        cache and the state, which are rebound here (``live``: the slots
+        a burst starts with, for :meth:`_count_sample_branch`).  What follows pulls
         them, so the worker loop's ``operands`` phase ends at the enqueue
         and ``decode_wait`` begins.  If the dispatch or a pull fails the
         donated state is gone with it: the next dispatch rebuilds it
@@ -1593,6 +1622,7 @@ class GenerationEngine:
         try:
             out = list(self._guarded(call, *args))
             self._count_operands()
+            self._count_sample_branch(live)
             _m.loop_phase_switch("decode_wait", "serve.decode.wait")
             self._cache, self._state = out[:2]
             yield out[2:]
@@ -1789,7 +1819,8 @@ class GenerationEngine:
                      _BUDGET: _np.asarray(budgets, _np.int32).reshape(S),
                      _EOS: _np.asarray(eos_ids, _np.int32).reshape(S),
                      _DONE: ~_np.asarray(active, bool).reshape(S)})
-        with self._advancing(self._decode_burst) as out:
+        with self._advancing(self._decode_burst,
+                             live=self._rows[:, _DONE] == 0) as out:
             counts = self._pop_extras(out)
             toks, emitted = _np.asarray(out[0]), _np.asarray(out[1])
         self._follow_burst(toks, emitted)

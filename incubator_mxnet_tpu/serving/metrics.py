@@ -130,6 +130,13 @@ SAMPLE_TOKENS = _telemetry.registry.counter(
     "mxtpu_sample_tokens",
     "tokens emitted by stochastically sampled (temperature > 0) "
     "requests, per model")
+SAMPLE_DISPATCHES = _telemetry.registry.counter(
+    "mxtpu_sample_dispatches",
+    "decode, burst and verify dispatches by the branch their sampling "
+    "step takes: branch=greedy (no live slot has a temperature: the "
+    "argmax alone) or full (at least one does: sort, filters and "
+    "Gumbel noise over every slot's logits); the program's own "
+    "predicate, evaluated on the host's rows at dispatch")
 SAMPLE_CONSTRAINED = _telemetry.registry.counter(
     "mxtpu_sample_constrained_requests",
     "generation requests decoded under a constrained-output grammar "

@@ -20,11 +20,25 @@ position, which is what makes seeded runs bit-identical across every
 dispatch path and at any speculative accept rate (the Gumbel-coupled
 acceptance argument in ``GenerationEngine.spec_step``).
 
-Sampling itself is branchless keyed Gumbel-max: filter the biased
-logits to the top-k/top-p support, add gumbel noise from the position
-key, argmax.  ``temperature == 0`` selects the plain biased argmax via
-``jnp.where``, so the greedy path emits bit-identical tokens to the
-pre-sampling programs while compiling to the same program set.
+Sampling itself is keyed Gumbel-max, branchless PER SLOT: filter the
+biased logits to the top-k/top-p support, add gumbel noise from the
+position key, argmax; ``temperature == 0`` selects the plain biased
+argmax via ``jnp.where``, so a greedy slot emits bit-identical tokens
+to the pre-sampling programs whatever its neighbours do.  Around the
+slots there is ONE branch a step, of the whole batch
+(:func:`sample_tokens`): ``lax.cond(any(temperature > 0 & live), full,
+greedy)``, read off the program's own operands.  A step in which no
+live slot samples takes the argmax alone — no stable sort, softmax,
+cumulative sum or Gumbel noise over ``[slots, vocabulary]``, which
+``where`` would compute and throw away (a fifth to a third of a greedy
+decode step on the chip, PERF.md PR 32).  The predicate is a scalar
+OUTSIDE the ``vmap``: a ``cond`` under ``vmap`` with a per-slot
+predicate lowers to a ``select`` that runs both sides.  It is masked by
+``live`` because a released slot keeps the temperature of the request
+that last held it (``GenerationEngine.set_slot_sampling``): unmasked,
+one sampled request would hold every later greedy batch on the full
+branch until its slot is joined again.  Both branches live in the same
+program: the compiled set stays closed.
 """
 from __future__ import annotations
 
@@ -187,9 +201,10 @@ def _gumbel_row(key, V):
 def _sample_row(lg, temperature, top_k, top_p, bias, key):
     """One slot: biased logits (V,) → sampled token id (scalar int32).
     Branchless — ``temperature == 0`` selects the biased argmax via
-    ``where``, so the greedy result is bit-identical to the
-    pre-sampling ``jnp.argmax`` while tracing ONE program for every
-    parameter setting.  Filter conventions follow
+    ``where``, so a greedy slot of a batch that samples is bit-identical
+    to the pre-sampling ``jnp.argmax`` (the batch's one branch, which
+    skips all of this when no live slot samples, is
+    :func:`sample_tokens`).  Filter conventions follow
     ``models/gpt.py:_sample_fn``: temperature scales before the
     filters, ``top_k <= 0`` (or >= vocab) disables top-k, and the
     nucleus filter's exclusive cumsum keeps the top-1 token
@@ -213,14 +228,47 @@ def _sample_row(lg, temperature, top_k, top_p, bias, key):
     return jnp.where(temperature > 0.0, sampled, greedy)
 
 
-def sample_tokens(logits, temperatures, top_ks, top_ps, biases, keys):
-    """Per-slot keyed Gumbel-max sampling: ``logits`` (S, V) →
-    token ids (S,) int32.  All parameters are traced operands —
-    ``temperatures``/``top_ks``/``top_ps`` (S,), ``biases`` (S, V),
-    ``keys`` (S, 2) uint32 from :func:`step_keys`."""
+def _greedy(logits, biases):
+    """The greedy branch's whole batch: the biased argmax over the last
+    axis, int32 — the expression :func:`_sample_row` selects for a
+    ``temperature == 0`` slot, so the branch taken never shows in a
+    greedy token."""
+    import jax.numpy as jnp
+    return jnp.argmax((logits + biases).astype(jnp.float32),
+                      axis=-1).astype(jnp.int32)
+
+
+def sample_tokens(logits, temperatures, top_ks, top_ps, biases, keys,
+                  live=None):
+    """Per-slot keyed Gumbel-max sampling behind the batch's one branch:
+    ``logits`` (S, V) → token ids (S,) int32.  All parameters are traced
+    operands — ``temperatures``/``top_ks``/``top_ps`` (S,), ``biases``
+    (S, V), ``keys`` (S, 2) uint32 from :func:`step_keys`, ``live`` (S,)
+    bool the slots whose token anyone reads (None: all).  Also the
+    verify grid — ``logits`` (S, Q, V), ``keys`` (S, Q, 2), a slot's Q
+    positions sharing its parameters → (S, Q) — and a prefill's one row
+    — ``logits`` (V,), scalar parameters → ().
+
+    ``full`` is :func:`_sample_row` over every slot, greedy ones
+    included; ``greedy`` the argmax it would have selected for them.
+    The scalar predicate keeps ``lax.cond`` a real conditional (module
+    docstring): where no live slot samples, the sort never runs."""
     import jax
-    return jax.vmap(_sample_row)(logits, temperatures, top_ks, top_ps,
-                                 biases, keys)
+    import jax.numpy as jnp
+    from jax import lax
+    row, greedy_biases = _sample_row, biases
+    if logits.ndim == 3:
+        row = jax.vmap(row, in_axes=(0, None, None, None, None, 0))
+        greedy_biases = biases[:, None, :]
+    if logits.ndim > 1:
+        row = jax.vmap(row)
+    sampling = temperatures > 0.0
+    if live is not None:
+        sampling &= live
+    return lax.cond(
+        jnp.any(sampling),
+        lambda: row(logits, temperatures, top_ks, top_ps, biases, keys),
+        lambda: _greedy(logits, greedy_biases))
 
 
 def topn_logprobs(logits, biases, n: int):

@@ -4,7 +4,9 @@ spec-on/off, temperature->0 greedy parity with the cache-free oracle,
 Gumbel-coupled speculative sampling preserving the no-draft sampled
 stream bit-for-bit, per-token logprobs, multi-token stop sequences,
 JSON-mode constrained output, n>1 candidate fan-out, and the seed
-replay contract over HTTP."""
+replay contract over HTTP; and the batch's one branch — a step in
+which no live slot samples takes the argmax alone, bit-identically.
+"""
 import json
 import urllib.error
 import urllib.request
@@ -21,9 +23,12 @@ from incubator_mxnet_tpu.serving import (ContinuousBatcher,
                                          GenerationEngine, ModelServer,
                                          SamplingParams)
 from incubator_mxnet_tpu.serving import slo as _slo
-from incubator_mxnet_tpu.serving.sampling import (JsonMaskMachine,
+from incubator_mxnet_tpu.serving import sampling as _sampling
+from incubator_mxnet_tpu.serving.sampling import (MASK_OFF,
+                                                  JsonMaskMachine,
                                                   derive_candidate_seed,
-                                                  root_key, stop_trim)
+                                                  root_key, sample_tokens,
+                                                  step_keys, stop_trim)
 
 
 @pytest.fixture(autouse=True)
@@ -234,6 +239,302 @@ def test_first_token_frequency_matches_model(paged_eng, _net):
     emp = counts / n
     assert abs(emp - p).max() < 0.2         # ~3 sigma at n=48 for any p
     assert 0.5 * abs(emp - p).sum() < 0.35  # total variation
+
+
+# ------------------------------------------------- the batch's one branch
+def _reference_row(lg, temperature, top_k, top_p, bias, key):
+    """The sampler as it stood before the batch-level branch, kept
+    here verbatim: every slot pays sort, softmax, cumsum and noise, and
+    ``where`` picks the argmax for a greedy one."""
+    import jax
+    import jax.numpy as jnp
+    V = lg.shape[-1]
+    lgb = (lg + bias).astype(jnp.float32)
+    greedy = jnp.argmax(lgb, axis=-1).astype(jnp.int32)
+    z = lgb / jnp.maximum(temperature.astype(jnp.float32), 1e-6)
+    srt = jnp.sort(z)[::-1]
+    kk = jnp.where(top_k <= 0, V, jnp.minimum(top_k, V))
+    keep = z >= srt[kk - 1]
+    probs = jax.nn.softmax(srt)
+    before = jnp.cumsum(probs) - probs
+    cutoff = jnp.min(jnp.where(before < top_p, srt, jnp.inf))
+    keep &= z >= cutoff
+    sampled = jnp.argmax(jnp.where(keep, z, MASK_OFF)
+                         + _sampling._gumbel_row(key, V),
+                         axis=-1).astype(jnp.int32)
+    return jnp.where(temperature > 0.0, sampled, greedy)
+
+
+def _reference_tokens(logits, temps, topks, topps, biases, keys):
+    """The three callers' old expressions: a prefill's row, the step's
+    ``vmap``, the verify grid's nested one."""
+    import jax
+    row = _reference_row
+    if logits.ndim == 3:
+        row = jax.vmap(row, in_axes=(0, None, None, None, None, 0))
+    if logits.ndim > 1:
+        row = jax.vmap(row)
+    return row(logits, temps, topks, topps, biases, keys)
+
+
+def _operands(shape, temps, seed=0):
+    """Random sampler operands for ``shape`` = (V,), (S, V) or
+    (S, Q, V): logits quantised so that every row holds ties (also at
+    its maximum), slot 0 (or the row) under a ``MASK_OFF`` bias that
+    leaves three tokens, top-k / top-p set on every slot."""
+    rng = np.random.RandomState(seed)
+    V = shape[-1]
+    lead = shape[:1] if len(shape) > 1 else ()
+    logits = np.round(rng.randn(*shape) * 2).astype(np.float32)
+    best = logits.max(axis=-1, keepdims=True)
+    logits[..., V // 2:] = np.where(rng.rand(*shape)[..., V // 2:] < 0.1,
+                                    best, logits[..., V // 2:])
+    biases = np.zeros(lead + (V,), np.float32)
+    masked = biases[0] if lead else biases
+    masked[:] = MASK_OFF
+    masked[[5, 17, V - 1]] = 0.0
+    temps = np.broadcast_to(np.asarray(temps, np.float32), lead).copy()
+    topks = np.full(lead, 7, np.int32)
+    topps = np.full(lead, 0.9, np.float32)
+    roots = rng.randint(0, 2 ** 31, lead + (2,)).astype(np.uint32)
+    pos = rng.randint(1, 60, shape[:-1]).astype(np.int32)
+    keys = step_keys(roots[:, None, :] if len(shape) == 3 else roots, pos)
+    return logits, temps, topks, topps, biases, keys
+
+
+SHAPES = {"prefill": (97,), "step": (6, 97), "verify": (6, 3, 97)}
+
+
+@pytest.mark.parametrize("kind", sorted(SHAPES))
+def test_greedy_batch_takes_argmax_bit_identically(kind):
+    """No slot samples: the branch's argmax is what the old sampler's
+    ``where`` selected, ties and a masked row included."""
+    import jax
+    args = _operands(SHAPES[kind], 0.0)
+    got = np.asarray(jax.jit(sample_tokens)(*args))
+    assert np.array_equal(got, np.asarray(
+        jax.jit(_reference_tokens)(*args)))
+    want = np.argmax(args[0] + (args[4][:, None] if kind == "verify"
+                                else args[4]), axis=-1)
+    assert np.array_equal(got, want)
+    assert got.dtype == np.int32
+
+
+@pytest.mark.parametrize("kind", sorted(SHAPES))
+def test_mixed_batch_bit_identical_to_old_sampler(kind):
+    """One sampled slot among greedy ones (the row itself in a prefill)
+    takes the full branch: every slot, greedy or not, as before."""
+    import jax
+    temps = 0.9 if kind == "prefill" else [0, 0, 0.9, 0, 0, 0]
+    for seed in range(4):
+        args = _operands(SHAPES[kind], temps, seed)
+        live = None if kind == "prefill" else np.ones(6, bool)
+        got = np.asarray(jax.jit(sample_tokens)(*args, live))
+        assert np.array_equal(got, np.asarray(
+            jax.jit(_reference_tokens)(*args)))
+
+
+@pytest.mark.parametrize("kind", ["step", "verify"])
+def test_slot_that_is_not_live_does_not_select_full(kind):
+    """The predicate is masked by ``live``: a slot nobody reads, hot
+    from the request that last held it, leaves the batch on the argmax
+    (seen in its own token: the argmax, not the noise's choice)."""
+    import jax
+    temps = [0, 0, 5.0, 0, 0, 0]
+    args = _operands(SHAPES[kind], temps)
+    full = np.asarray(jax.jit(sample_tokens)(*args, np.ones(6, bool)))
+    cold = np.asarray(jax.jit(sample_tokens)(*_operands(SHAPES[kind],
+                                                        0.0)))
+    assert not np.array_equal(full[2], cold[2])     # the noise shows
+    live = np.ones(6, bool)
+    live[2] = False
+    assert np.array_equal(
+        np.asarray(jax.jit(sample_tokens)(*args, live)), cold)
+
+
+def _drive(eng, reqs, burst):
+    """Run ``reqs`` — {slot: (prompt, budget, SamplingParams or None)}
+    — side by side through per-step decode or ``decode_burst`` until
+    each has its budget; {slot: tokens}."""
+    S = eng.max_slots
+    out = {}
+    for s, (prompt, budget, sp) in reqs.items():
+        eng.set_slot_sampling(s, sp)
+        out[s] = [eng.prefill(np.asarray(prompt, np.int32), s,
+                              reserve_tokens=len(prompt) + budget)]
+    live = set(reqs)
+    while live:
+        for s in [s for s in live if len(out[s]) >= reqs[s][1]]:
+            eng.release_slot(s)
+            live.discard(s)
+        if not live:
+            break
+        last, pos = np.zeros(S, np.int32), np.zeros(S, np.int32)
+        bud, act = np.ones(S, np.int32), np.zeros(S, bool)
+        for s in live:
+            last[s] = out[s][-1]
+            pos[s] = len(reqs[s][0]) + len(out[s]) - 1
+            bud[s] = reqs[s][1] - len(out[s])
+            act[s] = True
+        if burst:
+            toks, emitted = eng.decode_burst(
+                last, pos, bud, np.full(S, -1, np.int32), act)
+            for s in live:
+                out[s] += [int(t) for t in toks[:int(emitted[s]), s]]
+        else:
+            nxt = eng.decode(last, pos)
+            for s in live:
+                out[s].append(int(nxt[s]))
+    return out
+
+
+def _delta(eng, before):
+    return {k: v - before[k] for k, v in eng.sample_dispatches().items()}
+
+
+@pytest.mark.parametrize("burst", [False, True], ids=["step", "burst"])
+def test_mixed_engine_batch_matches_solo_runs(paged_eng, _net, burst):
+    """A seeded sampled slot beside a greedy one: each emits what it
+    emits alone, and the sampled one, budget 6 of the greedy one's 14,
+    ends inside a burst whose later steps (and the dispatches after it,
+    its slot released with its temperature still in the row) are greedy
+    again."""
+    eng = paged_eng
+    sp = SamplingParams(temperature=0.9, top_k=8, top_p=0.9, seed=11)
+    other = [2, 7, 1, 8]
+    solo = eng.generate(PROMPT, 6, sampling=sp)
+    greedy = greedy_reference(_net, other, 14)
+    before = eng.sample_dispatches()
+    out = _drive(eng, {0: (PROMPT, 6, sp), 1: (other, 14, None)}, burst)
+    assert out[0] == solo
+    assert out[1] == greedy
+    assert eng._samp_temp[0] > 0            # released, not cleared
+    got = _delta(eng, before)
+    # sampled tokens 2..6 need 5 steps or 2 bursts of 4; the greedy
+    # slot's other 8 steps or 2 bursts run with slot 0 freed
+    assert got == ({"full": 2, "greedy": 2} if burst
+                   else {"full": 5, "greedy": 8})
+
+
+def test_sample_dispatches_counter_and_models_line(paged_eng):
+    """A greedy run counts ``greedy`` alone; one sampled join flips the
+    next dispatch to ``full``; the slot it leaves behind flips nothing.
+    ``GET /v1/models`` and the registry carry the same counts."""
+    eng = paged_eng
+    b = ContinuousBatcher(eng, name="smp-p")
+    try:
+        before = eng.sample_dispatches()
+        b.submit(PROMPT, 10)
+        got = _delta(eng, before)
+        assert got["full"] == 0 and got["greedy"] >= 2
+        before = eng.sample_dispatches()
+        b.submit(PROMPT, 10, sampling=SamplingParams(temperature=0.8,
+                                                     seed=3))
+        got = _delta(eng, before)
+        assert got["greedy"] == 0 and got["full"] >= 2
+        before = eng.sample_dispatches()
+        b.submit(PROMPT, 10)
+        got = _delta(eng, before)
+        assert got["full"] == 0 and got["greedy"] >= 2
+        assert b.stats()["sample_dispatches"] == eng.sample_dispatches()
+        series = telemetry.registry.get(
+            "mxtpu_sample_dispatches").sample()["by"]
+    finally:
+        b.close()
+    for branch in ("greedy", "full"):
+        assert series[f"branch={branch},model=smp-p"] > 0
+
+
+def test_spec_greedy_counts_greedy_on_both_engines(_net):
+    """Verify dispatches count too, and a greedy speculative run keeps
+    target and draft on the argmax — also the draft's burst, which runs
+    EVERY slot (``spec_step``), beside a slot that holds no table and
+    still carries a temperature."""
+    tgt = GenerationEngine(_net, name="smp-ct", max_slots=2, max_len=64,
+                           block_size=8, prefix_cache=False, scan_steps=0)
+    dr = GenerationEngine(_gpt(seed=5), name="smp-cd", max_slots=2,
+                          max_len=64, block_size=8, prefix_cache=False)
+    tgt.attach_draft(dr, spec_k=3)
+    tgt.set_slot_sampling(1, SamplingParams(temperature=0.9, seed=3))
+    assert dr._samp_temp[1] > 0
+    assert tgt.generate(PROMPT, 12) == greedy_reference(_net, PROMPT, 12)
+    for eng in (tgt, dr):
+        got = eng.sample_dispatches()
+        assert got["full"] == 0 and got["greedy"] >= 2
+    tgt.generate(PROMPT, 12, sampling=SamplingParams(temperature=0.7,
+                                                     seed=7))
+    assert tgt.sample_dispatches()["full"] >= 2
+    assert dr.sample_dispatches()["full"] >= 2
+
+
+def _sorts_guarded(text):
+    """Of StableHLO ``text``: (its ``sort`` operations, whether every
+    path from ``main`` to each passes through a ``stablehlo.case``
+    branch).  jax outlines ``jnp.sort`` into a private function, so a
+    sort's guard may be the ``case`` around a call of its function."""
+    import re
+    lines = text.splitlines()
+    where, fn, depth, cases = [], None, 0, []
+    for line in lines:
+        if "func.func" in line:
+            fn, depth, cases = line.split("@")[1].split("(")[0], 0, []
+        where.append((fn, bool(cases)))     # the lines a case encloses
+        if '"stablehlo.case"' in line:
+            cases.append(depth)
+        depth += line.count("({") - line.count("})")
+        while cases and depth <= cases[-1]:
+            cases.pop()
+
+    def guarded(i, seen=()):
+        fn, in_case = where[i]
+        if in_case:
+            return True
+        if fn == "main" or fn in seen:
+            return False
+        calls = [j for j, l in enumerate(lines)
+                 if re.search(rf"call @{re.escape(fn)}\(", l)]
+        return bool(calls) and all(guarded(j, seen + (fn,))
+                                   for j in calls)
+
+    sorts = [i for i, l in enumerate(lines) if "stablehlo.sort" in l]
+    return len(sorts), all(guarded(i) for i in sorts)
+
+
+@pytest.mark.parametrize("program", ["decode_burst", "decode", "verify"])
+def test_lowered_program_sorts_only_inside_the_branch(paged_eng, program):
+    """The lowered text holds exactly one ``sort``, reached only
+    through a ``case`` branch: a refactor that moves the ``cond`` under
+    the ``vmap`` fails here instead of bringing the sort back in
+    silence."""
+    import jax
+    eng = paged_eng
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)
+    S = eng.max_slots
+    extra = (np.zeros((S, 4), np.int32), np.zeros(S, np.int32)) \
+        if program == "verify" else ()
+    args = jax.tree.map(sds, (eng._cache, eng._slot_state(), *extra,
+                              *eng._param_fn()))
+    text = getattr(eng, f"_{program}_jit").lower(*args).as_text()
+    assert _sorts_guarded(text) == (1, True)
+
+
+def test_cond_under_vmap_is_what_the_lowering_check_catches():
+    """The refactor the check above is for: a per-slot predicate under
+    ``vmap`` lowers ``cond`` to a ``select`` that runs both sides."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def per_slot(lg, t, k, p, b, key):
+        return lax.cond(t > 0.0,
+                        lambda: _reference_row(lg, t, k, p, b, key),
+                        lambda: jnp.argmax(lg + b).astype(jnp.int32))
+
+    args = _operands(SHAPES["step"], 0.0)
+    text = jax.jit(jax.vmap(per_slot)).lower(*args).as_text()
+    assert _sorts_guarded(text) == (1, False)
+    assert _sorts_guarded(jax.jit(sample_tokens).lower(*args).as_text()) \
+        == (1, True)
 
 
 # --------------------------------------------------------- batcher layer
