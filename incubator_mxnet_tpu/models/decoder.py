@@ -39,10 +39,15 @@ def rms_norm(x, w, eps):
     return (y * w.astype(jnp.float32)).astype(x.dtype)
 
 
-def rotary(x, positions, theta):
+def rotary(x, positions, theta, dims=None):
     """Rotary embedding, half-rotation form, no scaling: ``x`` (B, T, H, D),
-    ``positions`` (B, T) int32.  Float32 inside, ``x``'s type out."""
+    ``positions`` (B, T) int32.  Float32 inside, ``x``'s type out.  With
+    ``dims`` only a head's first ``dims`` features are turned (among
+    themselves); the rest pass as they are."""
     import jax.numpy as jnp
+    if dims is not None and dims != x.shape[-1]:
+        return jnp.concatenate(
+            [rotary(x[..., :dims], positions, theta), x[..., dims:]], -1)
     D = x.shape[-1]
     inv = 1.0 / (float(theta) ** (jnp.arange(0, D, 2, dtype=jnp.float32)
                                   / D))                        # (D/2,)
@@ -82,6 +87,13 @@ class ServedLayer(HybridBlock):
     the number of latest positions it reads.  The subclass writes
     ``_block``."""
 
+    #: None for a layer that keeps keys and values in the block pool; for a
+    #: layer that keeps a state of constant size a sequence instead, that
+    #: state's leaves as ``((shape, dtype), ...)`` — the engine holds one
+    #: array a leaf, a row a sequence, and hands the rows through
+    #: :meth:`serve_recurrent`
+    state_shapes = None
+
     def __init__(self, shapes, dtype, grad_req, window, random=(), **kwargs):
         super().__init__(**kwargs)
         self._names = tuple(shapes)
@@ -108,6 +120,12 @@ class ServedLayer(HybridBlock):
         (B, T).  Returns ``(h', k, v)``, k and v (B, T, kv_heads, D) as
         the cache is to hold them."""
         from ..kernels.flash_attention import prefill_attention
+        if self.state_shapes is not None:   # from an empty state, not kept
+            import jax.numpy as jnp
+            rows = tuple(jnp.zeros((h.shape[0],) + tuple(shape), dtype)
+                         for shape, dtype in self.state_shapes)
+            return self.serve_recurrent(h, positions, rows, live)[0], \
+                None, None
         kept = []
 
         def attend(q, k, v):
@@ -126,6 +144,21 @@ class ServedLayer(HybridBlock):
         (empty for a layer that counts nothing)."""
         h, counts = self._block(h, positions, attend, live)
         return h, dict(zip(MOE_COUNTERS, counts))
+
+    def serve_recurrent(self, h, positions, rows, live=None,
+                        snapshot_every=0):
+        """Positions of a layer with ``state_shapes``: ``rows`` the
+        sequences' state (a tuple of leaves, leading axis B).  Returns
+        ``(h', rows', snapshots, counts)`` as ``_block`` describes them."""
+        kept = []
+
+        def carry(update):
+            out, new, snaps = update(rows, int(snapshot_every))
+            kept.extend((new, snaps))
+            return out
+
+        h, counts = self._block(h, positions, carry, live)
+        return h, kept[0], kept[1], dict(zip(MOE_COUNTERS, counts))
 
     def hybrid_forward(self, F, x, **params):
         def run(xv):
@@ -194,6 +227,7 @@ class ServedDecoder(HybridBlock):
                 "kv_heads": c["num_key_value_heads"],
                 "head_dim": c["head_dim"], "dtype": str(c["dtype"]),
                 "windows": tuple(l.window for l in self.layers),
+                "states": tuple(l.state_shapes for l in self.layers),
                 "max_length": self._max_length}
 
     def serve_layers(self):

@@ -27,6 +27,7 @@ The jit is wrapped in :func:`telemetry.instrument_jit` under
 from __future__ import annotations
 
 import contextlib
+import threading
 import warnings
 import weakref
 from typing import Callable, List, Optional, Sequence, Tuple
@@ -41,6 +42,7 @@ from .. import telemetry as _telemetry
 from .. import telemetry_device as _telemetry_device
 from .. import health as _health
 from . import metrics as _m
+from .kvcache import NO_SNAPSHOTS
 
 __all__ = ["InferenceEngine", "GenerationEngine", "derive_buckets",
            "derive_prefill_buckets"]
@@ -116,7 +118,8 @@ def _register_device_observers(engine) -> None:
     if hasattr(engine, "cache_bytes"):
         def kv_bytes():
             eng = wref()
-            return eng.cache_bytes if eng is not None else 0
+            return eng.cache_bytes + eng.state_bytes \
+                if eng is not None else 0
         _telemetry_device.register_owner("kv:" + engine.name, kv_bytes)
 
 
@@ -488,7 +491,11 @@ class GenerationEngine:
     """Autoregressive generation as a closed set of compiled programs
     over a PREALLOCATED paged KV cache: per layer one K and one V pool of
     ``num_blocks`` blocks of ``block_size`` positions, managed by a
-    :class:`~.kvcache.BlockPool`.
+    :class:`~.kvcache.BlockPool` — and, for a layer that keeps a state of
+    constant size a sequence instead of keys (``KVLayout.states``), one
+    array a leaf of that state beside the pools, a row a slot plus the
+    snapshot rows prefix hits start from (docs/serving.md "The layer
+    interface", "State snapshots").
 
     The naive serving path re-runs prefill over the whole growing
     context every token — O(n^2) work and one fresh dispatch per request
@@ -569,6 +576,8 @@ class GenerationEngine:
                  prefix_cache: Optional[bool] = None,
                  scan_steps: Optional[int] = None,
                  logprobs_topn: Optional[int] = None,
+                 state_snapshot_tokens: Optional[int] = None,
+                 state_snapshot_rows: Optional[int] = None,
                  ctx=None):
         import jax
         from ..base import getenv_int, getenv_bool
@@ -659,9 +668,30 @@ class GenerationEngine:
                 f"num_blocks {nb} cannot hold even one max_len slot "
                 f"({self.max_blocks_per_slot} blocks + null block)")
         self.num_blocks = nb
+        # the layers that keep blocks (a K and a V pool each, in this
+        # order) and those that keep a state of constant size a sequence
+        # (``KVLayout.states``; one array a leaf, a row a sequence)
+        self._pool_of = {l: i for i, l in enumerate(self.layout.kv_layers)}
+        self._n_kv = len(self._pool_of)
+        self._state_layers = tuple(
+            l for l in range(self.num_layers) if l not in self._pool_of)
+        # the state store's rows: one a slot (row s is slot s's), then the
+        # snapshot rows the pool hands out, then one null row, where a
+        # program writes a state nobody keeps
+        self.state_snapshot_tokens = self.state_snapshot_rows = 0
+        if self._state_layers and self.prefix_cache_enabled:
+            self.state_snapshot_tokens = int(
+                state_snapshot_tokens or 128 * self.block_size)
+            self.state_snapshot_rows = int(
+                4 * self.max_slots if state_snapshot_rows is None
+                else state_snapshot_rows)
+        self._null_row = self.max_slots + self.state_snapshot_rows
         self.pool = BlockPool(nb, self.block_size,
                               prefix_cache=self.prefix_cache_enabled,
-                              model=self.name)
+                              model=self.name,
+                              snapshot_every=self.state_snapshot_tokens,
+                              snapshot_rows=self.state_snapshot_rows,
+                              first_snapshot_row=self.max_slots)
         #: the shape each pool is stored in on this device, and whether
         #: that is position-major (:meth:`~.kvcache.KVLayout.pool_shape`:
         #: where the stated [N, H, bs, D] would rest in a layout no
@@ -685,6 +715,7 @@ class GenerationEngine:
         # mid-process takes effect on the next engine, not this one
         self._health_on = _health.enabled()
         self._last_decode_health = None
+        self._params_lock = threading.Lock()
         self._settle_params()
         # sampling plane (serving/sampling.py, docs/serving.md
         # "Sampling"): per-slot temperature / top-k / top-p / bias row /
@@ -762,7 +793,8 @@ class GenerationEngine:
         self.draft: Optional["GenerationEngine"] = None
         self.spec_k = 0
         self._warmup_done = False
-        self._cache = ()
+        self._cache = self._recur = ()
+        self._prefilled = dict.fromkeys(("miss", "hit", "prefix_hit"), 0)
         self.reset()
         _register_device_observers(self)
 
@@ -781,8 +813,12 @@ class GenerationEngine:
         self._aux = [p for p in params if p.grad_req == "null"]
 
     def _param_fn(self):
-        return (tuple(p._data._data for p in self._trainable),
-                tuple(p._data._data for p in self._aux))
+        # not while a trace has its tracers in the Parameters
+        # (:meth:`_with_params`): a worker that replaces a hung one may
+        # dispatch while the old one is still tracing its first burst
+        with self._params_lock:
+            return (tuple(p._data._data for p in self._trainable),
+                    tuple(p._data._data for p in self._aux))
 
     def _with_params(self, param_vals, aux_vals, key, body):
         """functional_call's substitution mechanics with a custom body:
@@ -792,15 +828,16 @@ class GenerationEngine:
         from .. import random as _random
         all_params = self._trainable + self._aux
         all_vals = list(param_vals) + list(aux_vals)
-        saved = [p._data._data for p in all_params]
-        try:
-            for p, v in zip(all_params, all_vals):
-                p._data._set_data(v)
-            with _ag.pause(train_mode=False), _random.trace_stream(key):
-                return body()
-        finally:
-            for p, v in zip(all_params, saved):
-                p._data._set_data(v)
+        with self._params_lock:
+            saved = [p._data._data for p in all_params]
+            try:
+                for p, v in zip(all_params, all_vals):
+                    p._data._set_data(v)
+                with _ag.pause(train_mode=False), _random.trace_stream(key):
+                    return body()
+            finally:
+                for p, v in zip(all_params, saved):
+                    p._data._set_data(v)
 
     # -- sampling plane --------------------------------------------------
     # Host side: a slot's parameters are columns of its row of the slot
@@ -1169,6 +1206,71 @@ class GenerationEngine:
             tables, pool, q_heads, window, self._position_major))
         self._paged_attention = "+".join(sorted(self._paged_impls))
 
+    def _pools(self, l):
+        """Where layer ``l``'s K and V pools lie in a program's cache."""
+        return self._pool_of[l], self._n_kv + self._pool_of[l]
+
+    def _state_leaves(self, l):
+        """Where state layer ``l``'s leaves lie in a program's cache,
+        after the ``2 * n_kv`` pools."""
+        at = 2 * self._n_kv
+        for s in self._state_layers:
+            n = len(self.layout.states[s])
+            if s == l:
+                return range(at, at + n)
+            at += n
+        raise MXNetError(f"{self.name}: layer {l} keeps no state")
+
+    def _recur_prefill(self, caches, start, slot, snap_rows):
+        """The state layers' hand in a prefill program: a layer starts
+        from row ``start`` of its leaves in ``caches`` (None: zeros, a
+        sequence's beginning), its state after the prompt goes to row
+        ``slot`` and the states at the snapshot boundaries to the rows
+        ``snap_rows`` names, boundary by boundary (the null row where none
+        is kept).  ``caches`` is the program's list, updated in place."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        def put(arr, row, at):
+            return lax.dynamic_update_slice(
+                arr, row.astype(arr.dtype), (at,) + (0,) * (arr.ndim - 1))
+
+        def recur(l, layer, h, pos, live):
+            leaves = self._state_leaves(l)
+            rows = tuple(
+                jnp.zeros((1,) + caches[i].shape[1:], caches[i].dtype)
+                if start is None
+                else lax.dynamic_slice_in_dim(caches[i], start, 1)
+                for i in leaves)
+            h, new, snaps, counts = layer.serve_recurrent(
+                h, pos, rows, live, self.state_snapshot_tokens)
+            for j, i in enumerate(leaves):
+                if snaps is not None:       # in order: a later boundary
+                    for b in range(snaps[j].shape[1]):  # may reuse a row
+                        caches[i] = put(caches[i], snaps[j][:, b],
+                                        snap_rows[b])
+                caches[i] = put(caches[i], new[j], slot)
+            return h, counts
+        return recur
+
+    def _recur_decode(self, caches):
+        """The state layers' hand in the decode programs: rows ``0 .. S -
+        1`` of a layer's leaves are the slots' own, read and written in
+        place (a slot that is not live comes back as it was)."""
+        from jax import lax
+        S = self.max_slots
+
+        def recur(l, layer, h, pos, live):
+            leaves = self._state_leaves(l)
+            rows = tuple(lax.slice_in_dim(caches[i], 0, S) for i in leaves)
+            h, new, _, counts = layer.serve_recurrent(h, pos, rows, live)
+            for j, i in enumerate(leaves):
+                caches[i] = lax.dynamic_update_slice(
+                    caches[i], new[j].astype(caches[i].dtype),
+                    (0,) * caches[i].ndim)
+            return h, counts
+        return recur
+
     def _zero_counts(self):
         import jax.numpy as jnp
         return {n: jnp.zeros((), jnp.int32) for n in self._counters}
@@ -1176,16 +1278,21 @@ class GenerationEngine:
     def _sum_counts(self, total, counts):
         return {n: total[n] + counts.get(n, 0) for n in self._counters}
 
-    def _cached_layers(self, tokens, pos, attend_for, live, last=None):
+    def _cached_layers(self, tokens, pos, attend_for, live, last=None,
+                       recur=None):
         """Embed, run every layer through its ``serve_cached`` with the
-        program's ``attend_for(l)``, project: ``(logits, counts)`` — of
+        program's ``attend_for(l)`` — a layer that keeps a state through
+        the program's ``recur`` — and project: ``(logits, counts)`` — of
         every position, or of position ``last`` alone (a prefill samples
         from one row: a head over the whole vocabulary for every position
         of a prompt is gigabytes that nothing reads)."""
         h = self.block.serve_embed(tokens, pos)
         counts = self._zero_counts()
         for l, layer in enumerate(self._layers):
-            h, c = layer.serve_cached(h, pos, attend_for(l), live)
+            if l in self._pool_of:
+                h, c = layer.serve_cached(h, pos, attend_for(l), live)
+            else:
+                h, c = recur(l, layer, h, pos, live)
             counts = self._sum_counts(counts, c)
         return self.block.serve_head(self._row(h, last)), counts
 
@@ -1206,27 +1313,36 @@ class GenerationEngine:
         ``at`` int32 is ``[n_valid, slot]``: the table and the sampling
         operands are the slot's row of ``state``, which is read and not
         donated.  Positions past the table's reservation redirect to the
-        null block."""
+        null block.  For a model with state layers ``at`` goes on with the
+        row for the state at each snapshot boundary of the bucket; those
+        layers start from zeros and leave their last state in the slot's
+        row (:meth:`_recur_prefill`)."""
         import jax.numpy as jnp
-        L = self.num_layers
+        L = self._n_kv
         Tb = tokens.shape[1]
         bs = self.block_size
         n_valid = at[0]
         table, samp = self._slot_row(state, at[1])
         key = state["key"]
 
+        out = list(cache)
+        recur = self._recur_prefill(out, None, at[1], at[2:]) \
+            if self._state_layers else None
+
         def body():
             pos = jnp.arange(Tb, dtype=jnp.int32)[None]
             h = self.block.serve_embed(tokens, pos)
             ks, vs = [], []
-            for layer in self._layers:
-                h, k, v = layer.serve_prefill(h, pos, pos < n_valid)
-                ks.append(k)
-                vs.append(v)
+            for l, layer in enumerate(self._layers):
+                if l in self._pool_of:
+                    h, k, v = layer.serve_prefill(h, pos, pos < n_valid)
+                    ks.append(k)
+                    vs.append(v)
+                else:
+                    h, _ = recur(l, layer, h, pos, pos < n_valid)
             return self.block.serve_head(self._row(h, n_valid - 1)), ks, vs
 
         logits, ks, vs = self._with_params(param_vals, aux_vals, key, body)
-        out = list(cache)
         for l in range(L):
             kh = self._block_rows(ks[l][0], out[l])        # (H, Tb, D)
             vh = self._block_rows(vs[l][0], out[L + l])
@@ -1248,10 +1364,14 @@ class GenerationEngine:
         K/V at positions [ctx, ctx+Tb) and attending through the block
         table (:func:`paged_prefix_attention`).  ``at`` int32 is
         ``[n_valid, slot, ctx]`` — operands, so one program per suffix
-        bucket serves every hit length and every slot."""
+        bucket serves every hit length and every slot.  For a model with
+        state layers ``at`` goes on with the snapshot row those layers
+        start from (the state after ``ctx`` positions) and the rows for
+        the boundaries of the bucket, which lie at ``ctx`` + multiples of
+        the snapshot spacing: a hit always ends at a snapshot."""
         import jax.numpy as jnp
         from ..kernels.flash_attention import paged_prefix_attention
-        L = self.num_layers
+        L = self._n_kv
         Tb = tokens.shape[1]
         bs = self.block_size
         caches = list(cache)
@@ -1259,20 +1379,24 @@ class GenerationEngine:
         table, samp = self._slot_row(state, at[1])
         key = state["key"]
         j0 = ctx // bs
+        recur = self._recur_prefill(caches, at[3], at[1], at[4:]) \
+            if self._state_layers else None
 
-        def attend_for(l):
+        def attend_for(layer):
+            l, lv = self._pools(layer)
+
             def attend(q, k, v):             # (1, Tb, heads, D) each
                 knh = self._block_rows(k[0], caches[l])
-                vnh = self._block_rows(v[0], caches[L + l])
+                vnh = self._block_rows(v[0], caches[lv])
                 for j in range(-(-Tb // bs)):
                     caches[l] = self._scatter_block(
                         caches[l], self._strip(knh, j), table, j0 + j, True)
-                    caches[L + l] = self._scatter_block(
-                        caches[L + l], self._strip(vnh, j), table, j0 + j,
+                    caches[lv] = self._scatter_block(
+                        caches[lv], self._strip(vnh, j), table, j0 + j,
                         True)
                 attn = paged_prefix_attention(
-                    q.transpose(0, 2, 1, 3), caches[l], caches[L + l],
-                    table, ctx, self.layout.windows[l],
+                    q.transpose(0, 2, 1, 3), caches[l], caches[lv],
+                    table, ctx, self.layout.windows[layer],
                     position_major=self._position_major)
                 return attn.transpose(0, 2, 1, 3)
             return attend
@@ -1282,7 +1406,7 @@ class GenerationEngine:
             pos = jnp.minimum(ctx + q_idx, self.max_len - 1)[None]  # (1, Tb)
             return self._cached_layers(tokens, pos, attend_for,
                                        (q_idx < n_valid)[None],
-                                       n_valid - 1)[0]
+                                       n_valid - 1, recur)[0]
 
         logits = self._with_params(param_vals, aux_vals, key, body)
         first, lp = self._sample_prefill(logits[0, 0], ctx + n_valid, samp)
@@ -1296,15 +1420,15 @@ class GenerationEngine:
         through :func:`paged_decode_attention` bounded by the layer's
         window.  ``caches`` is the program's list, updated in place."""
         from ..kernels.flash_attention import paged_decode_attention
-        L = self.num_layers
 
-        def attend_for(l):
-            window = self.layout.windows[l]
+        def attend_for(layer):
+            window = self.layout.windows[layer]
+            l, lv = self._pools(layer)
 
             def attend(q, k, v):             # (S, 1, heads, D) each
                 ck = self._write_rows(caches[l], blk, off, k[:, 0])
-                cv = self._write_rows(caches[L + l], blk, off, v[:, 0])
-                caches[l], caches[L + l] = ck, cv
+                cv = self._write_rows(caches[lv], blk, off, v[:, 0])
+                caches[l], caches[lv] = ck, cv
                 self._note_paged_attention(tables, ck, q.shape[2], window)
                 return paged_decode_attention(
                     q[:, 0], ck, cv, tables, positions, window=window,
@@ -1341,7 +1465,8 @@ class GenerationEngine:
                 last_tokens, positions.reshape(S, 1),
                 self._decode_attend_for(caches, blk, off, tables,
                                         positions),
-                (tables[:, 0] != 0)[:, None])
+                (tables[:, 0] != 0)[:, None],
+                recur=self._recur_decode(caches))
 
         logits, counts = self._with_params(param_vals, aux_vals, key, body)
         lg = logits[:, 0, :]
@@ -1415,7 +1540,7 @@ class GenerationEngine:
                 logits, c = self._cached_layers(
                     lt, pos.reshape(S, 1),
                     self._decode_attend_for(caches, blk, off, tables, pos),
-                    (~done)[:, None])
+                    (~done)[:, None], recur=self._recur_decode(caches))
                 counts = self._sum_counts(counts, c)
                 lg = logits[:, 0, :]
                 # keyed at pos + 1 (the position this token will
@@ -1479,7 +1604,6 @@ class GenerationEngine:
         tables, samp = self._slot_operands(state)[5:]
         key_next, key = jax.random.split(state["key"])
         state = dict(state, key=key_next)
-        L = self.num_layers
         S, Q = tokens.shape
         bs = self.block_size
         NB = self.max_blocks_per_slot
@@ -1494,13 +1618,14 @@ class GenerationEngine:
                                    jnp.minimum(col, NB - 1)], 0)  # (S, Q)
         off = pos_q % bs                                          # (S, Q)
 
-        def attend_for(l):
-            window = self.layout.windows[l]
+        def attend_for(layer):
+            window = self.layout.windows[layer]
+            l, lv = self._pools(layer)
 
             def attend(q, k, v):             # (S, Q, heads, D) each
                 ck = self._write_rows(caches[l], blk, off, k)
-                cv = self._write_rows(caches[L + l], blk, off, v)
-                caches[l], caches[L + l] = ck, cv
+                cv = self._write_rows(caches[lv], blk, off, v)
+                caches[l], caches[lv] = ck, cv
                 self._note_paged_attention(tables, ck, q.shape[2], window)
                 attn = paged_verify_decode_attention(
                     q.transpose(0, 2, 1, 3), ck, cv, tables, positions,
@@ -1539,12 +1664,19 @@ class GenerationEngine:
         # the old pools go first: two sets need not fit side by side
         # (deleted, not unbound: a replaced worker's late dispatch
         # still finds a cache of the programs' shape, and fails on it)
-        for c in self._cache:
+        for c in self._cache + self._recur:
             c.delete()
         self._cache = tuple(
             jnp.zeros(self._pool_shape, jnp.dtype(self.layout.dtype),
                       device=dev)
-            for _ in range(2 * self.num_layers))
+            for _ in range(2 * self._n_kv))
+        # the state rows go with the blocks: a snapshot must never outlive
+        # the params that computed it either
+        self._recur = tuple(
+            jnp.zeros((self._null_row + 1,) + shape, jnp.dtype(dtype),
+                      device=dev)
+            for l in self._state_layers
+            for shape, dtype in self.layout.states[l])
         self.pool.reset()
         # bytes behind one block across all layers, as stored — lets
         # the pool report occupancy in bytes (device-memory
@@ -1554,6 +1686,31 @@ class GenerationEngine:
         self._tables[:] = 0
         self._rows[:, :_TOPK] = _FREE
         self.rebuild_slot_state()
+        self._note_state_rows()
+
+    def _rebind(self, cache) -> None:
+        """Take back what a program returned for its donated cache: the
+        pools, then the state layers' leaves."""
+        n = 2 * self._n_kv
+        self._cache, self._recur = tuple(cache[:n]), tuple(cache[n:])
+
+    @property
+    def state_bytes(self) -> int:
+        """Bytes of the state layers' rows (slots, snapshots, null)."""
+        return sum(int(c.size) * c.dtype.itemsize for c in self._recur)
+
+    def state_rows_in_use(self) -> int:
+        """Rows of the state store that hold something: a slot with a
+        request, a snapshot the pool keeps."""
+        if not self._state_layers:
+            return 0
+        return sum(1 for b in self._slot_blocks if b) \
+            + self.pool.snapshots_in_use
+
+    def _note_state_rows(self) -> None:
+        if self._state_layers:
+            _m.STATE_ROWS_IN_USE.set(self.state_rows_in_use(),
+                                     model=self.name)
 
     @property
     def pool_layout(self):
@@ -1597,8 +1754,8 @@ class GenerationEngine:
         state = self._slot_state()
         try:
             with _donating():
-                return call(self._cache, state, *args, param_vals,
-                            aux_vals)
+                return call(self._cache + self._recur, state, *args,
+                            param_vals, aux_vals)
         except Exception as e:
             # RESOURCE_EXHAUSTED here is the device running out of HBM
             # mid-dispatch: publish the oom FAULT so the flight recorder
@@ -1624,7 +1781,8 @@ class GenerationEngine:
             self._count_operands()
             self._count_sample_branch(live)
             _m.loop_phase_switch("decode_wait", "serve.decode.wait")
-            self._cache, self._state = out[:2]
+            self._rebind(out[0])
+            self._state = out[1]
             yield out[2:]
         except Exception:
             self.rebuild_slot_state()
@@ -1656,7 +1814,7 @@ class GenerationEngine:
             else:
                 cache, first = out
                 self._last_prefill_logprobs = None
-            self._cache = cache
+            self._rebind(cache)
             return int(first)
 
     def prefill(self, tokens, slot: int,
@@ -1717,19 +1875,21 @@ class GenerationEngine:
         reserve = int(reserve_tokens or self.max_len) \
             + (self.spec_k if self.draft is not None else 0)
         reserve = max(n + 1, min(reserve, self.max_len))
-        table, m = self.pool.allocate(toks, n, reserve,
-                                      share=not self._warming)
+        table, m, plan = self.pool.allocate(toks, n, reserve,
+                                            share=not self._warming)
         self._slot_blocks[slot] = table
         self._set_table(slot, table)
+        self._note_state_rows()
         try:
-            return self._prefill_paged_dispatch(toks, n, m, slot, span)
+            return self._prefill_paged_dispatch(toks, n, m, slot, span,
+                                                plan)
         except Exception:
             # The fresh (non-shared) blocks never got their K/V written;
             # allocate() already registered the full ones in the prefix
             # cache, so unregister them before release parks them idle —
             # a later same-prefix request must prefill cold, not "hit"
             # garbage.
-            self.pool.invalidate(table[m // self.pool.block_size:])
+            self.pool.invalidate(table[m // self.pool.block_size:], plan)
             self.release_slot(slot)
             raise
 
@@ -1742,22 +1902,51 @@ class GenerationEngine:
         self._dirty.add(slot)
 
     def _prefill_paged_dispatch(self, toks, n: int, m: int, slot: int,
-                                span) -> int:
+                                span, plan) -> int:
         """The slot's table and sampling operands reach the program as
         its row of the slot state (edited just before, by
         :meth:`_guarded`): what the call uploads is the padded prompt
-        and three integers."""
+        and a few integers — for a model with state layers the snapshot
+        rows of the pool's plan among them, so a hit starts from its
+        snapshot inside the one dispatch."""
         if m == 0:
-            out = self._guarded(
-                self._prefill, self._padded(toks, n, span),
-                _np.asarray([n, slot], _np.int32))
+            padded = self._padded(toks, n, span)
+            out = self._guarded(self._prefill, padded, self._prefill_at(
+                plan, padded.shape[1], n, slot))
         else:
             if span is not None:
                 span.attrs["prefix_hit_tokens"] = m
-            out = self._guarded(
-                self._prefill_ext, self._padded(toks[m:], n - m, span),
-                _np.asarray([n - m, slot, m], _np.int32))
+            padded = self._padded(toks[m:], n - m, span)
+            out = self._guarded(self._prefill_ext, padded, self._prefill_at(
+                plan, padded.shape[1], n - m, slot, m))
+        if not self._warming:
+            path = "hit" if m else "miss"
+            _m.PREFILL_TOKENS.inc(n - m, model=self.name, path=path)
+            self._prefilled[path] += n - m
+            if m:
+                _m.PREFIX_HIT_TOKENS.inc(m, model=self.name)
+                self._prefilled["prefix_hit"] += m
         return self._unpack_prefill(out)
+
+    def _prefill_at(self, plan, bucket: int, n_valid: int, slot: int,
+                    ctx=None):
+        """A prefill program's integer operand: ``[n_valid, slot]``, for
+        the hit program ``[n_valid, slot, ctx]``, and for a model with
+        state layers, from the pool's ``plan`` for this prompt
+        (``BlockPool.allocate``; :data:`~.kvcache.NO_SNAPSHOTS` for
+        warm-up's prompts): the snapshot row a hit starts from, then the
+        row for the state at each snapshot boundary of the bucket — the
+        null row where none is kept."""
+        head = (n_valid, slot) if ctx is None else (n_valid, slot, ctx)
+        if not self._state_layers:
+            return _np.asarray(head, _np.int32)
+        restore, keep = plan
+        if ctx is not None:
+            head += (self._null_row if restore is None else restore,)
+        every = self.state_snapshot_tokens
+        rows = [keep.get((ctx or 0) + (j + 1) * every, self._null_row)
+                for j in range(bucket // every if every else 0)]
+        return _np.asarray(head + tuple(rows), _np.int32)
 
     def _padded(self, toks, n: int, span):
         """``toks`` padded to its prompt-length bucket, (1, bucket)."""
@@ -1909,6 +2098,14 @@ class GenerationEngine:
         from ..base import getenv_int
         if draft is self:
             raise MXNetError(f"{self.name}: a model cannot draft itself")
+        for eng in (self, draft):
+            if eng._state_layers:
+                raise MXNetError(
+                    f"{eng.name}: no speculation over a recurrent state: "
+                    "a rejected draft token is rolled back by moving the "
+                    "position back, and a state that has consumed the "
+                    "token cannot be moved back (it would have to be "
+                    "snapshotted at every verify)")
         if int(draft.max_slots) != self.max_slots:
             raise MXNetError(
                 f"{self.name}: draft max_slots {draft.max_slots} != "
@@ -2060,6 +2257,7 @@ class GenerationEngine:
         self._slot_blocks[int(slot)] = []
         self._set_table(int(slot), ())
         self._rows[int(slot), :_TOPK] = _FREE
+        self._note_state_rows()
 
     def can_admit(self, tokens, reserve_tokens: int,
                   reserved_blocks: int = 0) -> bool:
@@ -2096,8 +2294,13 @@ class GenerationEngine:
     def kv_stats(self) -> dict:
         """Cache-utilization facts for ``GET /v1/models`` and
         ``stats()``."""
-        out = {"kv_capacity_tokens": self.kv_capacity_tokens()}
+        out = {"kv_capacity_tokens": self.kv_capacity_tokens(),
+               "prefill_tokens": dict(self._prefilled)}
         out.update(self.pool.stats())
+        if self._state_layers:
+            out.update(state_rows_total=self._null_row,
+                       state_rows_in_use=self.state_rows_in_use(),
+                       state_bytes=self.state_bytes)
         return out
 
     def slot_occupancy(self) -> List[dict]:
@@ -2175,7 +2378,7 @@ class GenerationEngine:
                     sn = max(1, min(b, self.max_len - 1))
                     self._unpack_prefill(self._guarded(
                         self._prefill_ext, _np.zeros((1, b), _np.int32),
-                        _np.asarray([sn, 0, 0], _np.int32)))
+                        self._prefill_at(NO_SNAPSHOTS, b, sn, 0, 0)))
             self.decode(_np.zeros(self.max_slots, _np.int32),
                         _np.zeros(self.max_slots, _np.int32))
             if self.scan_steps >= 1:

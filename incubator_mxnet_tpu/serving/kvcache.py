@@ -57,7 +57,8 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 from ..base import MXNetError
 from . import metrics as _m
 
-__all__ = ["BlockPool", "KVLayout", "blocks_for", "NULL_BLOCK"]
+__all__ = ["BlockPool", "KVLayout", "SnapshotPlan", "NO_SNAPSHOTS",
+           "blocks_for", "NULL_BLOCK"]
 
 NULL_BLOCK = 0
 
@@ -69,29 +70,45 @@ class KVLayout(NamedTuple):
     ``windows`` has one entry a layer: None where a layer reads every
     earlier position, else the number of latest positions it reads (a
     mask and a lower bound on the attention's work; no block is freed
-    behind a window yet, ROADMAP M3)."""
+    behind a window yet, ROADMAP M3).  ``states`` has one entry a layer
+    too: None where the layer keeps keys and values in the pool, else the
+    leaves ``((shape, dtype), ...)`` of the state of constant size that the
+    layer keeps a sequence INSTEAD (a recurrent layer: it has no blocks);
+    a model that does not state it has none."""
     num_layers: int
     kv_heads: int
     head_dim: int
     dtype: str
     windows: Tuple[Optional[int], ...]
     max_length: int
+    states: Tuple[Optional[tuple], ...] = ()
 
     @classmethod
     def of(cls, stated: dict) -> "KVLayout":
         try:
-            lay = cls(int(stated["num_layers"]), int(stated["kv_heads"]),
+            n = int(stated["num_layers"])
+            lay = cls(n, int(stated["kv_heads"]),
                       int(stated["head_dim"]), str(stated["dtype"]),
                       tuple(None if w is None else int(w)
                             for w in stated["windows"]),
-                      int(stated["max_length"]))
-        except (KeyError, TypeError) as e:
+                      int(stated["max_length"]),
+                      tuple(None if leaves is None else tuple(
+                          (tuple(int(d) for d in shape), str(dtype))
+                          for shape, dtype in leaves)
+                          for leaves in stated.get("states") or (None,) * n))
+        except (KeyError, TypeError, ValueError) as e:
             raise MXNetError(f"kv_layout() must give {cls._fields}: {e!r}")
-        if len(lay.windows) != lay.num_layers:
+        if not len(lay.windows) == len(lay.states) == lay.num_layers:
             raise MXNetError(
-                f"kv_layout(): {len(lay.windows)} windows for "
-                f"{lay.num_layers} layers")
+                f"kv_layout(): {len(lay.windows)} windows and "
+                f"{len(lay.states)} states for {lay.num_layers} layers")
         return lay
+
+    @property
+    def kv_layers(self) -> Tuple[int, ...]:
+        """The layers that keep keys and values: one K and one V pool
+        each."""
+        return tuple(l for l, s in enumerate(self.states) if s is None)
 
     #: a TPU's vector registers and the tiles its memory is laid in are this
     #: many features wide
@@ -149,9 +166,9 @@ class KVLayout(NamedTuple):
 
     def block_bytes(self, block_size: int) -> int:
         """Bytes behind one block of ``block_size`` positions: K and V of
-        every layer."""
+        every layer that keeps them."""
         import jax.numpy as jnp
-        return (2 * self.num_layers * self.kv_heads * int(block_size)
+        return (2 * len(self.kv_layers) * self.kv_heads * int(block_size)
                 * self.head_dim * jnp.dtype(self.dtype).itemsize)
 
 
@@ -160,16 +177,51 @@ def blocks_for(tokens: int, block_size: int) -> int:
     return max(0, -(-int(tokens) // int(block_size)))
 
 
+class SnapshotPlan(NamedTuple):
+    """What one prompt's prefill does with the state store
+    (:meth:`BlockPool.allocate`): the snapshot row it starts from (None: a
+    sequence's beginning) and, by boundary in tokens from the prompt's
+    start, the rows it keeps the state in."""
+    restore: Optional[int]
+    keep: Dict[int, int]
+
+
+NO_SNAPSHOTS = SnapshotPlan(None, {})
+
+
 class BlockPool:
     """Refcounted allocator over ``num_blocks`` fixed-size KV blocks.
 
     ``num_blocks`` includes the reserved null block, so ``num_blocks - 1``
     blocks are allocatable. ``prefix_cache=False`` disables sharing (every
     allocation takes fresh blocks) but keeps the same accounting.
+
+    **State snapshots** (a model with recurrent layers; ``snapshot_every``
+    tokens, a multiple of ``block_size``, and ``snapshot_rows`` row ids from
+    ``first_snapshot_row`` on).  A hash lookup finds a prefix's blocks, but
+    a recurrent layer's state after that prefix cannot be rebuilt from
+    them: it has to have been kept.  The prefill programs write the state
+    at every multiple of ``snapshot_every`` into the row this pool names
+    for it (the plan :meth:`allocate` returns), and the pool keeps the row
+    under the cached block that ENDS at that boundary.  A row is named only
+    where the prefix up to the boundary has been SEEN BEFORE — the block
+    registered for it is an earlier prompt's — since a prefix one prompt
+    ever sent would cost a row (megabytes to write) that nothing can hit:
+    the first prompt with a prefix registers its blocks, the second finds
+    them without a state, prefills whole and keeps the snapshots, the third
+    hits.  A match is then the longest chain of
+    cached blocks that ends at a block with a snapshot — cached blocks past
+    the last snapshot are no hit — and the snapshot goes when its block's
+    registration goes (LRU eviction, :meth:`invalidate`): a snapshot is
+    never found without its blocks.  When the rows run out the snapshot
+    used longest ago is dropped; its blocks stay cached and count for
+    nothing past the last snapshot that is left.
     """
 
     def __init__(self, num_blocks: int, block_size: int, *,
-                 prefix_cache: bool = True, model: str = "?"):
+                 prefix_cache: bool = True, model: str = "?",
+                 snapshot_every: int = 0, snapshot_rows: int = 0,
+                 first_snapshot_row: int = 0):
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         if num_blocks < 2:
@@ -179,6 +231,18 @@ class BlockPool:
         self.block_size = int(block_size)
         self.prefix_cache = bool(prefix_cache)
         self._model = model
+        if snapshot_every % self.block_size:
+            raise ValueError(
+                f"snapshot_every {snapshot_every} is no multiple of the "
+                f"block size {block_size}")
+        #: blocks between two state snapshots (0: the model keeps no state)
+        self._snap_blocks = int(snapshot_every) // self.block_size \
+            if prefix_cache and snapshot_rows else 0
+        self._snap_row_ids = range(int(first_snapshot_row),
+                                   int(first_snapshot_row)
+                                   + int(snapshot_rows))
+        self.snapshots_kept = self.snapshots_restored = 0
+        self.snapshots_evicted = 0
         # device bytes behind one block (set by the owning engine once
         # its cache arrays exist) — lets stats() speak bytes, the unit
         # the device-memory plane attributes in (telemetry_device)
@@ -201,6 +265,10 @@ class BlockPool:
             self._by_hash: Dict[bytes, int] = {}
             # cached blocks with refcount 0, in LRU order (oldest first)
             self._idle: "OrderedDict[int, None]" = OrderedDict()
+            # state snapshots: the row kept under a cached block, in the
+            # order of last use (oldest first), and the rows not in use
+            self._snap_of: "OrderedDict[int, int]" = OrderedDict()
+            self._snap_free: deque = deque(self._snap_row_ids)
             self._update_gauges()
 
     @property
@@ -252,7 +320,8 @@ class BlockPool:
         return out
 
     def _match(self, hashes: Sequence[bytes], usable: int) -> List[int]:
-        """Longest cached run of leading blocks, without increfing."""
+        """Longest cached run of leading blocks, without increfing — for a
+        model with state, the longest that ends at a snapshot."""
         if not self.prefix_cache:
             return []
         shared: List[int] = []
@@ -261,6 +330,12 @@ class BlockPool:
             if b is None:
                 break
             shared.append(b)
+        if self._snap_blocks:
+            per = self._snap_blocks
+            n = len(shared) // per * per
+            while n and shared[n - 1] not in self._snap_of:
+                n -= per
+            del shared[n:]
         return shared
 
     @staticmethod
@@ -289,14 +364,18 @@ class BlockPool:
             return free - shared_idle >= need - len(shared)
 
     def allocate(self, tokens: Sequence[int], n: int, reserve_tokens: int,
-                 share: bool = True) -> Tuple[List[int], int]:
+                 share: bool = True) -> Tuple[List[int], int, SnapshotPlan]:
         """Reserve blocks for a request with prompt ``tokens[:n]`` and a
         worst-case total of ``reserve_tokens`` positions.
 
-        Returns ``(table, shared_tokens)``: the ordered block table (length
-        ``ceil(reserve_tokens / block_size)``) and how many leading token
-        positions already hold valid K/V from the prefix cache (always a
-        multiple of ``block_size``). Raises :class:`MXNetError` when the
+        Returns ``(table, shared_tokens, plan)``: the ordered block table
+        (length ``ceil(reserve_tokens / block_size)``), how many leading
+        token positions already hold valid K/V from the prefix cache (always
+        a multiple of ``block_size``), and the state rows of this prompt's
+        prefill (:data:`NO_SNAPSHOTS` for a model without state and for
+        ``share=False``): the caller hands it to the prefill it dispatches
+        and, should that fail, back to :meth:`invalidate`. Raises
+        :class:`MXNetError` when the
         pool cannot satisfy the reservation. ``share=False`` skips both
         prefix matching and registration (warmup traffic must not poison
         the cache).
@@ -343,8 +422,52 @@ class BlockPool:
             if shared:
                 self.hits += len(shared)
                 _m.PREFIX_CACHE_HITS.inc(len(shared), model=self._model)
+            plan = self._plan_snapshots(hashes, shared, table) \
+                if self._snap_blocks and share else NO_SNAPSHOTS
             self._update_gauges()
-            return table, len(shared) * self.block_size
+            return table, len(shared) * self.block_size, plan
+
+    def _plan_snapshots(self, hashes, shared, table) -> SnapshotPlan:
+        """After a match of ``shared`` blocks of a prompt whose full blocks
+        hash to ``hashes`` and lie in ``table``: the row of the snapshot the
+        match ends at, and a row for the state at every boundary past it
+        that an EARLIER prompt's registered block ends at (the state after a
+        prefix is the prefix's, whoever computes it) — none from the first
+        boundary on whose block is this prompt's own: nobody has sent that
+        prefix before."""
+        per = self._snap_blocks
+        restore = None
+        if shared:
+            restore = self._snap_of[shared[-1]]
+            self._snap_of.move_to_end(shared[-1])
+            self.snapshots_restored += 1
+            _m.STATE_SNAPSHOTS.inc(model=self._model, event="restored")
+        keep = {}
+        for j in range(len(shared) // per + 1, len(hashes) // per + 1):
+            b = self._by_hash[hashes[j * per - 1]]
+            if b == table[j * per - 1]:
+                break
+            if b in self._snap_of:
+                continue
+            if not self._snap_free:             # the one used longest ago
+                self._drop_snapshot(next(iter(self._snap_of)))
+            self._snap_of[b] = keep[j * per * self.block_size] = \
+                self._snap_free.popleft()
+            self.snapshots_kept += 1
+            _m.STATE_SNAPSHOTS.inc(model=self._model, event="kept")
+        return SnapshotPlan(restore, keep)
+
+    def _drop_snapshot(self, block: int) -> None:
+        row = self._snap_of.pop(block, None)
+        if row is not None:
+            self._snap_free.append(row)
+            self.snapshots_evicted += 1
+            _m.STATE_SNAPSHOTS.inc(model=self._model, event="evicted")
+
+    @property
+    def snapshots_in_use(self) -> int:
+        with self._lock:
+            return len(self._snap_of)
 
     def release(self, table: Sequence[int]) -> None:
         """Decref every block in ``table``. Blocks reaching refcount 0
@@ -365,16 +488,21 @@ class BlockPool:
                         self._free.append(b)
             self._update_gauges()
 
-    def invalidate(self, blocks: Sequence[int]) -> None:
+    def invalidate(self, blocks: Sequence[int],
+                   plan: SnapshotPlan = NO_SNAPSHOTS) -> None:
         """Unregister ``blocks`` from the prefix cache without touching
         refcounts. For blocks whose K/V never became valid — a prefill
         that failed after :meth:`allocate` had already registered them —
         so a later request with the same prefix prefills cold instead of
-        "hitting" garbage. Unregistered blocks are a no-op."""
+        "hitting" garbage. Unregistered blocks are a no-op.  The snapshots
+        of that prefill's ``plan`` were its to write: they go too."""
         with self._lock:
             for b in blocks:
                 if b != NULL_BLOCK:
                     self._evict_hash(b)
+            rows = set(plan.keep.values())
+            for b in [b for b, r in self._snap_of.items() if r in rows]:
+                self._drop_snapshot(b)
 
     def copy_on_write(self, block: int) -> int:
         """Private handle for a block the caller wants to mutate. Returns
@@ -466,6 +594,7 @@ class BlockPool:
         if h is not None and self._by_hash.get(h) == b:
             del self._by_hash[h]
         self._hash[b] = None
+        self._drop_snapshot(b)      # a snapshot goes with its block
 
     def stats(self) -> Dict[str, object]:
         with self._lock:
@@ -482,6 +611,13 @@ class BlockPool:
                 "prefix_cache_evictions": self.evictions,
                 "rewinds": self.rewinds,
             }
+            if self._snap_row_ids:
+                out.update({
+                    "state_snapshot_rows": len(self._snap_row_ids),
+                    "state_snapshots_in_use": len(self._snap_of),
+                    "state_snapshots_kept": self.snapshots_kept,
+                    "state_snapshots_restored": self.snapshots_restored,
+                    "state_snapshots_evicted": self.snapshots_evicted})
             if self.block_bytes:
                 out["kv_bytes_total"] = total * self.block_bytes
                 out["kv_bytes_in_use"] = in_use * self.block_bytes

@@ -79,6 +79,24 @@ DECODE_WINDOW_TOKENS = _telemetry.registry.counter(
     "of mxtpu_decode_context_tokens, the positions a windowed layer "
     "reads: min(written positions, window) of each live slot, summed "
     "over decode steps; only for a model with windowed layers")
+STATE_ROWS_IN_USE = _telemetry.registry.gauge(
+    "mxtpu_state_rows_in_use",
+    "rows of a recurrent model's state store in use: one a slot that "
+    "holds a request, one a snapshot kept for the prefix cache")
+STATE_SNAPSHOTS = _telemetry.registry.counter(
+    "mxtpu_state_snapshots",
+    "state snapshots of a recurrent model by event=kept (a prefill was "
+    "given a row for the state at a snapshot boundary), restored (a "
+    "prefix hit started from one), evicted (its block's registration "
+    "went, or the rows ran out and it was used longest ago)")
+PREFILL_TOKENS = _telemetry.registry.counter(
+    "mxtpu_prefill_tokens",
+    "prompt positions the prefill programs computed, by path=miss (a "
+    "whole prompt) or hit (the part of a prompt past its cached prefix)")
+PREFIX_HIT_TOKENS = _telemetry.registry.counter(
+    "mxtpu_prefix_hit_tokens",
+    "prompt positions a join did not compute because cached blocks — "
+    "and, for a recurrent model, the snapshot they end at — held them")
 #: counters a served model's layers return from the decode programs
 #: (``block.serve_counters``), by the model's name for each
 MODEL_COUNTERS = {"moe_pairs_total": MOE_PAIRS_TOTAL,

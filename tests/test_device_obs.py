@@ -264,7 +264,7 @@ def test_profiler_capture_roundtrip_and_guard(tmp_path):
     assert telemetry_device.capture_active()
     with pytest.raises(telemetry_device.CaptureBusy):
         telemetry_device.capture_profile(0.05, out_dir=str(tmp_path))
-    t.join(10)
+    t.join(60)      # the profiler's stop took over 10 s on a loaded host
     assert done.is_set() and not telemetry_device.capture_active()
 
 

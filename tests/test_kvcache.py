@@ -70,7 +70,7 @@ def test_blocks_for():
 def test_pool_alloc_release_refcounts():
     pool = BlockPool(9, 16, model="t")            # 8 allocatable
     toks = list(range(40))
-    table, m = pool.allocate(toks, 40, 48)        # 3 blocks, cold
+    table, m, _ = pool.allocate(toks, 40, 48)        # 3 blocks, cold
     assert m == 0 and len(table) == 3
     assert 0 not in table                         # null block never leaves
     assert pool.blocks_in_use == 3
@@ -87,9 +87,9 @@ def test_pool_alloc_release_refcounts():
 def test_pool_prefix_sharing_and_refcounts():
     pool = BlockPool(17, 16, model="t")
     toks = list(range(40))                        # 2 full blocks shareable
-    t1, m1 = pool.allocate(toks, 40, 64)
+    t1, m1, _ = pool.allocate(toks, 40, 64)
     assert m1 == 0
-    t2, m2 = pool.allocate(toks, 40, 64)
+    t2, m2, _ = pool.allocate(toks, 40, 64)
     assert m2 == 32                               # both full blocks shared
     assert t2[:2] == t1[:2]                       # same physical blocks
     assert t2[2:] != t1[2:]
@@ -100,7 +100,7 @@ def test_pool_prefix_sharing_and_refcounts():
     pool.release(t2)
     assert pool.blocks_in_use == 0
     assert pool.cached_blocks == 2                # still hittable
-    t3, m3 = pool.allocate(toks, 40, 64)
+    t3, m3, _ = pool.allocate(toks, 40, 64)
     assert m3 == 32                               # idle cached blocks hit
     pool.release(t3)
 
@@ -108,8 +108,8 @@ def test_pool_prefix_sharing_and_refcounts():
 def test_pool_prefix_cache_disabled():
     pool = BlockPool(17, 16, prefix_cache=False, model="t")
     toks = list(range(40))
-    t1, m1 = pool.allocate(toks, 40, 64)
-    t2, m2 = pool.allocate(toks, 40, 64)
+    t1, m1, _ = pool.allocate(toks, 40, 64)
+    t2, m2, _ = pool.allocate(toks, 40, 64)
     assert m1 == m2 == 0
     assert not set(t1) & set(t2)
     assert pool.hits == 0
@@ -118,7 +118,7 @@ def test_pool_prefix_cache_disabled():
 def test_pool_copy_on_write():
     pool = BlockPool(9, 16, model="t")
     toks = list(range(40))
-    t1, _ = pool.allocate(toks, 40, 48)
+    t1, _, _ = pool.allocate(toks, 40, 48)
     # exclusively-owned mutable tail: no copy
     tail = t1[2]
     assert pool.copy_on_write(tail) == tail
@@ -126,7 +126,7 @@ def test_pool_copy_on_write():
     pub = t1[1]
     assert pool.copy_on_write(pub) == pub
     assert pool.refcount(pub) == 1
-    t2, m2 = pool.allocate(toks, 40, 48)
+    t2, m2, _ = pool.allocate(toks, 40, 48)
     assert m2 == 16                               # unpublished block misses
     shared = t1[0]
     assert pool.refcount(shared) == 2
@@ -142,7 +142,7 @@ def test_pool_copy_on_write():
 def test_pool_exhaustion_and_can_admit():
     pool = BlockPool(5, 16, model="t")            # 4 allocatable
     toks = list(range(3))
-    t1, _ = pool.allocate(toks, 3, 64)            # takes all 4
+    t1, _, _ = pool.allocate(toks, 3, 64)            # takes all 4
     assert not pool.can_admit([7] * 3, 3, 17)
     with pytest.raises(MXNetError):
         pool.allocate([7] * 3, 3, 17)
@@ -161,13 +161,13 @@ def test_pool_lru_eviction_under_pressure():
     assert pool.cached_blocks == 2
     # demand 3+ fresh blocks: free list has 2, so the OLDEST idle cached
     # block (prompt a's) must be reclaimed
-    c, m = pool.allocate([9] * 50, 50, 64)
+    c, m, _ = pool.allocate([9] * 50, 50, 64)
     assert m == 0
     assert pool.evictions >= 1
     # prompt a's block is gone from the cache; prompt b's may also have
     # been evicted depending on demand — re-allocating a must miss
     pool.release(c)
-    t, m = pool.allocate(list(range(16)) + [1], 17, 17)
+    t, m, _ = pool.allocate(list(range(16)) + [1], 17, 17)
     assert m == 0
 
 
@@ -195,7 +195,7 @@ def test_pool_shared_idle_blocks_not_double_counted():
     assert pool.cached_blocks == 4
     # one block less and the same request fits, sharing the idle block
     assert pool.can_admit(toks, 17, 64)
-    t, m = pool.allocate(toks, 17, 64)
+    t, m, _ = pool.allocate(toks, 17, 64)
     assert m == 16 and t[0] == a[0]
     pool.release(t)
     pool.release(live)
@@ -204,14 +204,14 @@ def test_pool_shared_idle_blocks_not_double_counted():
 def test_pool_invalidate_unregisters_prefix_entries():
     pool = BlockPool(9, 16, model="t")
     toks = list(range(40))
-    t, _ = pool.allocate(toks, 40, 48)            # 2 full blocks registered
+    t, _, _ = pool.allocate(toks, 40, 48)            # 2 full blocks registered
     assert pool.cached_blocks == 2
     pool.invalidate(t)
     assert pool.cached_blocks == 0
     assert all(pool.refcount(b) == 1 for b in t)  # refcounts untouched
     pool.release(t)
     assert pool.free_blocks == 8                  # all straight to free
-    t2, m2 = pool.allocate(toks, 40, 48)
+    t2, m2, _ = pool.allocate(toks, 40, 48)
     assert m2 == 0                                # no hit on invalidated
     pool.release(t2)
 
@@ -221,8 +221,8 @@ def test_prefix_keys_are_collision_resistant():
     # caching would alias these two distinct prompts onto the same
     # blocks; content digests must keep them apart.
     pool = BlockPool(17, 16, model="t")
-    t1, m1 = pool.allocate([-1] * 17, 17, 32)
-    t2, m2 = pool.allocate([-2] * 17, 17, 32)
+    t1, m1, _ = pool.allocate([-1] * 17, 17, 32)
+    t2, m2, _ = pool.allocate([-2] * 17, 17, 32)
     assert m1 == 0 and m2 == 0                    # no bogus prefix hit
     assert not set(t1) & set(t2)
     assert pool.hits == 0
@@ -255,11 +255,14 @@ def test_paged_prefix_hit_parity_and_sharing():
 
 def test_paged_midflight_join_parity():
     net, paged = _pair()
-    solo_a = greedy_reference(net, [9, 9, 4, 1], 30)
+    # A runs to the end of its 64 positions: on a loaded host the worker
+    # got through 27 more tokens (four bursts) before this thread's next
+    # 5 ms poll, and B joined an empty batch
+    solo_a = greedy_reference(net, [9, 9, 4, 1], 56)
     solo_b = greedy_reference(net, [3, 7, 11], 8)
     bat = ContinuousBatcher(paged, name="paged")
     try:
-        ra = bat.submit_async([9, 9, 4, 1], max_new_tokens=30)
+        ra = bat.submit_async([9, 9, 4, 1], max_new_tokens=56)
         deadline = time.monotonic() + 10
         while len(ra.tokens_out) < 3 and time.monotonic() < deadline:
             time.sleep(0.005)

@@ -729,3 +729,71 @@ def test_docqa_cell_pool_stays_as_stated_and_is_not_copied_on_v5e(
         compiled.output_formats[0]
     assert {f.layout.major_to_minor for f in (*pools_in, *pools_out)} \
         == {(0, 1, 2, 3)}
+
+
+# -- heads of 256 features (a gated attention layer beside recurrent ones): two
+# -- lane tiles a key, 8 query heads on the chip's one KV head ----------------
+def _wide_case(dtype, kv_heads, n_q, D=256, group=8, seed=0):
+    rng = np.random.default_rng(seed)
+    n_cols, n_blocks = 32, 100                     # 512 keys a slot
+    kp = jnp.asarray(rng.standard_normal((n_blocks, kv_heads, BS, D)), dtype)
+    vp = jnp.asarray(rng.standard_normal((n_blocks, kv_heads, BS, D)), dtype)
+    tables = np.zeros((4, n_cols), np.int32)
+    tables[1, :3] = [7, 8, 9]
+    tables[2, :] = np.arange(20, 52)
+    tables[3, :] = np.arange(60, 92)
+    pos = np.asarray([0, 37, 300, 512 - n_q], np.int32)
+    q = jnp.asarray(rng.standard_normal((4, kv_heads * group, n_q, D)),
+                    jnp.float32)
+    return q, kp, vp, jnp.asarray(tables), jnp.asarray(pos)
+
+
+@pytest.mark.parametrize("n_q", [1, 3])
+@pytest.mark.parametrize("kv_heads", [1, 2])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_gqa_kernel_on_heads_of_256_matches_lax_gather(dtype, kv_heads, n_q):
+    """D = 256 at scale 256^-1/2: the kernel against the lax twin, and the
+    entry point takes the kernel when forced."""
+    q, kp, vp, tables, pos = _wide_case(jnp.dtype(dtype), kv_heads, n_q)
+    scale = 256 ** -0.5
+    got = fa._paged_gqa_pallas(q, kp, vp, tables, pos, scale, None,
+                               interpret=True)
+    ref = fa._xla_paged_verify_decode_attention(q, kp, vp, tables, pos,
+                                                scale, None)
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(np.asarray(got)[1:], np.asarray(ref)[1:],
+                               atol=tol, rtol=tol)
+    assert fa._paged_kernel_kind(q, kp, q.shape[1], None) is None
+
+
+def test_heads_of_256_take_the_grouped_kernel_when_forced(monkeypatch):
+    q, kp, vp, tables, pos = _wide_case(jnp.bfloat16, 1, 1)
+    monkeypatch.setenv("MXNET_FA_DECODE_FORCE_PALLAS", "1")
+    assert fa._paged_kernel_kind(q, kp, 8, None) == "gqa"
+    assert fa.paged_attention_impl(tables, kp, 8, None) == "pallas"
+    got = fa.paged_decode_attention(q[:, :, 0], kp, vp, tables, pos)
+    monkeypatch.delenv("MXNET_FA_DECODE_FORCE_PALLAS")
+    ref = fa.paged_decode_attention(q[:, :, 0], kp, vp, tables, pos)
+    np.testing.assert_allclose(np.asarray(got)[1:], np.asarray(ref)[1:],
+                               atol=2e-2, rtol=2e-2)
+
+
+def test_gqa_kernel_compiles_for_v5e_at_the_corpusqa_cell_shapes(
+        one_chip, no_compile_cache):
+    """S 64, 8 query heads on 1 KV head of 256, bs 16, 1,088 table entries,
+    a bfloat16 pool of 69,633 blocks: the kernel takes the pool as it
+    rests, without a copy."""
+    S, n_cols, N = 64, 1088, 69633
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((N, 1, 16, 256), jnp.bfloat16)
+    compiled = _compile(
+        lambda q, k, v, t, p: fa._paged_gqa_pallas(
+            q, k, v, t, p, 0.0625, None, False),
+        sds((S, 8, 1, 256), jnp.bfloat16), pool, pool,
+        sds((S, n_cols), jnp.int32), sds((S,), jnp.int32))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"= bf16\[69633,[^\]]*\]\{[^}]*\} copy\(", text)

@@ -96,7 +96,7 @@ PROMPTS = [[3, 7, 11, 2], [5, 5, 9], [1, 2, 3, 4, 5, 6]]
 # ===================================================== BlockPool.rewind
 def test_rewind_private_blocks_is_identity():
     pool = BlockPool(8, 4, model="t")
-    table, shared = pool.allocate([1, 2, 3, 4, 5], 5, 12, share=False)
+    table, shared, _ = pool.allocate([1, 2, 3, 4, 5], 5, 12, share=False)
     assert shared == 0
     out = pool.rewind(table, keep_tokens=6)
     assert out == table                     # exclusive + unpublished
@@ -108,7 +108,7 @@ def test_rewind_cows_published_tail_block():
     # 8 prompt tokens = 2 full blocks, both published in the prefix
     # cache; the reservation extends into a third (private) block
     toks = [1, 2, 3, 4, 5, 6, 7, 8]
-    table, shared = pool.allocate(toks, 8, 12, share=True)
+    table, shared, _ = pool.allocate(toks, 8, 12, share=True)
     assert shared == 0                      # cold: registered, not hit
     # a rewind that dirties the whole published second block (keep only
     # the first block's 4 tokens) must unpublish it so the overwrite
@@ -118,15 +118,15 @@ def test_rewind_cows_published_tail_block():
     assert pool.rewinds == 1
     # the dirty block is now private: a second identical prompt shares
     # at most the first block
-    t2, shared2 = pool.allocate(toks, 8, 12, share=True)
+    t2, shared2, _ = pool.allocate(toks, 8, 12, share=True)
     assert shared2 <= 4
 
 
 def test_rewind_shared_block_gets_private_copy():
     pool = BlockPool(10, 4, model="t")
     toks = [1, 2, 3, 4, 5, 6, 7, 8, 9]
-    t1, _ = pool.allocate(toks, 9, 12, share=True)
-    t2, shared = pool.allocate(toks, 9, 12, share=True)
+    t1, _, _ = pool.allocate(toks, 9, 12, share=True)
+    t2, shared, _ = pool.allocate(toks, 9, 12, share=True)
     assert shared == 8                      # both full blocks reused
     # t2 rewinds into its shared second block: must get a fresh id,
     # t1's view stays intact
@@ -140,8 +140,8 @@ def test_rewind_shared_block_gets_private_copy():
 def test_rewind_refuses_cow_of_kept_positions():
     pool = BlockPool(10, 4, model="t")
     toks = [1, 2, 3, 4, 5, 6, 7, 8, 9]
-    t1, _ = pool.allocate(toks, 9, 12, share=True)
-    t2, shared = pool.allocate(toks, 9, 12, share=True)
+    t1, _, _ = pool.allocate(toks, 9, 12, share=True)
+    t2, shared, _ = pool.allocate(toks, 9, 12, share=True)
     assert shared == 8
     # keeping 6 tokens means block 1 (positions 4..7) holds kept
     # positions AND is shared — rolling it back on the host would lose

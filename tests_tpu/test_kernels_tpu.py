@@ -390,3 +390,103 @@ def test_flash_lse_output_matches_lax():
         out_r).reshape(b, h, t, d), **TOL)
     onp.testing.assert_allclose(onp.asarray(lse), onp.asarray(
         lse_r).reshape(b, h, t), **TOL)
+
+
+def test_gqa_paged_at_the_corpusqa_cell_shapes():
+    """The grouped-query kernel on heads of 256 at the
+    ``qwen3next-tp2-corpusqa-closed`` cell's shapes — 64 slots, 8 query
+    heads on 1 KV head of 256, block 16, 1,088 table entries, a bfloat16
+    pool, contexts 10,500-17,300 — against the lax gather at ``highest``
+    (both round K, V and the softmax weights to bfloat16: 2e-2), with a
+    timing line a call (a record, not a claim)."""
+    rng = onp.random.default_rng(35)
+    S, Hq, Dh, bs, n_cols = 64, 8, 256, 16, 1088
+    N = 1 + S * n_cols
+    kp = _rand(rng, (N, 1, bs, Dh), jnp.bfloat16)
+    vp = _rand(rng, (N, 1, bs, Dh), jnp.bfloat16)
+    tables = jnp.asarray(
+        1 + rng.permutation(S * n_cols).reshape(S, n_cols), jnp.int32)
+    pos = jnp.asarray(rng.integers(10500, 17300, S), jnp.int32)
+    q = _rand(rng, (S, Hq, Dh), jnp.bfloat16)
+    scale = 1.0 / math.sqrt(Dh)
+
+    def kernel(q, k, v, t, p):
+        return fa._paged_gqa_pallas(q[:, :, None, :], k, v, t, p, scale,
+                                    None, False)[:, :, 0, :]
+
+    def gather(q, k, v, t, p):
+        return fa._xla_paged_decode_attention(q, k, v, t, p, scale, None)
+
+    assert fa._paged_kernel_kind(q, kp, Hq, None) == "gqa"
+    got = onp.asarray(jax.jit(kernel)(q, kp, vp, tables, pos), onp.float32)
+    ref = _ref(gather, q, kp, vp, tables, pos)
+    onp.testing.assert_allclose(got, ref, **TOL)
+    line = {name: round(_ms_per_call(step, q, kp, vp, tables, pos), 4)
+            for name, step in (("pallas", kernel), ("lax_gather", gather))}
+    keys = int(onp.sum(onp.asarray(pos) + 1))
+    print(f"\ngqa paged attention, corpusqa cell (D 256), {keys} keys "
+          f"read: ms a call {line}", flush=True)
+
+
+@pytest.mark.parametrize("T,from_state", [(5120, True), (8192, False)])
+def test_gated_delta_prefill_at_the_corpusqa_cell_shapes(T, from_state):
+    """The chunked delta rule — the WY operands by XLA, the chunk-to-chunk
+    recurrence by the Pallas kernel — at the cell's shapes (16 value heads
+    of 128 x 128, a hit's suffix from a restored state, snapshots every
+    2,048) against the recurrence token by token and against its own
+    ``lax.scan`` twin; everything float32 at ``highest``: 1e-4.  And the
+    one-token step over 64 rows.  Timing lines are records."""
+    gd = importlib.import_module("incubator_mxnet_tpu.kernels.gated_delta")
+    rng = onp.random.default_rng(T)
+    Hv, Dk = 16, 128
+    q, k = (_rand(rng, (T, Hv, Dk)) for _ in range(2))
+    q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True) for x in (q, k))
+    v = _rand(rng, (T, Hv, Dk))
+    g = -jnp.exp(_rand(rng, (T, Hv)) - 3.0)
+    beta = jax.nn.sigmoid(_rand(rng, (T, Hv)))
+    s0 = _rand(rng, (Hv, Dk, Dk)) if from_state \
+        else jnp.zeros((Hv, Dk, Dk), jnp.float32)
+    live = jnp.arange(T) < T - 100
+    assert gd.gated_delta_impl(q) == "pallas"
+    run = jax.jit(lambda *a: gd.gated_delta_prefill(*a, snapshot_every=2048))
+    o, snaps, last = run(q, k, v, g, beta, s0, live)
+    with jax.default_matmul_precision("highest"):
+        o_ref, last_ref = jax.jit(gd.gated_delta_scan)(q, k, v, g, beta, s0,
+                                                       live)
+        twin = jax.jit(lambda *a: gd._recurrence_scan(
+            *gd._chunk_operands(*(x.reshape(T // 64, 64, *x.shape[1:])
+                                  for x in a[:5])), a[5], 32))(
+            q, k, v, jnp.where(live[:, None], g, 0.0),
+            jnp.where(live[:, None], beta, 0.0), s0)
+    tol = dict(rtol=1e-4, atol=1e-4)
+    onp.testing.assert_allclose(onp.asarray(o)[:T - 100],
+                                onp.asarray(o_ref)[:T - 100], **tol)
+    onp.testing.assert_allclose(onp.asarray(last), onp.asarray(last_ref),
+                                **tol)
+    assert snaps.shape == (T // 2048, Hv, Dk, Dk)
+    onp.testing.assert_allclose(
+        onp.asarray(snaps), onp.asarray(jnp.moveaxis(twin[1], 0, 1)), **tol)
+    t0 = time.perf_counter()
+    for _ in range(5):
+        jax.block_until_ready(run(q, k, v, g, beta, s0, live))
+    ms = (time.perf_counter() - t0) / 5 * 1e3
+    # the step: 64 rows
+    S = 64
+    rows = _rand(rng, (S, Hv, Dk, Dk))
+    step = jax.jit(gd.gated_delta_step)
+    args = (q[:S], k[:S], v[:S], g[:S], beta[:S], rows,
+            (jnp.arange(S) % 7 != 0)[:, None])
+    o1, rows2 = step(*args)
+    with jax.default_matmul_precision("highest"):
+        o1_ref, rows_ref = jax.jit(gd.gated_delta_step)(*args)
+    onp.testing.assert_allclose(onp.asarray(rows2), onp.asarray(rows_ref),
+                                **tol)
+    onp.testing.assert_array_equal(onp.asarray(rows2)[0],
+                                   onp.asarray(rows)[0])
+    t0 = time.perf_counter()
+    for _ in range(20):
+        jax.block_until_ready(step(*args))
+    print(f"\ngated delta prefill, {T} positions x 16 heads, from_state "
+          f"{from_state}: {ms:.2f} ms a call (dispatch included); step over "
+          f"64 rows {(time.perf_counter() - t0) / 20 * 1e3:.3f} ms a call",
+          flush=True)
