@@ -22,17 +22,21 @@ TPU-first design:
 """
 from __future__ import annotations
 
+import contextlib
 import math
+import threading
 
 import numpy as _np
 
 from ..base import MXNetError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
+from ..kernels.grouped_experts import (DECODE_PAIRS, held_experts_impl,
+                                       held_experts_pallas)
 from ..ndarray.ndarray import _invoke
 
 __all__ = ["MoEFFN", "MoELoss", "ep_rules", "route_token_choice",
-           "held_experts_ffn"]
+           "held_experts_ffn", "held_experts_impl"]
 
 
 # ---------------------------------------------------------------------------
@@ -93,6 +97,25 @@ def _swiglu(x, w_gate, w_up, w_down):
     return _glu(x, w_gate, w_up, w_down, "silu")
 
 
+#: the sets this thread's callers of :func:`traced_expert_impls` collect into
+_tracing = threading.local()
+
+
+@contextlib.contextmanager
+def traced_expert_impls():
+    """Collects what :func:`held_experts_impl` answered for every expert
+    layer this thread traces inside the block: a set of ``"pallas"`` /
+    ``"lax_loop"`` (empty for a model without one).  The serving engine
+    keeps it with each program it traces."""
+    seen = set()
+    sets = _tracing.__dict__.setdefault("sets", [])
+    sets.append(seen)
+    try:
+        yield seen
+    finally:
+        sets.remove(seen)
+
+
 def held_experts_ffn(x, idx, w, held, w_gate, w_up, w_down, live=None,
                      tile=None, act="silu"):
     """The part of ``sum_k w_k * Expert_k(x)`` that the experts HELD here
@@ -111,7 +134,13 @@ def held_experts_ffn(x, idx, w, held, w_gate, w_up, w_down, live=None,
     walks the held pairs ``tile`` rows at a time — one step per
     (expert, tile of its rows), each reading that expert's matrices once.
     An expert no token chose is not visited and its weights are not
-    read; work follows the pairs held, never the published count."""
+    read; work follows the pairs held, never the published count.
+
+    A decode-shaped call on a TPU is ONE Pallas kernel whose grid walks
+    the touched experts, the next one's matrices arriving while the last
+    one's rows are multiplied (:func:`held_experts_impl` says which;
+    ``kernels/grouped_experts.py``); the loop is a prompt's path, the
+    CPU's, and the kernel's reference.  A ``tile`` asks for the loop's."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -119,7 +148,19 @@ def held_experts_ffn(x, idx, w, held, w_gate, w_up, w_down, live=None,
     d = x.shape[-1]
     P = T * k
     first, count = int(held[0]), int(held[1])
-    tile = min(int(tile or (128 if P >= 1024 else 32)), P)
+    impl = "lax_loop" if tile else held_experts_impl(x, w_gate, P)
+    for seen in getattr(_tracing, "sets", ()):
+        seen.add(impl)
+    if impl == "pallas":
+        on = (idx >= first) & (idx < first + count)
+        n_live = jnp.asarray(T, jnp.int32)
+        if live is not None:
+            on = on & live[:, None]
+            n_live = jnp.sum(live, dtype=jnp.int32)
+        y, pairs_held, touched = held_experts_pallas(
+            x, jnp.where(on, idx - first, -1), w, w_gate, w_up, w_down, act)
+        return y, (n_live * k, pairs_held, touched)
+    tile = min(int(tile or (128 if P >= DECODE_PAIRS else 32)), P)
     local = idx.reshape(P) - first
     is_held = (local >= 0) & (local < count)
     n_live = jnp.asarray(T, jnp.int32)

@@ -1913,6 +1913,10 @@ class ContinuousBatcher(DynamicBatcher):
             sd = getattr(self.engine, "sample_dispatches", None)
             if sd is not None:
                 out["sample_dispatches"] = sd()
+            ed = getattr(self.engine, "expert_dispatches", None)
+            paths = ed() if ed is not None else None
+            if paths:           # a model with an expert layer
+                out["expert_dispatches"] = paths
             if self._decode_health_last is not None:
                 out["decode_health"] = dict(self._decode_health_last)
                 out["nonfinite_generations"] = \

@@ -598,6 +598,11 @@ class GenerationEngine:
         #: then
         self._paged_attention = None
         self._paged_impls = set()
+        #: what ``held_experts_impl`` answered when each program was
+        #: traced (program -> "pallas" | "lax_loop"; a model without an
+        #: expert layer leaves it empty), and the dispatches by it
+        self._experts_impl = {}
+        self._expert_paths = {}
         self.max_slots = int(max_slots
                              or getenv_int("MXNET_GEN_MAX_SLOTS", 8))
         if self.max_slots < 1:
@@ -786,6 +791,9 @@ class GenerationEngine:
             "serving:" + self.name + ":verify", self._verify_jit)
         self._slot_edit = _telemetry.instrument_jit(
             "serving:" + self.name + ":slot_edit", self._slot_edit_jit)
+        for program in ("prefill", "prefill_ext", "decode", "decode_burst",
+                        "verify"):
+            getattr(self, "_" + program).program = program
         # speculative decoding: a draft engine attached via attach_draft
         # proposes spec_k tokens per slot; THE verify program scores all
         # spec_k + 1 positions in one dispatch (exactly one extra
@@ -820,12 +828,15 @@ class GenerationEngine:
             return (tuple(p._data._data for p in self._trainable),
                     tuple(p._data._data for p in self._aux))
 
-    def _with_params(self, param_vals, aux_vals, key, body):
+    def _with_params(self, param_vals, aux_vals, key, body, program):
         """functional_call's substitution mechanics with a custom body:
         swap jax values/tracers into the Parameters, run ``body`` in
-        inference mode under the traced RNG stream, restore."""
+        inference mode under the traced RNG stream, restore.  What the
+        expert layers traced inside it picked is kept under ``program``
+        (:meth:`_count_expert_dispatch`)."""
         from .. import autograd as _ag
         from .. import random as _random
+        from ..models.moe import traced_expert_impls
         all_params = self._trainable + self._aux
         all_vals = list(param_vals) + list(aux_vals)
         with self._params_lock:
@@ -833,8 +844,13 @@ class GenerationEngine:
             try:
                 for p, v in zip(all_params, all_vals):
                     p._data._set_data(v)
-                with _ag.pause(train_mode=False), _random.trace_stream(key):
-                    return body()
+                with _ag.pause(train_mode=False), \
+                        _random.trace_stream(key), \
+                        traced_expert_impls() as seen:
+                    out = body()
+                if seen:
+                    self._experts_impl[program] = "+".join(sorted(seen))
+                return out
             finally:
                 for p, v in zip(all_params, saved):
                     p._data._set_data(v)
@@ -1058,6 +1074,26 @@ class GenerationEngine:
         """Lifetime counts of :meth:`_count_sample_branch`, for ``GET
         /v1/models``."""
         return dict(self._sample_branches)
+
+    def _count_expert_dispatch(self, program) -> None:
+        """One dispatch of ``program`` (a prefill, decode, burst or
+        verify) of a model with an expert layer, by what its grouped
+        expert product is (``mxtpu_moe_expert_dispatches{path}``:
+        ``kernel`` or ``loop``): the answer ``held_experts_impl`` gave
+        when the program was traced, kept with it — nothing is pulled
+        from the device.  Warm-up traffic is not counted."""
+        impl = self._experts_impl.get(program)
+        if impl is None or self._warming:
+            return
+        for path in impl.split("+"):
+            path = {"pallas": "kernel", "lax_loop": "loop"}[path]
+            _m.MOE_EXPERT_DISPATCHES.inc(model=self.name, path=path)
+            self._expert_paths[path] = self._expert_paths.get(path, 0) + 1
+
+    def expert_dispatches(self) -> dict:
+        """Lifetime counts of :meth:`_count_expert_dispatch`, for ``GET
+        /v1/models``: empty for a model without an expert layer."""
+        return dict(self._expert_paths)
 
     def _slot_operands(self, state):
         """Traced: the slot state's columns as the programs use them —
@@ -1342,7 +1378,8 @@ class GenerationEngine:
                     h, _ = recur(l, layer, h, pos, pos < n_valid)
             return self.block.serve_head(self._row(h, n_valid - 1)), ks, vs
 
-        logits, ks, vs = self._with_params(param_vals, aux_vals, key, body)
+        logits, ks, vs = self._with_params(param_vals, aux_vals, key, body,
+                                            "prefill")
         for l in range(L):
             kh = self._block_rows(ks[l][0], out[l])        # (H, Tb, D)
             vh = self._block_rows(vs[l][0], out[L + l])
@@ -1408,7 +1445,8 @@ class GenerationEngine:
                                        (q_idx < n_valid)[None],
                                        n_valid - 1, recur)[0]
 
-        logits = self._with_params(param_vals, aux_vals, key, body)
+        logits = self._with_params(param_vals, aux_vals, key, body,
+                                   "prefill_ext")
         first, lp = self._sample_prefill(logits[0, 0], ctx + n_valid, samp)
         if lp is not None:
             return tuple(caches), first, lp
@@ -1468,7 +1506,8 @@ class GenerationEngine:
                 (tables[:, 0] != 0)[:, None],
                 recur=self._recur_decode(caches))
 
-        logits, counts = self._with_params(param_vals, aux_vals, key, body)
+        logits, counts = self._with_params(param_vals, aux_vals, key, body,
+                                           "decode")
         lg = logits[:, 0, :]
         live = tables[:, 0] != 0
         nxt = self._sample_step(lg, positions + 1, samp, live)
@@ -1566,7 +1605,7 @@ class GenerationEngine:
             return lax.scan(step, carry0, None, length=k)
 
         (caches, lt, pos, done, emitted, counts), ys = self._with_params(
-            param_vals, aux_vals, key, run_scan)
+            param_vals, aux_vals, key, run_scan, "decode_burst")
         state = self._advanced(state, key_next, lt[:, 0], pos,
                                budgets - emitted, eos_ids, done)
         ys = list(ys)
@@ -1638,7 +1677,8 @@ class GenerationEngine:
                 tokens, jnp.minimum(pos_q, self.max_len - 1), attend_for,
                 jnp.broadcast_to((tables[:, 0] != 0)[:, None], (S, Q)))[0]
 
-        logits = self._with_params(param_vals, aux_vals, key, body)
+        logits = self._with_params(param_vals, aux_vals, key, body,
+                                   "verify")
         nxt = self._sample_verify(logits, pos_q, samp, tables[:, 0] != 0)
         if self.logprobs_topn:
             from .sampling import topn_logprobs
@@ -1754,8 +1794,10 @@ class GenerationEngine:
         state = self._slot_state()
         try:
             with _donating():
-                return call(self._cache + self._recur, state, *args,
-                            param_vals, aux_vals)
+                out = call(self._cache + self._recur, state, *args,
+                           param_vals, aux_vals)
+            self._count_expert_dispatch(getattr(call, "program", None))
+            return out
         except Exception as e:
             # RESOURCE_EXHAUSTED here is the device running out of HBM
             # mid-dispatch: publish the oom FAULT so the flight recorder
@@ -2330,6 +2372,7 @@ class GenerationEngine:
             "warm": self.warm,
             "paged_attention": self._paged_attention,
             "pool_layout": self.pool_layout,
+            "experts_impl": dict(self._experts_impl) or None,
             "scan_steps": self.scan_steps,
             "spec_k": self.spec_k if self.draft is not None else 0,
             "programs": _telemetry.dispatch_ledger(prefix=prefix),

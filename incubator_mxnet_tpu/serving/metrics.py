@@ -155,6 +155,13 @@ SAMPLE_DISPATCHES = _telemetry.registry.counter(
     "argmax alone) or full (at least one does: sort, filters and "
     "Gumbel noise over every slot's logits); the program's own "
     "predicate, evaluated on the host's rows at dispatch")
+MOE_EXPERT_DISPATCHES = _telemetry.registry.counter(
+    "mxtpu_moe_expert_dispatches",
+    "prefill, decode, burst and verify dispatches of a model with an "
+    "expert layer by what its grouped expert product is: path=kernel "
+    "(one Pallas kernel a layer over the touched experts: a decode-"
+    "shaped call on a TPU) or loop (the lax loop: prompts, the CPU); "
+    "held_experts_impl's answer when the program was traced")
 SAMPLE_CONSTRAINED = _telemetry.registry.counter(
     "mxtpu_sample_constrained_requests",
     "generation requests decoded under a constrained-output grammar "

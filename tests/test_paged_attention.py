@@ -424,6 +424,35 @@ def test_gqa_kernel_compiles_for_v5e_at_the_agent_cell_shapes(
     assert not re.search(r"= bf16\[32769,[^\]]*\]\{[^}]*\} copy\(", text)
 
 
+@pytest.mark.parametrize("cell,T,k,count,d,f,act", [
+    ("docqa", 32, 6, 64, 2560, 768, "relu"),
+    ("corpusqa", 64, 10, 256, 2048, 512, "silu"),
+    ("agent", 64, 4, 32, 3072, 3072, "silu")])
+def test_experts_kernel_compiles_for_v5e_at_the_cell_shapes(
+        cell, T, k, count, d, f, act, one_chip, no_compile_cache,
+        monkeypatch):
+    """The grouped expert product of a decode step at the three expert
+    cells' widths (bfloat16): ``held_experts_ffn`` takes the kernel where a
+    TPU would run it, ONE call and no loop, the stacked matrices read where
+    they rest (no copy of one), two buffers of its blocks inside the
+    kernel's VMEM limit."""
+    from incubator_mxnet_tpu.models import moe
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        lambda x, idx, w, g, u, dn: moe.held_experts_ffn(
+            x, idx, w, (0, count), g, u, dn, act=act),
+        sds((T, d)), sds((T, k), jnp.int32), sds((T, k), jnp.float32),
+        sds((count, d, f)), sds((count, d, f)), sds((count, f, d)))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and " while(" not in text
+    assert not re.search(rf"= bf16\[{count},[^\]]*\]\{{[^}}]*\}} copy\(",
+                         text)
+
+
 @pytest.mark.parametrize("window", [None, 4096])
 def test_gqa_kernel_compiles_for_v5e_at_the_docqa_cell_shapes(
         window, one_chip, no_compile_cache):
@@ -723,7 +752,10 @@ def test_docqa_cell_pool_stays_as_stated_and_is_not_copied_on_v5e(
         lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
     assert eng.program_inventory()["paged_attention"] == "pallas"
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # two layers: an attention kernel and (since PR 36) an expert kernel each
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert len(re.findall(r"%_paged_gqa_pallas[.\d]* = ", text)) == 2
+    assert len(re.findall(r"%held_experts[.\d]* = ", text)) == 2
     assert not re.search(r"= bf16\[16385,[^\]]*\]\{[^}]*\} copy\(", text)
     pools_in, pools_out = compiled.input_formats[0][0], \
         compiled.output_formats[0]

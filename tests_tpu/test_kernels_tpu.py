@@ -490,3 +490,96 @@ def test_gated_delta_prefill_at_the_corpusqa_cell_shapes(T, from_state):
           f"{from_state}: {ms:.2f} ms a call (dispatch included); step over "
           f"64 rows {(time.perf_counter() - t0) / 20 * 1e3:.3f} ms a call",
           flush=True)
+
+
+# ---------------------------------------------------------------------------
+# the grouped expert product of a decode step (kernels/grouped_experts.py):
+# one kernel over the touched experts against the lax loop it replaces
+# ---------------------------------------------------------------------------
+
+def _experts_case(T, k, published, count, d, f, seed):
+    """A decode step's operands at a cell's widths: bfloat16 tokens and
+    stacked matrices, the first ``count`` of ``published`` experts held, a
+    softmax router over random logits (an even router: 61 of 64, ~190 of
+    256 and ~20 of 32 experts touched)."""
+    from incubator_mxnet_tpu.models import moe
+    rng = onp.random.default_rng(seed)
+    x = _rand(rng, (T, d), jnp.bfloat16)
+    gate, up = (jnp.asarray(rng.standard_normal((count, d, f)) * 0.02,
+                            jnp.bfloat16) for _ in range(2))
+    down = jnp.asarray(rng.standard_normal((count, f, d)) * 0.02,
+                       jnp.bfloat16)
+    idx, w = moe.route_token_choice(_rand(rng, (T, published)), None, k,
+                                    score="softmax")
+    return x, (idx, w, gate, up, down)
+
+
+def _us_per_call(step, x, rest):
+    """:func:`_ms_per_call` for a step over ``(x, *rest)``, in µs."""
+    def loop(n):
+        def run(x, *rest):
+            def body(_, xx):            # each trip needs the last
+                return xx + (step(xx, *rest) * 0).astype(xx.dtype)
+            return jax.lax.fori_loop(0, n, body, x)
+        return jax.jit(run)
+    took = {}
+    for n in (10, 60):
+        f = loop(n)
+        f(x, *rest).block_until_ready()
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            f(x, *rest).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
+        took[n] = best
+    return (took[60] - took[10]) / 50 * 1e6
+
+
+def _experts_kernel_against_the_loop(cell, T, k, published, count, d, f, act):
+    from incubator_mxnet_tpu.models import moe
+    x, rest = _experts_case(T, k, published, count, d, f, seed=36)
+    assert moe.held_experts_impl(x, rest[2], T * k) == "pallas"
+
+    def kernel(x, idx, w, gate, up, down):
+        return moe.held_experts_ffn(x, idx, w, (0, count), gate, up, down,
+                                    act=act)
+
+    def loop(x, idx, w, gate, up, down):        # a tile asks for the loop
+        return moe.held_experts_ffn(x, idx, w, (0, count), gate, up, down,
+                                    act=act, tile=32)
+
+    got, counts = jax.jit(kernel)(x, *rest)
+    ref, counts_loop = jax.jit(loop)(x, *rest)
+    assert [int(c) for c in counts] == [int(c) for c in counts_loop]
+    onp.testing.assert_allclose(onp.asarray(got), onp.asarray(ref), **TOL)
+    visits = int(counts[2])
+    us = {name: _us_per_call(lambda *a, s=step: s(*a)[0], x, rest) / visits
+          for name, step in (("pallas", kernel), ("lax_loop", loop))}
+    least = 3 * d * f * 2 / 819e9 * 1e6
+    print(f"\nexpert product, {cell} cell ({T} tokens, {visits} of {count} "
+          f"experts touched, {3 * d * f * 2 / 1e6:.2f} MB a visit = "
+          f"{least:.2f} us at 819 GB/s): us a visit "
+          f"{ {n: round(v, 2) for n, v in us.items()} }", flush=True)
+    assert us["pallas"] < us["lax_loop"]
+
+
+def test_experts_kernel_at_the_docqa_cell_shapes():
+    """SmallThinker's decode step: 32 tokens, top-6 of 64 ReGLU experts of
+    2560 x 768, all held — parity with the loop (bfloat16 operands,
+    float32 sums in another order: 2e-2) and the µs a visit of both."""
+    _experts_kernel_against_the_loop("docqa", 32, 6, 64, 64, 2560, 768,
+                                     "relu")
+
+
+def test_experts_kernel_at_the_corpusqa_cell_shapes():
+    """Qwen3-Next's: 64 tokens, top-10 of 512 published, 256 of 2048 x 512
+    held."""
+    _experts_kernel_against_the_loop("corpusqa", 64, 10, 512, 256, 2048,
+                                     512, "silu")
+
+
+def test_experts_kernel_at_the_agent_cell_shapes():
+    """AFMoE's: 64 tokens, top-4 of 256 published, 32 of 3072 x 3072 held
+    (the hidden width in six blocks of 512)."""
+    _experts_kernel_against_the_loop("agent", 64, 4, 256, 32, 3072, 3072,
+                                     "silu")
