@@ -893,12 +893,14 @@ class ContinuousBatcher(DynamicBatcher):
       shows a request's whole decode lifetime;
     * the worker thread is in exactly one named phase of its loop at
       any time (``metrics.PHASES``: wait, admit, prefill_host,
-      prefill_wait, operands, decode_wait, emit).  The loop is cut once,
-      where the work happens, and every boundary feeds both
-      ``mxtpu_serve_loop_seconds{phase}`` and — while the tracer is
-      active — a ``serve.*`` span, which a profiler capture also puts
-      into the device trace (docs/observability.md "Worker-loop
-      phases").
+      prefill_wait, operands, decode_wait, emit), and inside a host
+      phase in at most one named step (``metrics.loop_step``).  The loop
+      is cut once, where the work happens, and every boundary feeds
+      ``mxtpu_serve_loop_seconds{phase}``, the thread's CPU time beside
+      it, ``mxtpu_serve_loop_step_seconds{phase,step}`` and — while the
+      tracer is active — a ``serve.*`` span, which a profiler capture
+      also puts into the device trace (docs/observability.md
+      "Worker-loop phases").
     """
 
     def __init__(self, engine, token_strs=None, **kw):
@@ -908,10 +910,13 @@ class ContinuousBatcher(DynamicBatcher):
         self._step = 0
         self._tokens_emitted = 0
         # tokens by the dispatch that produced them, and the worker
-        # thread's seconds by phase of its loop (metrics.LoopClock)
+        # thread's seconds by phase of its loop (metrics.LoopClock):
+        # wall, CPU, and by (phase, step) inside a phase
         self._tokens_by_path = dict.fromkeys(
             ("prefill", "step", "burst", "spec"), 0)
         self._loop_seconds = dict.fromkeys(_m.PHASES, 0.0)
+        self._loop_cpu_seconds = dict.fromkeys(_m.PHASES, 0.0)
+        self._loop_step_seconds = {}
         self._peak_slots = 0
         # sampling plane: token id -> string mapping for the
         # constrained-output (json_mode) machine (default: byte-level,
@@ -1146,7 +1151,8 @@ class ContinuousBatcher(DynamicBatcher):
     def _worker(self, gen: int):
         # this thread's phase clock; a replaced worker brings its own,
         # so one that comes back from a hang cannot disturb it
-        _m.LoopClock(self.name, self._loop_seconds).bind()
+        _m.LoopClock(self.name, self._loop_seconds, self._loop_cpu_seconds,
+                     self._loop_step_seconds).bind()
         # a replaced worker's slots (and the donated cache a dying
         # dispatch may have consumed) are not trusted: start clean
         with self._cv:
@@ -1178,14 +1184,26 @@ class ContinuousBatcher(DynamicBatcher):
         ``(leavers, joins, live)`` — or ``(None, None, None)`` when this
         worker generation is done (closed+drained or replaced).  With
         nothing to do the worker sleeps on ``_cv``: the loop's ``wait``
-        phase, the only one in which it is idle."""
-        with self._cv:
+        phase, the only one in which it is idle (a ``serve.wait`` span a
+        poll while a profiler capture runs).  Taking ``_cv`` is the
+        ``lock`` step of ``admit``: handler threads hold it to submit."""
+        with _m.loop_step("lock", "serve.admit.lock"):
+            self._cv.acquire()
+        try:
             while True:
                 out = self._admit_locked(gen)
                 if out is not None:
                     return out
-                with _m.loop_phase("wait"):
+                # the idle worker's span exists for a capture's idle gaps
+                # alone: a root every 50 ms would push the last requests'
+                # spans out of /trace and the flight ring on a quiet
+                # server whose telemetry is on
+                with _m.loop_phase(
+                        "wait", "serve.wait"
+                        if _telemetry.tracer.annotate is not None else None):
                     self._cv.wait(0.05)
+        finally:
+            self._cv.release()
 
     def _admit_locked(self, gen: int):
         """One pass of :meth:`_boundary`; None when there is nothing to
@@ -1281,7 +1299,8 @@ class ContinuousBatcher(DynamicBatcher):
                                       slots=len(live),
                                       links=rids) as batch:
             if leavers:
-                with _m.loop_phase("emit", "serve.emit", step=self._step):
+                with _m.loop_phase("emit", "serve.emit", step=self._step), \
+                        _m.loop_step("finish", "serve.emit.finish"):
                     for slot, req, reason in leavers:
                         self._leave(slot, req, reason)
             for slot, req in joins:
@@ -1322,10 +1341,11 @@ class ContinuousBatcher(DynamicBatcher):
                     # constraint mask row, for json_mode) must be
                     # installed BEFORE prefill so the first sampled
                     # token is keyed
-                    self.engine.set_slot_sampling(slot, req.sampling)
-                    if req._machine is not None:
-                        self.engine.update_slot_bias(
-                            slot, req._machine.mask(budget=req.budget))
+                    with _m.loop_step("sampling", "serve.join.sampling"):
+                        self.engine.set_slot_sampling(slot, req.sampling)
+                        if req._machine is not None:
+                            self.engine.update_slot_bias(
+                                slot, req._machine.mask(budget=req.budget))
                     first = self.engine.prefill(
                         req.tokens, slot,
                         reserve_tokens=req.n + req.budget,
@@ -1337,7 +1357,8 @@ class ContinuousBatcher(DynamicBatcher):
                 self._fail(req, e)
                 return
             with _m.loop_phase("emit", "serve.emit", slot=slot,
-                               request_id=req.request_id):
+                               request_id=req.request_id), \
+                    _m.loop_step("fanout", "serve.emit.fanout"):
                 lp = getattr(self.engine, "last_prefill_logprobs",
                              lambda: None)()
                 if lp is not None:
@@ -1356,15 +1377,14 @@ class ContinuousBatcher(DynamicBatcher):
         blocks on its tokens, then ``emit``."""
         import numpy as _np
         with _m.loop_phase("operands", "serve.operands", step=self._step):
-            S = int(self.engine.max_slots)
-            last = _np.zeros(S, _np.int32)
-            pos = _np.zeros(S, _np.int32)
-            for s, r in live:
-                last[s] = r.tokens_out[-1]
-                pos[s] = r.n + len(r.tokens_out) - 1
-            rids = [r.request_id for _, r in live]
-            _m.BATCHES.inc(model=self.name)
-            _m.BATCH_SIZE.observe(len(live))
+            with _m.loop_step("carry", "serve.carry"):
+                S = int(self.engine.max_slots)
+                last = _np.zeros(S, _np.int32)
+                pos = _np.zeros(S, _np.int32)
+                for s, r in live:
+                    last[s] = r.tokens_out[-1]
+                    pos[s] = r.n + len(r.tokens_out) - 1
+                rids = [r.request_id for _, r in live]
 
             def run():
                 _fault.inject("serving.infer", model=self.name,
@@ -1391,15 +1411,16 @@ class ContinuousBatcher(DynamicBatcher):
                 model=self.name)
             self._fold_decode_health(live)
             lp = self.engine.last_logprobs()    # (S, N) pair or None
-            for s, r in live:
-                if lp is not None:
-                    self._push_logprobs(r, lp[0][s], lp[1][s])
-                # the stream boundary: ONE scalar pull per emitted token
-                tok = int(nxt[s])  # mxtpu-lint: disable=host-sync-in-hot-path
-                self._emit(r, tok, "step")
-                self._advance_machine(s, r, tok)
-                if self._maybe_finished(r):
-                    self._free_slot(s, r, "finished")
+            with _m.loop_step("fanout", "serve.emit.fanout"):
+                for s, r in live:
+                    if lp is not None:
+                        self._push_logprobs(r, lp[0][s], lp[1][s])
+                    # the stream boundary: ONE scalar pull per emitted token
+                    tok = int(nxt[s])  # mxtpu-lint: disable=host-sync-in-hot-path
+                    self._emit(r, tok, "step")
+                    self._advance_machine(s, r, tok)
+                    if self._maybe_finished(r):
+                        self._free_slot(s, r, "finished")
 
     def _dispatch_ok(self, dt: float):
         """A decode dispatch of any path came back after ``dt`` seconds."""
@@ -1453,22 +1474,21 @@ class ContinuousBatcher(DynamicBatcher):
         phases as in :meth:`_decode_once`."""
         import numpy as _np
         with _m.loop_phase("operands", "serve.operands", step=self._step):
-            S = int(self.engine.max_slots)
-            last = _np.zeros(S, _np.int32)
-            pos = _np.zeros(S, _np.int32)
-            bud = _np.ones(S, _np.int32)
-            eos = _np.full(S, -1, _np.int32)
-            act = _np.zeros(S, bool)
-            for s, r in live:
-                last[s] = r.tokens_out[-1]
-                pos[s] = r.n + len(r.tokens_out) - 1
-                bud[s] = r.budget - len(r.tokens_out)
-                if r.eos_id is not None:
-                    eos[s] = int(r.eos_id)
-                act[s] = True
-            rids = [r.request_id for _, r in live]
-            _m.BATCHES.inc(model=self.name)
-            _m.BATCH_SIZE.observe(len(live))
+            with _m.loop_step("carry", "serve.carry"):
+                S = int(self.engine.max_slots)
+                last = _np.zeros(S, _np.int32)
+                pos = _np.zeros(S, _np.int32)
+                bud = _np.ones(S, _np.int32)
+                eos = _np.full(S, -1, _np.int32)
+                act = _np.zeros(S, bool)
+                for s, r in live:
+                    last[s] = r.tokens_out[-1]
+                    pos[s] = r.n + len(r.tokens_out) - 1
+                    bud[s] = r.budget - len(r.tokens_out)
+                    if r.eos_id is not None:
+                        eos[s] = int(r.eos_id)
+                    act[s] = True
+                rids = [r.request_id for _, r in live]
 
             def run():
                 _fault.inject("serving.infer", model=self.name,
@@ -1492,38 +1512,39 @@ class ContinuousBatcher(DynamicBatcher):
             self._burst_dispatches += 1
             lp = self.engine.last_logprobs()    # (k, S, N) pair or None
             total = 0
-            for s, r in live:
-                # the stream boundary: one bounded pull per rider burst
-                n = int(emitted[s])  # mxtpu-lint: disable=host-sync-in-hot-path
-                if n < 1:
-                    continue
-                # mxtpu-lint: disable=host-sync-in-hot-path
-                new = [int(t) for t in toks[:n, s]]
-                stopped = False
-                if r.stops:
-                    # stop sequences are detected host-side AT the emit
-                    # boundary: keep through the stop, discard the
-                    # over-generated tail BEFORE anything reaches the
-                    # client's stream
-                    kept, stopped = stop_trim(r.tokens_out, new, r.stops)
-                    if stopped:
-                        self._stop_hits += 1
-                        self._stop_trimmed += n - kept
-                        _m.SAMPLE_STOP_HITS.inc(model=self.name)
-                        _m.SAMPLE_STOP_TRIMMED.inc(n - kept,
-                                                   model=self.name)
-                        new = new[:kept]
-                        n = kept
-                if lp is not None:
-                    for j in range(n):
-                        self._push_logprobs(r, lp[0][j, s], lp[1][j, s])
-                self._emit_burst(r, new)
-                total += n
-                # `stopped` already counted the hit — bypass the
-                # endswith re-check in _maybe_finished to keep the
-                # counter honest
-                if stopped or self._maybe_finished(r):
-                    self._free_slot(s, r, "finished")
+            with _m.loop_step("fanout", "serve.emit.fanout"):
+                for s, r in live:
+                    # the stream boundary: one bounded pull per rider burst
+                    n = int(emitted[s])  # mxtpu-lint: disable=host-sync-in-hot-path
+                    if n < 1:
+                        continue
+                    # mxtpu-lint: disable=host-sync-in-hot-path
+                    new = [int(t) for t in toks[:n, s]]
+                    stopped = False
+                    if r.stops:
+                        # stop sequences are detected host-side AT the emit
+                        # boundary: keep through the stop, discard the
+                        # over-generated tail BEFORE anything reaches the
+                        # client's stream
+                        kept, stopped = stop_trim(r.tokens_out, new, r.stops)
+                        if stopped:
+                            self._stop_hits += 1
+                            self._stop_trimmed += n - kept
+                            _m.SAMPLE_STOP_HITS.inc(model=self.name)
+                            _m.SAMPLE_STOP_TRIMMED.inc(n - kept,
+                                                       model=self.name)
+                            new = new[:kept]
+                            n = kept
+                    if lp is not None:
+                        for j in range(n):
+                            self._push_logprobs(r, lp[0][j, s], lp[1][j, s])
+                    self._emit_burst(r, new)
+                    total += n
+                    # `stopped` already counted the hit — bypass the
+                    # endswith re-check in _maybe_finished to keep the
+                    # counter honest
+                    if stopped or self._maybe_finished(r):
+                        self._free_slot(s, r, "finished")
             _m.DECODE_BURST_TOKENS.observe(total)
             # dispatch economy: ONE dispatch bought up to k tokens per slot
             self._dpt_dispatches += 1
@@ -1581,16 +1602,15 @@ class ContinuousBatcher(DynamicBatcher):
         draft's dispatch, once for the verify), then ``emit``."""
         import numpy as _np
         with _m.loop_phase("operands", "serve.operands", step=self._step):
-            S = int(self.engine.max_slots)
-            k = int(self.engine.spec_k)
-            last = _np.zeros(S, _np.int32)
-            pos = _np.zeros(S, _np.int32)
-            for s, r in live:
-                last[s] = r.tokens_out[-1]
-                pos[s] = r.n + len(r.tokens_out) - 1
-            rids = [r.request_id for _, r in live]
-            _m.BATCHES.inc(model=self.name)
-            _m.BATCH_SIZE.observe(len(live))
+            with _m.loop_step("carry", "serve.carry"):
+                S = int(self.engine.max_slots)
+                k = int(self.engine.spec_k)
+                last = _np.zeros(S, _np.int32)
+                pos = _np.zeros(S, _np.int32)
+                for s, r in live:
+                    last[s] = r.tokens_out[-1]
+                    pos[s] = r.n + len(r.tokens_out) - 1
+                rids = [r.request_id for _, r in live]
 
             def run():
                 _fault.inject("serving.infer", model=self.name,
@@ -1621,23 +1641,24 @@ class ContinuousBatcher(DynamicBatcher):
                          lambda: None)()     # (S, Q, N) pair or None
             step_emitted = 0
             step_accepted = 0
-            for s, r in live:
-                n_emit = 0
-                # the stream boundary: scalar pulls gate each emitted token
-                # mxtpu-lint: disable=host-sync-in-hot-path
-                for j in range(int(accepted[s]) + 1):
-                    if lp is not None:
-                        self._push_logprobs(r, lp[0][s, j], lp[1][s, j])
+            with _m.loop_step("fanout", "serve.emit.fanout"):
+                for s, r in live:
+                    n_emit = 0
+                    # the stream boundary: scalar pulls gate each emitted token
                     # mxtpu-lint: disable=host-sync-in-hot-path
-                    self._emit(r, int(burst[s, j]), "spec")
-                    n_emit += 1
-                    if self._maybe_finished(r):
-                        self._free_slot(s, r, "finished")
-                        break
-                r.draft_tokens += k
-                r.accepted_tokens += n_emit - 1
-                step_emitted += n_emit
-                step_accepted += n_emit - 1
+                    for j in range(int(accepted[s]) + 1):
+                        if lp is not None:
+                            self._push_logprobs(r, lp[0][s, j], lp[1][s, j])
+                        # mxtpu-lint: disable=host-sync-in-hot-path
+                        self._emit(r, int(burst[s, j]), "spec")
+                        n_emit += 1
+                        if self._maybe_finished(r):
+                            self._free_slot(s, r, "finished")
+                            break
+                    r.draft_tokens += k
+                    r.accepted_tokens += n_emit - 1
+                    step_emitted += n_emit
+                    step_accepted += n_emit - 1
             self._spec_emitted += step_emitted
             self._spec_accepted += step_accepted
             self._spec_drafted += len(live) * k
@@ -1738,13 +1759,14 @@ class ContinuousBatcher(DynamicBatcher):
         return False
 
     def _free_slot(self, slot: int, req: _GenRequest, reason: str):
-        with self._cv:
-            if self._slots[slot] is req:
-                self._slots[slot] = None
-            _m.SLOTS_IN_USE.set(
-                sum(1 for r in self._slots if r is not None),
-                model=self.name)
-        self._leave(slot, req, reason)
+        with _m.loop_step("finish", "serve.emit.finish"):
+            with self._cv:
+                if self._slots[slot] is req:
+                    self._slots[slot] = None
+                _m.SLOTS_IN_USE.set(
+                    sum(1 for r in self._slots if r is not None),
+                    model=self.name)
+            self._leave(slot, req, reason)
 
     def _leave(self, slot: int, req: _GenRequest, reason: str):
         """Emit the ``slot.leave`` event and settle the request: ok for
@@ -1860,6 +1882,14 @@ class ContinuousBatcher(DynamicBatcher):
         with self._cv:
             return sum(1 for r in self._slots if r is not None)
 
+    def _steps_by_phase(self) -> dict:
+        """The clock's step totals as ``{phase: {step: seconds}}``."""
+        out = {}
+        # the worker adds keys as steps first run: copy before iterating
+        for (phase, step), s in dict(self._loop_step_seconds).items():
+            out.setdefault(phase, {})[step] = s
+        return out
+
     def stats(self) -> dict:
         out = super().stats()
         with self._cv:
@@ -1876,6 +1906,8 @@ class ContinuousBatcher(DynamicBatcher):
                 "tokens_emitted": self._tokens_emitted,
                 "tokens_by_path": dict(self._tokens_by_path),
                 "loop_seconds": dict(self._loop_seconds),
+                "loop_cpu_seconds": dict(self._loop_cpu_seconds),
+                "loop_step_seconds": self._steps_by_phase(),
                 "peak_slots_in_use": self._peak_slots,
                 "prefill_buckets": list(self.engine.prefill_buckets),
                 "kv_cache_bytes": int(self.engine.cache_bytes),

@@ -951,6 +951,16 @@ class GenerationEngine:
         that row.  More changed rows than that (a draft's burst advances
         every slot past what the target accepts) go as the whole
         ``rows`` matrix in one upload; the bias matrix never does."""
+        if self._state is not None \
+                and not (self._dirty or self._dirty_bias):
+            return self._state
+        with _m.loop_step("edit", "serve.edit"):
+            self._upload_slot_state()
+        return self._state
+
+    def _upload_slot_state(self) -> None:
+        """:meth:`_slot_state` where something has to reach the device
+        (the worker loop's ``edit`` step)."""
         from .. import random as _random
         if self._state is None:
             self._state = {"rows": self._put(self._rows),
@@ -962,9 +972,7 @@ class GenerationEngine:
             self._dirty.clear()
             self._dirty_bias.clear()
             self._rebuilt = True
-            return self._state
-        if not (self._dirty or self._dirty_bias):
-            return self._state
+            return
         self._edited += len(self._dirty | self._dirty_bias)
         if len(self._dirty) > _EDIT_ROWS:
             self._state["rows"] = self._put(self._rows)
@@ -985,7 +993,6 @@ class GenerationEngine:
             raise
         self._dirty.clear()
         self._dirty_bias.clear()
-        return self._state
 
     def _edit_slots(self, slots, with_bias: bool) -> None:
         """Write the host's rows of ``slots`` (at most ``_EDIT_ROWS``)
@@ -1790,10 +1797,11 @@ class GenerationEngine:
     def _guarded(self, call, *args):
         """Enqueue ``call`` over the cache, the slot state brought up to
         the host's rows, ``args`` and the current parameters."""
-        param_vals, aux_vals = self._param_fn()
+        with _m.loop_step("params", "serve.params"):
+            param_vals, aux_vals = self._param_fn()
         state = self._slot_state()
         try:
-            with _donating():
+            with _m.loop_step("enqueue", "serve.enqueue"), _donating():
                 out = call(self._cache + self._recur, state, *args,
                            param_vals, aux_vals)
             self._count_expert_dispatch(getattr(call, "program", None))
@@ -1912,16 +1920,17 @@ class GenerationEngine:
                                reserve_tokens=int(
                                    reserve_tokens or self.max_len)
                                + self.spec_k)
-        if self._slot_blocks[slot]:
-            self.release_slot(slot)
-        reserve = int(reserve_tokens or self.max_len) \
-            + (self.spec_k if self.draft is not None else 0)
-        reserve = max(n + 1, min(reserve, self.max_len))
-        table, m, plan = self.pool.allocate(toks, n, reserve,
-                                            share=not self._warming)
-        self._slot_blocks[slot] = table
-        self._set_table(slot, table)
-        self._note_state_rows()
+        with _m.loop_step("alloc", "serve.join.alloc"):
+            if self._slot_blocks[slot]:
+                self.release_slot(slot)
+            reserve = int(reserve_tokens or self.max_len) \
+                + (self.spec_k if self.draft is not None else 0)
+            reserve = max(n + 1, min(reserve, self.max_len))
+            table, m, plan = self.pool.allocate(toks, n, reserve,
+                                                share=not self._warming)
+            self._slot_blocks[slot] = table
+            self._set_table(slot, table)
+            self._note_state_rows()
         try:
             return self._prefill_paged_dispatch(toks, n, m, slot, span,
                                                 plan)
@@ -1951,16 +1960,18 @@ class GenerationEngine:
         and a few integers — for a model with state layers the snapshot
         rows of the pool's plan among them, so a hit starts from its
         snapshot inside the one dispatch."""
-        if m == 0:
-            padded = self._padded(toks, n, span)
-            out = self._guarded(self._prefill, padded, self._prefill_at(
-                plan, padded.shape[1], n, slot))
-        else:
-            if span is not None:
-                span.attrs["prefix_hit_tokens"] = m
-            padded = self._padded(toks[m:], n - m, span)
-            out = self._guarded(self._prefill_ext, padded, self._prefill_at(
-                plan, padded.shape[1], n - m, slot, m))
+        with _m.loop_step("enqueue", "serve.enqueue"):
+            if m == 0:
+                padded = self._padded(toks, n, span)
+                out = self._guarded(self._prefill, padded, self._prefill_at(
+                    plan, padded.shape[1], n, slot))
+            else:
+                if span is not None:
+                    span.attrs["prefix_hit_tokens"] = m
+                padded = self._padded(toks[m:], n - m, span)
+                out = self._guarded(
+                    self._prefill_ext, padded, self._prefill_at(
+                        plan, padded.shape[1], n - m, slot, m))
         if not self._warming:
             path = "hit" if m else "miss"
             _m.PREFILL_TOKENS.inc(n - m, model=self.name, path=path)
@@ -2012,8 +2023,10 @@ class GenerationEngine:
         device's result."""
         S = self.max_slots
         _m.loop_phase_switch("operands", "serve.operands")
-        self._carry({_LAST: _np.asarray(last_tokens, _np.int32).reshape(S),
-                     _POS: _np.asarray(positions, _np.int32).reshape(S)})
+        with _m.loop_step("carry", "serve.carry"):
+            self._carry(
+                {_LAST: _np.asarray(last_tokens, _np.int32).reshape(S),
+                 _POS: _np.asarray(positions, _np.int32).reshape(S)})
         with self._advancing(self._decode) as out:
             counts = self._pop_extras(out)
             nxt = _np.asarray(out[0])
@@ -2045,11 +2058,13 @@ class GenerationEngine:
                 f"{self.scan_steps}; set MXNET_DECODE_SCAN_STEPS >= 1)")
         S = self.max_slots
         _m.loop_phase_switch("operands", "serve.operands")
-        self._carry({_LAST: _np.asarray(last_tokens, _np.int32).reshape(S),
-                     _POS: _np.asarray(positions, _np.int32).reshape(S),
-                     _BUDGET: _np.asarray(budgets, _np.int32).reshape(S),
-                     _EOS: _np.asarray(eos_ids, _np.int32).reshape(S),
-                     _DONE: ~_np.asarray(active, bool).reshape(S)})
+        with _m.loop_step("carry", "serve.carry"):
+            self._carry(
+                {_LAST: _np.asarray(last_tokens, _np.int32).reshape(S),
+                 _POS: _np.asarray(positions, _np.int32).reshape(S),
+                 _BUDGET: _np.asarray(budgets, _np.int32).reshape(S),
+                 _EOS: _np.asarray(eos_ids, _np.int32).reshape(S),
+                 _DONE: ~_np.asarray(active, bool).reshape(S)})
         with self._advancing(self._decode_burst,
                              live=self._rows[:, _DONE] == 0) as out:
             counts = self._pop_extras(out)
@@ -2291,15 +2306,16 @@ class GenerationEngine:
         """Return ``slot``'s blocks to the pool (decref — shared prefix
         blocks stay live for their other readers / the prefix cache).
         Cascades to the draft engine's mirrored slot."""
-        if self.draft is not None:
-            self.draft.release_slot(slot)
-        blocks = self._slot_blocks[int(slot)]
-        if blocks:
-            self.pool.release(blocks)
-        self._slot_blocks[int(slot)] = []
-        self._set_table(int(slot), ())
-        self._rows[int(slot), :_TOPK] = _FREE
-        self._note_state_rows()
+        with _m.loop_step("alloc", "serve.join.alloc"):
+            if self.draft is not None:
+                self.draft.release_slot(slot)
+            blocks = self._slot_blocks[int(slot)]
+            if blocks:
+                self.pool.release(blocks)
+            self._slot_blocks[int(slot)] = []
+            self._set_table(int(slot), ())
+            self._rows[int(slot), :_TOPK] = _FREE
+            self._note_state_rows()
 
     def can_admit(self, tokens, reserve_tokens: int,
                   reserved_blocks: int = 0) -> bool:

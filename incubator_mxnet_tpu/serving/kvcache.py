@@ -312,11 +312,12 @@ class BlockPool:
         bs = self.block_size
         out: List[bytes] = []
         h = ("mxtpu-kv:%d" % bs).encode()
-        for i in range(int(limit) // bs):
-            blk = b",".join(b"%d" % int(t)
-                            for t in tokens[i * bs:(i + 1) * bs])
-            h = hashlib.blake2b(h + b"|" + blk, digest_size=16).digest()
-            out.append(h)
+        with _m.loop_step("hash", "serve.join.hash"):
+            for i in range(int(limit) // bs):
+                blk = b",".join(b"%d" % int(t)
+                                for t in tokens[i * bs:(i + 1) * bs])
+                h = hashlib.blake2b(h + b"|" + blk, digest_size=16).digest()
+                out.append(h)
         return out
 
     def _match(self, hashes: Sequence[bytes], usable: int) -> List[int]:
@@ -350,7 +351,7 @@ class BlockPool:
         """Would :meth:`allocate` succeed right now? ``reserved_blocks``
         discounts capacity already promised to earlier admits in the same
         scheduling step."""
-        with self._lock:
+        with _m.loop_step("alloc", "serve.join.alloc"), self._lock:
             need = blocks_for(reserve_tokens, self.block_size)
             hashes = self.chain_hashes(tokens, (int(n) // self.block_size)
                                        * self.block_size)
@@ -384,7 +385,7 @@ class BlockPool:
         need = blocks_for(reserve_tokens, self.block_size)
         if need < 1:
             raise ValueError(f"reserve_tokens must be >= 1, got {reserve_tokens}")
-        with self._lock:
+        with _m.loop_step("alloc", "serve.join.alloc"), self._lock:
             full = (n // self.block_size) * self.block_size
             hashes = self.chain_hashes(tokens, full) if share else []
             shared = self._match(
@@ -473,7 +474,7 @@ class BlockPool:
         """Decref every block in ``table``. Blocks reaching refcount 0
         return to the free list, unless cached — those stay evictable in
         LRU order for future prefix hits."""
-        with self._lock:
+        with _m.loop_step("alloc", "serve.join.alloc"), self._lock:
             for b in table:
                 if b == NULL_BLOCK:
                     continue

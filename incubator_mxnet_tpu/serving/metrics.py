@@ -41,6 +41,18 @@ LOOP_SECONDS = _telemetry.registry.counter(
     "seconds the generation worker thread spent in each phase of its "
     "loop (phase=wait|admit|prefill_host|prefill_wait|operands|"
     "decode_wait|emit); the phases partition the thread's time")
+LOOP_CPU_SECONDS = _telemetry.registry.counter(
+    "mxtpu_serve_loop_cpu_seconds",
+    "of mxtpu_serve_loop_seconds, the seconds the generation worker "
+    "thread was running on a CPU (time.thread_time), by the same phase; "
+    "wall minus CPU is time the thread was off the CPU: the interpreter "
+    "lock given away, a lock waited for, a blocking call into the runtime")
+LOOP_STEP_SECONDS = _telemetry.registry.counter(
+    "mxtpu_serve_loop_step_seconds",
+    "of mxtpu_serve_loop_seconds, the seconds inside a named step of a "
+    "phase (step=lock|hash|alloc|sampling|params|edit|enqueue|carry|"
+    "fanout|finish); steps subdivide a phase and what none covers is the "
+    "phase's remainder")
 BURST_GATE = _telemetry.registry.counter(
     "mxtpu_serve_burst_gate",
     "decode dispatches that were not a burst, by the reason the gate "
@@ -383,39 +395,85 @@ class LoopClock:
     Phases nest as ``with`` blocks and time is exclusive: a phase is
     charged only while it is the innermost.  Outside any block the
     thread is in ``admit`` — the batcher's own scheduling between
-    phases.  The batcher binds a clock to its worker thread
-    (:meth:`bind`); the engine reaches it through :func:`loop_phase` and
-    :func:`loop_phase_switch`, which do nothing on a thread that has
-    none (an engine driven directly)."""
+    phases.  A *step* (:func:`loop_step`) is a block inside whatever
+    phase is open, exclusive in the same way: its time stays in the
+    phase's seconds and is also added to
+    ``mxtpu_serve_loop_step_seconds{model, phase, step}``, so the steps
+    subdivide a phase and what none covers is its remainder.  Each
+    boundary between two phases also reads the thread's CPU time
+    (``mxtpu_serve_loop_cpu_seconds{model, phase}``): wall minus CPU is
+    time the thread was not running.  The batcher binds a clock to its
+    worker thread (:meth:`bind`); the engine and the block pool reach it
+    through :func:`loop_phase`, :func:`loop_phase_switch` and
+    :func:`loop_step`, which do nothing on a thread that has none (an
+    engine driven directly)."""
 
-    def __init__(self, model: str, seconds: dict):
+    def __init__(self, model: str, seconds: dict, cpu_seconds: dict,
+                 step_seconds: dict):
         self.model = model
-        self.seconds = seconds      # the batcher's totals, by PHASES
+        # the batcher's totals: wall and CPU by PHASES, steps by
+        # (phase, step)
+        self.seconds = seconds
+        self.cpu_seconds = cpu_seconds
+        self.step_seconds = step_seconds
         self.phase = "admit"
+        self.step = None
         self._t = time.perf_counter()
+        self._cpu = time.thread_time()
         self._open = []             # the nested _Phase blocks
+        self._series = {}           # (phase, step) -> the registry's adders
 
     def bind(self) -> None:
         _loop_tl.clock = self
 
-    def _turn(self, phase: str) -> None:
+    def _adders(self, phase: str, step: str):
+        """The three series a turn out of ``(phase, step)`` adds to, their
+        label keys built once (a turn costs the loop a few microseconds,
+        a dozen times a dispatch)."""
+        labels = {"model": self.model, "phase": phase}
+        return (LOOP_SECONDS.bound(**labels),
+                LOOP_CPU_SECONDS.bound(**labels),
+                LOOP_STEP_SECONDS.bound(step=step, **labels)
+                if step is not None else None)
+
+    def _turn(self, phase: str, step: str = None) -> None:
         now = time.perf_counter()
         dt, self._t = now - self._t, now
-        self.seconds[self.phase] += dt
-        LOOP_SECONDS.inc(dt, model=self.model, phase=self.phase)
-        self.phase = phase
+        was = (self.phase, self.step)
+        adders = self._series.get(was)
+        if adders is None:
+            adders = self._series[was] = self._adders(*was)
+        wall, cpu_s, in_step = adders
+        self.seconds[was[0]] += dt
+        wall(dt)
+        if in_step is not None:
+            self.step_seconds[was] = self.step_seconds.get(was, 0.0) + dt
+            in_step(dt)
+        if phase != was[0]:
+            # CPU time is by phase: the thread clock (a system call, where
+            # the wall clock is not) is read where the phase changes only
+            cpu = time.thread_time()
+            dcpu, self._cpu = cpu - self._cpu, cpu
+            self.cpu_seconds[was[0]] += dcpu
+            cpu_s(dcpu)
+        self.phase, self.step = phase, step
 
 
 class _Phase:
-    __slots__ = ("clock", "name", "span_name", "attrs", "span", "outer")
+    """One ``with`` block of the loop: a phase (``step`` False: ``name``
+    is the phase, and no step is open inside it until one begins) or a
+    step of the phase that is open (``name`` is the step)."""
+    __slots__ = ("clock", "name", "span_name", "attrs", "span", "outer",
+                 "step")
 
-    def __init__(self, clock, name, span_name, attrs):
+    def __init__(self, clock, name, span_name, attrs, step=False):
         self.clock = clock
         self.name = name
         self.span_name = span_name
         self.attrs = attrs
         self.span = None
         self.outer = None
+        self.step = step
 
     def _begin_span(self):
         if self.span_name is not None and _telemetry.tracer.active:
@@ -431,8 +489,11 @@ class _Phase:
     def __enter__(self):
         clock = self.clock
         if clock is not None:
-            self.outer = clock.phase
-            clock._turn(self.name)
+            self.outer = (clock.phase, clock.step)
+            if self.step:
+                clock._turn(clock.phase, self.name)
+            else:
+                clock._turn(self.name)
             clock._open.append(self)
             self._begin_span()
         return self
@@ -442,7 +503,7 @@ class _Phase:
         if clock is not None:
             self._end_span()
             clock._open.pop()       # ``with`` blocks close innermost first
-            clock._turn(self.outer)
+            clock._turn(*self.outer)
         return False
 
 
@@ -453,18 +514,32 @@ def loop_phase(name: str, span: str = None, **attrs) -> _Phase:
     return _Phase(getattr(_loop_tl, "clock", None), name, span, attrs)
 
 
+def loop_step(name: str, span: str = None, **attrs) -> _Phase:
+    """``with loop_step("hash", "serve.join.hash"): ...`` — the block is
+    step ``name`` of whatever phase this thread's worker loop is in,
+    under a span named ``span``.  Exclusive like the phases: a step is
+    charged only while it is the innermost block, and a block that opens
+    the step it is already inside is that step going on (no boundary, no
+    second span)."""
+    clock = getattr(_loop_tl, "clock", None)
+    if clock is not None and clock.step == name:
+        clock = None
+    return _Phase(clock, name, span, attrs, step=True)
+
+
 def loop_phase_switch(name: str, span: str = None, **attrs) -> None:
     """End the innermost open phase of this thread's loop here and begin
     ``name`` in its place, as its sibling; the ``with`` that opened the
     first then closes the second.  For a boundary that lies inside a
     callee: the batcher opens ``operands`` around the engine call, and
     the engine, once the program is enqueued, switches to
-    ``decode_wait``."""
+    ``decode_wait``.  Steps are closed by then: a switch made inside an
+    open step does nothing."""
     clock = getattr(_loop_tl, "clock", None)
     if clock is None or not clock._open:
         return
     ph = clock._open[-1]
-    if ph.name == name:
+    if ph.step or ph.name == name:
         return
     ph._end_span()
     clock._turn(name)

@@ -272,6 +272,18 @@ class Counter:
         with self._lock:
             self._values[key] = self._values.get(key, 0.0) + amount
 
+    def bound(self, **labels):
+        """``add(amount)`` for ONE label set, its key built once — for a
+        caller that adds to the same few series at every turn of a loop
+        (``serving.metrics.LoopClock``); ``amount`` is not checked."""
+        key = _label_key(labels)
+        lock, values = self._lock, self._values
+
+        def add(amount: float) -> None:
+            with lock:
+                values[key] = values.get(key, 0.0) + amount
+        return add
+
     @property
     def value(self) -> float:
         return sum(self._values.values())

@@ -49,6 +49,26 @@ def test_host_sync_flags_marked_roots_and_callees(tmp_path):
     assert all(f.check == "host-sync-in-hot-path" for f in found)
 
 
+def test_host_sync_flagged_inside_a_loop_step_block(tmp_path):
+    """A ``with loop_step(...)`` block of the worker loop's clock hides
+    nothing from the rule: its body is the hot path's body."""
+    src = """
+        import jax.numpy as jnp
+        from incubator_mxnet_tpu.serving import metrics as _m
+
+        # mxtpu-lint: hot-path
+        def emit(toks, live):
+            with _m.loop_step("fanout", "serve.emit.fanout"):
+                for s in live:
+                    tok = int(toks[s])
+            with _m.loop_step("carry", "serve.carry"):
+                n = len(live)
+            return tok, n
+    """
+    found = _lint(tmp_path, src, ["host-sync-in-hot-path"])
+    assert [f.line for f in found] == [9]
+
+
 def test_host_sync_clean_negative(tmp_path):
     assert _lint(tmp_path, """
         # mxtpu-lint: hot-path

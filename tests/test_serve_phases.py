@@ -2,8 +2,9 @@
 "Worker-loop phases"): the tracer's bridge into the profiler's trace,
 ``capture_profile`` with the program's spans in the capture, the phase
 clock of the ``ContinuousBatcher`` worker (counters and spans cut at the
-same boundaries), queue wait, tokens by path, the burst gate, and the
-token-gap histogram on bursts."""
+same boundaries), the steps that subdivide a host phase and the thread's
+CPU time beside its wall time, queue wait, tokens by path, the burst
+gate, and the token-gap histogram on bursts."""
 import ast
 import glob
 import json
@@ -211,8 +212,9 @@ def _gpt(max_length=64, seed=3):
 
 @pytest.fixture(scope="module")
 def engine():
+    # blocks of four: an eight-token prompt has whole blocks to hash
     eng = GenerationEngine(_gpt(), name="ph", max_slots=2, max_len=64,
-                           prefill_buckets=[8], scan_steps=4)
+                           prefill_buckets=[8], scan_steps=4, block_size=4)
     eng.warmup()
     return eng
 
@@ -282,6 +284,215 @@ def test_phases_partition_the_worker_loop(batcher):
     assert all(idle[p] == 0.0 for p in _m.PHASES
                if p not in ("wait", "admit"))
     assert idle["admit"] < 0.02
+
+
+# ------------------------------------------------- steps under a phase
+STEPS = {"lock": {"admit"}, "hash": {"admit", "prefill_host"},
+         "alloc": {"admit", "prefill_host", "emit"},
+         "sampling": {"prefill_host"}, "params": {"prefill_host", "operands"},
+         "edit": {"prefill_host", "operands"},
+         "enqueue": {"prefill_host", "operands"}, "carry": {"operands"},
+         "fanout": {"emit"}, "finish": {"emit"}}
+
+
+def _by_phase_step(counter):
+    out = {}
+    for key, v in counter.items():
+        labels = dict(part.split("=", 1) for part in key.split(","))
+        if labels["model"] == "ph":
+            out[labels["phase"], labels["step"]] = v
+    return out
+
+
+def _serve_some(batcher):
+    """One join, bursts, single steps behind a full slot set, leaves."""
+    # prompts of two whole blocks: the chain hash has work to do
+    reqs = [batcher.submit_async(list(range(1, 9)), max_new_tokens=20),
+            batcher.submit_async(list(range(12, 19)), max_new_tokens=9),
+            batcher.submit_async([9, 4, 1], max_new_tokens=5)]
+    for r in reqs:
+        r.result(60)
+
+
+def test_steps_subdivide_their_phase(batcher):
+    batcher.submit([3, 7], max_new_tokens=4)        # worker up and warm
+    st0 = batcher.stats()
+    c0 = _counter("mxtpu_serve_loop_step_seconds")
+    p0 = _counter("mxtpu_serve_loop_seconds")
+    _serve_some(batcher)
+    time.sleep(0.2)
+    st1 = batcher.stats()
+    phases = _delta(st1["loop_seconds"], st0["loop_seconds"])
+    # the phases are what they were: all of them, and nothing else
+    assert set(phases) == set(_m.PHASES)
+    for phase, steps in st1["loop_step_seconds"].items():
+        d = _delta(steps, st0["loop_step_seconds"].get(phase, {}))
+        assert all(v >= 0.0 for v in d.values())
+        assert 0.0 < sum(d.values()) <= phases[phase] + 1e-9, (phase, d)
+    # the registry's step counter is fed at the same boundaries, and a
+    # phase's steps stay inside the phase's own counter
+    steps = _by_phase_step(_delta(
+        _counter("mxtpu_serve_loop_step_seconds"), c0))
+    walls = _delta(_counter("mxtpu_serve_loop_seconds"), p0)
+    for phase in {p for p, _ in steps}:
+        inside = sum(v for (p, _), v in steps.items() if p == phase)
+        assert inside <= walls[f"model=ph,phase={phase}"] + 1e-9
+    for (phase, step), v in steps.items():
+        mine = st1["loop_step_seconds"][phase][step] \
+            - st0["loop_step_seconds"].get(phase, {}).get(step, 0.0)
+        assert abs(v - mine) <= 1e-9
+    # only the host phases have steps
+    assert {p for p, _ in steps} <= {"admit", "prefill_host", "operands",
+                                     "emit"}
+
+
+@pytest.mark.parametrize("step", sorted(STEPS))
+def test_every_step_appears_in_its_phases(batcher, step):
+    c0 = _counter("mxtpu_serve_loop_step_seconds")
+    _serve_some(batcher)
+    got = _by_phase_step(_delta(
+        _counter("mxtpu_serve_loop_step_seconds"), c0))
+    phases = {p for (p, s), v in got.items() if s == step and v > 0.0}
+    assert phases == STEPS[step], (step, phases)
+
+
+def test_cpu_seconds_stay_under_wall_seconds(batcher):
+    batcher.submit([3, 7], max_new_tokens=4)
+    st0 = batcher.stats()
+    c0 = _counter("mxtpu_serve_loop_cpu_seconds")
+    _serve_some(batcher)
+    time.sleep(0.5)                                 # idle: wall, no CPU
+    st1 = batcher.stats()
+    wall = _delta(st1["loop_seconds"], st0["loop_seconds"])
+    cpu = _delta(st1["loop_cpu_seconds"], st0["loop_cpu_seconds"])
+    assert set(cpu) == set(_m.PHASES)
+    for phase in _m.PHASES:
+        # the two clocks are read one after the other at each boundary
+        assert 0.0 <= cpu[phase] <= wall[phase] + 1e-3, (phase, cpu, wall)
+    assert cpu["wait"] < 0.2 * wall["wait"]         # asleep, not running
+    assert sum(cpu[p] for p in ("admit", "prefill_host", "operands",
+                                "emit")) > 0.0
+    cd = _delta(_counter("mxtpu_serve_loop_cpu_seconds"), c0)
+    for phase in _m.PHASES:
+        slack = 0.01 if phase in ("wait", "admit") else 1e-9
+        assert abs(cd[f"model=ph,phase={phase}"] - cpu[phase]) <= slack
+
+
+def test_loop_step_does_nothing_off_the_worker_thread(engine):
+    """An engine driven directly: no clock is bound to this thread, so
+    the cuts inside the engine and the pool count nothing."""
+    engine.reset()
+    c0 = (_counter("mxtpu_serve_loop_step_seconds"),
+          _counter("mxtpu_serve_loop_cpu_seconds"),
+          _counter("mxtpu_serve_loop_seconds"))
+    with _m.loop_step("hash", "serve.join.hash") as blk:
+        assert blk.clock is None
+    assert engine.can_admit(list(range(1, 9)), 16)
+    first = engine.prefill(list(range(1, 9)), 0, reserve_tokens=16)
+    S = engine.max_slots
+    last, pos = np.zeros(S, np.int32), np.zeros(S, np.int32)
+    last[0], pos[0] = first, 8
+    engine.decode(last, pos)
+    engine.release_slot(0)
+    assert (_counter("mxtpu_serve_loop_step_seconds"),
+            _counter("mxtpu_serve_loop_cpu_seconds"),
+            _counter("mxtpu_serve_loop_seconds")) == c0
+
+
+@pytest.fixture
+def clock():
+    """A clock bound to the test's own thread."""
+    totals = (dict.fromkeys(_m.PHASES, 0.0), dict.fromkeys(_m.PHASES, 0.0),
+              {})
+    _m.LoopClock("unit", *totals).bind()
+    yield totals
+    del _m._loop_tl.clock
+
+
+def test_steps_are_exclusive_and_reentrant(clock, bridge):
+    wall, cpu, steps = clock
+    took = {}
+
+    def spend(what, seconds):           # the test's own clock beside it
+        t0 = time.perf_counter()
+        time.sleep(seconds)
+        took[what] = took.get(what, 0.0) + time.perf_counter() - t0
+
+    with _m.loop_phase("prefill_host"):
+        with _m.loop_step("enqueue", "serve.enqueue"):
+            spend("enqueue", 0.02)
+            with _m.loop_step("params", "serve.params"):
+                spend("params", 0.03)
+            # the step it is already inside: no boundary, no second span
+            with _m.loop_step("enqueue", "serve.enqueue") as again:
+                assert again.clock is None
+                spend("enqueue", 0.01)
+            # a phase opened inside a step suspends the step
+            with _m.loop_phase("prefill_wait", "serve.prefill.wait"):
+                spend("waits", 0.02)
+                _m.loop_phase_switch("decode_wait", "serve.decode.wait")
+            # a switch inside an open step does nothing
+            _m.loop_phase_switch("operands", "serve.operands")
+        spend("remainder", 0.01)
+    assert set(steps) == {("prefill_host", "enqueue"),
+                          ("prefill_host", "params")}
+    near = lambda got, want: want <= got <= want + 2e-3
+    assert near(steps["prefill_host", "enqueue"], took["enqueue"])
+    assert near(steps["prefill_host", "params"], took["params"])
+    assert near(wall["prefill_host"] - sum(steps.values()),
+                took["remainder"])
+    assert near(wall["prefill_wait"] + wall["decode_wait"], took["waits"])
+    assert wall["operands"] == 0.0
+    assert all(cpu[p] <= wall[p] + 1e-3 for p in _m.PHASES)
+    entered = [n for k, n, _, _ in bridge.log if k == "enter"]
+    assert entered == ["serve.enqueue", "serve.params",
+                       "serve.prefill.wait", "serve.decode.wait"]
+    by = _by_phase_step({k.replace("model=unit", "model=ph"): v for k, v in
+                         _counter("mxtpu_serve_loop_step_seconds").items()
+                         if "model=unit" in k})
+    assert by == steps
+
+
+def test_bound_adder_feeds_the_series_inc_feeds():
+    add = _m.LOOP_STEP_SECONDS.bound(model="b", phase="emit", step="fanout")
+    add(0.25)
+    _m.LOOP_STEP_SECONDS.inc(0.5, step="fanout", phase="emit", model="b")
+    add(0.25)
+    assert _counter("mxtpu_serve_loop_step_seconds") == {
+        "model=b,phase=emit,step=fanout": 1.0}
+    telemetry.reset()                   # in place: the adder stays good
+    add(2.0)
+    assert _m.LOOP_STEP_SECONDS.value == 2.0
+
+
+def test_generation_dispatches_feed_no_batch_series(batcher):
+    """``mxtpu_serve_batches`` / ``mxtpu_serve_batch_size`` are the
+    one-shot ``DynamicBatcher``'s: a generation dispatch is counted by
+    its tokens' path, the dispatch ledger and the slots in use."""
+    b0, n0 = _m.BATCHES.value, _m.BATCH_SIZE.count
+    t0 = _counter("mxtpu_generate_tokens")
+    _serve_some(batcher)
+    assert (_m.BATCHES.value, _m.BATCH_SIZE.count) == (b0, n0)
+    by = _delta(_counter("mxtpu_generate_tokens"), t0)
+    assert sum(by.values()) == 34 and by["model=ph,path=burst"] > 0
+    assert batcher.stats()["decode_burst_dispatches"] > 0
+
+
+def test_idle_worker_opens_no_span_outside_a_capture(batcher):
+    batcher.submit([3, 7], max_new_tokens=4)
+    time.sleep(0.2)                     # the worker is back in its wait
+    telemetry.tracer.enable()
+    try:
+        telemetry.tracer.clear()
+        time.sleep(0.3)                 # idle polls with the tracer active
+        assert telemetry.tracer.tree()["finished"] == []
+        telemetry.tracer.annotate = FakeAnnotation  # as a capture sets it
+        time.sleep(0.3)
+        telemetry.tracer.annotate = None
+        names = [d["name"] for d in telemetry.tracer.tree()["finished"]]
+    finally:
+        telemetry.tracer.disable()
+    assert names and set(names) == {"serve.wait"}
 
 
 # ------------------------------------------------------ tokens by path
@@ -393,6 +604,78 @@ def test_capture_holds_the_program_spans(batcher, tmp_path):
     assert telemetry.tracer.tree()["finished"] == []
 
 
+@pytest.fixture(scope="module")
+def captured(engine, tmp_path_factory):
+    """One capture over a worker that joins, bursts, leaves and idles:
+    ``(spans.json, the serve.* event names of the profiler's trace)``."""
+    from jax.profiler import ProfileData
+    telemetry.stop()
+    telemetry.tracer.annotate = None
+    engine.reset()
+    batcher = ContinuousBatcher(engine)
+    stop = threading.Event()
+
+    def load():
+        seed = 0
+        while not stop.is_set():
+            seed += 1
+            batcher.submit([1 + (seed + i) % 48 for i in range(8)],
+                           max_new_tokens=12)
+            time.sleep(0.12)            # the worker idles between requests
+
+    try:
+        batcher.submit([3, 7], max_new_tokens=4)
+        t = threading.Thread(target=load, daemon=True)
+        t.start()
+        try:
+            path = telemetry_device.capture_profile(
+                0.6, out_dir=str(tmp_path_factory.mktemp("capture")))
+        finally:
+            stop.set()
+            t.join(60)
+    finally:
+        batcher.close()
+    events = set()
+    xplane = glob.glob(os.path.join(path, "**", "*.xplane.pb"),
+                       recursive=True)
+    for plane in ProfileData.from_file(xplane[-1]).planes:
+        for line in plane.lines:
+            events.update(ev.name for ev in line.events
+                          if ev.name.startswith("serve."))
+    with open(os.path.join(path, "spans.json")) as f:
+        return json.load(f), events
+
+
+@pytest.mark.parametrize("span,under", [
+    ("serve.join.hash", "serve.admit"), ("serve.join.hash", "serve.prefill"),
+    ("serve.join.alloc", "serve.prefill"), ("serve.join.alloc", "serve.emit"),
+    ("serve.join.sampling", "slot.join"),
+    ("serve.params", "serve.operands"), ("serve.carry", "serve.operands"),
+    ("serve.edit", "serve.operands"), ("serve.edit", "serve.prefill"),
+    ("serve.enqueue", "serve.operands"), ("serve.enqueue", "serve.prefill"),
+    ("serve.emit.fanout", "serve.emit"), ("serve.emit.finish", "serve.emit"),
+    ("serve.wait", None), ("serve.admit.lock", None)])
+def test_capture_nests_the_step_spans_under_their_phase(captured, span,
+                                                        under):
+    spans, events = captured
+    found = []
+
+    def walk(d, above):
+        if d["name"] == span:
+            found.append(above)
+        for c in d.get("children", []):
+            walk(c, above + [d["name"]])
+    for d in spans["finished"] + spans["live"]:
+        walk(d, [])
+    assert found, span
+    if under is None:                   # the idle worker's and the lock's
+        assert all(above == [] for above in found)      # are roots
+    else:
+        assert any(under in above for above in found), (span, found)
+    # and the step's annotation lies in the profiler's own trace
+    assert span in events
+
+
 # -------------------------------------- one request, end to end, /trace
 def test_trace_endpoint_shows_a_request_through_the_loop(engine):
     telemetry.start()
@@ -413,8 +696,17 @@ def test_trace_endpoint_shows_a_request_through_the_loop(engine):
         with urllib.request.urlopen(url + "/trace?request_id=walk-1",
                                     timeout=10) as r:
             body = json.loads(r.read())
+        with urllib.request.urlopen(url + "/v1/models", timeout=10) as r:
+            row = json.loads(r.read())["models"]["ph"]
     finally:
         srv.stop()
+    # the clock's totals, next to each other
+    assert set(row["loop_cpu_seconds"]) == set(row["loop_seconds"]) \
+        == set(_m.PHASES)
+    assert row["loop_step_seconds"]["prefill_host"]["enqueue"] > 0.0
+    assert row["loop_step_seconds"]["emit"]["fanout"] > 0.0
+    assert sum(row["loop_step_seconds"]["operands"].values()) \
+        <= row["loop_seconds"]["operands"]
     root = body["spans"][0]
     assert root["name"] == "serve.request"
     kids = root["children"]
