@@ -42,13 +42,18 @@ import jax.numpy as jnp
 
 __all__ = ["flash_attention", "flash_attention_lse",
            "paged_decode_attention", "paged_verify_decode_attention",
-           "paged_attention_impl", "prefill_attention",
+           "paged_attention_impl", "paged_run_pages", "paged_run_lengths",
+           "prefill_attention",
            "paged_prefix_attention"]
 
 _BLOCK_Q = 128
 _BLOCK_K = 128
 # keys one grid step of the paged decode kernel takes (a group of pool pages)
 _PAGED_GROUP_KEYS = 128
+# such groups one grid step of the GROUPED paged kernel takes: each a run —
+# one copy where its blocks lie in a row in the pool — so that what a step
+# costs whatever it reads is paid once for 512 keys
+_PAGED_GQA_STEP_GROUPS = 4
 
 
 def _platform_of(x):
@@ -691,6 +696,54 @@ def _paged_kernel_kind(q, k_pages, q_heads, window, position_major=False):
     return None
 
 
+def _paged_group_pages(block_size, n_cols):
+    """Pool blocks one step of a paged kernel's work list takes:
+    ``_PAGED_GROUP_KEYS`` keys' worth, the whole table where it is
+    shorter."""
+    return min(max(1, _PAGED_GROUP_KEYS // int(block_size)), int(n_cols))
+
+
+def _paged_gqa_step(block_size, n_cols):
+    """``(pages a group, groups a step)`` of the grouped kernel's work
+    list over tables of ``n_cols`` columns."""
+    pages = _paged_group_pages(block_size, n_cols)
+    return pages, min(_PAGED_GQA_STEP_GROUPS, -(-int(n_cols) // pages))
+
+
+def paged_run_pages(q, k_pages, q_heads, window, n_cols,
+                    position_major=False):
+    """``(pages a group, groups a step)`` for a paged call that takes the
+    grouped kernel — the one that fetches a group's blocks in ONE copy
+    where the table names them in a row (:func:`paged_run_lengths`) — and
+    None for a call that takes another kernel or the gather (arguments as
+    :func:`paged_attention_impl`'s; ``n_cols`` the table's columns).  What
+    the engine's ``mxtpu_paged_groups_total`` is counted by."""
+    if _paged_kernel_kind(q, k_pages, q_heads, window,
+                          position_major) != "gqa":
+        return None
+    return _paged_gqa_step(k_pages.shape[2], n_cols)
+
+
+def paged_run_lengths(table, n_pages, pool_blocks):
+    """The host's half of the grouped kernel's run flags
+    (:func:`_paged_work_list`), in numpy: for each group of ``n_pages``
+    columns of one block ``table``, how many of its leading columns name
+    consecutive blocks, ``table[c + j] == table[c] + j`` — 0 where
+    ``n_pages`` blocks from ``table[c]`` would pass the pool's end.  A
+    step whose write head leaves ``n`` columns of the group live is a run
+    iff ``n <=`` the group's entry."""
+    import numpy as np
+    table = np.asarray(table, np.int64)
+    n_groups = -(-len(table) // n_pages)
+    ids = np.full(n_groups * n_pages, -1, np.int64)
+    ids[:len(table)] = table
+    ids = ids.reshape(n_groups, n_pages)
+    in_a_row = ids == ids[:, :1] + np.arange(n_pages)
+    lengths = np.where(in_a_row.all(axis=1), n_pages,
+                       np.argmin(in_a_row, axis=1))
+    return np.where(ids[:, 0] + n_pages <= pool_blocks, lengths, 0)
+
+
 def paged_attention_impl(q, k_pages, q_heads=None, window=None,
                          position_major=False):
     """Which implementation the two paged entry points trace for a call
@@ -903,7 +956,8 @@ def _paged_kernel(slot_ref, group_ref, page_ref, pos_ref, q_ref, *refs,
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def _paged_work_list(tables, positions, n_q, bs, n_pages, window=None):
+def _paged_work_list(tables, positions, n_q, bs, n_pages, window=None,
+                     runs=None):
     """The (slot, group) steps that hold a live key, slot-major, and the
     pool page each of a step's ``n_pages`` operands reads: every slot's
     groups up to its write head (at least its first), none past it —
@@ -914,7 +968,19 @@ def _paged_work_list(tables, positions, n_q, bs, n_pages, window=None):
 
     A column of a live group that lies past the write head names the
     page the same operand read one step earlier — Pallas fetches an
-    operand only when its block index moves, so it costs nothing."""
+    operand only when its block index moves, so it costs nothing.
+
+    With ``runs = (pages a group, blocks in the pool)`` — the grouped
+    kernel's call, whose step is ``n_pages // pages`` groups of columns —
+    a fifth array says which groups are RUNS, ``n_pages // pages`` flags
+    a step: 1 where the group's live columns — the table's own entries:
+    such a call's dead columns are not filled — name consecutive blocks,
+    ``tables[s, c + j] == tables[s, c] + j``, and ``pages`` blocks from
+    the first lie inside the pool, so that ONE copy brings every live key
+    of the group (:func:`_paged_gqa_kernel`); 2, on all of a step's
+    groups, where the same holds of the whole step and its ``n_pages``
+    blocks: one copy brings them all; else 0.  ``BlockPool`` hands blocks
+    out in a row, so an unfragmented table is all runs."""
     S, n_cols = tables.shape
     n_groups = -(-n_cols // n_pages)
     last = jnp.minimum((positions + n_q - 1) // bs, n_cols - 1)    # (S,)
@@ -936,6 +1002,24 @@ def _paged_work_list(tables, positions, n_q, bs, n_pages, window=None):
         + jnp.arange(n_pages, dtype=jnp.int32)[None, :]   # (steps, pages)
     live = col <= last[slot][:, None]
     page = tables[slot[:, None], jnp.minimum(col, n_cols - 1)]
+    if runs is not None:
+        pages, pool_blocks = runs
+
+        def in_a_row(ids, live):
+            """Over the last axis: the live ids follow the first, and as
+            many blocks from the first lie inside the pool."""
+            n = ids.shape[-1]
+            follow = ids == ids[..., :1] + jnp.arange(n, dtype=jnp.int32)
+            return jnp.all(follow | ~live, axis=-1) \
+                & (ids[..., 0] + n <= int(pool_blocks))
+
+        by_group = (-1, n_pages // pages, pages)
+        run = jnp.where(
+            in_a_row(page, live)[:, None], 2,
+            in_a_row(page.reshape(by_group), live.reshape(by_group))
+            .astype(jnp.int32))
+        # the kernel copies live columns by hand: nothing to fill
+        return n_steps, slot, group, page.reshape(-1), run.reshape(-1)
     # forward-fill the dead columns from the operand's last live step
     # (the null block 0 before any)
     src = jax.lax.cummax(jnp.where(live, step[:, None], -1), axis=0)
@@ -944,33 +1028,94 @@ def _paged_work_list(tables, positions, n_q, bs, n_pages, window=None):
     return n_steps, slot, group, page.reshape(-1)
 
 
-def _paged_gqa_kernel(slot_ref, group_ref, page_ref, pos_ref, q_ref, *refs,
-                      scale, n_pages, n_cols, n_q, q_heads, kv_heads, window):
+def _paged_gqa_kernel(slot_ref, group_ref, page_ref, run_ref, pos_ref,
+                      steps_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf,
+                      sem, acc_ref, m_ref, l_ref, *, scale, n_pages,
+                      run_pages, n_cols, bs, n_q, q_heads, kv_heads, window):
     """:func:`_paged_kernel` for a grouped-query layer: the ``q_heads``
     query heads of each of the ``kv_heads`` KV heads of slot
-    ``slot_ref[i]`` against that head's part of the ``group_ref[i]``-th
-    group of pages.  A page is one block of the pool, all its KV heads —
-    ``kv_heads`` (bs, D) tiles in the pool's own type, one run of memory,
-    ONE fetch whatever the head count.  A KV head's query rows —
-    ``n_q`` positions times ``q_heads`` heads, position-major, padded to
-    whole tiles — meet its tiles on the MXU: scores ``q k^T`` and ``p v``
-    with operands of the pool's type, accumulated in float32; the softmax
-    runs in float32.  With a ``window`` row j reads keys ``head - window <
-    t <= head`` only, and the slot's first step is the group that holds
-    the first of them."""
+    ``slot_ref[i]`` against that head's part of its ``group_ref[i]``-th
+    ``n_pages`` pages.  A page is one block of the pool, all its KV heads —
+    ``kv_heads`` (bs, D) tiles in the pool's own type, one run of memory
+    whatever the head count.  A KV head's query rows — ``n_q`` positions
+    times ``q_heads`` heads, position-major, padded to whole tiles — meet
+    its tiles on the MXU: scores ``q k^T`` and ``p v`` with operands of
+    the pool's type, accumulated in float32; the softmax runs in float32.
+    With a ``window`` row j reads keys ``head - window < t <= head`` only,
+    and the slot's first step is the group that holds the first of them.
+
+    The pools stay where they rest (``k_hbm``/``v_hbm``, ``[N * H * bs,
+    D]``) and the kernel fetches by hand, a step ahead, into the two
+    halves of ``k_buf``/``v_buf`` (``[2, n_pages * H * bs, D]``), a
+    group of ``run_pages`` columns at a time: where ``run_ref`` says the
+    group's blocks lie in a row, ONE copy of ``run_pages`` blocks from
+    the first; where it does not, a copy a live column; nothing for a
+    group past the write head; and where it says that of the whole step,
+    one copy of its ``n_pages`` blocks.  A copy costs about the same
+    whatever it brings, and a step about the same whatever it reads, so
+    a run costs a fraction of its blocks and a step takes several
+    groups.  Rows past the write head — a run's tail, a skipped column's
+    leftovers — are whatever finite values the pool or the zeroed buffer
+    held: masked by position, their weights exact zeros."""
     from jax.experimental import pallas as pl
-    del page_ref
-    k_refs, v_refs = refs[:n_pages], refs[n_pages:2 * n_pages]
-    o_ref, acc_ref, m_ref, l_ref = refs[2 * n_pages:]
+    from jax.experimental.pallas import tpu as pltpu
     i = pl.program_id(0)
     g = group_ref[i]
     pos = pos_ref[slot_ref[i]]
     R = q_ref.shape[1] // kv_heads      # a KV head's rows, whole tiles
-    bs = k_refs[0].shape[1] // kv_heads
+    P = kv_heads * bs                   # a page's rows in the pool
     T = n_pages * bs
     n_keys = n_cols * bs
     first = 0 if window is None \
         else jnp.maximum(pos - window + 1, 0) // T
+
+    def fetch(t, op):
+        """Start (``op`` "start") or await ("wait") step ``t``'s copies."""
+        half = jax.lax.rem(t, 2)
+
+        def copy(src, dst, rows):
+            for c, (pool, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                getattr(pltpu.make_async_copy(
+                    pool.at[pl.ds(pl.multiple_of(src, P), rows)],
+                    buf.at[half, pl.ds(dst, rows)], sem.at[c, half]), op)()
+
+        n_runs = n_pages // run_pages
+
+        @pl.when(run_ref[t * n_runs] == 2)
+        def _step():
+            copy(page_ref[t * n_pages] * P, 0, n_pages * P)
+
+        @pl.when(run_ref[t * n_runs] != 2)
+        def _groups():
+            # the columns of step t's table that are live, from its first
+            live = jnp.minimum((pos_ref[slot_ref[t]] + n_q - 1) // bs,
+                               n_cols - 1) - group_ref[t] * n_pages + 1
+            for u in range(n_runs):
+                c, run = u * run_pages, run_ref[t * n_runs + u]
+
+                @pl.when((run == 1) & (c < live))
+                def _run(c=c):
+                    copy(page_ref[t * n_pages + c] * P, c * P, run_pages * P)
+
+                @pl.when(run == 0)
+                def _blocks(c=c):
+                    def block(j, _):
+                        copy(page_ref[t * n_pages + j] * P,
+                             pl.multiple_of(j * P, P), P)
+
+                    jax.lax.fori_loop(c, jnp.minimum(c + run_pages, live),
+                                      block, None)
+
+    @pl.when(i == 0)
+    def _zero():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    # the next step's copies — and, first of all, the first step's own
+    jax.lax.fori_loop(jnp.where(i == 0, 0, i + 1),
+                      jnp.minimum(i + 2, steps_ref[0]),
+                      lambda t, _: fetch(t, "start"), None)
+    fetch(i, "wait")
 
     @pl.when(g == first)
     def _init():
@@ -986,10 +1131,12 @@ def _paged_gqa_kernel(slot_ref, group_ref, page_ref, pos_ref, q_ref, *refs,
     live = idx <= jnp.minimum(head, n_keys - 1)
     if window is not None:
         live = live & (idx > head - window)
+    half = jax.lax.rem(i, 2)
     for h in range(kv_heads):
-        rows, keys = pl.ds(h * R, R), pl.ds(h * bs, bs)
-        k = jnp.concatenate([r[0, keys] for r in k_refs], axis=0)   # (T, D)
-        v = jnp.concatenate([r[0, keys] for r in v_refs], axis=0)
+        rows = pl.ds(h * R, R)
+        # a page's rows are its heads' (bs, D) tiles: head h's keys
+        k, v = (buf[half].reshape(n_pages, kv_heads, bs, -1)[:, h].reshape(
+            T, -1) for buf in (k_buf, v_buf))                     # (T, D)
         s = jax.lax.dot_general(
             q_ref[0, rows].astype(k.dtype), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale           # (R, T)
@@ -1017,19 +1164,24 @@ def _paged_gqa_pallas(q, k_pages, v_pages, tables, positions, scale, window,
                       interpret):
     """:func:`_paged_verify_pallas` for ``q`` (S, Hq, n_q, D) over a pool
     of ``H`` KV heads ``[N, H, bs, D]``, ``Hq // H`` query heads a KV
-    head — taken as ``[N, H * bs, D]``, the same bytes: a block with all
-    its heads is one page operand, so a step's fetches do not grow with
-    ``H`` — with the work list bounded from below by ``window``."""
+    head — taken as ``[N * H * bs, D]``, the same bytes, and left where
+    it rests: the kernel copies a step's blocks itself, a group of
+    ``_PAGED_GROUP_KEYS`` keys in ONE copy where the table names its
+    blocks in a row (:func:`_paged_work_list`'s run flags),
+    ``_PAGED_GQA_STEP_GROUPS`` groups a step — with the work list bounded
+    from below by ``window``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     S, Hq, n_q, D = q.shape
     N, H, bs, _ = k_pages.shape
     G = Hq // H
     n_cols = tables.shape[1]
-    n_pages = min(max(1, _PAGED_GROUP_KEYS // bs), n_cols)
+    run_pages, n_runs = _paged_gqa_step(bs, n_cols)
+    n_pages = run_pages * n_runs
     positions = positions.astype(jnp.int32)
-    n_steps, slot, group, page = _paged_work_list(
-        tables.astype(jnp.int32), positions, n_q, bs, n_pages, window)
+    n_steps, slot, group, page, run = _paged_work_list(
+        tables.astype(jnp.int32), positions, n_q, bs, n_pages, window,
+        runs=(run_pages, N))
     R = n_q * G
     Rp = -(-R // 16) * 16               # whole tiles of either type
     rows = jnp.swapaxes(q.reshape(S, H, G, n_q, D), 2, 3).reshape(
@@ -1038,25 +1190,25 @@ def _paged_gqa_pallas(q, k_pages, v_pages, tables, positions, scale, window,
         S, H * Rp, D)
     spec_q = pl.BlockSpec((1, H * Rp, D),
                           lambda i, slot, *_: (slot[i], 0, 0))
-    spec_pages = [
-        pl.BlockSpec((1, H * bs, D),
-                     lambda i, slot, group, page, pos, j=j:
-                     (page[i * n_pages + j], 0, 0))
-        for j in range(n_pages)]
+    spec_pool = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=6,
         grid=(n_steps,),
-        in_specs=[spec_q] + spec_pages + spec_pages,
+        in_specs=[spec_q, spec_pool, spec_pool],
         out_specs=spec_q,
         scratch_shapes=[
+            pltpu.VMEM((2, n_pages * H * bs, D), k_pages.dtype),
+            pltpu.VMEM((2, n_pages * H * bs, D), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((H * Rp, D), jnp.float32),
             pltpu.VMEM((H * Rp, 1), jnp.float32),
             pltpu.VMEM((H * Rp, 1), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _paged_gqa_kernel, scale=scale, n_pages=n_pages, n_cols=n_cols,
-        n_q=n_q, q_heads=G, kv_heads=H, window=window)
+        _paged_gqa_kernel, scale=scale, n_pages=n_pages,
+        run_pages=run_pages, n_cols=n_cols, bs=bs, n_q=n_q, q_heads=G,
+        kv_heads=H, window=window)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -1065,9 +1217,8 @@ def _paged_gqa_pallas(q, k_pages, v_pages, tables, positions, scale, window,
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=32 * 2 ** 20),
         interpret=interpret,
-    )(slot, group, page, positions, rows,
-      *([k_pages.reshape(N, H * bs, D)] * n_pages),
-      *([v_pages.reshape(N, H * bs, D)] * n_pages))
+    )(slot, group, page, run, positions, jnp.reshape(n_steps, (1,)), rows,
+      k_pages.reshape(N * H * bs, D), v_pages.reshape(N * H * bs, D))
     out = out.reshape(S, H, Rp, D)[:, :, :R].reshape(S, H, n_q, G, D)
     return jnp.swapaxes(out, 2, 3).reshape(S, Hq, n_q, D).astype(q.dtype)
 
@@ -1109,7 +1260,7 @@ def _paged_verify_pallas(q, k_pages, v_pages, tables, positions, scale,
     else:
         k_pages, v_pages = (jnp.swapaxes(k_pages, 1, 2),
                             jnp.swapaxes(v_pages, 1, 2))
-    n_pages = min(max(1, _PAGED_GROUP_KEYS // bs), n_cols)
+    n_pages = _paged_group_pages(bs, n_cols)
     positions = positions.astype(jnp.int32)
     n_steps, slot, group, page = _paged_work_list(
         tables.astype(jnp.int32), positions, n_q, bs, n_pages)

@@ -598,6 +598,17 @@ class GenerationEngine:
         #: then
         self._paged_attention = None
         self._paged_impls = set()
+        #: the layers whose paged calls take the grouped kernel, which
+        #: fetches a step's blocks in one copy where the table names them
+        #: in a row (layer -> its window), the blocks a group and the
+        #: groups a step of it takes, and each slot's table reduced to
+        #: what the run flags need (:meth:`_count_paged_groups`: each
+        #: group's run length and the full runs before it, a row a slot,
+        #: redone for the slots whose table changed)
+        self._run_layers = {}
+        self._run_step = None
+        self._run_rows = None
+        self._run_stale = set()
         #: what ``held_experts_impl`` answered when each program was
         #: traced (program -> "pallas" | "lax_loop"; a model without an
         #: expert layer leaves it empty), and the dispatches by it
@@ -1241,13 +1252,28 @@ class GenerationEngine:
         return pool.reshape(N, H * bs, D).at[blk[..., None], col].set(
             rows).reshape(pool.shape)
 
-    def _note_paged_attention(self, tables, pool, q_heads, window):
-        """Record what the paged attention entry points pick for a layer
-        of the program being traced (``program_inventory``)."""
-        from ..kernels.flash_attention import paged_attention_impl
+    def _note_paged_attention(self, layer, tables, pool, q_heads, window):
+        """Record what the paged attention entry points pick for
+        ``layer`` of the program being traced (``program_inventory``),
+        and whether it is the kernel that fetches by runs
+        (``mxtpu_paged_groups_total``)."""
+        from ..kernels.flash_attention import (paged_attention_impl,
+                                               paged_run_pages)
         self._paged_impls.add(paged_attention_impl(
             tables, pool, q_heads, window, self._position_major))
         self._paged_attention = "+".join(sorted(self._paged_impls))
+        step = paged_run_pages(tables, pool, q_heads, window,
+                               tables.shape[1], self._position_major)
+        if step:
+            self._run_layers[layer], self._run_step = window, step
+            for fetch in ("run", "blocks"):
+                self._decode_counts.setdefault("paged_groups_" + fetch, 0)
+            if self._run_rows is None:
+                n_groups = -(-self.max_blocks_per_slot // step[0])
+                self._run_rows = (
+                    _np.zeros((self.max_slots, n_groups), _np.int64),
+                    _np.zeros((self.max_slots, n_groups + 1), _np.int64))
+                self._run_stale.update(range(self.max_slots))
 
     def _pools(self, l):
         """Where layer ``l``'s K and V pools lie in a program's cache."""
@@ -1474,7 +1500,8 @@ class GenerationEngine:
                 ck = self._write_rows(caches[l], blk, off, k[:, 0])
                 cv = self._write_rows(caches[lv], blk, off, v[:, 0])
                 caches[l], caches[lv] = ck, cv
-                self._note_paged_attention(tables, ck, q.shape[2], window)
+                self._note_paged_attention(layer, tables, ck, q.shape[2],
+                                           window)
                 return paged_decode_attention(
                     q[:, 0], ck, cv, tables, positions, window=window,
                     position_major=self._position_major)[:, None]
@@ -1672,7 +1699,8 @@ class GenerationEngine:
                 ck = self._write_rows(caches[l], blk, off, k)
                 cv = self._write_rows(caches[lv], blk, off, v)
                 caches[l], caches[lv] = ck, cv
-                self._note_paged_attention(tables, ck, q.shape[2], window)
+                self._note_paged_attention(layer, tables, ck, q.shape[2],
+                                           window)
                 attn = paged_verify_decode_attention(
                     q.transpose(0, 2, 1, 3), ck, cv, tables, positions,
                     window=window, position_major=self._position_major)
@@ -1731,6 +1759,7 @@ class GenerationEngine:
         self.pool.block_bytes = self.cache_bytes // self.num_blocks
         self._slot_blocks = [[] for _ in range(self.max_slots)]
         self._tables[:] = 0
+        self._run_stale.update(range(self.max_slots))
         self._rows[:, :_TOPK] = _FREE
         self.rebuild_slot_state()
         self._note_state_rows()
@@ -1951,6 +1980,7 @@ class GenerationEngine:
         row[:len(blocks)] = blocks
         row[len(blocks):] = 0
         self._dirty.add(slot)
+        self._run_stale.add(slot)
 
     def _prefill_paged_dispatch(self, toks, n: int, m: int, slot: int,
                                 span, plan) -> int:
@@ -2126,6 +2156,48 @@ class GenerationEngine:
             v = int(v)
             _m.MODEL_COUNTERS[n].inc(v, model=self.name)
             self._decode_counts[n] += v
+        if self._run_layers:
+            self._count_paged_groups(positions, steps)
+
+    def _count_paged_groups(self, positions, steps) -> None:
+        """``mxtpu_paged_groups_total{fetch}``: the steps of the grouped
+        paged kernel's work list that the live slots' decode steps made —
+        a layer that takes it, a live slot and a step, the groups of
+        128 keys from the first of the kernel step that holds the
+        window's first key to the write head's — by how the kernel
+        fetched them: ``run`` (the group's live columns name blocks in a
+        row: one copy) or ``blocks`` (a copy a column).  The kernel's own
+        predicate (``kernels.flash_attention._paged_work_list``) on the
+        host's tables: a table is fixed from its join on, so what is
+        kept a slot is each group's run length and the full runs before
+        each group (``paged_run_lengths``)."""
+        from collections import Counter
+        from ..kernels.flash_attention import paged_run_lengths
+        bs, (P, per_step) = self.block_size, self._run_step
+        lengths, full_before = self._run_rows
+        for s in self._run_stale:
+            lengths[s] = paged_run_lengths(self._tables[s], P,
+                                           self.num_blocks)
+            full_before[s, 1:] = _np.cumsum(lengths[s] == P)
+        self._run_stale.clear()
+        k = _np.arange(int(steps.max(initial=0)))[None, :]
+        on = k < steps[:, None]                      # (S, steps): live
+        pos = positions[:, None] + k
+        last = _np.minimum(pos // bs, self.max_blocks_per_slot - 1)
+        head = last // P                     # the write head's group
+        slot = _np.arange(self.max_slots)[:, None]
+        to_head = full_before[slot, head] \
+            + (lengths[slot, head] >= last - head * P + 1)
+        run = blocks = 0
+        for window, layers in Counter(self._run_layers.values()).items():
+            first = 0 * pos if window is None else _np.maximum(
+                pos - window + 1, 0) // (bs * P * per_step) * per_step
+            runs = int(_np.sum((to_head - full_before[slot, first])[on]))
+            run += layers * runs
+            blocks += layers * (int(_np.sum((head - first + 1)[on])) - runs)
+        for fetch, n in (("run", run), ("blocks", blocks)):
+            _m.PAGED_GROUPS.inc(n, model=self.name, fetch=fetch)
+            self._decode_counts["paged_groups_" + fetch] += n
 
     def decode_counters(self) -> dict:
         """Lifetime totals of :meth:`_count_decode`'s series, for

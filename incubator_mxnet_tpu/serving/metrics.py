@@ -91,6 +91,14 @@ DECODE_WINDOW_TOKENS = _telemetry.registry.counter(
     "of mxtpu_decode_context_tokens, the positions a windowed layer "
     "reads: min(written positions, window) of each live slot, summed "
     "over decode steps; only for a model with windowed layers")
+PAGED_GROUPS = _telemetry.registry.counter(
+    "mxtpu_paged_groups_total",
+    "steps of the grouped paged kernel's work list (a layer that takes "
+    "it, a live slot and a decode step: the 128-key groups from the "
+    "window's first to the write head's) by fetch=run (the table names "
+    "the group's live blocks in a row: one copy) or blocks (a copy a "
+    "block); the kernel's predicate on the host's tables; only for a "
+    "model whose paged calls take that kernel")
 STATE_ROWS_IN_USE = _telemetry.registry.gauge(
     "mxtpu_state_rows_in_use",
     "rows of a recurrent model's state store in use: one a slot that "

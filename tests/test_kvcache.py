@@ -524,3 +524,50 @@ def test_the_dense_mode_is_gone():
     eng = GenerationEngine(net, name="g", max_slots=2, max_len=64,
                            paged=True)
     assert eng.pool is not None and "kv_blocks_total" in eng.kv_stats()
+
+
+# -- what the grouped paged kernel leans on: the pool hands blocks out in a
+# -- row, and nothing the pool does multiplies the joints --------------------
+def _joints(ids):
+    """Neighbours in ``ids`` that are not consecutive block ids."""
+    ids = list(ids)
+    return sum(1 for a, b in zip(ids, ids[1:]) if b != a + 1)
+
+
+@pytest.mark.parametrize("order", ["admission", "random"])
+@pytest.mark.parametrize("hashed", [False, True],
+                         ids=["unhashed", "hashed"])
+def test_tables_are_runs_of_consecutive_blocks(hashed, order):
+    """Six live tables of 15-100 blocks through a pool of 1,024, 300 joins:
+    the FIFO wraps twenty times.  A table is cut off the head of the free
+    list (then off the idle LRU's old end) and goes back whole and in table
+    order, so a joint is only ever made where a piece goes back behind
+    blocks it does not follow: at most one a release — two with hashed
+    prompt blocks, which go back apart, to the idle LRU — and one a join
+    that passes from the free list to the LRU.  Nothing multiplies them:
+    over the free list, the LRU and the live tables they stay under that
+    count; released in admission order and unhashed there is ONE, the
+    wrap's, and every table is one run or two."""
+    rng = np.random.default_rng(5)
+    pool = BlockPool(1025, 4, prefix_cache=hashed, model="t")
+    live, releases, joins = [], 0, 0
+    for _ in range(300):
+        while len(live) >= 6:
+            at = 0 if order == "admission" else int(rng.integers(len(live)))
+            pool.release(live.pop(at))
+            releases += 1
+        n = int(rng.integers(40, 200))
+        table, shared, _ = pool.allocate(
+            rng.integers(0, 1000, n), n, n + int(rng.integers(20, 200)),
+            share=hashed)
+        assert shared == 0                       # unshared prompts
+        joins += 1
+        live.append(table)
+        if releases == 0:                        # a fresh pool: all in a row
+            assert _joints(table) == 0
+        if not hashed and order == "admission":
+            assert _joints(table) <= 1
+        total = _joints(pool._free) + _joints(pool._idle) \
+            + sum(_joints(t) for t in live)
+        assert total <= (2 * releases + joins if hashed else releases + 1)
+    assert releases > 250

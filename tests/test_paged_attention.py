@@ -337,6 +337,31 @@ def test_inventory_reports_what_the_trace_picked(force, want, monkeypatch):
     out = eng.generate([3, 7, 11], max_new_tokens=6)
     assert eng.program_inventory()["paged_attention"] == want
     assert out == greedy_reference(eng.block, [3, 7, 11], 6)
+    # one query head a KV head: not the kernel that fetches by runs, so
+    # no mxtpu_paged_groups_total
+    assert not [k for k in eng.decode_counters() if k.startswith("paged_g")]
+
+
+def test_the_gpt2_burst_program_traces_what_it_traced(monkeypatch):
+    """The run flags are the grouped kernel's alone: the burst program of
+    a model with one query head a KV head, traced where a TPU would run it
+    (the GPT-2 kernel in every layer, its work list of four arrays), is
+    the program it was before the grouped kernel fetched by runs — its
+    jaxpr, the kernel's body included, letter for letter (the digest of
+    commit 7b357d3's; the lowered StableHLO agrees too, but carries the
+    kernel as bytes that hold ``flash_attention.py``'s line numbers).  A
+    change that means to alter that program restates the digest."""
+    import hashlib
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+    eng = GenerationEngine(_gpt(), name="digest", max_slots=2, max_len=64,
+                           scan_steps=2)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)     # noqa: E731
+    args = jax.tree.map(sds, (eng._cache + eng._recur, eng._slot_state(),
+                              *eng._param_fn()))
+    text = str(eng._decode_burst_jit.trace(*args).jaxpr)
+    assert text.count("pallas_call") == 1           # the layers share a jit
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "f4d8478cd2013ac5f375ba7748a8ac68d3ff705206fd5bd3812187efca9bfb4c")
 
 
 # --- compiled for a v5e that is not attached --------------------------------
@@ -829,3 +854,189 @@ def test_gqa_kernel_compiles_for_v5e_at_the_corpusqa_cell_shapes(
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert not re.search(r"= bf16\[69633,[^\]]*\]\{[^}]*\} copy\(", text)
+
+
+# -- the grouped kernel's fetch: a step whose live blocks lie in a row in the
+# -- pool takes them in ONE copy, any other a copy a block -------------------
+RUN_COLS, RUN_BLOCKS, RUN_PAGES = 24, 64, 8       # 3 groups of 8 blocks
+
+
+def _run_tables(name):
+    """``(tables (4, 24), positions (4,), junk)`` of one way a table can
+    lie in a pool of 64 blocks; ``junk``: the null block holds rubbish."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    t = np.zeros((4, RUN_COLS), np.int32)
+    pos, junk = [383, 200, 130, 17], False
+    if name == "in_a_row":
+        for s, n in enumerate((24, 14, 9, 2)):
+            t[s, :n] = 1 + 12 * s + np.arange(n)   # reserved past the head
+    elif name == "shuffled":
+        for s, ids in enumerate(np.split(1 + rng.permutation(60), 4)):
+            t[s, :15] = ids
+        pos = [239, 200, 130, 17]
+    elif name == "joints":
+        t[0] = 1 + np.arange(24)
+        t[0, 3:] += 7                              # inside group 0
+        t[1, :8], t[1, 8:16] = 1 + np.arange(8), 40 + np.arange(8)   # edge
+        t[2, :9] = [5, 6, 7, 8, 9, 10, 11, 13, 14]   # the group's last column
+        t[3, :2] = [30, 20]
+    elif name == "pool_end":                       # first block + 8 == N
+        t[0] = RUN_BLOCKS - 24 + np.arange(24)
+        t[1, :16] = np.r_[1 + np.arange(8), RUN_BLOCKS - 8 + np.arange(8)]
+        t[2, :9], t[3, :2] = 10 + np.arange(9), [RUN_BLOCKS - 8,
+                                                 RUN_BLOCKS - 7]
+    elif name == "past_pool_end":                  # first block + 8 == N + 1
+        t[0, :23] = RUN_BLOCKS - 23 + np.arange(23)
+        t[1, :15] = np.r_[1 + np.arange(8), RUN_BLOCKS - 7 + np.arange(7)]
+        t[2, :9], t[3, :2] = 10 + np.arange(9), [RUN_BLOCKS - 2,
+                                                 RUN_BLOCKS - 1]
+        pos = [23 * BS - 3, 200, 130, 17]
+    elif name == "short_table":                    # null block past the end
+        t[0, :3], t[1, :13], t[3, :1] = [4, 5, 6], 20 + np.arange(13), [9]
+        pos = [40, 200, 0, 3]
+    elif name == "shared_prefix":                  # 10 blocks every slot reads
+        for s, n in enumerate((24, 14, 12, 11)):
+            t[s, :10] = 1 + np.arange(10)
+            t[s, 10:n] = 11 + 13 * s + np.arange(n - 10)
+        pos = [383, 200, 170, 165]
+    elif name == "frozen_slot":
+        # a slot frozen mid-burst wrote its replayed steps to block 0, and
+        # a run from block 0 brings that block along
+        junk = True
+        t[0, :6], t[1, :14] = 1 + np.arange(6), 20 + np.arange(14)
+        pos = [40, 200, 0, 0]
+    else:
+        raise KeyError(name)
+    return t, np.asarray(pos, np.int32), junk
+
+
+RUN_TABLES = ["in_a_row", "shuffled", "joints", "pool_end", "past_pool_end",
+              "short_table", "shared_prefix", "frozen_slot"]
+
+
+def _run_flags_oracle(tables, pos, n_q, window, n_runs):
+    """``[(slot, step's group, [run flag of each of its live groups of 8
+    columns])]`` of the work list whose step is ``n_runs`` such groups, by
+    loops."""
+    out = []
+    step = n_runs * RUN_PAGES
+    for s in range(len(tables)):
+        last = min((int(pos[s]) + n_q - 1) // BS, RUN_COLS - 1)
+        first = 0 if window is None else \
+            max(int(pos[s]) - window + 1, 0) // (BS * step)
+        for g in range(first, last // step + 1):
+            flags = []
+            for c in range(g * step, min((g + 1) * step, last + 1),
+                           RUN_PAGES):
+                run = int(tables[s, c]) + RUN_PAGES <= RUN_BLOCKS
+                for j in range(RUN_PAGES):
+                    if c + j <= last \
+                            and tables[s, c + j] != tables[s, c] + j:
+                        run = False
+                flags.append(int(run))
+            out.append((s, g, flags))
+    return out
+
+
+@pytest.mark.parametrize("n_q", [1, 3])
+@pytest.mark.parametrize("n_runs", [1, 3])
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("name", RUN_TABLES)
+def test_run_flags_match_a_numpy_oracle(name, window, n_runs, n_q):
+    """The work list's run flags — live columns in a row, the copy inside
+    the pool, dead columns and the window's first step as they fall, a
+    step one group or several — and the host's half
+    (``paged_run_lengths``) against plain loops."""
+    tables, pos, _ = _run_tables(name)
+    n_pages = n_runs * RUN_PAGES
+    n_steps, slot, group, page, run = fa._paged_work_list(
+        jnp.asarray(tables), jnp.asarray(pos), n_q, BS, n_pages, window,
+        runs=(RUN_PAGES, RUN_BLOCKS))
+    n = int(n_steps)
+    want = _run_flags_oracle(tables, pos, n_q, window, n_runs)
+    assert np.asarray(slot)[:n].tolist() == [s for s, _, _ in want]
+    assert np.asarray(group)[:n].tolist() == [g for _, g, _ in want]
+    run = np.asarray(run).reshape(-1, n_runs)
+    page = np.asarray(page).reshape(-1, n_pages)
+    lengths = [fa.paged_run_lengths(t, RUN_PAGES, RUN_BLOCKS)
+               for t in tables]
+    for i, (s, g, flags) in enumerate(want):
+        last = min((int(pos[s]) + n_q - 1) // BS, RUN_COLS - 1)
+        # a group past the write head is not fetched, whatever its flag
+        assert (run[i, :len(flags)] >= 1).tolist() == flags, (s, g)
+        # ... and 2 on all its groups where the step's blocks lie in a row
+        ids, n_live = tables[s, g * n_pages:], last - g * n_pages + 1
+        whole = int(ids[0]) + n_pages <= RUN_BLOCKS and all(
+            ids[j] == ids[0] + j for j in range(min(n_live, n_pages)))
+        assert (run[i] == 2).all() if whole else (run[i] < 2).all(), (s, g)
+        for u, flag in enumerate(flags):
+            c = g * n_pages + u * RUN_PAGES
+            # a run's copy starts at the group's first page
+            assert page[i, u * RUN_PAGES] == tables[s, c]
+            live = min(last - c + 1, RUN_PAGES)
+            assert int(lengths[s][c // RUN_PAGES] >= live) == flag, (s, c)
+    # without the pool's size the list is the one the GPT-2 kernel takes
+    four = fa._paged_work_list(jnp.asarray(tables), jnp.asarray(pos), n_q,
+                               BS, n_pages, window)
+    assert len(four) == 4
+    filled = np.asarray(four[3]).reshape(page.shape)[:n]
+    col = np.asarray(group)[:n, None] * n_pages + np.arange(n_pages)
+    live = col <= np.minimum((pos + n_q - 1) // BS,
+                             RUN_COLS - 1)[np.asarray(slot)[:n], None]
+    np.testing.assert_array_equal(filled[live], page[:n][live])
+    flags = [f for _, _, fs in want for f in fs]
+    if name in ("in_a_row", "pool_end", "frozen_slot"):
+        assert all(flags)
+    if name == "shuffled":
+        assert sum(flags) <= 4          # a slot's last group of one column
+    if name == "past_pool_end":
+        assert not all(flags)
+
+
+_INTERPRETED = []          # the shapes the grouped kernel is compiled for
+
+
+@pytest.mark.parametrize("name", RUN_TABLES)        # innermost: one compile
+@pytest.mark.parametrize("n_q", [1, 3])
+@pytest.mark.parametrize("window", [None, 200])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("D", [128, 256])
+@pytest.mark.parametrize("kv_heads", [1, 4])
+def test_gqa_kernel_fetches_runs_and_blocks(name, kv_heads, D, dtype, window,
+                                            n_q):
+    """The grouped kernel, interpreted, against the lax gather over every
+    way a table can lie in the pool: one copy a step where it lies in a
+    row, a copy a block where it does not, both in one call where a table
+    has joints.  Tolerances as in
+    :func:`test_gqa_kernel_matches_lax_gather`.
+
+    An interpreted kernel is a CPU executable of ~500 memory mappings
+    that lives as long as the jit's cache, and a process may hold 65,530
+    (``vm.max_map_count``; past it XLA's compiler dies of a segmentation
+    fault, and this file's process is near 50,000 by here): the eight
+    tables of a shape share one compile, and the last shape's goes when
+    the next one's comes."""
+    if _INTERPRETED != [(kv_heads, D, dtype, window, n_q)]:
+        fa._paged_gqa_pallas.clear_cache()
+        _INTERPRETED[:] = [(kv_heads, D, dtype, window, n_q)]
+    tables, pos, junk = _run_tables(name)
+    rng = np.random.default_rng(5)
+    shape = (RUN_BLOCKS, kv_heads, BS, D)
+    kp, vp = (rng.standard_normal(shape).astype(np.float32)
+              for _ in range(2))
+    if junk:
+        kp[0], vp[0] = 1e3, -1e3
+    kp, vp = jnp.asarray(kp, dtype), jnp.asarray(vp, dtype)
+    q = jnp.asarray(rng.standard_normal((4, 2 * kv_heads, n_q, D)),
+                    jnp.float32)
+    scale = D ** -0.5
+    got = fa._paged_gqa_pallas(q, kp, vp, jnp.asarray(tables),
+                               jnp.asarray(pos), scale, window,
+                               interpret=True)
+    ref = fa._xla_paged_verify_decode_attention(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(pos), scale, window)
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    live = tables[:, 0] != 0            # a free slot's row is nobody's
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(ref)[live],
+                               atol=tol, rtol=tol)
+    assert np.isfinite(np.asarray(got, np.float32)).all()

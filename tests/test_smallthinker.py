@@ -392,3 +392,23 @@ def test_the_constructor_refuses_what_it_cannot_write_down():
         prog.build_net(dict(cfg, rope_layout=[0, 1]))
     with pytest.raises(MXNetError, match="not among"):
         prog.build_net(dict(cfg, first_expert=60))
+
+
+def test_paged_groups_counter_is_the_kernels_work_list(monkeypatch):
+    """``mxtpu_paged_groups_total{fetch}`` — host arithmetic over the
+    engine's tables — is the grouped kernel's work list: with the kernels
+    forced and heads of 128 features, through a miss, a prefix hit,
+    contexts that cross the window, single steps and bursts, over a pool
+    whose FIFO has been turned (so a table spans its seam and has a joint),
+    both totals equal a count by loops and show in ``decode_counters()``
+    (``GET /v1/models``)."""
+    from paged_groups import check_paged_groups, turn_pool
+    monkeypatch.setenv("MXNET_FA_DECODE_FORCE_PALLAS", "1")
+    cfg = _cfg()
+    cfg["head_dim"] = 128
+    eng, _ = _engine(cfg, name="groups")
+    assert "paged_groups_run" not in eng.decode_counters()    # not traced
+    turn_pool(eng, 16)
+    got = check_paged_groups(
+        eng, lambda: _serve(eng, cfg["vocab_size"]), monkeypatch)
+    assert got["run"] > 0 and got["blocks"] > 0

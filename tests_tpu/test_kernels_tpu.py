@@ -133,6 +133,36 @@ def _ms_per_call(step, q, k, v, t, p):
     return (took[60] - took[10]) / 50 * 1e3
 
 
+def _cell_tables(rng, S, n_cols, used, shared=0):
+    """A cell's block tables, ``used`` blocks a slot, two ways: ``row`` — as
+    the pool hands them out, each slot's blocks in a row after ``shared``
+    blocks that every slot reads (the grouped kernel fetches a step in one
+    copy) — and ``shuffled`` block by block (a copy a block: the traffic
+    of the kernel before it fetched by runs)."""
+    own = used - shared
+    row = onp.zeros((S, n_cols), onp.int32)
+    row[:, :shared] = 1 + onp.arange(shared)
+    row[:, shared:used] = 1 + shared + onp.arange(S * own).reshape(S, own)
+    shuffled = onp.zeros((S, n_cols), onp.int32)
+    shuffled[:, :used] = 1 + rng.permutation(S * used).reshape(S, used)
+    return {"row": jnp.asarray(row), "shuffled": jnp.asarray(shuffled)}
+
+
+def _gqa_against_the_gather(kernel, gather, q, kp, vp, tables, pos):
+    """Parity of ``kernel`` with ``gather`` at ``highest`` over both ways a
+    table can lie, and ``{tables: {implementation: ms a call}}``."""
+    line = {}
+    for name, t in tables.items():
+        got = onp.asarray(jax.jit(kernel)(q, kp, vp, t, pos), onp.float32)
+        onp.testing.assert_allclose(got, _ref(gather, q, kp, vp, t, pos),
+                                    **TOL)
+        line[name] = {"pallas": round(
+            _ms_per_call(kernel, q, kp, vp, t, pos), 4)}
+    line["row"]["lax_gather"] = round(
+        _ms_per_call(gather, q, kp, vp, tables["row"], pos), 4)
+    return line
+
+
 @pytest.mark.parametrize("n_q", [1, 5])
 @pytest.mark.parametrize("fill", ["closed", "chat"])
 def test_paged_at_the_serve_cell_shapes(fill, n_q):
@@ -166,16 +196,16 @@ def test_gqa_paged_at_the_agent_cell_shapes(window):
     is a record, not a claim: at these shapes (59% of every table live)
     the gather, whose cost does not depend on what is live, was the
     faster on the v5e — 1.44 / 1.69 ms a call against the kernel's 2.74 /
-    2.47 (my chip run 1, PR 26): the kernel pays ~0.07 us a 4 KB page
-    (PERF.md, PR 26)."""
+    2.47 (my chip run 1, PR 26).  Since PR 38 the kernel fetches by runs,
+    512 keys a step: tables as the pool hands them out (256 shared blocks,
+    then each slot's own in a row) and shuffled block by block, one timing
+    line each (PERF.md section 6, PR 38, has what was read)."""
     rng = onp.random.default_rng(11)
-    S, Hq, Dh, bs, n_cols = 64, 6, 128, 16, 512
-    N = 1 + S * 336
+    S, Hq, Dh, bs, n_cols, used = 64, 6, 128, 16, 512, 336
+    N = 1 + S * used
     kp = _rand(rng, (N, 1, bs, Dh), jnp.bfloat16)
     vp = _rand(rng, (N, 1, bs, Dh), jnp.bfloat16)
-    tables = onp.zeros((S, n_cols), onp.int32)
-    tables[:, :336] = 1 + rng.permutation(S * 336).reshape(S, 336)
-    tables = jnp.asarray(tables)
+    tables = _cell_tables(rng, S, n_cols, used, shared=256)
     pos = jnp.asarray(rng.integers(4300, 5300, S), jnp.int32)
     q = _rand(rng, (S, Hq, Dh), jnp.bfloat16)
     scale = 1.0 / math.sqrt(Dh)
@@ -187,11 +217,7 @@ def test_gqa_paged_at_the_agent_cell_shapes(window):
     def gather(q, k, v, t, p):
         return fa._xla_paged_decode_attention(q, k, v, t, p, scale, window)
 
-    got = onp.asarray(jax.jit(kernel)(q, kp, vp, tables, pos), onp.float32)
-    ref = _ref(gather, q, kp, vp, tables, pos)
-    onp.testing.assert_allclose(got, ref, **TOL)
-    line = {name: round(_ms_per_call(step, q, kp, vp, tables, pos), 4)
-            for name, step in (("pallas", kernel), ("lax_gather", gather))}
+    line = _gqa_against_the_gather(kernel, gather, q, kp, vp, tables, pos)
     keys = int(onp.sum(onp.minimum(onp.asarray(pos) + 1, window or 10**9)))
     print(f"\ngqa paged attention, agent cell, window {window}, {keys} "
           f"keys read: ms a call {line}", flush=True)
@@ -205,15 +231,15 @@ def test_gqa_paged_at_the_docqa_cell_shapes(window):
     pool, contexts 1,500-7,700 on both sides of the window — against the
     lax gather at ``highest``, with and without the window's lower bound,
     tolerances as at the agent cell's shapes.  A page is a block with all
-    its four heads (16 KB, one fetch).  The timing line is a record, not a
-    claim (PERF.md, PR 31, has what was read)."""
+    its four heads (16 KB).  Tables in a row and shuffled block by block,
+    one timing line each: a record, not a claim (PERF.md, PR 31 and PR 38,
+    has what was read)."""
     rng = onp.random.default_rng(13)
     S, H, Hq, Dh, bs, n_cols = 32, 4, 28, 128, 16, 512
     N = 1 + S * n_cols
     kp = _rand(rng, (N, H, bs, Dh), jnp.bfloat16)
     vp = _rand(rng, (N, H, bs, Dh), jnp.bfloat16)
-    tables = jnp.asarray(
-        1 + rng.permutation(S * n_cols).reshape(S, n_cols), jnp.int32)
+    tables = _cell_tables(rng, S, n_cols, n_cols)
     pos = jnp.asarray(rng.integers(1500, 7700, S), jnp.int32)
     q = _rand(rng, (S, Hq, Dh), jnp.bfloat16)
     scale = 1.0 / math.sqrt(Dh)
@@ -225,11 +251,7 @@ def test_gqa_paged_at_the_docqa_cell_shapes(window):
     def gather(q, k, v, t, p):
         return fa._xla_paged_decode_attention(q, k, v, t, p, scale, window)
 
-    got = onp.asarray(jax.jit(kernel)(q, kp, vp, tables, pos), onp.float32)
-    ref = _ref(gather, q, kp, vp, tables, pos)
-    onp.testing.assert_allclose(got, ref, **TOL)
-    line = {name: round(_ms_per_call(step, q, kp, vp, tables, pos), 4)
-            for name, step in (("pallas", kernel), ("lax_gather", gather))}
+    line = _gqa_against_the_gather(kernel, gather, q, kp, vp, tables, pos)
     keys = int(onp.sum(onp.minimum(onp.asarray(pos) + 1, window or 10**9)))
     nbytes = 2 * H * Dh * 2 * keys
     print(f"\ngqa paged attention, docqa cell, window {window}, {keys} "
@@ -397,15 +419,16 @@ def test_gqa_paged_at_the_corpusqa_cell_shapes():
     ``qwen3next-tp2-corpusqa-closed`` cell's shapes — 64 slots, 8 query
     heads on 1 KV head of 256, block 16, 1,088 table entries, a bfloat16
     pool, contexts 10,500-17,300 — against the lax gather at ``highest``
-    (both round K, V and the softmax weights to bfloat16: 2e-2), with a
-    timing line a call (a record, not a claim)."""
+    (both round K, V and the softmax weights to bfloat16: 2e-2), tables as
+    the pool hands them out (512 shared blocks, then each slot's own in a
+    row) and shuffled block by block, a timing line each (a record, not a
+    claim)."""
     rng = onp.random.default_rng(35)
     S, Hq, Dh, bs, n_cols = 64, 8, 256, 16, 1088
     N = 1 + S * n_cols
     kp = _rand(rng, (N, 1, bs, Dh), jnp.bfloat16)
     vp = _rand(rng, (N, 1, bs, Dh), jnp.bfloat16)
-    tables = jnp.asarray(
-        1 + rng.permutation(S * n_cols).reshape(S, n_cols), jnp.int32)
+    tables = _cell_tables(rng, S, n_cols, n_cols, shared=512)
     pos = jnp.asarray(rng.integers(10500, 17300, S), jnp.int32)
     q = _rand(rng, (S, Hq, Dh), jnp.bfloat16)
     scale = 1.0 / math.sqrt(Dh)
@@ -418,11 +441,7 @@ def test_gqa_paged_at_the_corpusqa_cell_shapes():
         return fa._xla_paged_decode_attention(q, k, v, t, p, scale, None)
 
     assert fa._paged_kernel_kind(q, kp, Hq, None) == "gqa"
-    got = onp.asarray(jax.jit(kernel)(q, kp, vp, tables, pos), onp.float32)
-    ref = _ref(gather, q, kp, vp, tables, pos)
-    onp.testing.assert_allclose(got, ref, **TOL)
-    line = {name: round(_ms_per_call(step, q, kp, vp, tables, pos), 4)
-            for name, step in (("pallas", kernel), ("lax_gather", gather))}
+    line = _gqa_against_the_gather(kernel, gather, q, kp, vp, tables, pos)
     keys = int(onp.sum(onp.asarray(pos) + 1))
     print(f"\ngqa paged attention, corpusqa cell (D 256), {keys} keys "
           f"read: ms a call {line}", flush=True)
