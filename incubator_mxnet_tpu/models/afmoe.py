@@ -33,7 +33,45 @@ from ..base import MXNetError
 from .decoder import ServedDecoder, ServedLayer, rms_norm, rotary
 from .moe import _glu, held_experts_ffn, route_token_choice
 
-__all__ = ["AFMoELayer", "AFMoEModel", "rms_norm", "rotary"]
+__all__ = ["AFMoELayer", "AFMoEModel", "swiglu_ffn", "rms_norm",
+           "rotary"]
+
+
+def swiglu_ffn(layer, x, live):
+    """The FFN of a ``layer`` that names its parameters as
+    :class:`AFMoELayer` does, over x (B, T, d): ``(y, counts)``.  A
+    ``_dense`` layer is one SwiGLU (``mlp_*``; counts is ``()``); any other
+    routes by sigmoid scores with a choice-only bias
+    (``moe.route_token_choice``), computes the part of the sum that the
+    experts HELD here give (``moe.held_experts_ffn``) and adds the shared
+    expert whole; counts are the three of ``MOE_COUNTERS``.  ``layer._c``
+    gives ``num_experts_per_tok``, ``route_norm``, ``route_scale``,
+    ``first_expert`` and ``num_experts``."""
+    import jax
+    import jax.numpy as jnp
+    c, w = layer._c, layer._w
+    B, T, d = x.shape
+    xt = x.reshape(B * T, d)
+    if layer._dense:
+        y = _glu(xt, w("mlp_gate"), w("mlp_up"), w("mlp_down"))
+        return y.astype(x.dtype).reshape(B, T, d), ()
+    with jax.named_scope("moe.route"):
+        # the router's scores in float32, over all published experts
+        logits = jnp.dot(xt.astype(jnp.float32),
+                         w("router").astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        idx, wt = route_token_choice(
+            logits, w("expert_bias"), c["num_experts_per_tok"],
+            c["route_norm"], c["route_scale"])
+    with jax.named_scope("moe.experts"):
+        y, counts = held_experts_ffn(
+            xt, idx, wt, (c["first_expert"], c["num_experts"]),
+            w("experts_gate"), w("experts_up"), w("experts_down"),
+            None if live is None else live.reshape(B * T))
+    with jax.named_scope("moe.shared"):
+        y = y + _glu(xt, w("shared_gate"), w("shared_up"),
+                     w("shared_down"))
+    return y.astype(x.dtype).reshape(B, T, d), counts
 
 
 class AFMoELayer(ServedLayer):
@@ -95,37 +133,6 @@ class AFMoELayer(ServedLayer):
             k = rotary(k, positions, c["rope_theta"])
         return q, k, v, g
 
-    def _ffn(self, x, live):
-        """FFN over x (B, T, d): ``(y, counts)``; counts is () for a dense
-        layer, the three of ``MOE_COUNTERS`` for an expert layer."""
-        import jax
-        import jax.numpy as jnp
-        c = self._c
-        B, T, d = x.shape
-        xt = x.reshape(B * T, d)
-        if self._dense:
-            y = _glu(xt, self._w("mlp_gate"), self._w("mlp_up"),
-                     self._w("mlp_down"))
-            return y.astype(x.dtype).reshape(B, T, d), ()
-        with jax.named_scope("moe.route"):
-            # the router's scores in float32, over all published experts
-            logits = jnp.dot(xt.astype(jnp.float32),
-                             self._w("router").astype(jnp.float32),
-                             precision=jax.lax.Precision.HIGHEST)
-            idx, w = route_token_choice(
-                logits, self._w("expert_bias"), c["num_experts_per_tok"],
-                c["route_norm"], c["route_scale"])
-        with jax.named_scope("moe.experts"):
-            y, counts = held_experts_ffn(
-                xt, idx, w, (c["first_expert"], c["num_experts"]),
-                self._w("experts_gate"), self._w("experts_up"),
-                self._w("experts_down"),
-                None if live is None else live.reshape(B * T))
-        with jax.named_scope("moe.shared"):
-            y = y + _glu(xt, self._w("shared_gate"), self._w("shared_up"),
-                         self._w("shared_down"))
-        return y.astype(x.dtype).reshape(B, T, d), counts
-
     def _block(self, h, positions, attend, live):
         import jax
         import jax.numpy as jnp
@@ -141,8 +148,8 @@ class AFMoELayer(ServedLayer):
         o = jnp.dot(a.reshape(B, T, -1), self._w("o_proj"),
                     preferred_element_type=jnp.float32).astype(h.dtype)
         h = h + rms_norm(o, self._w("post_attention_layernorm"), eps)
-        m, counts = self._ffn(
-            rms_norm(h, self._w("pre_mlp_layernorm"), eps), live)
+        m, counts = swiglu_ffn(
+            self, rms_norm(h, self._w("pre_mlp_layernorm"), eps), live)
         return h + rms_norm(m, self._w("post_mlp_layernorm"), eps), counts
 
 

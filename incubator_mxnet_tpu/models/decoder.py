@@ -93,6 +93,13 @@ class ServedLayer(HybridBlock):
     #: array a leaf, a row a sequence, and hands the rows through
     #: :meth:`serve_recurrent`
     state_shapes = None
+    #: None for a layer that keeps K and V of the model's ``kv_heads x
+    #: head_dim``; for a latent layer the rows a cached position keeps
+    #: INSTEAD, ``((features, dtype), ...)`` — the latent row, then the
+    #: index key of a layer that chooses its keys (``KVLayout.rows``) — and
+    #: ``select`` how many keys such a layer chooses (``KVLayout.selects``)
+    kv_rows = None
+    select = None
 
     def __init__(self, shapes, dtype, grad_req, window, random=(), **kwargs):
         super().__init__(**kwargs)
@@ -112,13 +119,24 @@ class ServedLayer(HybridBlock):
         """h (B, T, d), positions (B, T), ``attend(q, k, v)`` -> the
         attention of q (B, T, heads, D) over k, v (B, T, kv_heads, D),
         ``live`` (B, T) bool or None.  Returns ``(h', counts)``, counts a
-        tuple of int32 scalars in the model's ``serve_counters`` order."""
+        tuple of int32 scalars in the model's ``serve_counters`` order.
+
+        A layer with ``kv_rows`` is handed ``attend(q_n, q_r, row, w_uk,
+        w_uv, scale, index=None)`` instead: queries ``q_n`` (B, T, H, d_n)
+        and ``q_r`` (B, T, H, d_r, rotary applied), the position's
+        ``row`` (B, T, r + d_r) as the cache is to hold it, the two
+        halves of the up-projection ``w_uk`` (r, H, d_n) and ``w_uv`` (r,
+        H, d_v), and with ``select`` the index's ``(q_i (B, T, HI, dI),
+        w_i (B, T, HI), k_i (B, T, dI))``; it returns the heads' outputs
+        (B, T, H, d_v) over the positions the layer may read — the window,
+        or the ``select`` positions the index scores highest."""
         raise NotImplementedError
 
     def serve_prefill(self, h, positions, live=None):
         """A whole prompt with nothing cached: h (B, T, d), positions
         (B, T).  Returns ``(h', k, v)``, k and v (B, T, kv_heads, D) as
-        the cache is to hold them."""
+        the cache is to hold them — of a layer with ``kv_rows``, ``h'``
+        and then those rows, (B, T, features) each."""
         from ..kernels.flash_attention import prefill_attention
         if self.state_shapes is not None:   # from an empty state, not kept
             import jax.numpy as jnp
@@ -127,6 +145,10 @@ class ServedLayer(HybridBlock):
             return self.serve_recurrent(h, positions, rows, live)[0], \
                 None, None
         kept = []
+        if self.kv_rows is not None:
+            h, _ = self._block(h, positions,
+                               self._latent_prompt(positions, kept), live)
+            return (h,) + tuple(kept)
 
         def attend(q, k, v):
             kept.extend((k, v))
@@ -134,6 +156,27 @@ class ServedLayer(HybridBlock):
 
         h, _ = self._block(h, positions, attend, live)
         return h, kept[0], kept[1]
+
+    def _latent_prompt(self, positions, kept):
+        """The ``attend`` of a latent layer over a whole prompt with
+        nothing cached: each sequence's queries over its own rows, in the
+        unabsorbed form (``kernels/latent_attention.py``); the rows the
+        cache is to hold go to ``kept``, in ``kv_rows``' order."""
+        import jax
+        from ..kernels.latent_attention import latent_prompt_attention
+
+        def attend(q_n, q_r, row, w_uk, w_uv, scale, index=None):
+            kept.append(row)
+            if index is not None:
+                kept.append(index[2])
+
+            def one(qn, qr, rows, pos, sel):
+                return latent_prompt_attention(
+                    qn, qr, rows, pos, pos, w_uk, w_uv, scale, self.window,
+                    None if sel is None else sel + (self.select,))
+
+            return jax.vmap(one)(q_n, q_r, row, positions, index)
+        return attend
 
     def serve_cached(self, h, positions, attend, live=None):
         """Positions that attend through the cache: ``attend(q, k, v)``
@@ -228,6 +271,8 @@ class ServedDecoder(HybridBlock):
                 "head_dim": c["head_dim"], "dtype": str(c["dtype"]),
                 "windows": tuple(l.window for l in self.layers),
                 "states": tuple(l.state_shapes for l in self.layers),
+                "rows": tuple(l.kv_rows for l in self.layers),
+                "selects": tuple(l.select for l in self.layers),
                 "max_length": self._max_length}
 
     def serve_layers(self):

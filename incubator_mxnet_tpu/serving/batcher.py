@@ -1097,13 +1097,18 @@ class ContinuousBatcher(DynamicBatcher):
             # capacity-aware backpressure: a queue the KV cache can never
             # drain fast enough is just a slow 504 — bound admissions by
             # how many streams of THIS request's footprint the cache
-            # sustains, and tell the client when to come back
+            # sustains, and tell the client when to come back.  The
+            # footprint is counted as if nothing were shared (a prompt's
+            # cached prefix is known only once it is hashed, at
+            # admission), so the bound never falls under one request a
+            # slot: those the engine has a slot for, and a deployment
+            # whose streams share most of a long prompt holds them all
             allowed = self.queue_size
             cap_fn = getattr(self.engine, "kv_capacity_tokens", None)
             if cap_fn is not None:
-                streams = max(1, min(int(self.engine.max_slots),
-                                     int(cap_fn()) // (n + budget)))
-                allowed = min(allowed, 4 * streams)
+                slots = int(self.engine.max_slots)
+                streams = max(1, min(slots, int(cap_fn()) // (n + budget)))
+                allowed = min(allowed, max(4 * streams, slots))
             if len(self._queue) >= allowed:
                 _m.REJECTED.inc(model=self.name)
                 retry = max(1.0, min(30.0,

@@ -689,6 +689,30 @@ class GenerationEngine:
         # (``KVLayout.states``; one array a leaf, a row a sequence)
         self._pool_of = {l: i for i, l in enumerate(self.layout.kv_layers)}
         self._n_kv = len(self._pool_of)
+        # where each of those layers' pools lie in a program's cache: the
+        # layers' first rows in layer order, then their second rows (K
+        # pools, then V pools of a grouped-query model), the state
+        # layers' leaves after the last
+        kept = {l: len(self.layout.layer_rows(l)) for l in self._pool_of}
+        ids = {l: [] for l in self._pool_of}
+        self._n_pools = 0
+        for j in range(max(kept.values(), default=0)):
+            for l in self._pool_of:
+                if j < kept[l]:
+                    ids[l].append(self._n_pools)
+                    self._n_pools += 1
+        self._pool_ids = {l: tuple(v) for l, v in ids.items()}
+        #: the layers that keep rows of their own (``KVLayout.rows``: a
+        #: latent layer) and those among them that choose the keys they
+        #: read (layer -> how many, ``KVLayout.selects``)
+        self._row_layers = tuple(l for l in self._pool_of
+                                 if self.layout.rows[l] is not None)
+        self._select_layers = {l: self.layout.selects[l]
+                               for l in self._row_layers
+                               if self.layout.selects[l] is not None}
+        if self._select_layers:
+            self._decode_counts.update(index_keys_scored=0,
+                                       index_keys_selected=0)
         self._state_layers = tuple(
             l for l in range(self.num_layers) if l not in self._pool_of)
         # the state store's rows: one a slot (row s is slot s's), then the
@@ -713,7 +737,8 @@ class GenerationEngine:
         #: where the stated [N, H, bs, D] would rest in a layout no
         #: program keeps)
         self._pool_shape, self._position_major = self.layout.pool_shape(
-            self.num_blocks, self.block_size, self._ctx.jax_device())
+            self.num_blocks, self.block_size, self._ctx.jax_device()) \
+            if len(self._row_layers) < self._n_kv else (None, False)
         self._warming = False
         # multi-token decode bursts (docs/serving.md): lax.scan
         # ``scan_steps`` decode steps into ONE dispatch with in-program
@@ -1191,15 +1216,17 @@ class GenerationEngine:
     def _block_rows(self, kv, pool):
         """A prompt's K or V ``kv`` (Tb, H, D) laid as ``pool`` holds a
         block's positions, once a layer: (H, Tb, D) for a pool stored as
-        stated, (Tb, H, Dp) for a position-major one."""
-        if self._position_major:
+        stated, (Tb, H, Dp) for a position-major one; a latent layer's
+        rows (Tb, F) as its row pool ``[N, bs, Fp]`` holds them, (Tb,
+        Fp)."""
+        if self._position_major or pool.ndim == 3:
             return self._to_lanes(kv, pool)
         return kv.transpose(1, 0, 2)
 
-    def _strip(self, rows, j):
+    def _strip(self, rows, j, pool):
         """Block ``j``'s positions of :meth:`_block_rows`' ``rows``."""
         bs = self.block_size
-        if self._position_major:
+        if self._position_major or pool.ndim == 3:
             return rows[j * bs:(j + 1) * bs]
         return rows[:, j * bs:(j + 1) * bs]
 
@@ -1218,7 +1245,8 @@ class GenerationEngine:
         else:
             blk = table[idx]
         return lax.dynamic_update_slice(
-            pool, strip[None].astype(pool.dtype), (blk, 0, 0, 0))
+            pool, strip[None].astype(pool.dtype),
+            (blk,) + (0,) * (pool.ndim - 1))
 
     @staticmethod
     def _to_lanes(rows, pool):
@@ -1233,10 +1261,11 @@ class GenerationEngine:
 
     def _write_rows(self, pool, blk, off, rows):
         """Set position ``off`` of block ``blk`` — (S,) or (S, Q) each —
-        to ``rows`` (S, H, D) or (S, Q, H, D)."""
+        to ``rows`` (S, H, D) or (S, Q, H, D); of a latent layer's row
+        pool, to ``rows`` (S, F)."""
         import jax.numpy as jnp
         rows = rows.astype(pool.dtype)
-        if self._position_major:
+        if self._position_major or pool.ndim == 3:
             return pool.at[blk, off].set(self._to_lanes(rows, pool))
         N, H, bs, D = pool.shape
         if H == 1 or D % self.layout.LANES:
@@ -1251,6 +1280,102 @@ class GenerationEngine:
         col = jnp.arange(H, dtype=off.dtype) * bs + off[..., None]
         return pool.reshape(N, H * bs, D).at[blk[..., None], col].set(
             rows).reshape(pool.shape)
+
+    def _scatter_prompt(self, caches, ids, rows, table, j0, traced):
+        """Write a prompt's ``rows`` — one array a pool of ``ids``, (Tb,
+        H, D) or a latent layer's (Tb, F) — into the blocks ``table``
+        names from column ``j0`` on (``traced``: ``j0`` is an operand,
+        and columns past the table go to the null block).  ``caches`` is
+        the program's list, updated in place."""
+        laid = [self._block_rows(r, caches[i]) for i, r in zip(ids, rows)]
+        for j in range(-(-rows[0].shape[0] // self.block_size)):
+            for i, a in zip(ids, laid):
+                caches[i] = self._scatter_block(
+                    caches[i], self._strip(a, j, caches[i]), table, j0 + j,
+                    traced)
+
+    def _latent_suffix_attend(self, caches, layer, table, ctx, Tb):
+        """``attend`` of the hit program for a latent layer
+        (``ServedLayer._block``): the suffix's rows written at ``ctx`` on,
+        then its queries over the slot's strip in the unabsorbed form —
+        every cached row a full layer keeps, its index keys with them; the
+        blocks a window can touch of a sliding one."""
+        import jax.numpy as jnp
+        from ..kernels.latent_attention import latent_prompt_attention
+        bs = self.block_size
+        window = self.layout.windows[layer]
+        ids = self._pools(layer)
+        NB = self.max_blocks_per_slot
+
+        def strip(pool, cols, features):
+            """The slot's rows of the table columns ``cols``."""
+            blocks = jnp.take(table, jnp.minimum(cols, NB - 1))
+            return pool[blocks].reshape(-1, pool.shape[-1])[:, :features]
+
+        def attend(q_n, q_r, row, w_uk, w_uv, scale, index=None):
+            rows = (row[0],) if index is None else (row[0], index[2][0])
+            self._scatter_prompt(caches, ids, rows, table, ctx // bs, True)
+            if window is None:
+                cols = jnp.arange(NB, dtype=jnp.int32)
+            else:                   # from the window's first block on
+                n = min(NB, -(-Tb // bs) + (window + bs - 2) // bs)
+                cols = jnp.maximum(ctx - window + 1, 0) // bs \
+                    + jnp.arange(n, dtype=jnp.int32)
+            key_pos = (cols[:, None] * bs
+                       + jnp.arange(bs, dtype=jnp.int32)[None]).reshape(-1)
+            # a column past the table holds no key whatever it names
+            key_pos = jnp.where(key_pos < NB * bs, key_pos,
+                                jnp.iinfo(jnp.int32).max)
+            q_pos = ctx + jnp.arange(Tb, dtype=jnp.int32)
+            select = None if index is None else (
+                index[0][0], index[1][0],
+                strip(caches[ids[1]], cols, index[2].shape[-1]),
+                self.layout.selects[layer])
+            return latent_prompt_attention(
+                q_n[0], q_r[0], strip(caches[ids[0]], cols, row.shape[-1]),
+                q_pos, key_pos, w_uk, w_uv, scale, window, select)[None]
+        return attend
+
+    def _latent_step_attend(self, caches, layer, blk, off, tables,
+                            positions):
+        """``attend`` of the two decode programs for a latent layer: one
+        position a slot, its row written to block ``blk`` at offset
+        ``off``, then the absorbed form over the pool — the window of a
+        sliding layer (:func:`paged_latent_decode`: a page read once, key
+        and value both), and in a layer that chooses its keys the index
+        key written beside the row, every cached index key scored, and
+        the rows of the chosen positions alone read."""
+        import jax
+        from ..kernels import latent_attention as la
+        window = self.layout.windows[layer]
+        ids = self._pools(layer)
+
+        def attend(q_n, q_r, row, w_uk, w_uv, scale, index=None):
+            r_kv = w_uk.shape[0]
+            caches[ids[0]] = pool = self._write_rows(
+                caches[ids[0]], blk, off, row[:, 0])
+            if index is None:
+                self._paged_impls.add(la.latent_decode_impl(row, pool))
+                self._paged_attention = "+".join(sorted(self._paged_impls))
+
+                def read(q_abs):
+                    return la.paged_latent_decode(
+                        q_abs, pool, tables, positions, r_kv, scale, window)
+            else:
+                q_i, w_i, k_i = index
+                caches[ids[1]] = keys = self._write_rows(
+                    caches[ids[1]], blk, off, k_i[:, 0])
+                with jax.named_scope("attn.index"):
+                    chosen, valid = la.paged_index_select(
+                        q_i[:, 0], w_i[:, 0], keys, tables, positions,
+                        self.layout.selects[layer])
+
+                def read(q_abs):
+                    return la.paged_sparse_latent(
+                        q_abs, pool, chosen, valid, r_kv, scale)
+            return la.absorbed_attention(q_n[:, 0], q_r[:, 0], w_uk, w_uv,
+                                         read)[:, None]
+        return attend
 
     def _note_paged_attention(self, layer, tables, pool, q_heads, window):
         """Record what the paged attention entry points pick for
@@ -1276,13 +1401,14 @@ class GenerationEngine:
                 self._run_stale.update(range(self.max_slots))
 
     def _pools(self, l):
-        """Where layer ``l``'s K and V pools lie in a program's cache."""
-        return self._pool_of[l], self._n_kv + self._pool_of[l]
+        """Where layer ``l``'s pools lie in a program's cache: K and V of
+        a grouped-query layer, the rows a latent layer states."""
+        return self._pool_ids[l]
 
     def _state_leaves(self, l):
         """Where state layer ``l``'s leaves lie in a program's cache,
-        after the ``2 * n_kv`` pools."""
-        at = 2 * self._n_kv
+        after the pools."""
+        at = self._n_pools
         for s in self._state_layers:
             n = len(self.layout.states[s])
             if s == l:
@@ -1387,9 +1513,7 @@ class GenerationEngine:
         layers start from zeros and leave their last state in the slot's
         row (:meth:`_recur_prefill`)."""
         import jax.numpy as jnp
-        L = self._n_kv
         Tb = tokens.shape[1]
-        bs = self.block_size
         n_valid = at[0]
         table, samp = self._slot_row(state, at[1])
         key = state["key"]
@@ -1401,26 +1525,19 @@ class GenerationEngine:
         def body():
             pos = jnp.arange(Tb, dtype=jnp.int32)[None]
             h = self.block.serve_embed(tokens, pos)
-            ks, vs = [], []
+            kept = {}
             for l, layer in enumerate(self._layers):
                 if l in self._pool_of:
-                    h, k, v = layer.serve_prefill(h, pos, pos < n_valid)
-                    ks.append(k)
-                    vs.append(v)
+                    h, *kept[l] = layer.serve_prefill(h, pos, pos < n_valid)
                 else:
                     h, _ = recur(l, layer, h, pos, pos < n_valid)
-            return self.block.serve_head(self._row(h, n_valid - 1)), ks, vs
+            return self.block.serve_head(self._row(h, n_valid - 1)), kept
 
-        logits, ks, vs = self._with_params(param_vals, aux_vals, key, body,
-                                            "prefill")
-        for l in range(L):
-            kh = self._block_rows(ks[l][0], out[l])        # (H, Tb, D)
-            vh = self._block_rows(vs[l][0], out[L + l])
-            for j in range(-(-Tb // bs)):
-                out[l] = self._scatter_block(
-                    out[l], self._strip(kh, j), table, j, False)
-                out[L + l] = self._scatter_block(
-                    out[L + l], self._strip(vh, j), table, j, False)
+        logits, kept = self._with_params(param_vals, aux_vals, key, body,
+                                         "prefill")
+        for l, rows in kept.items():    # K and V (H, Tb, D), or the rows
+            self._scatter_prompt(out, self._pools(l),
+                                 [r[0] for r in rows], table, 0, False)
         first, lp = self._sample_prefill(logits[0, 0], n_valid, samp)
         if lp is not None:
             return tuple(out), first, lp
@@ -1441,7 +1558,6 @@ class GenerationEngine:
         the snapshot spacing: a hit always ends at a snapshot."""
         import jax.numpy as jnp
         from ..kernels.flash_attention import paged_prefix_attention
-        L = self._n_kv
         Tb = tokens.shape[1]
         bs = self.block_size
         caches = list(cache)
@@ -1453,17 +1569,14 @@ class GenerationEngine:
             if self._state_layers else None
 
         def attend_for(layer):
+            if self.layout.rows[layer] is not None:
+                return self._latent_suffix_attend(caches, layer, table, ctx,
+                                                  Tb)
             l, lv = self._pools(layer)
 
             def attend(q, k, v):             # (1, Tb, heads, D) each
-                knh = self._block_rows(k[0], caches[l])
-                vnh = self._block_rows(v[0], caches[lv])
-                for j in range(-(-Tb // bs)):
-                    caches[l] = self._scatter_block(
-                        caches[l], self._strip(knh, j), table, j0 + j, True)
-                    caches[lv] = self._scatter_block(
-                        caches[lv], self._strip(vnh, j), table, j0 + j,
-                        True)
+                self._scatter_prompt(caches, (l, lv), (k[0], v[0]), table,
+                                     j0, True)
                 attn = paged_prefix_attention(
                     q.transpose(0, 2, 1, 3), caches[l], caches[lv],
                     table, ctx, self.layout.windows[layer],
@@ -1494,6 +1607,9 @@ class GenerationEngine:
 
         def attend_for(layer):
             window = self.layout.windows[layer]
+            if self.layout.rows[layer] is not None:
+                return self._latent_step_attend(caches, layer, blk, off,
+                                                tables, positions)
             l, lv = self._pools(layer)
 
             def attend(q, k, v):             # (S, 1, heads, D) each
@@ -1741,10 +1857,14 @@ class GenerationEngine:
         # still finds a cache of the programs' shape, and fails on it)
         for c in self._cache + self._recur:
             c.delete()
-        self._cache = tuple(
-            jnp.zeros(self._pool_shape, jnp.dtype(self.layout.dtype),
-                      device=dev)
-            for _ in range(2 * self._n_kv))
+        pools = [None] * self._n_pools
+        for l, ids in self._pool_ids.items():
+            for i, (features, dtype) in zip(ids, self.layout.layer_rows(l)):
+                shape = self._pool_shape if self.layout.rows[l] is None \
+                    else self.layout.row_pool_shape(
+                        self.num_blocks, self.block_size, features, dev)
+                pools[i] = jnp.zeros(shape, jnp.dtype(dtype), device=dev)
+        self._cache = tuple(pools)
         # the state rows go with the blocks: a snapshot must never outlive
         # the params that computed it either
         self._recur = tuple(
@@ -1757,6 +1877,8 @@ class GenerationEngine:
         # the pool report occupancy in bytes (device-memory
         # attribution)
         self.pool.block_bytes = self.cache_bytes // self.num_blocks
+        _m.KV_BYTES_PER_TOKEN.set(self.layout.block_bytes(1),
+                                  model=self.name)
         self._slot_blocks = [[] for _ in range(self.max_slots)]
         self._tables[:] = 0
         self._run_stale.update(range(self.max_slots))
@@ -1767,7 +1889,7 @@ class GenerationEngine:
     def _rebind(self, cache) -> None:
         """Take back what a program returned for its donated cache: the
         pools, then the state layers' leaves."""
-        n = 2 * self._n_kv
+        n = self._n_pools
         self._cache, self._recur = tuple(cache[:n]), tuple(cache[n:])
 
     @property
@@ -1989,9 +2111,33 @@ class GenerationEngine:
         :meth:`_guarded`): what the call uploads is the padded prompt
         and a few integers — for a model with state layers the snapshot
         rows of the pool's plan among them, so a hit starts from its
-        snapshot inside the one dispatch."""
+        snapshot inside the one dispatch.
+
+        What is left to compute of a prompt — all of a miss, a hit's
+        suffix — may be longer than the largest bucket: it then goes as
+        consecutive CHUNKS of that bucket through the hit program, which
+        is a chunk's program already (a suffix at offset ``ctx`` over what
+        the slot's blocks hold), ``ctx`` advancing, back to back; the
+        last chunk's token is the request's first.  (Not for a model with
+        state layers, whose snapshot plan is per dispatch: its prompts
+        fit a bucket or are refused, as before.)"""
+        big = self.prefill_buckets[-1]
         with _m.loop_step("enqueue", "serve.enqueue"):
-            if m == 0:
+            if n - m > big and not self._state_layers \
+                    and big % self.block_size == 0:
+                if span is not None:
+                    span.attrs["prefix_hit_tokens"] = m
+                    span.attrs["chunks"] = -(-(n - m) // big)
+                for at in range(m, n - big, big):
+                    self._rebind(self._guarded(
+                        self._prefill_ext, toks[None, at:at + big],
+                        self._prefill_at(plan, big, big, slot, at))[0])
+                    last = at + big
+                padded = self._padded(toks[last:], n - last, span)
+                out = self._guarded(
+                    self._prefill_ext, padded, self._prefill_at(
+                        plan, padded.shape[1], n - last, slot, last))
+            elif m == 0:
                 padded = self._padded(toks, n, span)
                 out = self._guarded(self._prefill, padded, self._prefill_at(
                     plan, padded.shape[1], n, slot))
@@ -2142,20 +2288,31 @@ class GenerationEngine:
         def ramp(first, n):         # first + (first + 1) + ... n terms
             return int(_np.sum(n * first + n * (n - 1) // 2))
 
+        def capped(cap):            # the same, each term at most cap
+            under = _np.clip(cap - positions, 0, steps)
+            return ramp(positions + 1, under) \
+                + int(_np.sum((steps - under) * cap))
+
         ctx = ramp(positions + 1, steps)
         _m.DECODE_CONTEXT_TOKENS.inc(ctx, model=self.name)
         self._decode_counts["decode_context_tokens"] += ctx
         if self._window:
-            # the steps whose written positions are still inside the window
-            under = _np.clip(self._window - positions, 0, steps)
-            win = ramp(positions + 1, under) \
-                + int(_np.sum((steps - under) * self._window))
+            win = capped(self._window)
             _m.DECODE_WINDOW_TOKENS.inc(win, model=self.name)
             self._decode_counts["decode_window_tokens"] += win
         for n, v in zip(self._counters, counts):
             v = int(v)
             _m.MODEL_COUNTERS[n].inc(v, model=self.name)
             self._decode_counts[n] += v
+        if self._select_layers:
+            # a layer that chooses its keys scores every written position
+            # and reads min(written, k) of them
+            scored = len(self._select_layers) * ctx
+            chosen = sum(capped(k) for k in self._select_layers.values())
+            _m.INDEX_KEYS_SCORED.inc(scored, model=self.name)
+            _m.INDEX_KEYS_SELECTED.inc(chosen, model=self.name)
+            self._decode_counts["index_keys_scored"] += scored
+            self._decode_counts["index_keys_selected"] += chosen
         if self._run_layers:
             self._count_paged_groups(positions, steps)
 
@@ -2228,6 +2385,12 @@ class GenerationEngine:
         if draft is self:
             raise MXNetError(f"{self.name}: a model cannot draft itself")
         for eng in (self, draft):
+            if eng._row_layers:
+                raise MXNetError(
+                    f"{eng.name}: no speculation over a latent cache yet: "
+                    "the verify program has no latent form (a block of "
+                    "drafted positions a slot, each with its own choice "
+                    "of keys in a layer that chooses them; ROADMAP M2)")
             if eng._state_layers:
                 raise MXNetError(
                     f"{eng.name}: no speculation over a recurrent state: "

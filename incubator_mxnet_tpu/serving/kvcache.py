@@ -74,7 +74,15 @@ class KVLayout(NamedTuple):
     too: None where the layer keeps keys and values in the pool, else the
     leaves ``((shape, dtype), ...)`` of the state of constant size that the
     layer keeps a sequence INSTEAD (a recurrent layer: it has no blocks);
-    a model that does not state it has none."""
+    a model that does not state it has none.  ``rows`` says what a cached
+    position keeps in a layer that is not grouped-query: None where the
+    layer keeps K and V of ``kv_heads x head_dim`` each (two pools,
+    :meth:`pool_shape`), else the rows ``((features, dtype), ...)`` it
+    keeps INSTEAD, one pool a row (:meth:`row_pool_shape`) — a latent
+    layer one row that is key and value both, an indexed latent layer that
+    row and its index key.  ``selects`` names, for a layer whose attention
+    runs over a chosen set of its cached positions, how many it chooses
+    (None: it reads them all, or its window)."""
     num_layers: int
     kv_heads: int
     head_dim: int
@@ -82,6 +90,8 @@ class KVLayout(NamedTuple):
     windows: Tuple[Optional[int], ...]
     max_length: int
     states: Tuple[Optional[tuple], ...] = ()
+    rows: Tuple[Optional[tuple], ...] = ()
+    selects: Tuple[Optional[int], ...] = ()
 
     @classmethod
     def of(cls, stated: dict) -> "KVLayout":
@@ -95,20 +105,38 @@ class KVLayout(NamedTuple):
                       tuple(None if leaves is None else tuple(
                           (tuple(int(d) for d in shape), str(dtype))
                           for shape, dtype in leaves)
-                          for leaves in stated.get("states") or (None,) * n))
+                          for leaves in stated.get("states") or (None,) * n),
+                      tuple(None if kept is None else tuple(
+                          (int(features), str(dtype))
+                          for features, dtype in kept)
+                          for kept in stated.get("rows") or (None,) * n),
+                      tuple(None if k is None else int(k)
+                            for k in stated.get("selects") or (None,) * n))
         except (KeyError, TypeError, ValueError) as e:
             raise MXNetError(f"kv_layout() must give {cls._fields}: {e!r}")
-        if not len(lay.windows) == len(lay.states) == lay.num_layers:
+        if not len(lay.windows) == len(lay.states) == len(lay.rows) \
+                == len(lay.selects) == lay.num_layers:
             raise MXNetError(
-                f"kv_layout(): {len(lay.windows)} windows and "
-                f"{len(lay.states)} states for {lay.num_layers} layers")
+                f"kv_layout(): {len(lay.windows)} windows, "
+                f"{len(lay.states)} states, {len(lay.rows)} rows and "
+                f"{len(lay.selects)} selects for {lay.num_layers} layers")
         return lay
 
     @property
     def kv_layers(self) -> Tuple[int, ...]:
-        """The layers that keep keys and values: one K and one V pool
-        each."""
+        """The layers that keep blocks: a K and a V pool each, or the
+        pools of the rows they state."""
         return tuple(l for l, s in enumerate(self.states) if s is None)
+
+    def layer_rows(self, l: int) -> tuple:
+        """What a cached position keeps in layer ``l``, as ``((features,
+        dtype), ...)``: K and V of a grouped-query layer, the stated rows
+        of another, nothing of a layer that keeps a state instead."""
+        if self.states[l] is not None:
+            return ()
+        if self.rows[l] is not None:
+            return self.rows[l]
+        return ((self.kv_heads * self.head_dim, self.dtype),) * 2
 
     #: a TPU's vector registers and the tiles its memory is laid in are this
     #: many features wide
@@ -164,12 +192,25 @@ class KVLayout(NamedTuple):
             return by_position, True
         return stated, False
 
+    def row_pool_shape(self, num_blocks: int, block_size: int,
+                       features: int, device):
+        """The shape the pool of one stated row (``rows``) is stored in on
+        ``device``: ``[N, bs, F]`` — a block's positions, each one row —
+        with the features rounded up to whole :attr:`LANES` on a TPU,
+        where the tiles memory is laid in take those bytes anyway (the
+        lanes past ``F`` hold zeros) and a kernel's page is whole tiles."""
+        if device.platform == "tpu":
+            features = -(-int(features) // self.LANES) * self.LANES
+        return int(num_blocks), int(block_size), int(features)
+
     def block_bytes(self, block_size: int) -> int:
-        """Bytes behind one block of ``block_size`` positions: K and V of
-        every layer that keeps them."""
+        """Bytes behind one block of ``block_size`` positions: what every
+        layer keeps a position (:meth:`layer_rows`), as stated."""
         import jax.numpy as jnp
-        return (2 * len(self.kv_layers) * self.kv_heads * int(block_size)
-                * self.head_dim * jnp.dtype(self.dtype).itemsize)
+        return int(block_size) * sum(
+            features * jnp.dtype(dtype).itemsize
+            for l in range(self.num_layers)
+            for features, dtype in self.layer_rows(l))
 
 
 def blocks_for(tokens: int, block_size: int) -> int:

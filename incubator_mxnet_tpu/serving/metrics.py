@@ -99,6 +99,23 @@ PAGED_GROUPS = _telemetry.registry.counter(
     "the group's live blocks in a row: one copy) or blocks (a copy a "
     "block); the kernel's predicate on the host's tables; only for a "
     "model whose paged calls take that kernel")
+INDEX_KEYS_SCORED = _telemetry.registry.counter(
+    "mxtpu_index_keys_scored",
+    "cached index keys the decode programs' indexers scored: the written "
+    "positions of the live slots, summed over decode steps and over the "
+    "layers that choose the keys they read; only for a model with such "
+    "layers")
+INDEX_KEYS_SELECTED = _telemetry.registry.counter(
+    "mxtpu_index_keys_selected",
+    "of mxtpu_index_keys_scored, the positions chosen and attended over: "
+    "min(written positions, the layer's top-k) of each live slot, summed "
+    "over decode steps and those layers")
+KV_BYTES_PER_TOKEN = _telemetry.registry.gauge(
+    "mxtpu_kv_bytes_per_token",
+    "bytes the model's layers keep a cached position, as its layout "
+    "states them (KVLayout.block_bytes(1)): K and V of a grouped-query "
+    "layer, the latent row and index key of a latent one; lanes a device "
+    "pads a row with are not counted")
 STATE_ROWS_IN_USE = _telemetry.registry.gauge(
     "mxtpu_state_rows_in_use",
     "rows of a recurrent model's state store in use: one a slot that "

@@ -500,6 +500,82 @@ def test_gqa_kernel_compiles_for_v5e_at_the_docqa_cell_shapes(
     assert not re.search(r"= bf16\[16385,[^\]]*\]\{[^}]*\} copy\(", text)
 
 
+@pytest.mark.parametrize("kind,heads,features,r_kv,window", [
+    ("sliding", 8, 1088, 1024, 513), ("full", 16, 576, 512, None)])
+def test_latent_kernel_compiles_for_v5e_at_the_repoagent_cell_shapes(
+        kind, heads, features, r_kv, window, one_chip, no_compile_cache):
+    """S 64, the chip's share of a latent layer's heads over ONE row pool
+    of 24,577 blocks stored on whole lanes (1,088 -> 1,152, 576 -> 640),
+    1,696 table entries: the kernel takes the pool as it rests (``[N * bs,
+    Fp]`` is the same bytes), without a copy, its two page buffers inside
+    the VMEM limit.  (The full layers' chosen rows do not come through it
+    in the cell; its shape must compile all the same.)"""
+    import importlib
+    la = importlib.import_module(
+        "incubator_mxnet_tpu.kernels.latent_attention")
+    S, n_cols, N = 64, 1696, 24577
+    Fp = -(-features // 128) * 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        lambda q, pool, t, p: la._paged_latent_pallas(
+            q, pool, t, p, r_kv, 0.0625, window, False),
+        sds((S, heads, features), jnp.bfloat16),
+        sds((N, 16, Fp), jnp.bfloat16), sds((S, n_cols), jnp.int32),
+        sds((S,), jnp.int32))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(r"= bf16\[24577,[^\]]*\]\{[^}]*\} copy\(", text)
+
+
+def test_index_kernel_compiles_for_v5e_at_the_repoagent_cell_shapes(
+        one_chip, no_compile_cache):
+    """S 64, 64 index heads of 128 over the index-key pool of 24,577
+    blocks, 1,696 table entries, 4,096 keys a step: the pool read where it
+    rests, the (slots, heads, keys) scores nowhere in the program."""
+    import importlib
+    la = importlib.import_module(
+        "incubator_mxnet_tpu.kernels.latent_attention")
+    S, n_cols, N = 64, 1696, 24577
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        lambda q, w, pool, t, p: la._paged_index_pallas(q, w, pool, t, p,
+                                                        False),
+        sds((S, 64, 128), jnp.bfloat16), sds((S, 64), jnp.float32),
+        sds((N, 16, 128), jnp.bfloat16), sds((S, n_cols), jnp.int32),
+        sds((S,), jnp.int32))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(r"= bf16\[24577,[^\]]*\]\{[^}]*\} copy\(", text)
+    assert "f32[64,64,27136]" not in text
+
+
+def test_prompt_index_kernel_compiles_for_v5e_at_the_repoagent_cell_shapes(
+        one_chip, no_compile_cache):
+    """A hit's suffix of 1,024 queries, 64 index heads of 128, against a
+    slot's 27,136 index keys: one kernel, eight queries x 2,048 keys a
+    step, its (512, 2048) float32 tile inside the VMEM limit."""
+    import importlib
+    la = importlib.import_module(
+        "incubator_mxnet_tpu.kernels.latent_attention")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        lambda q, w, k: la._index_scores_pallas(q, w, k, False),
+        sds((1024, 64, 128), jnp.bfloat16), sds((1024, 64), jnp.float32),
+        sds((27136, 128), jnp.bfloat16))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "f32[1024,64,27136]" not in text
+
+
 def _position_major(pages, lanes=128):
     """A stated pool (N, H, bs, D) as ``KVLayout.pool_shape`` stores it
     position-major: (N, bs, H, Dp), zeros on the lanes past D."""

@@ -602,3 +602,109 @@ def test_experts_kernel_at_the_agent_cell_shapes():
     (the hidden width in six blocks of 512)."""
     _experts_kernel_against_the_loop("agent", 64, 4, 256, 32, 3072, 3072,
                                      "silu")
+
+
+# -- the latent cache's three decode reads (kernels/latent_attention.py) ------
+
+def _slope_ms(fn, first, *rest):
+    """Device time of one ``fn(first, *rest)`` inside a program: the slope
+    of a ``fori_loop`` between 5 and 25 trips, each trip needing the last.
+    The big operands ride in ``rest`` (a closed-over pool would be a
+    constant of the executable)."""
+    def loop(n):
+        def run(first, *rest):
+            def body(_, a):
+                o = fn(a, *rest)
+                return a + (jnp.sum(o) * 0).astype(a.dtype)
+            return jax.lax.fori_loop(0, n, body, first)
+        return jax.jit(run)
+    took = {}
+    for n in (5, 25):
+        f = loop(n)
+        f(first, *rest).block_until_ready()
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            f(first, *rest).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
+        took[n] = best
+    return (took[25] - took[5]) / 20 * 1e3
+
+
+def test_latent_reads_at_the_repoagent_cell_shapes():
+    """`dots3-tp8-repoagent-closed`: 64 slots whose contexts of ~26 k share
+    1,536 blocks.  (a) the sliding layers' window read, kernel against
+    gather; (b) the full layers' index scoring, kernel against gather, and
+    the choice of 2,048; (c) the read of the chosen rows; and a hit
+    prefill's dense index scores, kernel against einsum.  Prints the ms a
+    call of each."""
+    la = importlib.import_module(
+        "incubator_mxnet_tpu.kernels.latent_attention")
+    rng = onp.random.default_rng(0)
+    S, n_cols, bs, N = 64, 1696, 16, 24577
+    shared, own = 1536, 100
+    tables = onp.zeros((S, n_cols), onp.int32)
+    tables[:, :shared] = 1 + onp.arange(shared)
+    for s in range(S):
+        tables[s, shared:shared + own] = 1 + shared + s * 160 + onp.arange(own)
+    tables = jnp.asarray(tables)
+    positions = jnp.asarray(
+        rng.integers(shared * bs + 300, (shared + own) * bs - 1, S),
+        jnp.int32)
+    key = jax.random.PRNGKey(0)
+    mk = lambda i, shape: jax.random.normal(               # noqa: E731
+        jax.random.fold_in(key, i), shape, jnp.float32).astype(jnp.bfloat16)
+    ms = {}
+    # (a) a sliding layer: 8 heads over rows of 1,088 (stored 1,152)
+    pool = jnp.pad(mk(1, (N, bs, 1088)), ((0, 0), (0, 0), (0, 64)))
+    q = mk(2, (S, 8, 1088))
+    assert la.latent_decode_impl(q, pool) == "pallas"
+    for name, fn in (("latent_window_pallas", la.paged_latent_decode),
+                     ("latent_window_gather", la._xla_paged_latent_decode)):
+        read = lambda q, pool, t, p, fn=fn: fn(      # noqa: E731
+            q, pool, t, p, 1024, 0.0625, 513)
+        ms[name] = _slope_ms(read, q, pool, tables, positions)
+    got = la.paged_latent_decode(q, pool, tables, positions, 1024, 0.0625,
+                                 513)
+    want = la._xla_paged_latent_decode(q, pool, tables, positions, 1024,
+                                       0.0625, 513)
+    onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want),
+                                atol=3e-2, rtol=3e-2)
+    del pool
+    # (b) a full layer's index: 64 heads of 128 over every written key
+    keys = mk(3, (N, bs, 128))
+    q_i, w_i = mk(4, (S, 64, 128)), mk(5, (S, 64)).astype(jnp.float32)
+    live = onp.arange(n_cols * bs)[None, :] <= onp.asarray(positions)[:, None]
+    got = onp.asarray(la._paged_index_pallas(q_i, w_i, keys, tables,
+                                             positions, False))
+    want = onp.asarray(la._xla_paged_index_scores(q_i, w_i, keys, tables))
+    onp.testing.assert_allclose(got[live], want[live], atol=0.05, rtol=0.02)
+    ms["index_scores_pallas"] = _slope_ms(
+        lambda q_i, w_i, keys, t, p: la._paged_index_pallas(
+            q_i, w_i, keys, t, p, False), q_i, w_i, keys, tables, positions)
+    ms["index_scores_gather"] = _slope_ms(
+        lambda q_i, w_i, keys, t: la._xla_paged_index_scores(
+            q_i, w_i, keys, t), q_i, w_i, keys, tables)
+    rows, valid = la.paged_index_select(q_i, w_i, keys, tables, positions,
+                                        2048)
+    assert bool(valid.all()) and rows.shape == (S, 2048)
+    ms["index_select"] = _slope_ms(
+        lambda q_i, w_i, keys, t, p: la.paged_index_select(
+            q_i, w_i, keys, t, p, 2048)[0].astype(jnp.float32),
+        q_i, w_i, keys, tables, positions)
+    # (c) 16 heads over the chosen rows of 576 (stored 640)
+    lat = jnp.pad(mk(6, (N, bs, 576)), ((0, 0), (0, 0), (0, 64)))
+    q16 = mk(7, (S, 16, 576))
+    ms["sparse_rows"] = _slope_ms(
+        lambda q, lat, rows, valid: la.paged_sparse_latent(
+            q, lat, rows, valid, 512, 0.072), q16, lat, rows, valid)
+    # a hit prefill's dense scores: 1,024 queries against 27,136 keys
+    qp, wp = mk(8, (1024, 64, 128)), mk(9, (1024, 64)).astype(jnp.float32)
+    kp = mk(10, (27136, 128))
+    got = onp.asarray(la._index_scores_pallas(qp, wp, kp, False))
+    want = onp.asarray(la.index_scores(qp[:64], wp[:64], kp))
+    onp.testing.assert_allclose(got[:64], want, atol=0.05, rtol=0.02)
+    ms["prompt_index_pallas"] = _slope_ms(
+        lambda q, w, k: la._index_scores_pallas(q, w, k, False), qp, wp, kp)
+    print("\nms a call at the repoagent cell's shapes: " + ", ".join(
+        f"{k} {v:.3f}" for k, v in ms.items()))
