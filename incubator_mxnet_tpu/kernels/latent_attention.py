@@ -21,15 +21,22 @@ An indexed layer keeps a second, narrow row a position, the index key
 ``k_I``.  A query scores every cached index key, ``I(t, s) = sum_j w_j(t)
 relu(q_I,j(t) . k_I(s))``, and attends over exactly the ``k`` positions of
 largest score (all of them while there are no more than ``k``).  The
-choice is EXACT — ``lax.top_k``, lowest position first among equals —
-never ``approx_max_k``.
+choice is EXACT — :func:`choose_topk`'s set, lowest position first among
+equals — never ``approx_max_k``; and it is a SET: nothing reads its
+order, so no path sorts scores.  The ``k``-th largest is found by
+counting passes over the scores' order keys (:func:`_kth_key`), ties at
+it settled by position (:func:`_take_ties`); a prompt attends under the
+mask (:func:`chosen_mask`), a decode step compacts it into the chosen
+positions' pool rows (:func:`paged_index_select`).
 
 The paged entry points (``paged_*``) read the block pool through a slot's
 table as ``kernels/flash_attention.py``'s do; a pool of rows is stored
-``[N, bs, Fp]`` (``serving.kvcache.KVLayout.row_pool_shape``).  The
-window-bounded decode read is a Pallas kernel on a TPU
-(:func:`latent_decode_impl` says which); everything else is lax, which is
-also the CPU path and the kernels' reference.
+``[N, bs, Fp]`` (``serving.kvcache.KVLayout.row_pool_shape``).  On a TPU
+the window-bounded decode read, the index scoring and the decode step's
+choice are Pallas kernels (:func:`latent_decode_impl`,
+:func:`index_select_impl` and :func:`prompt_index_impl` say which a call
+takes); the read of the chosen rows is lax, and every kernel has a lax
+form that is the CPU path and its reference.
 """
 from __future__ import annotations
 
@@ -76,42 +83,64 @@ def choose_topk(scores, k):
     return idx, vals > -jnp.inf
 
 
+def _signed_key(x):
+    """float32 -> int32 whose signed order is the floats' (``-inf``
+    least)."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(b < 0, b ^ jnp.int32(0x7FFFFFFF), b)
+
+
 def _order_key(x):
     """float32 -> uint32 whose unsigned order is the floats' (``-inf``
     least)."""
-    b = jax.lax.bitcast_convert_type(x, jnp.int32)
-    b = jnp.where(b < 0, b ^ jnp.int32(0x7FFFFFFF), b)
-    return jax.lax.bitcast_convert_type(b, jnp.uint32) \
+    return jax.lax.bitcast_convert_type(_signed_key(x), jnp.uint32) \
         ^ jnp.uint32(0x80000000)
+
+
+def _kth_key(count_ge, k, zero):
+    """The largest order key ``t`` — the bits of a uint32, held in
+    ``zero``'s integer type and shape — that ``k`` or more keys reach
+    (``count_ge(t) >= k``): found bit by bit from the top, 32 counting
+    passes.  The one threshold search of the prompt's mask and the decode
+    step's choice; ``zero`` may be a tuple (searches side by side, one
+    loop), which ``count_ge`` then takes and returns."""
+    def bit(i, t):
+        cand = jax.tree.map(lambda t: t | jax.lax.shift_left(
+            jnp.ones_like(t), (31 - i).astype(t.dtype)), t)
+        return jax.tree.map(
+            lambda n, cand, t: jnp.where(n >= int(k), cand, t),
+            count_ge(cand), cand, t)
+    return jax.lax.fori_loop(0, 32, bit, zero)
+
+
+def _take_ties(above, tied, rank, room):
+    """The one tie rule: every key ``above`` the threshold, and of those
+    ``tied`` at it the first ``room`` in position order (``rank``: a tied
+    key's count among the tied up to and with itself)."""
+    return above | (tied & (rank <= room))
 
 
 def chosen_mask(scores, k):
     """:func:`choose_topk`'s set as a mask over the keys, (..., K) bool —
-    what a prompt's dense attention runs under.  A mask needs the ``k``-th
-    largest score, not the order of the others: it is found bit by bit
-    (32 counting passes over the scores, where a sort of a prompt's
-    ``(queries, keys)`` rows costs several times as much), and equal
-    scores at the threshold are taken lowest position first."""
+    what a prompt's dense attention runs under, and the lax form of a
+    decode step's choice.  A mask needs the ``k``-th largest score, not
+    the order of the others: it is found bit by bit (:func:`_kth_key`,
+    where a sort of a prompt's ``(queries, keys)`` rows costs several
+    times as much), and equal scores at the threshold are taken lowest
+    position first."""
     K = scores.shape[-1]
     finite = scores > -jnp.inf
     if int(k) >= K:
         return finite
     u = _order_key(scores)
-
-    def bit(i, t):          # the largest t with k or more keys >= t
-        cand = t | jax.lax.shift_left(jnp.uint32(1),
-                                      (31 - i).astype(jnp.uint32))
-        enough = jnp.sum(u >= cand, axis=-1, keepdims=True) >= int(k)
-        return jnp.where(enough, cand, t)
-
-    thr = jax.lax.fori_loop(
-        0, 32, bit, jnp.zeros(scores.shape[:-1] + (1,), jnp.uint32))
+    thr = _kth_key(lambda c: jnp.sum(u >= c, axis=-1, keepdims=True), k,
+                   jnp.zeros(scores.shape[:-1] + (1,), jnp.uint32))
     above = u > thr
     tied = (u == thr) & finite
     room = int(k) - jnp.sum(above, axis=-1, keepdims=True)
     return jax.lax.cond(
         jnp.any(jnp.sum(tied, axis=-1, keepdims=True) > room),
-        lambda: above | (tied & (jnp.cumsum(tied, axis=-1) <= room)),
+        lambda: _take_ties(above, tied, jnp.cumsum(tied, axis=-1), room),
         lambda: above | tied)
 
 
@@ -307,14 +336,22 @@ def paged_latent_decode(q_abs, pool, tables, positions, r_kv, scale,
 
 
 def index_select_impl(q, pool):
-    """Which implementation scores :func:`paged_index_select`'s keys:
-    ``"pallas"`` — the pages read in place, a step's scores summed over
-    the index heads where they were made — on a TPU (and where
+    """Which implementation :func:`paged_index_select` traces for a call
+    with operand ``q`` over the index-key ``pool`` (N, bs, dIp):
+    ``"select:kernel"`` on a TPU (and where
     ``MXNET_FA_DECODE_FORCE_PALLAS=1`` asks, interpreted) for a pool
-    whose page is whole tiles, else ``"lax_gather"`` (the slot's strip
-    gathered, the scores of every head written before they are summed).
-    The choice itself is ``lax.top_k`` either way."""
-    return latent_decode_impl(q, pool)
+    whose page is whole tiles of 16 to 128 positions — the pages
+    scored in place (:func:`_paged_index_pallas`), then the threshold's
+    counting passes and the compaction by rank over a slot's resident
+    scores (:func:`_index_choose_pallas`); else ``"select:lax"`` — the
+    slot's strip gathered, :func:`chosen_mask`, and one single-operand
+    sort of the chosen positions' pool rows.  No form orders scores."""
+    N, bs, _ = pool.shape
+    # a 128-position group's table entries, three base-256 digits each,
+    # must fit the choice kernel's small operand
+    ok = latent_decode_impl(q, pool) == "pallas" and 128 % bs == 0 \
+        and 3 * (128 // bs) <= _CHOOSE_AUX_ROWS - 2 and N < 2 ** 24
+    return "select:kernel" if ok else "select:lax"
 
 
 def _xla_paged_index_scores(q_i, w_i, pool, tables):
@@ -324,6 +361,29 @@ def _xla_paged_index_scores(q_i, w_i, pool, tables):
     return index_scores(q_i[:, None], w_i[:, None], keys)[:, 0]
 
 
+def _pool_rows(tables, bs):
+    """(S, n_cols * bs): where each position of a slot's table lies in a
+    pool taken as ``[N * bs, F]``."""
+    return (tables[:, :, None] * bs + jnp.arange(
+        bs, dtype=jnp.int32)[None, None, :]).reshape(tables.shape[0], -1)
+
+
+def _xla_index_choose(scores, tables, positions, bs, k):
+    """The lax form of the choice: :func:`chosen_mask` over the written
+    positions, then the mask's pool rows to the front by ONE sort of one
+    operand — the row is the key, nothing rides along and nothing needs
+    stability (the mask settled the ties)."""
+    S, K = scores.shape
+    live = jnp.arange(K, dtype=jnp.int32)[None, :] <= positions[:, None]
+    mask = chosen_mask(jnp.where(live, scores, -jnp.inf), k)
+    rows = jax.lax.sort(
+        jnp.where(mask, _pool_rows(tables, bs), jnp.iinfo(jnp.int32).max),
+        dimension=1, is_stable=False)[:, :k]
+    valid = jnp.arange(k, dtype=jnp.int32)[None, :] \
+        < jnp.sum(mask, axis=-1, keepdims=True)
+    return jnp.where(valid, rows, 0), valid
+
+
 def paged_index_select(q_i, w_i, pool, tables, positions, k):
     """A decode step's choice: index queries ``q_i`` (S, HI, dI) and
     weights ``w_i`` (S, HI) against every index key ``pool`` (N, bs, dIp)
@@ -331,29 +391,31 @@ def paged_index_select(q_i, w_i, pool, tables, positions, k):
     largest score as ``(rows (S, k'), valid (S, k'))``: where each chosen
     position's row lies in a pool taken as ``[N * bs, F]`` (``table[p //
     bs] * bs + p % bs``), which is what :func:`paged_sparse_latent`
-    reads.  :func:`choose_topk`'s set — exact, lowest position first
-    among equals — by ONE stable sort that carries the rows along: a
-    ``top_k`` of positions would leave 2,048 table lookups a slot."""
+    reads.  :func:`choose_topk`'s SET — exact, lowest position first
+    among equals — in no stated order (attention over it is a sum): the
+    ``k``-th largest score by counting passes, then a compaction of the
+    mask (:func:`index_select_impl` says by which code); no score is ever
+    sorted.  While a table holds no more than ``k`` positions every
+    written one is chosen and nothing is counted."""
     S, n_cols = tables.shape
     _, bs, dIp = pool.shape
     dI = q_i.shape[-1]
+    K = n_cols * bs
     positions = positions.astype(jnp.int32)
+    tables = tables.astype(jnp.int32)
+    if int(k) >= K:
+        return _pool_rows(tables, bs), jnp.arange(
+            K, dtype=jnp.int32)[None, :] <= positions[:, None]
     if dIp != dI:
         q_i = jnp.pad(q_i, ((0, 0), (0, 0), (0, dIp - dI)))
-    if index_select_impl(q_i, pool) == "pallas":
-        scores = _paged_index_pallas(
-            q_i, w_i.astype(jnp.float32), pool, tables.astype(jnp.int32),
-            positions, _fa._platform_of(q_i) != "tpu")
-    else:
-        scores = _xla_paged_index_scores(q_i, w_i, pool, tables)
-    K = n_cols * bs
-    live = jnp.arange(K, dtype=jnp.int32)[None, :] <= positions[:, None]
-    rows = (tables[:, :, None] * bs
-            + jnp.arange(bs, dtype=jnp.int32)[None, None, :]).reshape(S, K)
-    worst, rows = jax.lax.sort(
-        (jnp.where(live, -scores, jnp.inf), rows), dimension=1,
-        is_stable=True, num_keys=1)
-    return rows[:, :min(int(k), K)], worst[:, :min(int(k), K)] < jnp.inf
+    if index_select_impl(q_i, pool) == "select:kernel":
+        interpret = _fa._platform_of(q_i) != "tpu"
+        scores = _paged_index_pallas(q_i, w_i.astype(jnp.float32), pool,
+                                     tables, positions, interpret)
+        return _index_choose_pallas(scores, tables, positions, int(bs),
+                                    int(k), interpret)
+    return _xla_index_choose(_xla_paged_index_scores(q_i, w_i, pool, tables),
+                             tables, positions, bs, int(k))
 
 
 def paged_sparse_latent(q_abs, pool, rows, valid, r_kv, scale):
@@ -628,6 +690,165 @@ def _paged_index_pallas(q_i, w_i, pool, tables, positions, interpret):
       w_i[..., None], pool.reshape(N * bs, dIp))
     return out.reshape(S, n_groups * T)[:, :n_cols * bs]
 
+
+# ---------------------------------------------------------------------------
+# the Pallas kernel of the choice: threshold and compaction, scores resident
+# ---------------------------------------------------------------------------
+
+#: slots one step of the choice kernel takes: their 32 counting passes are
+#: independent chains that the scheduler interleaves
+_CHOOSE_SLOTS = 8
+#: rows of the choice kernel's small operand: base-256 digits of the table
+#: entries of a 128-position group (3 x 128 / bs rows), then two rows the
+#: kernel fills (the digits of a group's running count)
+_CHOOSE_AUX_ROWS = 32
+
+_NEG_INF_KEY = -2139095041          # _signed_key(-inf)
+_INT32_MIN = -2 ** 31
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _group_prefix(m, tri, lower):
+    """``m`` (G, 128) of 0 / 1 in bfloat16, position ``g * 128 + l``:
+    ``(own, before)`` float32 (G, 128) — the count of ``m`` inside the
+    group up to and with lane ``l``, and the count in the groups before
+    ``g`` (every lane the same).  Two triangular products on the MXU,
+    exact: a group holds at most 128, float32 sums them."""
+    own = _dot(m, tri, ((1,), (0,)))
+    each = jnp.broadcast_to(own[:, 127:128], own.shape).astype(jnp.bfloat16)
+    return own, _dot(lower, each, ((1,), (0,)))
+
+
+def _index_choose_kernel(pos_ref, s_ref, aux_ref, o_ref, key_ref, *, k, bs):
+    """:func:`_index_choose_pallas` for ``_CHOOSE_SLOTS`` slots whose scores
+    ``s_ref`` (slots, G, 128) rest in VMEM.  The threshold: the scores'
+    order keys (:func:`_signed_key`; a position past the write head is
+    ``-inf``) and :func:`_kth_key`'s 32 counting passes, the slots' chains
+    side by side; :func:`_take_ties` at the threshold.  The compaction, a
+    slot at a time, by RANK and with no sort, gather or scatter: output
+    cell ``j`` belongs to the group whose running count spans it (a
+    comparison, ``(G, k)``), a one-hot product on the MXU fetches that
+    group's lane counts, its running count and its table entries (values
+    a bfloat16 holds exactly, float32 sums), and a comparison down the
+    lanes finds the cell's position in the group."""
+    from jax.experimental import pallas as pl
+    n, G, _ = s_ref.shape
+    i = pl.program_id(0)
+    bf = jnp.bfloat16
+    idx = jax.lax.broadcasted_iota(jnp.int32, (G, 128), 0) * 128 \
+        + jax.lax.broadcasted_iota(jnp.int32, (G, 128), 1)
+    for s in range(n):
+        key_ref[s] = _signed_key(
+            jnp.where(idx <= pos_ref[i * n + s], s_ref[s], -jnp.inf))
+
+    def count_ge(cands):
+        return tuple(
+            jnp.sum(jnp.sum((key_ref[s] >= (c ^ _INT32_MIN)).astype(
+                jnp.int32), axis=0, keepdims=True), axis=1, keepdims=True)
+            for s, c in enumerate(cands))
+
+    thr = _kth_key(count_ge, k, (jnp.zeros((1, 1), jnp.int32),) * n)
+
+    li = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+    lj = jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1)
+    tri = (li <= lj).astype(bf)           # [l', l]: l' <= l
+    gi = jax.lax.broadcasted_iota(jnp.int32, (G, G), 0)
+    gj = jax.lax.broadcasted_iota(jnp.int32, (G, G), 1)
+    lower = (gj < gi).astype(bf)          # [g, g']: g' < g
+    upper = (gi < gj).astype(bf)          # [g', g]: g' < g
+    cells = o_ref.shape[1]                # k, in whole lane tiles
+    cell_f = jax.lax.broadcasted_iota(jnp.int32, (1, cells), 1).astype(
+        jnp.float32)
+    aux_row = jax.lax.broadcasted_iota(jnp.int32, (_CHOOSE_AUX_ROWS, G), 0)
+    nb = 128 // bs
+    blk_row = jax.lax.broadcasted_iota(jnp.int32, (nb, cells), 0)
+
+    for s in range(n):
+        key = key_ref[s]
+        t = thr[s] ^ _INT32_MIN
+        above = key > t
+        tied = (key == t) & (key > _NEG_INF_KEY)
+        room = k - jnp.sum(jnp.sum(above.astype(jnp.int32), axis=0,
+                                   keepdims=True), axis=1, keepdims=True)
+        own, before = _group_prefix(tied.astype(bf), tri, lower)
+        m = _take_ties(above, tied, own + before,
+                       room.astype(jnp.float32)).astype(bf)
+        # the mask's counts, groups down the sublanes (for the one-hot) ...
+        own, before = _group_prefix(m, tri, lower)
+        lo = before[:, :1]
+        hi = lo + own[:, 127:128]
+        total = hi[G - 1:G, :]                                  # (1, 1)
+        onehot = ((lo <= cell_f) & (cell_f < hi)).astype(bf)    # (G, k)
+        # ... and along the lanes (the product's left operand)
+        lanes = _dot(tri, m, ((0,), (1,)))                      # (128, G)
+        each = jnp.broadcast_to(lanes[127:128, :], (8, G)).astype(bf)
+        run = _dot(each, upper, ((1,), (0,)))[:1]               # (1, G)
+        run_hi = jnp.floor(run * (1.0 / 256))
+        aux = jnp.where(aux_row == _CHOOSE_AUX_ROWS - 2, run_hi,
+                        jnp.where(aux_row == _CHOOSE_AUX_ROWS - 1,
+                                  run - 256 * run_hi, aux_ref[s]))
+        got = _dot(lanes.astype(bf), onehot, ((1,), (0,)))      # (128, k)
+        fetched = _dot(aux.astype(bf), onehot, ((1,), (0,)))    # (32, k)
+        rank = cell_f - (256 * fetched[_CHOOSE_AUX_ROWS - 2:
+                                       _CHOOSE_AUX_ROWS - 1]
+                         + fetched[_CHOOSE_AUX_ROWS - 1:])
+        lane = jnp.sum((got <= rank).astype(jnp.int32), axis=0,
+                       keepdims=True)                           # (1, k)
+        entry = fetched[:nb] + 256 * fetched[nb:2 * nb] \
+            + 65536 * fetched[2 * nb:3 * nb]                    # (nb, k)
+        block = jnp.sum(jnp.where(blk_row == lane // bs, entry, 0.0),
+                        axis=0, keepdims=True).astype(jnp.int32)
+        o_ref[s:s + 1, :] = jnp.where(cell_f < total,
+                                      block * bs + lane % bs, -1)
+
+
+@functools.partial(jax.jit, static_argnames=("bs", "k", "interpret"))
+def _index_choose_pallas(scores, tables, positions, bs, k, interpret):
+    """``(rows, valid)`` (S, k) of :func:`paged_index_select` from the
+    ``scores`` float32 (S, K) of the positions ``tables`` (S, K / bs) name,
+    ``K > k`` and ``128 % bs == 0``.  Positions are taken in groups of 128
+    lanes, ``G`` of them (whole sublane tiles: the columns past ``K`` are
+    past every write head); a slot's rows come out in position order."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    S, K = scores.shape
+    n, nb = _CHOOSE_SLOTS, 128 // bs
+    assert 3 * nb <= _CHOOSE_AUX_ROWS - 2 and k <= 2 ** 16, (bs, k)
+    cells = -(-k // 128) * 128
+    G = -(-K // 1024) * 8
+    Sp = -(-S // n) * n
+    scores = jnp.pad(scores, ((0, Sp - S), (0, G * 128 - K)))
+    positions = jnp.pad(positions, (0, Sp - S))
+    # the table entry of a group's b-th block, groups along the lanes
+    entries = jnp.pad(tables, ((0, Sp - S), (0, G * nb - tables.shape[1])))
+    entries = entries.reshape(Sp, G, nb).transpose(0, 2, 1)     # (Sp, nb, G)
+    aux = jnp.concatenate(
+        [(entries >> sh) & 255 for sh in (0, 8, 16)]
+        + [jnp.zeros((Sp, _CHOOSE_AUX_ROWS - 3 * nb, G), jnp.int32)],
+        axis=1).astype(jnp.float32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(Sp // n,),
+        in_specs=[
+            pl.BlockSpec((n, G, 128), lambda i, pos: (i, 0, 0)),
+            pl.BlockSpec((n, _CHOOSE_AUX_ROWS, G), lambda i, pos: (i, 0, 0))],
+        out_specs=pl.BlockSpec((n, cells), lambda i, pos: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((n, G, 128), jnp.int32)],
+    )
+    rows = pl.pallas_call(
+        functools.partial(_index_choose_kernel, k=k, bs=bs),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Sp, cells), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 2 ** 20),
+        interpret=interpret,
+    )(positions, scores.reshape(Sp, G, 128), aux)[:S, :k]
+    return jnp.maximum(rows, 0), rows >= 0
 
 
 # ---------------------------------------------------------------------------

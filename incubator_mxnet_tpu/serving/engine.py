@@ -1356,7 +1356,6 @@ class GenerationEngine:
                 caches[ids[0]], blk, off, row[:, 0])
             if index is None:
                 self._paged_impls.add(la.latent_decode_impl(row, pool))
-                self._paged_attention = "+".join(sorted(self._paged_impls))
 
                 def read(q_abs):
                     return la.paged_latent_decode(
@@ -1365,6 +1364,7 @@ class GenerationEngine:
                 q_i, w_i, k_i = index
                 caches[ids[1]] = keys = self._write_rows(
                     caches[ids[1]], blk, off, k_i[:, 0])
+                self._paged_impls.add(la.index_select_impl(q_i, keys))
                 with jax.named_scope("attn.index"):
                     chosen, valid = la.paged_index_select(
                         q_i[:, 0], w_i[:, 0], keys, tables, positions,
@@ -1373,6 +1373,7 @@ class GenerationEngine:
                 def read(q_abs):
                     return la.paged_sparse_latent(
                         q_abs, pool, chosen, valid, r_kv, scale)
+            self._paged_attention = "+".join(sorted(self._paged_impls))
             return la.absorbed_attention(q_n[:, 0], q_r[:, 0], w_uk, w_uv,
                                          read)[:, None]
         return attend

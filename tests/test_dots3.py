@@ -174,7 +174,9 @@ def test_the_counters_of_an_indexed_model(served):
     assert [c.shape for c in eng._cache] == [
         (25, 16, 40)] * 2 + [(25, 16, 48)] * 3 + [(25, 16, 16)] * 2
     assert eng.cache_bytes == 25 * eng.layout.block_bytes(16)
-    assert eng.program_inventory()["paged_attention"] == "lax_gather"
+    # the sliding layers' read and the indexed layers' choice, by name
+    assert eng.program_inventory()["paged_attention"] \
+        == "lax_gather+select:lax"
 
 
 def test_the_model_alone_is_the_reference():
@@ -522,7 +524,7 @@ def test_the_index_kernel_is_the_gather(monkeypatch):
     positions = jnp.asarray([9, 150, 600], jnp.int32)
     q = jnp.asarray(rng.standard_normal((S, HI, dI)), jnp.bfloat16)
     w = jnp.asarray(rng.standard_normal((S, HI)), jnp.float32)
-    assert la.index_select_impl(q, pool) == "pallas"
+    assert la.index_select_impl(q, pool) == "select:kernel"
     got = la._paged_index_pallas(q, w, pool, tables, positions, True)
     want = la._xla_paged_index_scores(q, w, pool, tables)
     live = np.arange(cols * bs)[None, :] <= np.asarray(positions)[:, None]
@@ -616,3 +618,110 @@ def test_the_mask_is_the_top_k_on_random_scores(k):
     want = np.zeros(s.shape, bool)
     np.put_along_axis(want, np.asarray(idx), np.asarray(valid), -1)
     assert np.asarray(la.chosen_mask(s, k)).tolist() == want.tolist()
+
+
+# -- the decode step's choice: a threshold and a compaction, no sort -----------
+
+def _choice_case(name):
+    """``(scores (S, K) of bfloat16-exact values, positions (S,), bs, k)``
+    of one case of :func:`test_the_choice_is_choose_topks_set`."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    S, K, bs, k = 2, 640, 16, 200
+    scores = np.round(rng.standard_normal((S, K)) * 16) / 8
+    positions = np.array([K - 1, K - 37])
+    if name == "ties":
+        # 300 equal scores from position 100 on — across the 128-lane
+        # group edges at 128, 256 and 384 — under ~110 larger ones, so
+        # the threshold falls among the equal: ~90 of them are taken
+        scores = -np.abs(scores) - 1
+        scores[:, 100:400] = 0.5
+        scores[:, rng.choice(np.r_[0:100, 400:600], 110, False)] = 3.0
+    elif name == "all_equal":
+        scores[:] = 1.25
+    elif name == "few_written":
+        positions = np.array([k - 2, 17])
+    elif name == "free_slot":
+        positions = np.array([0, K - 1])
+    elif name == "short_table":              # K <= k: nothing is counted
+        k = K + 60
+    elif name == "cell_shape":               # 2,048 of 27,136, two slots
+        K, k = 27136, 2048
+        scores = np.round(rng.standard_normal((S, K)) * 16) / 8
+        positions = np.array([K - 1, 25731])
+    # + 0.0: a rounded -0.0 would come back from the index as +0.0, and
+    # the exact choice tells the two apart
+    return (scores + 0.0).astype(np.float32), positions.astype(np.int32), \
+        bs, k
+
+
+def _choice_operands(scores, bs, seed=0):
+    """A pool and PERMUTED tables whose index keys score exactly
+    ``scores`` against the returned queries: feature 0 of a position's key
+    is its score, two heads ``relu(k_0) - relu(-k_0)``."""
+    rng = np.random.default_rng(seed)
+    S, K = scores.shape
+    n_cols = K // bs
+    tables = 1 + rng.permutation(S * n_cols).reshape(S, n_cols)
+    pool = np.zeros((S * n_cols + 1, bs, 128), np.float32)
+    pool[tables, :, 0] = scores.reshape(S, n_cols, bs)
+    q = np.zeros((S, 4, 128), np.float32)
+    q[:, 0, 0], q[:, 1, 0] = 1.0, -1.0
+    w = np.tile(np.asarray([1.0, -1.0, 0.0, 0.0], np.float32), (S, 1))
+    return (jnp.asarray(q, jnp.bfloat16), jnp.asarray(w),
+            jnp.asarray(pool, jnp.bfloat16),
+            jnp.asarray(tables.astype(np.int32)))
+
+
+def _assert_choose_topks_set(rows, valid, scores, tables, positions, bs, k):
+    """``rows`` where ``valid`` are, as a SET, the pool rows of the
+    positions ``choose_topk`` takes of the written scores."""
+    K = scores.shape[1]
+    live = np.arange(K)[None, :] <= np.asarray(positions)[:, None]
+    idx, ok = la.choose_topk(
+        jnp.where(jnp.asarray(live), jnp.asarray(scores), -jnp.inf), k)
+    assert rows.shape == valid.shape == idx.shape
+    assert np.asarray(valid).sum(-1).tolist() \
+        == np.asarray(ok).sum(-1).tolist()
+    tables = np.asarray(tables)
+    for s in range(len(scores)):
+        pos = np.asarray(idx[s])[np.asarray(ok[s])]
+        got = np.asarray(rows[s])[np.asarray(valid[s])]
+        assert len(set(got.tolist())) == len(got)
+        assert sorted(got.tolist()) == sorted(
+            (tables[s, pos // bs] * bs + pos % bs).tolist()), s
+
+
+@pytest.mark.parametrize("name,impl", [
+    (name, impl) for name in ("random", "ties", "all_equal", "few_written",
+                              "free_slot", "cell_shape", "scan_carry")
+    for impl in ("select:kernel", "select:lax")] + [
+        ("short_table", "select:lax")])
+def test_the_choice_is_choose_topks_set(name, impl, monkeypatch):
+    """``paged_index_select`` — the threshold by counting passes and the
+    compaction, interpreted kernel and lax form — against ``choose_topk``
+    through a permuted block table: the same set, ties taken lowest
+    position first, in every case of :func:`_choice_case`; under ``jit``
+    with ``positions`` riding a scan's carry in ``scan_carry``."""
+    if impl == "select:kernel":
+        monkeypatch.setenv("MXNET_FA_DECODE_FORCE_PALLAS", "1")
+    scores, positions, bs, k = _choice_case(name)
+    q, w, pool, tables = _choice_operands(scores, bs)
+    assert la.index_select_impl(q, pool) == impl
+    if name != "scan_carry":
+        rows, valid = la.paged_index_select(q, w, pool, tables,
+                                            jnp.asarray(positions), k)
+        _assert_choose_topks_set(rows, valid, scores, tables, positions,
+                                 bs, k)
+        return
+    start = jnp.asarray([150, 397], jnp.int32)
+
+    @jax.jit
+    def steps(q, w, pool, tables, start):
+        def step(pos, _):
+            return pos + 1, la.paged_index_select(q, w, pool, tables, pos, k)
+        return jax.lax.scan(step, start, None, length=3)[1]
+
+    rows, valid = steps(q, w, pool, tables, start)
+    for i in range(3):
+        _assert_choose_topks_set(rows[i], valid[i], scores, tables,
+                                 np.asarray(start) + i, bs, k)

@@ -631,13 +631,12 @@ def _slope_ms(fn, first, *rest):
     return (took[25] - took[5]) / 20 * 1e3
 
 
-def test_latent_reads_at_the_repoagent_cell_shapes():
-    """`dots3-tp8-repoagent-closed`: 64 slots whose contexts of ~26 k share
-    1,536 blocks.  (a) the sliding layers' window read, kernel against
-    gather; (b) the full layers' index scoring, kernel against gather, and
-    the choice of 2,048; (c) the read of the chosen rows; and a hit
-    prefill's dense index scores, kernel against einsum.  Prints the ms a
-    call of each."""
+def _repoagent_case():
+    """`dots3-tp8-repoagent-closed`'s decode shapes: ``(the latent module,
+    (slots, table columns, block size, pool blocks), tables, positions,
+    mk)`` — 64 slots whose tables share 1,536 blocks and go on with 100 of
+    their own, write heads (numpy) at 24.9–26.2 k, ``mk(i, shape)`` seeded
+    bfloat16 normals."""
     la = importlib.import_module(
         "incubator_mxnet_tpu.kernels.latent_attention")
     rng = onp.random.default_rng(0)
@@ -647,13 +646,23 @@ def test_latent_reads_at_the_repoagent_cell_shapes():
     tables[:, :shared] = 1 + onp.arange(shared)
     for s in range(S):
         tables[s, shared:shared + own] = 1 + shared + s * 160 + onp.arange(own)
-    tables = jnp.asarray(tables)
-    positions = jnp.asarray(
-        rng.integers(shared * bs + 300, (shared + own) * bs - 1, S),
-        jnp.int32)
+    positions = rng.integers(shared * bs + 300, (shared + own) * bs - 1,
+                             S).astype(onp.int32)
     key = jax.random.PRNGKey(0)
     mk = lambda i, shape: jax.random.normal(               # noqa: E731
         jax.random.fold_in(key, i), shape, jnp.float32).astype(jnp.bfloat16)
+    return la, (S, n_cols, bs, N), jnp.asarray(tables), positions, mk
+
+
+def test_latent_reads_at_the_repoagent_cell_shapes():
+    """`dots3-tp8-repoagent-closed`: 64 slots whose contexts of ~26 k share
+    1,536 blocks.  (a) the sliding layers' window read, kernel against
+    gather; (b) the full layers' index scoring, kernel against gather, and
+    the choice of 2,048; (c) the read of the chosen rows; and a hit
+    prefill's dense index scores, kernel against einsum.  Prints the ms a
+    call of each."""
+    la, (S, n_cols, bs, N), tables, positions, mk = _repoagent_case()
+    positions = jnp.asarray(positions)
     ms = {}
     # (a) a sliding layer: 8 heads over rows of 1,088 (stored 1,152)
     pool = jnp.pad(mk(1, (N, bs, 1088)), ((0, 0), (0, 0), (0, 64)))
@@ -707,4 +716,64 @@ def test_latent_reads_at_the_repoagent_cell_shapes():
     ms["prompt_index_pallas"] = _slope_ms(
         lambda q, w, k: la._index_scores_pallas(q, w, k, False), qp, wp, kp)
     print("\nms a call at the repoagent cell's shapes: " + ", ".join(
-        f"{k} {v:.3f}" for k, v in ms.items()))
+        f"{name} {v:.3f}" for name, v in ms.items()))
+
+
+def test_the_choice_at_the_repoagent_cell_shapes():
+    """`dots3-tp8-repoagent-closed`'s choice on the chip: 64 slots, 27,136
+    keys behind tables that share a 1,536-block prefix, 2,048 chosen —
+    `paged_index_select` (the kernels: counting passes, compaction by
+    rank) against the PARENT's form kept here as the oracle, one stable
+    sort of the negated scores that carries the rows: the same sets and
+    the same counts, on the index's own scores and on scores rounded until
+    ~200 are equal at the threshold.  Prints the ms a call of each."""
+    la, (S, _, bs, N), tables, positions, mk = _repoagent_case()
+    k = 2048
+    positions[:3] = [0, 1500, 2047]         # a free slot, fewer than k
+    positions = jnp.asarray(positions)
+    keys = mk(3, (N, bs, 128))
+    q_i, w_i = mk(4, (S, 64, 128)), mk(5, (S, 64)).astype(jnp.float32)
+    assert la.index_select_impl(q_i, keys) == "select:kernel"
+
+    @jax.jit
+    def sort_form(scores, tables, positions):
+        K = scores.shape[1]
+        live = jnp.arange(K, dtype=jnp.int32)[None, :] <= positions[:, None]
+        worst, rows = jax.lax.sort(
+            (jnp.where(live, -scores, jnp.inf), la._pool_rows(tables, bs)),
+            dimension=1, is_stable=True, num_keys=1)
+        return rows[:, :k], worst[:, :k] < jnp.inf
+
+    def sets(rows, valid):
+        rows, valid = onp.asarray(rows), onp.asarray(valid)
+        return [sorted(rows[s][valid[s]].tolist()) for s in range(S)]
+
+    scores = la._paged_index_pallas(q_i, w_i, keys, tables, positions, False)
+    rows, valid = la.paged_index_select(q_i, w_i, keys, tables, positions, k)
+    assert rows.shape == valid.shape == (S, k)
+    want = sort_form(scores, tables, positions)
+    assert onp.asarray(valid).sum(-1).tolist() \
+        == onp.asarray(want[1]).sum(-1).tolist()
+    assert onp.asarray(valid).sum(-1)[:4].tolist() == [1, 1501, 2048, 2048]
+    assert sets(rows, valid) == sets(*want)
+    # + 0.0: the oracle's sort takes -0.0 and +0.0 as equal, the exact
+    # choice (``lax.top_k``'s order) does not
+    tied = jnp.round(scores / 4) + 0.0
+    got = la._index_choose_pallas(tied, tables, positions, bs, k, False)
+    assert sets(*got) == sets(*sort_form(tied, tables, positions))
+    ms = {
+        "select_kernels": _slope_ms(
+            lambda p, q_i, w_i, keys, t: la.paged_index_select(
+                q_i, w_i, keys, t, p, k)[0].astype(jnp.float32),
+            positions, q_i, w_i, keys, tables),
+        "choice_kernel": _slope_ms(
+            lambda p, sc, t: la._index_choose_pallas(
+                sc, t, p, bs, k, False)[0].astype(jnp.float32),
+            positions, scores, tables),
+        "choice_sort_form": _slope_ms(
+            lambda p, sc, t: sort_form(sc, t, p)[0].astype(jnp.float32),
+            positions, scores, tables),
+    }
+    print("\nms a call at the repoagent cell's shapes: " + ", ".join(
+        f"{name} {v:.3f}" for name, v in ms.items()))
+
