@@ -21,7 +21,8 @@ from ..base import MXNetError
 from ..gluon.block import HybridBlock
 from ..ndarray.ndarray import NDArray, _invoke
 
-__all__ = ["ServedLayer", "ServedDecoder", "rms_norm", "rotary"]
+__all__ = ["ServedLayer", "ServedDecoder", "rms_norm", "rotary",
+           "causal_conv", "tail_after"]
 
 #: the integer counters an expert layer returns from ``serve_cached``
 #: (summed over layers and steps by the engine, added on the host to
@@ -58,6 +59,35 @@ def rotary(x, positions, theta, dims=None):
     x1, x2 = xf[..., :D // 2], xf[..., D // 2:]
     rot = jnp.concatenate([-x2, x1], -1)
     return (xf * cos + rot * sin).astype(x.dtype)
+
+
+def causal_conv(x, tail, w, bias=None):
+    """Depthwise causal convolution (``bias`` (C,) added where given), then
+    SiLU: ``x`` (B, T, C) the positions' inputs, ``tail`` (B, K - 1, C) the
+    inputs of the K - 1 positions before them, ``w`` (K, C) with ``w[K -
+    1]`` on the position itself.  Returns ``(y (B, T, C) in x's type,
+    seq)``: ``seq`` (B, T + K - 1, C) float32 is ``tail`` then ``x``, of
+    which ``seq[b, n : n + K - 1]`` is the tail after ``n`` positions
+    (:func:`tail_after`) — ``tail`` itself, bit for bit, for ``n = 0``."""
+    import jax
+    import jax.numpy as jnp
+    K, T = w.shape[0], x.shape[1]
+    seq = jnp.concatenate([tail.astype(jnp.float32),
+                           x.astype(jnp.float32)], axis=1)
+    wf = w.astype(jnp.float32)
+    y = sum(seq[:, j:j + T] * wf[j] for j in range(K))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return jax.nn.silu(y).astype(x.dtype), seq
+
+
+def tail_after(seq, n, width):
+    """``seq[b, n[b] : n[b] + width]`` for every row: ``seq`` (B, L, C),
+    ``n`` (B,) int32 -> (B, width, C)."""
+    import jax
+    from jax import lax
+    return jax.vmap(lambda s, i: lax.dynamic_slice_in_dim(s, i, width, 0))(
+        seq, n)
 
 
 def _adopt(param, value):
@@ -100,6 +130,16 @@ class ServedLayer(HybridBlock):
     #: ``select`` how many keys such a layer chooses (``KVLayout.selects``)
     kv_rows = None
     select = None
+    #: the softmax scale of the layer's attention where it is not
+    #: ``head_dim ** -0.5`` (handed to the attention kernels, not folded
+    #: into a weight)
+    attn_scale = None
+    #: a state layer whose one-token step updates the engine's state
+    #: leaves where they lie: the decode programs hand
+    #: :meth:`serve_recurrent` each WHOLE leaf (the sequences' rows are its
+    #: first B) and take it back whole, where another layer gets its rows
+    #: sliced out and written back
+    state_in_place = False
 
     def __init__(self, shapes, dtype, grad_req, window, random=(), **kwargs):
         super().__init__(**kwargs)
@@ -152,7 +192,8 @@ class ServedLayer(HybridBlock):
 
         def attend(q, k, v):
             kept.extend((k, v))
-            return prefill_attention(q, k, v, window=self.window)
+            return prefill_attention(q, k, v, window=self.window,
+                                     scale=self.attn_scale)
 
         h, _ = self._block(h, positions, attend, live)
         return h, kept[0], kept[1]
@@ -214,15 +255,21 @@ class ServedDecoder(HybridBlock):
     ``layers`` is one maker a layer (``make(prefix=...)`` -> a
     :class:`ServedLayer`); ``cfg`` holds what the seam reads:
     ``num_key_value_heads``, ``head_dim``, ``rms_norm_eps`` and the
-    parameters' ``dtype``."""
+    parameters' ``dtype``.  Three things a model file may state of itself
+    (none is an operator's setting): ``tied_head`` — the head IS the
+    embedding's array, one parameter adopted once, and there is no
+    ``lm_head``; ``embed_scale`` — the embedding's rows times it;
+    ``logit_divisor`` — the logits over it."""
 
     #: counters the expert layers return from ``serve_cached``
     serve_counters = MOE_COUNTERS
 
     def __init__(self, vocab_size, hidden_size, max_length, cfg, layers,
-                 grad_req, **kwargs):
+                 grad_req, tied_head=False, embed_scale=None,
+                 logit_divisor=None, **kwargs):
         super().__init__(**kwargs)
         self._cfg = cfg
+        self._embed_scale, self._logit_divisor = embed_scale, logit_divisor
         self._vocab_size = int(vocab_size)
         self._units = int(hidden_size)
         self._max_length = int(max_length)
@@ -238,7 +285,7 @@ class ServedDecoder(HybridBlock):
             self.norm = self.params.get(
                 "norm", shape=(hidden_size,), dtype=cfg["dtype"],
                 grad_req=grad_req, init="ones")
-            self.lm_head = self.params.get(
+            self.lm_head = None if tied_head else self.params.get(
                 "lm_head", shape=(hidden_size, vocab_size),
                 dtype=cfg["dtype"], grad_req=grad_req)
 
@@ -248,10 +295,15 @@ class ServedDecoder(HybridBlock):
         ``tree = {"embed_tokens", "norm", "lm_head", "layers": [{name:
         array}]}`` with a layer's names as it registers them (the
         model's reference under ``benchmark/chip/reference/`` makes
-        exactly this)."""
+        exactly this; no ``"lm_head"`` for a tied head)."""
+        if (self.lm_head is None) != ("lm_head" not in tree):
+            raise MXNetError(
+                "a tied head is the embedding's array: the tree holds "
+                "\"lm_head\" exactly where the model has one")
         _adopt(self.embed_tokens, tree["embed_tokens"])
         _adopt(self.norm, tree["norm"])
-        _adopt(self.lm_head, tree["lm_head"])
+        if self.lm_head is not None:
+            _adopt(self.lm_head, tree["lm_head"])
         if len(tree["layers"]) != len(self.layers):
             raise MXNetError(f"{len(tree['layers'])} layers given, the "
                              f"model has {len(self.layers)}")
@@ -282,14 +334,34 @@ class ServedDecoder(HybridBlock):
         """tokens, positions (B, T) int32 -> h (B, T, d).  Positions are
         the layers' business (rotary, where a layer carries it)."""
         del positions
-        return self.embed_tokens.data()._data[tokens]
+        h = self.embed_tokens.data()._data[tokens]
+        if self._embed_scale is None:
+            return h
+        import jax.numpy as jnp
+        return h * jnp.asarray(self._embed_scale, h.dtype)
+
+    def _head_logits(self, x):
+        """x (B, T, d) normed -> float32 logits: the head's product (with
+        a tied head the embedding's rows, contracted where they lie), over
+        the model's divisor."""
+        import jax.numpy as jnp
+        from jax import lax
+        if self.lm_head is None:
+            logits = lax.dot_general(
+                x, self.embed_tokens.data()._data,
+                (((x.ndim - 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.dot(x, self.lm_head.data()._data,
+                             preferred_element_type=jnp.float32)
+        if self._logit_divisor is None:
+            return logits
+        return logits / self._logit_divisor
 
     def serve_head(self, h):
         """h (B, T, d) -> float32 logits (B, T, vocab)."""
-        import jax.numpy as jnp
-        x = rms_norm(h, self.norm.data()._data, self._cfg["rms_norm_eps"])
-        return jnp.dot(x, self.lm_head.data()._data,
-                       preferred_element_type=jnp.float32)
+        return self._head_logits(
+            rms_norm(h, self.norm.data()._data, self._cfg["rms_norm_eps"]))
 
     def hybrid_forward(self, F, ids, **params):
         """Full causal forward, no cache: ids (B, T) -> logits (B, T, V)."""

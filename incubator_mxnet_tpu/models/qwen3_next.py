@@ -50,40 +50,14 @@ from __future__ import annotations
 import functools
 
 from ..base import MXNetError
-from .decoder import ServedDecoder, ServedLayer, rms_norm, rotary
+from .decoder import (ServedDecoder, ServedLayer, causal_conv, rms_norm,
+                      rotary, tail_after)
 from .moe import _glu, held_experts_ffn, route_token_choice
 
 __all__ = ["Qwen3NextLayer", "Qwen3NextModel", "causal_conv"]
 
 #: tokens the expert layer takes at a time (a longer prompt in pieces)
 _EXPERT_ROWS = 8192
-
-
-def causal_conv(x, tail, w):
-    """Depthwise causal convolution without bias, then SiLU: ``x`` (B, T, C)
-    the positions' inputs, ``tail`` (B, K - 1, C) the inputs of the K - 1
-    positions before them, ``w`` (K, C) with ``w[K - 1]`` on the position
-    itself.  Returns ``(y (B, T, C) in x's type, seq)``: ``seq`` (B, T + K -
-    1, C) float32 is ``tail`` then ``x``, of which ``seq[b, n : n + K - 1]``
-    is the tail after ``n`` positions (:func:`tail_after`) — ``tail``
-    itself, bit for bit, for ``n = 0``."""
-    import jax
-    import jax.numpy as jnp
-    K, T = w.shape[0], x.shape[1]
-    seq = jnp.concatenate([tail.astype(jnp.float32),
-                           x.astype(jnp.float32)], axis=1)
-    wf = w.astype(jnp.float32)
-    y = sum(seq[:, j:j + T] * wf[j] for j in range(K))
-    return jax.nn.silu(y).astype(x.dtype), seq
-
-
-def tail_after(seq, n, width):
-    """``seq[b, n[b] : n[b] + width]`` for every row: ``seq`` (B, L, C),
-    ``n`` (B,) int32 -> (B, width, C)."""
-    import jax
-    from jax import lax
-    return jax.vmap(lambda s, i: lax.dynamic_slice_in_dim(s, i, width, 0))(
-        seq, n)
 
 
 class Qwen3NextLayer(ServedLayer):
@@ -341,7 +315,6 @@ class Qwen3NextModel(ServedDecoder):
         """h (B, T, d) -> float32 logits (B, T, vocab); the final norm is
         zero-centred like the layers'."""
         import jax.numpy as jnp
-        x = rms_norm(h, 1.0 + self.norm.data()._data.astype(jnp.float32),
-                     self._cfg["rms_norm_eps"])
-        return jnp.dot(x, self.lm_head.data()._data,
-                       preferred_element_type=jnp.float32)
+        return self._head_logits(rms_norm(
+            h, 1.0 + self.norm.data()._data.astype(jnp.float32),
+            self._cfg["rms_norm_eps"]))

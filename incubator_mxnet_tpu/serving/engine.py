@@ -726,6 +726,18 @@ class GenerationEngine:
                 4 * self.max_slots if state_snapshot_rows is None
                 else state_snapshot_rows)
         self._null_row = self.max_slots + self.state_snapshot_rows
+        #: bytes of ONE sequence's state over the state-space layers
+        #: (those that state ``ssm_layers``), 0 for a model without any:
+        #: ``mxtpu_ssm_state_bytes``, and the switch of the two counters
+        self._ssm_bytes = sum(
+            int(_np.prod(shape)) * _np.dtype(dtype).itemsize
+            for l in self._state_layers
+            if getattr(self._layers[l], "ssm_layers", 0)
+            for shape, dtype in self.layout.states[l])
+        if self._ssm_bytes:
+            _m.SSM_STATE_BYTES.set(self._ssm_bytes, model=self.name)
+            self._decode_counts.update(ssm_step_rows=0,
+                                       ssm_prefill_tokens=0)
         self.pool = BlockPool(nb, self.block_size,
                               prefix_cache=self.prefix_cache_enabled,
                               model=self.name,
@@ -1401,6 +1413,11 @@ class GenerationEngine:
                     _np.zeros((self.max_slots, n_groups + 1), _np.int64))
                 self._run_stale.update(range(self.max_slots))
 
+    def _attn_scale(self, l):
+        """The softmax scale layer ``l`` states for its attention (None:
+        the kernels' own, ``head_dim ** -0.5``)."""
+        return getattr(self._layers[l], "attn_scale", None)
+
     def _pools(self, l):
         """Where layer ``l``'s pools lie in a program's cache: K and V of
         a grouped-query layer, the rows a latent layer states."""
@@ -1452,16 +1469,22 @@ class GenerationEngine:
     def _recur_decode(self, caches):
         """The state layers' hand in the decode programs: rows ``0 .. S -
         1`` of a layer's leaves are the slots' own, read and written in
-        place (a slot that is not live comes back as it was)."""
+        place (a slot that is not live comes back as it was).  A layer
+        that states ``state_in_place`` is handed the leaves whole and
+        gives them back whole: its step writes the slots' rows where they
+        lie, and nothing slices them out."""
         from jax import lax
         S = self.max_slots
 
         def recur(l, layer, h, pos, live):
             leaves = self._state_leaves(l)
-            rows = tuple(lax.slice_in_dim(caches[i], 0, S) for i in leaves)
+            whole = getattr(layer, "state_in_place", False)
+            rows = tuple(caches[i] if whole
+                         else lax.slice_in_dim(caches[i], 0, S)
+                         for i in leaves)
             h, new, _, counts = layer.serve_recurrent(h, pos, rows, live)
             for j, i in enumerate(leaves):
-                caches[i] = lax.dynamic_update_slice(
+                caches[i] = new[j] if whole else lax.dynamic_update_slice(
                     caches[i], new[j].astype(caches[i].dtype),
                     (0,) * caches[i].ndim)
             return h, counts
@@ -1581,6 +1604,7 @@ class GenerationEngine:
                 attn = paged_prefix_attention(
                     q.transpose(0, 2, 1, 3), caches[l], caches[lv],
                     table, ctx, self.layout.windows[layer],
+                    scale=self._attn_scale(layer),
                     position_major=self._position_major)
                 return attn.transpose(0, 2, 1, 3)
             return attend
@@ -1620,7 +1644,8 @@ class GenerationEngine:
                 self._note_paged_attention(layer, tables, ck, q.shape[2],
                                            window)
                 return paged_decode_attention(
-                    q[:, 0], ck, cv, tables, positions, window=window,
+                    q[:, 0], ck, cv, tables, positions,
+                    scale=self._attn_scale(layer), window=window,
                     position_major=self._position_major)[:, None]
             return attend
         return attend_for
@@ -1820,7 +1845,8 @@ class GenerationEngine:
                                            window)
                 attn = paged_verify_decode_attention(
                     q.transpose(0, 2, 1, 3), ck, cv, tables, positions,
-                    window=window, position_major=self._position_major)
+                    scale=self._attn_scale(layer), window=window,
+                    position_major=self._position_major)
                 return attn.transpose(0, 2, 1, 3)
             return attend
 
@@ -2153,6 +2179,9 @@ class GenerationEngine:
             path = "hit" if m else "miss"
             _m.PREFILL_TOKENS.inc(n - m, model=self.name, path=path)
             self._prefilled[path] += n - m
+            if self._ssm_bytes:     # every computed position is scanned
+                _m.SSM_PREFILL_TOKENS.inc(n - m, model=self.name)
+                self._decode_counts["ssm_prefill_tokens"] += n - m
             if m:
                 _m.PREFIX_HIT_TOKENS.inc(m, model=self.name)
                 self._prefilled["prefix_hit"] += m
@@ -2297,6 +2326,10 @@ class GenerationEngine:
         ctx = ramp(positions + 1, steps)
         _m.DECODE_CONTEXT_TOKENS.inc(ctx, model=self.name)
         self._decode_counts["decode_context_tokens"] += ctx
+        if self._ssm_bytes:         # a live slot a step: one row updated
+            rows = int(_np.sum(steps))
+            _m.SSM_STEP_ROWS.inc(rows, model=self.name)
+            self._decode_counts["ssm_step_rows"] += rows
         if self._window:
             win = capped(self._window)
             _m.DECODE_WINDOW_TOKENS.inc(win, model=self.name)

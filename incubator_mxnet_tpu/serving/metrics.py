@@ -134,6 +134,21 @@ PREFIX_HIT_TOKENS = _telemetry.registry.counter(
     "mxtpu_prefix_hit_tokens",
     "prompt positions a join did not compute because cached blocks — "
     "and, for a recurrent model, the snapshot they end at — held them")
+SSM_STEP_ROWS = _telemetry.registry.counter(
+    "mxtpu_ssm_step_rows_total",
+    "state rows the decode programs' one-token step of the state-space "
+    "layers updated: a live slot a decode step (a row is one sequence's "
+    "state over all those layers: mxtpu_ssm_state_bytes, read once and "
+    "written once); only for a model with such layers")
+SSM_PREFILL_TOKENS = _telemetry.registry.counter(
+    "mxtpu_ssm_prefill_tokens_total",
+    "live prompt positions the prefill programs took through the "
+    "state-space layers' chunked scan (every position a prefill "
+    "computes, miss or hit); only for a model with such layers")
+SSM_STATE_BYTES = _telemetry.registry.gauge(
+    "mxtpu_ssm_state_bytes",
+    "bytes of ONE sequence's state over the model's state-space layers "
+    "(the matrices and the convolution's tail), whatever its context")
 #: counters a served model's layers return from the decode programs
 #: (``block.serve_counters``), by the model's name for each
 MODEL_COUNTERS = {"moe_pairs_total": MOE_PAIRS_TOTAL,

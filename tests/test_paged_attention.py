@@ -932,6 +932,41 @@ def test_gqa_kernel_compiles_for_v5e_at_the_corpusqa_cell_shapes(
     assert not re.search(r"= bf16\[69633,[^\]]*\]\{[^}]*\} copy\(", text)
 
 
+def test_state_step_kernel_compiles_for_v5e_in_place_at_the_chat_cell_shapes(
+        one_chip, no_compile_cache, monkeypatch):
+    """``granite4hm-chat-open``: 48 slots + 12 snapshots + 1 null = 61 rows
+    of a run of 9 Mamba-2 layers, 128 x 4,096 floats a row and layer (1.15
+    GB a leaf).  The one-token step in a ``lax.scan`` over the run's layers,
+    the leaf donated: ONE kernel (the scan's body), the leaf aliased through
+    it and never copied, under a megabyte of temporaries."""
+    from jax import lax
+    from incubator_mxnet_tpu.kernels import mamba2
+    R, n, S, H, P, N = 61, 9, 48, 64, 64, 128
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def steps(leaf, x, dt, g, B, C, D, live):
+        def body(carry, i):
+            leaf, acc = carry
+            y, leaf = mamba2.ssd_step_rows(leaf, i, x + acc, dt, g, B, C, D,
+                                           live)
+            return (leaf, acc + y), None
+        return lax.scan(body, (leaf, jnp.zeros_like(x)),
+                        jnp.arange(n, dtype=jnp.int32))[0]
+
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+    compiled = jax.jit(steps, donate_argnums=(0,)).trace(
+        sds((R, n, N, H * P)), sds((S, H, P)), sds((S, H)), sds((S, H)),
+        sds((S, N)), sds((S, N)), sds((H,)), sds((S,), jnp.bool_)
+    ).lower(lowering_platforms=("tpu",)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(r"= f32\[61,9,128,4096\]\{[^}]*\} copy\(", text)
+    assert mem.alias_size_in_bytes >= R * n * N * H * P * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
 # -- the grouped kernel's fetch: a step whose live blocks lie in a row in the
 # -- pool takes them in ONE copy, any other a copy a block -------------------
 RUN_COLS, RUN_BLOCKS, RUN_PAGES = 24, 64, 8       # 3 groups of 8 blocks

@@ -777,3 +777,64 @@ def test_the_choice_at_the_repoagent_cell_shapes():
     print("\nms a call at the repoagent cell's shapes: " + ", ".join(
         f"{name} {v:.3f}" for name, v in ms.items()))
 
+
+
+def test_state_step_at_the_chat_cell_shapes():
+    """``granite4hm-chat-open``: the one-token Mamba-2 step over 48 rows of
+    one layer of a leaf (64 heads x 64 x 128 floats a row), in place: the
+    Pallas kernel against the lax step at ``highest`` (no matmul in the
+    kernel: float32 products and sums on the VPU), every number it was not
+    asked for bit for bit as it came, and the time a call takes against
+    the 201 MB it has to move (``-s`` shows it)."""
+    from incubator_mxnet_tpu.kernels import mamba2
+    rng = onp.random.default_rng(42)
+    R, n, rows, Hm, P, N = 52, 3, 48, 64, 64, 128
+    leaf = _rand(rng, (R, n, N, Hm * P))
+    x, B, C, Dm = (_rand(rng, s) for s in ((rows, Hm, P), (rows, N),
+                                           (rows, N), (Hm,)))
+    dt = jnp.asarray(rng.uniform(0.001, 0.1, (rows, Hm)), jnp.float32)
+    g = -jnp.asarray(rng.uniform(1, 16, (Hm,)), jnp.float32) * dt
+    live = jnp.asarray(rng.random(rows) < 0.8)
+    assert mamba2.ssd_impl(x) == "pallas"
+    y, out = jax.jit(mamba2.ssd_step_rows)(leaf, jnp.int32(1), x, dt, g, B,
+                                           C, Dm, live)
+    with jax.default_matmul_precision("highest"):
+        want_y, want_S = (onp.asarray(a) for a in jax.jit(mamba2.ssd_step)(
+            x, dt, g, B, C, Dm, leaf[:rows, 1].reshape(rows, N, Hm, P),
+            live))
+    on = onp.asarray(live)
+    onp.testing.assert_allclose(onp.asarray(y)[on], want_y[on], rtol=1e-4,
+                                atol=1e-4)
+    onp.testing.assert_allclose(onp.asarray(out[:rows, 1]),
+                                want_S.reshape(rows, N, Hm * P), rtol=1e-5,
+                                atol=1e-5)
+    untouched = onp.array(out)
+    untouched[:rows, 1][on] = onp.asarray(leaf)[:rows, 1][on]
+    assert (untouched == onp.asarray(leaf)).all()
+
+    def loop(trips):
+        def run(leaf, x):
+            def body(i, c):
+                leaf, acc = c
+                y, leaf = mamba2.ssd_step_rows(leaf, i % n, x + acc * 0, dt,
+                                               g, B, C, Dm, live)
+                return leaf, y
+            return jax.lax.fori_loop(0, trips, body,
+                                     (leaf, jnp.zeros_like(x)))
+        return jax.jit(run)
+    took = {}
+    for trips in (10, 60):
+        f = loop(trips)
+        jax.block_until_ready(f(leaf, x))
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            jax.block_until_ready(f(leaf, x))
+            best = min(best, time.perf_counter() - t0)
+        took[trips] = best
+    us = (took[60] - took[10]) / 50 * 1e6
+    moved = 2 * rows * N * Hm * P * 4
+    print(f"\nssd_step, 48 rows of one layer: {us:.1f} us a call, "
+          f"{moved / 819e9 * 1e6:.1f} us at 819 GB/s", flush=True)
+    assert us < 4 * moved / 819e9 * 1e6
+
