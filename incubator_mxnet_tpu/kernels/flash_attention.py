@@ -60,15 +60,15 @@ def _platform_of(x):
     """The platform a kernel over ``x`` will run on — the one place every
     dispatcher below decides Pallas-vs-lax and compiled-vs-interpret
     from.  A concrete array says where it lives (a CPU-committed array in
-    a TPU-default process must interpret); a tracer (or a host numpy
-    array) has no devices, so the answer is the current default context's
-    device — jax's default backend unless the caller is inside a
-    ``with ctx:`` scope."""
-    try:
+    a TPU-default process must interpret); a tracer or a host numpy array
+    has no devices, so the answer is the current default context's device
+    — jax's default backend unless the caller is inside a ``with ctx:``
+    scope.  A tracer is NOT asked: jax answers with an error whose message
+    walks the tracer's whole ancestry, seconds a program (PERF.md, PR 44)."""
+    if not isinstance(x, jax.core.Tracer) and hasattr(x, "devices"):
         return next(iter(x.devices())).platform
-    except (AttributeError, jax.errors.ConcretizationTypeError):
-        from ..context import current_context
-        return current_context().jax_device().platform
+    from ..context import current_context
+    return current_context().jax_device().platform
 
 
 def _causal_mask(s, q0, k0):

@@ -31,8 +31,11 @@ import numpy as _np
 from ..base import MXNetError
 from ..gluon import nn
 from ..gluon.block import HybridBlock
-from ..kernels.grouped_experts import (DECODE_PAIRS, held_experts_impl,
-                                       held_experts_pallas)
+from ..kernels.grouped_experts import (DECODE_PAIRS, group_pairs,
+                                       held_experts_impl,
+                                       held_experts_pallas,
+                                       held_experts_route,
+                                       held_experts_sorted)
 from ..ndarray.ndarray import _invoke
 
 __all__ = ["MoEFFN", "MoELoss", "ep_rules", "route_token_choice",
@@ -105,8 +108,8 @@ _tracing = threading.local()
 def traced_expert_impls():
     """Collects what :func:`held_experts_impl` answered for every expert
     layer this thread traces inside the block: a set of ``"pallas"`` /
-    ``"lax_loop"`` (empty for a model without one).  The serving engine
-    keeps it with each program it traces."""
+    ``"pallas_sorted"`` / ``"lax_loop"`` (empty for a model without one).
+    The serving engine keeps it with each program it traces."""
     seen = set()
     sets = _tracing.__dict__.setdefault("sets", [])
     sets.append(seen)
@@ -136,11 +139,12 @@ def held_experts_ffn(x, idx, w, held, w_gate, w_up, w_down, live=None,
     An expert no token chose is not visited and its weights are not
     read; work follows the pairs held, never the published count.
 
-    A decode-shaped call on a TPU is ONE Pallas kernel whose grid walks
-    the touched experts, the next one's matrices arriving while the last
-    one's rows are multiplied (:func:`held_experts_impl` says which;
-    ``kernels/grouped_experts.py``); the loop is a prompt's path, the
-    CPU's, and the kernel's reference.  A ``tile`` asks for the loop's."""
+    On a TPU the product is ONE Pallas kernel (``kernels/grouped_experts.py``;
+    :func:`held_experts_impl` says which): a decode-shaped call's grid walks
+    the touched experts with all the tokens, a prompt's walks the sorted
+    rows a tile at a time, and either way the next expert's matrices arrive
+    while the last one's rows are multiplied.  The loop is the CPU's path,
+    the kernels' reference and what a ``tile`` asks for."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -148,7 +152,8 @@ def held_experts_ffn(x, idx, w, held, w_gate, w_up, w_down, live=None,
     d = x.shape[-1]
     P = T * k
     first, count = int(held[0]), int(held[1])
-    impl = "lax_loop" if tile else held_experts_impl(x, w_gate, P)
+    impl, interpret = ("lax_loop", False) if tile \
+        else held_experts_route(x, w_gate, P)
     for seen in getattr(_tracing, "sets", ()):
         seen.add(impl)
     if impl == "pallas":
@@ -158,24 +163,17 @@ def held_experts_ffn(x, idx, w, held, w_gate, w_up, w_down, live=None,
             on = on & live[:, None]
             n_live = jnp.sum(live, dtype=jnp.int32)
         y, pairs_held, touched = held_experts_pallas(
-            x, jnp.where(on, idx - first, -1), w, w_gate, w_up, w_down, act)
+            x, jnp.where(on, idx - first, -1), w, w_gate, w_up, w_down, act,
+            interpret=interpret)
         return y, (n_live * k, pairs_held, touched)
+    if impl == "pallas_sorted":
+        # one jitted body for every layer of a program that calls alike
+        # (what it would not see a second time is recorded above)
+        return held_experts_sorted(x, idx, w, (first, count), w_gate, w_up,
+                                   w_down, live, act, interpret=interpret)
     tile = min(int(tile or (128 if P >= DECODE_PAIRS else 32)), P)
-    local = idx.reshape(P) - first
-    is_held = (local >= 0) & (local < count)
-    n_live = jnp.asarray(T, jnp.int32)
-    if live is not None:
-        is_held = is_held & jnp.repeat(live, k)
-        n_live = jnp.sum(live, dtype=jnp.int32)
-    key = jnp.where(is_held, local, count)                       # (P,)
-    onehot = (key[:, None] == jnp.arange(count + 1, dtype=jnp.int32)[None]
-              ).astype(jnp.int32)                                # (P, c+1)
-    n = jnp.sum(onehot, axis=0)                # pairs of each expert held
-    starts = jnp.cumsum(n) - n
-    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=1)
-    dest = starts[key] + rank                  # a pair's row once sorted
-    src = jnp.zeros(P, jnp.int32).at[dest].set(
-        jnp.arange(P, dtype=jnp.int32))
+    is_held, n_live, n, starts, dest, src = group_pairs(
+        idx, (first, count), live)
     xs = x[src // k]                                             # (P, d)
     n_e = n[:count]
     chunks = (n_e + tile - 1) // tile

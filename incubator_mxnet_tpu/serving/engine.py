@@ -610,7 +610,7 @@ class GenerationEngine:
         self._run_rows = None
         self._run_stale = set()
         #: what ``held_experts_impl`` answered when each program was
-        #: traced (program -> "pallas" | "lax_loop"; a model without an
+        #: traced (program -> "pallas[_sorted]" | "lax_loop"; one without an
         #: expert layer leaves it empty), and the dispatches by it
         self._experts_impl = {}
         self._expert_paths = {}
@@ -1141,7 +1141,7 @@ class GenerationEngine:
         if impl is None or self._warming:
             return
         for path in impl.split("+"):
-            path = {"pallas": "kernel", "lax_loop": "loop"}[path]
+            path = {"lax_loop": "loop"}.get(path, "kernel")
             _m.MOE_EXPERT_DISPATCHES.inc(model=self.name, path=path)
             self._expert_paths[path] = self._expert_paths.get(path, 0) + 1
 

@@ -211,8 +211,8 @@ MOE_EXPERT_DISPATCHES = _telemetry.registry.counter(
     "mxtpu_moe_expert_dispatches",
     "prefill, decode, burst and verify dispatches of a model with an "
     "expert layer by what its grouped expert product is: path=kernel "
-    "(one Pallas kernel a layer over the touched experts: a decode-"
-    "shaped call on a TPU) or loop (the lax loop: prompts, the CPU); "
+    "(one Pallas kernel a layer, over the touched experts or a "
+    "prompt's sorted rows: a TPU) or loop (the lax loop: the CPU); "
     "held_experts_impl's answer when the program was traced")
 SAMPLE_CONSTRAINED = _telemetry.registry.counter(
     "mxtpu_sample_constrained_requests",

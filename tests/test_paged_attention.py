@@ -478,6 +478,43 @@ def test_experts_kernel_compiles_for_v5e_at_the_cell_shapes(
                          text)
 
 
+@pytest.mark.parametrize("cell,T,k,count,d,f,act", [
+    ("docqa", 6144, 6, 64, 2560, 768, "relu"),
+    ("corpusqa", 8192, 10, 256, 2048, 512, "silu"),
+    ("agent", 512, 4, 32, 3072, 3072, "silu"),
+    ("repoagent", 1024, 8, 32, 5120, 1536, "silu")])
+def test_sorted_experts_kernel_compiles_for_v5e_at_the_prefill_shapes(
+        cell, T, k, count, d, f, act, one_chip, no_compile_cache,
+        monkeypatch):
+    """A prefill's grouped expert product at the four expert cells' widths
+    (bfloat16, a bucket each) through the sorted-form kernel, which the
+    rule takes for all four: ONE call and no loop under it, no copy of a
+    stacked matrix, its row tile and blocks inside the kernel's VMEM limit
+    (one block of the hidden width, six and four)."""
+    from incubator_mxnet_tpu.models import moe
+    ge = importlib.import_module(
+        "incubator_mxnet_tpu.kernels.grouped_experts")
+    monkeypatch.setattr(fa, "_platform_of", lambda x: "tpu")
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x = sds((T, d))
+    assert moe.held_experts_impl(x, sds((count, d, f)), T * k) \
+        == "pallas_sorted"
+    compiled = _compile(
+        lambda x, idx, w, g, u, dn, live: ge.held_experts_sorted(
+            x, idx, w, (0, count), g, u, dn, live, act),
+        x, sds((T, k), jnp.int32), sds((T, k), jnp.float32),
+        sds((count, d, f)), sds((count, d, f)), sds((count, f, d)),
+        sds((T,), jnp.bool_))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and " while(" not in text
+    assert "held_experts_sorted" in text
+    assert not re.search(rf"= bf16\[{count},[^\]]*\]\{{[^}}]*\}} copy\(",
+                         text)
+
+
 @pytest.mark.parametrize("window", [None, 4096])
 def test_gqa_kernel_compiles_for_v5e_at_the_docqa_cell_shapes(
         window, one_chip, no_compile_cache):

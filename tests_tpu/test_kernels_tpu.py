@@ -604,6 +604,71 @@ def test_experts_kernel_at_the_agent_cell_shapes():
                                      "silu")
 
 
+# -- a prompt's expert product: the sorted form (kernels/grouped_experts.py) --
+
+def _prompt_experts_against_the_loop(cell, T, k, published, count, d, f, act):
+    """A prefill's call at a cell's shapes through the sorted-form kernel
+    (``held_experts_impl``'s ``"pallas_sorted"``) and through the loop (``tile=128``, the parent's
+    prompt path), both in this process: the difference, the three counts,
+    and the ms a call of each — on the router's routing and with every
+    token choosing as the first does (a warm-up's zero tokens: all the
+    pairs on ``k`` experts), where the kernel's time has to follow the
+    pairs as the loop's does.  The grouping reads ``idx`` alone, so XLA
+    hoists it out of the timing loop on both sides: a call here is the
+    rows' gather, the product, the un-sort and the weighted sum."""
+    from incubator_mxnet_tpu.models import moe
+    ge = importlib.import_module(
+        "incubator_mxnet_tpu.kernels.grouped_experts")
+    x, (idx, w, gate, up, down) = _experts_case(T, k, published, count, d, f,
+                                                seed=44)
+    assert moe.held_experts_impl(x, gate, T * k) == "pallas_sorted"
+
+    def kernel(x, idx, w, gate, up, down):      # whatever the rule says
+        return ge.held_experts_sorted(x, idx, w, (0, count), gate, up, down,
+                                      act=act)
+
+    def loop(x, idx, w, gate, up, down):        # a tile asks for the loop
+        return moe.held_experts_ffn(x, idx, w, (0, count), gate, up, down,
+                                    act=act, tile=128)
+
+    alike = jnp.broadcast_to(idx[:1], idx.shape)
+    for routing, ids in (("routed", idx), ("alike", alike)):
+        rest = (ids, w, gate, up, down)
+        got, counts = jax.jit(kernel)(x, *rest)
+        ref, counts_loop = jax.jit(loop)(x, *rest)
+        assert [int(c) for c in counts] == [int(c) for c in counts_loop]
+        onp.testing.assert_allclose(onp.asarray(got), onp.asarray(ref),
+                                    **TOL)
+        ms = {name: _us_per_call(lambda *a, s=step: s(*a)[0], x, rest) / 1e3
+              for name, step in (("pallas_sorted", kernel),
+                                 ("lax_loop", loop))}
+        held, touched = int(counts[1]), int(counts[2])
+        flops = 6 * d * f * held
+        moved = touched * 3 * d * f * 2 + held * d * (2 + 4)
+        least = max(flops / 197e12, moved / 819e9) * 1e3
+        print(f"\nprompt's expert product, {cell} cell, {routing}: {T} tokens"
+              f", {held} pairs held on {touched} of {count} experts, least "
+              f"{least:.2f} ms ({flops / 1e9:.0f} GFLOP, {moved / 1e6:.0f} MB"
+              f"): ms a call { {n: round(v, 2) for n, v in ms.items()} }, "
+              f"widest difference "
+              f"{float(jnp.max(jnp.abs(got - ref))):.2e}", flush=True)
+        assert ms["pallas_sorted"] < ms["lax_loop"]
+
+
+def test_prompt_experts_at_the_docqa_cell_shapes():
+    """SmallThinker's miss prefill of the 6,144 bucket: 36,864 pairs on 64
+    ReGLU experts of 2560 x 768, all held."""
+    _prompt_experts_against_the_loop("docqa", 6144, 6, 64, 64, 2560, 768,
+                                     "relu")
+
+
+def test_prompt_experts_at_the_corpusqa_cell_shapes():
+    """Qwen3-Next's hit prefill of the 8,192 bucket: 81,920 pairs, top-10 of
+    512 published, the 256 of 2048 x 512 held here."""
+    _prompt_experts_against_the_loop("corpusqa", 8192, 10, 512, 256, 2048,
+                                     512, "silu")
+
+
 # -- the latent cache's three decode reads (kernels/latent_attention.py) ------
 
 def _slope_ms(fn, first, *rest):
