@@ -31,18 +31,22 @@ and an add over the state (``lax.scan`` everywhere: unlike the delta rule's
 state inside it, so there is nothing for a kernel to keep in VMEM).
 
 ``ssd_step`` is one token a row of state in ONE pass: each row read once and
-written once.  ``ssd_step_rows`` is the same over rows ``0 .. B - 1`` of one
-layer of the engine's whole state leaf ``(R, n, N, H * P)``, in place: a
-Pallas kernel on a TPU whose output IS its input (``input_output_aliases``;
-the rows it does not visit — snapshot rows, other layers — are never
-touched), ``lax`` slices elsewhere (``MXNET_FA_DECODE_FORCE_PALLAS=1``, the
-test hook of the paged attention kernels, interprets the kernel on a CPU).
+written once.  ``ssd_step_rows`` is the same over the LIVE ones of rows ``0
+.. B - 1`` of one layer of the engine's whole state leaf ``(R, n, N, H *
+P)``, in place: a Pallas kernel on a TPU whose output IS its input
+(``input_output_aliases``) and whose grid steps are named by a work list —
+``step_work_list``: the live rows' indices, by scalar prefetch, their count
+the grid's first bound — so the rows it does not visit (a slot nobody is
+on, snapshot rows, other layers) are never touched; ``lax`` slices elsewhere
+(``MXNET_FA_DECODE_FORCE_PALLAS=1``, the test hook of the paged attention
+kernels, interprets the kernel on a CPU).
 
 Everything here is float32 with products at ``Precision.HIGHEST``, as
 ``gated_delta.py`` states and for its reason: the state is float32 by the
 model's statement.  A position that is not ``live`` (padding of a prompt's
 bucket, a free slot) leaves the state bit for bit: its ``g`` and ``dt`` are
-taken as 0 in the prefill, and the step selects the old state.
+taken as 0 in the prefill; the step's kernel does not visit its row (the
+``lax`` step selects the old state), and its output is zeros.
 """
 from __future__ import annotations
 
@@ -57,7 +61,7 @@ from jax import lax
 _fa = importlib.import_module(__package__ + ".flash_attention")
 
 __all__ = ["CHUNK", "ssd_scan", "ssd_prefill", "ssd_step", "ssd_step_rows",
-           "ssd_impl"]
+           "step_work_list", "ssd_impl"]
 
 #: positions a chunk of the prefill holds (the source's ``mamba_chunk_size``)
 CHUNK = 256
@@ -170,25 +174,36 @@ def ssd_prefill(x, dt, g, B, C, D, s0, live=None, snapshot_every=0):
     return y, ends[k - 1::k][:T // every], last
 
 
-def _step_kernel(layer_ref, live_ref, a_ref, xd_ref, b_ref, c_ref, s_ref,
+def step_work_list(live):
+    """The step kernel's work list for ``live`` (B,) bool: ``(order (B,)
+    int32, n_live () int32)`` — ``order[:n_live]`` the live rows' indices
+    in ascending order (what follows them is never read: 0).  A rank by
+    cumulative sum and a comparison of B x B (no sort, no scatter: 48
+    rows)."""
+    on = live.astype(jnp.int32)
+    r = jnp.arange(live.shape[0], dtype=jnp.int32)
+    mine = live[:, None] & ((jnp.cumsum(on) - 1)[:, None] == r[None, :])
+    return jnp.sum(jnp.where(mine, r[:, None], 0), axis=0,
+                   dtype=jnp.int32), jnp.sum(on)
+
+
+def _step_kernel(layer_ref, order_ref, a_ref, xd_ref, b_ref, c_ref, s_ref,
                  y_ref, o_ref):
-    """One row, ``_STEP_LANES`` lanes of it: the state's block is read once
-    and written once — decay, outer product and ``S^T C`` on 128 x 128
-    tiles, ``B`` and ``C`` handed in spread over the lanes."""
-    from jax.experimental import pallas as pl
-    del layer_ref
-    on = live_ref[pl.program_id(0)] != 0
+    """One live row, ``_STEP_LANES`` lanes of it (which row, the block
+    specs read off the work list): the state's block is read once and
+    written once — decay, outer product and ``S^T C`` on 128 x 128 tiles,
+    ``B`` and ``C`` handed in spread over the lanes."""
+    del layer_ref, order_ref
     Bc, Cc = b_ref[...], c_ref[...]                          # (N, 128)
     for j in range(s_ref.shape[1] // 128):
         at = slice(j * 128, (j + 1) * 128)
-        S = s_ref[:, at]
-        S2 = a_ref[:, at] * S + Bc * xd_ref[:, at]
+        S2 = a_ref[:, at] * s_ref[:, at] + Bc * xd_ref[:, at]
         y_ref[:, at] = jnp.sum(S2 * Cc, axis=0, keepdims=True)
-        o_ref[:, at] = jnp.where(on, S2, S)
+        o_ref[:, at] = S2
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _step_pallas(leaf, layer, live, a, xd, B, C, interpret):
+def _step_pallas(leaf, layer, order, n_live, a, xd, B, C, interpret):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     _, _, N, F = leaf.shape
@@ -196,14 +211,20 @@ def _step_pallas(leaf, layer, live, a, xd, B, C, interpret):
     lanes = _STEP_LANES if F % _STEP_LANES == 0 else F
     spread = lambda v: jnp.broadcast_to(v[:, :, None],           # noqa: E731
                                         (rows, N, 128))
-    row = pl.BlockSpec((None, 1, lanes), lambda r, j, *_: (r, 0, j))
-    col = pl.BlockSpec((None, N, 128), lambda r, j, *_: (r, 0, 0))
-    state = pl.BlockSpec((None, None, N, lanes),
-                         lambda r, j, layer, live: (r, layer[0], 0, j))
+    # grid step r is the r-th LIVE row, ``order[r]``, and the grid's first
+    # bound is their count: a row nobody is on costs neither a fetch nor a
+    # write, and a step with nobody live is an empty grid
+    row = pl.BlockSpec((None, 1, lanes),
+                       lambda r, j, layer, order: (order[r], 0, j))
+    col = pl.BlockSpec((None, N, 128),
+                       lambda r, j, layer, order: (order[r], 0, 0))
+    state = pl.BlockSpec(
+        (None, None, N, lanes),
+        lambda r, j, layer, order: (order[r], layer[0], 0, j))
     y, leaf = pl.pallas_call(
         _step_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(rows, F // lanes),
+            num_scalar_prefetch=2, grid=(n_live, F // lanes),
             in_specs=[row, row, col, col, state],
             out_specs=[row, state]),
         out_shape=[jax.ShapeDtypeStruct((rows, 1, F), jnp.float32),
@@ -213,30 +234,37 @@ def _step_pallas(leaf, layer, live, a, xd, B, C, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret, name="ssd_step",
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), live.astype(jnp.int32),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), order,
       a[:, None, :], xd[:, None, :], spread(B), spread(C), leaf)
     return y[:, 0], leaf
 
 
-def ssd_step_rows(leaf, layer, x, dt, g, B, C, D, live=None):
+def ssd_step_rows(leaf, layer, x, dt, g, B, C, D, live=None, work=None):
     """One token for rows ``0 .. B - 1`` of layer ``layer`` (an int32
     scalar, traced or not) of the state leaf ``leaf`` (R, n, N, H * P)
     float32, in place: ``x`` (B, H, P), ``dt``, ``g`` (B, H), ``B``, ``C``
-    (B, N), ``D`` (H,), ``live`` (B,) bool or None.  Returns ``(y (B, H, P)
-    float32, the leaf)`` — every row and layer it was not asked for as it
-    came."""
+    (B, N), ``D`` (H,), ``live`` (B,) bool or None, ``work``
+    :func:`step_work_list` of ``live`` where the caller has made it (one
+    list serves every layer of a run).  Returns ``(y (B, H, P) float32,
+    the leaf)`` — every row and layer it was not asked for as it came, a
+    row that is not live among them, whose ``y`` is zeros."""
     x, dt, g, B, C, D = _f32(x, dt, g, B, C, D)
     rows, H, P = x.shape
     on = jnp.ones(rows, bool) if live is None else live
     if ssd_impl(x) == "pallas":
+        order, n_live = step_work_list(on) if work is None else work
         a = jnp.repeat(jnp.exp(g), P, axis=-1)                   # (B, H * P)
         y, leaf = _step_pallas(
-            leaf, layer, on, a, (dt[..., None] * x).reshape(rows, H * P),
-            B, C, interpret=_fa._platform_of(x) == "cpu")
-        return y.reshape(rows, H, P) + D[:, None] * x, leaf
-    N = leaf.shape[2]
-    S = lax.dynamic_index_in_dim(leaf[:rows], layer, 1, keepdims=False)
-    y, S2 = ssd_step(x, dt, g, B, C, D, S.reshape(rows, N, H, P), on)
-    return y, lax.dynamic_update_slice(
-        leaf, S2.reshape(rows, 1, N, H * P).astype(leaf.dtype),
-        (0, layer, 0, 0))
+            leaf, layer, order, n_live, a,
+            (dt[..., None] * x).reshape(rows, H * P), B, C,
+            interpret=_fa._platform_of(x) == "cpu")
+        y = y.reshape(rows, H, P) + D[:, None] * x
+    else:
+        N = leaf.shape[2]
+        S = lax.dynamic_index_in_dim(leaf[:rows], layer, 1, keepdims=False)
+        y, S2 = ssd_step(x, dt, g, B, C, D, S.reshape(rows, N, H, P), on)
+        leaf = lax.dynamic_update_slice(
+            leaf, S2.reshape(rows, 1, N, H * P).astype(leaf.dtype),
+            (0, layer, 0, 0))
+    # a row the kernel did not visit wrote no output at all
+    return jnp.where(on[:, None, None], y, 0.0), leaf

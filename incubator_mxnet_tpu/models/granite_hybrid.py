@@ -43,9 +43,12 @@ conv)`` a sequence, and its ``_block`` is a ``lax.scan`` over its ``n`` layers,
 mixer and MLP both.  The published forty are nine served layers here: runs of
 5, 9, 9, 9 and 4 around the four attention layers.  A run states
 ``state_in_place``: the decode programs hand it the engine's whole leaves and
-:func:`~..kernels.mamba2.ssd_step_rows` updates the slots' rows where they
-lie, because a program that sliced 48 rows of 19 MB out and wrote them back
-would move the state twice more than the recurrence does.
+:func:`~..kernels.mamba2.ssd_step_rows` updates the LIVE slots' rows where
+they lie — a slot nobody is on is not read at all: the run makes the step's
+list of live rows once (:func:`~..kernels.mamba2.step_work_list`) and every
+layer's call walks it — because a program that sliced 48 rows of 19 MB out
+and wrote them back would move the state twice more than the recurrence
+does.
 
 **Heads of 64.**  A KV head of 64 features would rest on half a lane tile,
 so the attention layer keeps two KV heads side by side as ONE head of 128
@@ -140,13 +143,13 @@ class GraniteMambaRun(ServedLayer):
             ((n * (c["mamba_d_conv"] - 1) * conv,), "float32"))
         super().__init__(shapes, c["dtype"], c["grad_req"], None, **kwargs)
 
-    def _mixer(self, x, w, S, tail, leaf, i, on, every):
+    def _mixer(self, x, w, S, tail, leaf, i, on, every, work=None):
         """One layer's mixer over x (B, T, d) normed.  A prompt (``leaf``
         None): from the rows' state ``S`` (B, N, H * P) and ``tail``;
         returns ``(mix, S', tail', snapshots)``.  One token a row (``leaf``
-        the engine's whole matrices' leaf, ``i`` this layer's index in it):
-        the rows are updated where they lie; returns ``(mix, leaf', tail',
-        None)``."""
+        the engine's whole matrices' leaf, ``i`` this layer's index in it,
+        ``work`` the step's list of the live rows): the live rows are
+        updated where they lie; returns ``(mix, leaf', tail', None)``."""
         import jax
         import jax.numpy as jnp
         from ..kernels import mamba2
@@ -173,7 +176,7 @@ class GraniteMambaRun(ServedLayer):
             with jax.named_scope("ssm.step"):
                 y, S2 = mamba2.ssd_step_rows(
                     leaf, i, xs[:, 0], dt[:, 0], g[:, 0], Bm[:, 0], Cm[:, 0],
-                    w("D"), on[:, 0])
+                    w("D"), on[:, 0], work)
             y = y[:, None]
         else:
             with jax.named_scope("ssm.scan"):
@@ -199,14 +202,14 @@ class GraniteMambaRun(ServedLayer):
         return jnp.dot(y, w("out_proj"),
                        preferred_element_type=jnp.float32), S2, new_tail, snaps
 
-    def _layer(self, h, w, S, tail, leaf, i, on, every):
+    def _layer(self, h, w, S, tail, leaf, i, on, every, work=None):
         """One block of the run: ``(h', S' | leaf', tail', snapshots)``."""
         import jax
         c = self._c
         with jax.named_scope("attn.ssm"):
             mix, S2, tail2, snaps = self._mixer(
                 rms_norm(h, w("input_layernorm"), c["rms_norm_eps"]), w, S,
-                tail, leaf, i, on, every)
+                tail, leaf, i, on, every, work)
             h = h + (c["residual_multiplier"] * mix).astype(h.dtype)
         return _mlp(self, h, w), S2, tail2, snaps
 
@@ -218,6 +221,7 @@ class GraniteMambaRun(ServedLayer):
         snapshots)``."""
         import jax.numpy as jnp
         from jax import lax
+        from ..kernels.mamba2 import step_work_list
         del positions                       # the recurrence carries order
         B, T, _ = h.shape
         n = self.ssm_layers
@@ -230,13 +234,17 @@ class GraniteMambaRun(ServedLayer):
         def update(rows, every):
             S_all, tails = rows
             if T == 1:      # one token a row, where the rows lie
+                # which rows are live is the same for every layer of the
+                # run: their list is made once, ahead of the scan
+                work = step_work_list(on[:, 0])
+
                 def step(c, xs):
                     hh, leaf, tl = c
                     p, i = xs
                     t0 = lax.dynamic_slice(tl, (0, i * W), (B, W))
                     hh, leaf, t2, _ = self._layer(
                         hh, p.__getitem__, None, t0.reshape(B, K1, conv),
-                        leaf, i, on, 0)
+                        leaf, i, on, 0, work)
                     tl = lax.dynamic_update_slice(
                         tl, t2.reshape(B, W).astype(tl.dtype), (0, i * W))
                     return (hh, leaf, tl), None

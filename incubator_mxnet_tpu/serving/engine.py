@@ -737,6 +737,7 @@ class GenerationEngine:
         if self._ssm_bytes:
             _m.SSM_STATE_BYTES.set(self._ssm_bytes, model=self.name)
             self._decode_counts.update(ssm_step_rows=0,
+                                       ssm_step_rows_skipped=0,
                                        ssm_prefill_tokens=0)
         self.pool = BlockPool(nb, self.block_size,
                               prefix_cache=self.prefix_cache_enabled,
@@ -2243,7 +2244,7 @@ class GenerationEngine:
         rows[:, _POS] += live
         rows[:, _BUDGET] -= live
         self._count_decode(counts, _np.asarray(positions, _np.int64)
-                           .reshape(-1), live.astype(_np.int64))
+                           .reshape(-1), live.astype(_np.int64), 1)
         return nxt
 
     def decode_burst(self, last_tokens, positions, budgets, eos_ids,
@@ -2277,7 +2278,7 @@ class GenerationEngine:
             toks, emitted = _np.asarray(out[0]), _np.asarray(out[1])
         self._follow_burst(toks, emitted)
         self._count_decode(counts, _np.asarray(positions, _np.int64)
-                           .reshape(-1), emitted.astype(_np.int64))
+                           .reshape(-1), emitted.astype(_np.int64), k)
         return toks, emitted
 
     def _follow_burst(self, toks, emitted) -> None:
@@ -2298,14 +2299,14 @@ class GenerationEngine:
         rows[:, _BUDGET] -= emitted
         rows[:, _DONE] |= ended
 
-    def _count_decode(self, counts, positions, steps) -> None:
-        """After a decode or burst dispatch, with its results already on
-        the host: add what the model's layers counted in the program to
-        their series, and the context the steps had behind them to
-        ``mxtpu_decode_context_tokens`` — slot ``s`` was live for
-        ``steps[s]`` steps from write head ``positions[s]``, so its
-        written positions sum to ``steps * (pos + 1) + steps * (steps -
-        1) / 2`` (nothing is pulled from the device for this one).  For
+    def _count_decode(self, counts, positions, steps, dispatch_steps) -> None:
+        """After a decode or burst dispatch of ``dispatch_steps`` steps,
+        with its results already on the host: add what the model's layers
+        counted in the program to their series, and the context the steps
+        had behind them to ``mxtpu_decode_context_tokens`` — slot ``s`` was
+        live for ``steps[s]`` steps from write head ``positions[s]``, so
+        its written positions sum to ``steps * (pos + 1) + steps * (steps
+        - 1) / 2`` (nothing is pulled from the device for this one).  For
         a model with windowed layers the same sum with each step's
         written positions capped at the window goes to
         ``mxtpu_decode_window_tokens``: a batch holds contexts on both
@@ -2326,10 +2327,15 @@ class GenerationEngine:
         ctx = ramp(positions + 1, steps)
         _m.DECODE_CONTEXT_TOKENS.inc(ctx, model=self.name)
         self._decode_counts["decode_context_tokens"] += ctx
-        if self._ssm_bytes:         # a live slot a step: one row updated
+        if self._ssm_bytes:
+            # a live slot a step: one row updated; the step's work list
+            # leaves every other slot's row out
             rows = int(_np.sum(steps))
+            skipped = self.max_slots * dispatch_steps - rows
             _m.SSM_STEP_ROWS.inc(rows, model=self.name)
+            _m.SSM_STEP_ROWS_SKIPPED.inc(skipped, model=self.name)
             self._decode_counts["ssm_step_rows"] += rows
+            self._decode_counts["ssm_step_rows_skipped"] += skipped
         if self._window:
             win = capped(self._window)
             _m.DECODE_WINDOW_TOKENS.inc(win, model=self.name)

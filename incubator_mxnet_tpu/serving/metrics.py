@@ -140,6 +140,13 @@ SSM_STEP_ROWS = _telemetry.registry.counter(
     "layers updated: a live slot a decode step (a row is one sequence's "
     "state over all those layers: mxtpu_ssm_state_bytes, read once and "
     "written once); only for a model with such layers")
+SSM_STEP_ROWS_SKIPPED = _telemetry.registry.counter(
+    "mxtpu_ssm_step_rows_skipped_total",
+    "state rows the one-token step's work list left out: a slot that was "
+    "not live a decode step of a dispatch (free, or ended inside the "
+    "burst) — max_slots x the dispatch's steps less "
+    "mxtpu_ssm_step_rows_total; the kernel's own predicate on the host's "
+    "rows; only for a model with such layers")
 SSM_PREFILL_TOKENS = _telemetry.registry.counter(
     "mxtpu_ssm_prefill_tokens_total",
     "live prompt positions the prefill programs took through the "

@@ -62,10 +62,10 @@ def check_paged_groups(eng, serve, monkeypatch, group_keys=32, step_groups=2):
     fa._paged_gqa_pallas.clear_cache()
     calls, count = [], eng._count_decode
 
-    def spy(counts, positions, steps):
+    def spy(counts, positions, steps, dispatch_steps):
         calls.append((eng._tables.copy(), _np.array(positions),
                       _np.array(steps)))
-        return count(counts, positions, steps)
+        return count(counts, positions, steps, dispatch_steps)
 
     monkeypatch.setattr(eng, "_count_decode", spy)
 

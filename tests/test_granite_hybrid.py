@@ -182,12 +182,17 @@ def test_a_snapshot_hit_equals_the_miss(served_f32):
 
 def test_counters_and_the_state_gauge(served_f32):
     """``mxtpu_ssm_step_rows_total`` counts a live slot a decode step (2
-    slots x 3 steps + 16 + 13 in the bursts), ``mxtpu_ssm_prefill_tokens_
-    total`` every computed prompt position, ``mxtpu_ssm_state_bytes`` one
-    sequence's state — and the stock state-row series count these rows."""
+    slots x 3 steps + 16 + 13 in the bursts), ``mxtpu_ssm_step_rows_
+    skipped_total`` the slots the step's work list left out (a step of a
+    dispatch has ``max_slots`` rows: updated or skipped),
+    ``mxtpu_ssm_prefill_tokens_total`` every computed prompt position,
+    ``mxtpu_ssm_state_bytes`` one sequence's state — and the stock
+    state-row series count these rows."""
     cfg, _, _, eng = served_f32
     got = eng.decode_counters()
     assert got["ssm_step_rows"] == 35 and got["ssm_prefill_tokens"] == 664
+    # 3 single steps and 2 bursts of 8 over 3 slots: 57 rows in all
+    assert got["ssm_step_rows_skipped"] == 3 * (3 + 2 * 8) - 35
     H, P, N = cfg["mamba_n_heads"], cfg["mamba_d_head"], cfg["mamba_d_state"]
     row = 6 * 4 * (H * P * N + 3 * (H * P + 2 * N))     # six Mamba layers
     assert eng._ssm_bytes == row
@@ -196,6 +201,7 @@ def test_counters_and_the_state_gauge(served_f32):
     assert stats["state_bytes"] == (3 + 4 + 1) * row
     text = telemetry.registry.render_prometheus()
     for name in ("mxtpu_ssm_step_rows_total", "mxtpu_ssm_state_bytes",
+                 "mxtpu_ssm_step_rows_skipped_total",
                  "mxtpu_ssm_prefill_tokens_total"):
         assert f'{name}{{model="tiny"}}' in text
     assert f'mxtpu_ssm_state_bytes{{model="tiny"}} {row}' in text
@@ -276,6 +282,53 @@ def test_a_stacked_run_is_its_layers_one_by_one():
                 p._data, p._deferred_init = mx.nd.NDArray(arr), None
             got = one(got)
     np.testing.assert_allclose(got.asnumpy(), want, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("forced", [False, True], ids=["lax", "pallas"])
+def test_one_compiled_step_of_a_run_serves_any_live_rows(forced,
+                                                         monkeypatch):
+    """One token a row through the run of three layers, the engine's whole
+    leaves handed in (``T == 1``): which rows are live is DATA — two calls
+    of one compiled program with other rows live trace once — and each call
+    gives its live rows what a call with every row live gives them, and
+    leaves the others' state, both leaves, bit for bit."""
+    from incubator_mxnet_tpu.kernels import mamba2
+    monkeypatch.setenv("MXNET_FA_DECODE_FORCE_PALLAS", "1" if forced else "0")
+    mamba2._step_pallas.clear_cache()
+    cfg = _cfg()
+    run = _net(cfg).layers[2]
+    rng = np.random.RandomState(5)
+    B, R = 4, 6                         # sequences, rows of the leaves
+    leaves = tuple(jnp.asarray(rng.normal(size=(R,) + shape), dtype)
+                   for shape, dtype in run.state_shapes)
+    h = jnp.asarray(rng.normal(size=(B, 1, cfg["hidden_size"])), jnp.float32)
+    traced = []
+
+    @jax.jit
+    def step(h, leaves, live):
+        traced.append(1)
+        out, new, _, _ = run.serve_recurrent(
+            h, jnp.zeros((B, 1), jnp.int32), leaves, live)
+        return out, new
+
+    want_h, want = step(h, leaves, jnp.ones((B, 1), bool))
+    assert any((np.asarray(a) != np.asarray(b)).any()
+               for a, b in zip(want, leaves))
+    for live in ([True, False, True, True], [False, True, False, False],
+                 [False, False, False, False]):
+        on = np.asarray(live)
+        got_h, got = step(h, leaves, jnp.asarray(on)[:, None])
+        assert np.isfinite(np.asarray(got_h)).all()
+        np.testing.assert_allclose(np.asarray(got_h)[on],
+                                   np.asarray(want_h)[on], atol=1e-6, rtol=0)
+        for new, full, old in zip(got, want, leaves):
+            new, full, old = (np.asarray(a) for a in (new, full, old))
+            np.testing.assert_allclose(new[:B][on], full[:B][on], atol=1e-6,
+                                       rtol=0)
+            assert (new[:B][~on] == old[:B][~on]).all()
+            assert (new[B:] == old[B:]).all()
+    assert len(traced) == 1
+    mamba2._step_pallas.clear_cache()
 
 
 def _zero_conv_bias(params):

@@ -87,38 +87,80 @@ def _rows(seed=1, R=5, n=3, rows=3, H=4, P=32, N=16):
 #: (name, kernel forced, lanes a grid step of the kernel takes)
 STEP_CASES = [("lax", False, None), ("pallas_one_block", True, None),
               ("pallas_two_blocks", True, 128)]
+#: which of the three sequences' rows are live
+LIVE_CASES = [("all", [True, True, True]), ("none", [False, False, False]),
+              ("first", [True, False, False]),
+              ("last", [False, False, True]),
+              ("alternating", [False, True, False]),
+              ("middle_dead", [True, False, True])]
 
 
-@pytest.mark.parametrize("case", STEP_CASES, ids=[c[0] for c in STEP_CASES])
-def test_step_rows_is_the_step_in_place(case, monkeypatch):
-    """One token for rows 0..2 of layer 1 of a leaf of five rows and three
-    layers, the middle row not live: the live rows' outputs and new state
-    are the step's, and every other number of the leaf — the row that is not
-    live, the rows past the sequences', the other layers — is as it came,
-    bit for bit.  The Pallas kernel runs interpreted."""
-    _, forced, lanes = case
+@pytest.fixture
+def step_form(request, monkeypatch):
+    """One of :data:`STEP_CASES` set up (the Pallas kernel runs
+    interpreted): the lanes a grid step takes, or None."""
+    _, forced, lanes = request.param
     monkeypatch.setenv("MXNET_FA_DECODE_FORCE_PALLAS", "1" if forced else "0")
     if lanes:
         monkeypatch.setattr(m, "_STEP_LANES", lanes)
     m._step_pallas.clear_cache()
+    yield request.param
+    m._step_pallas.clear_cache()
+
+
+@pytest.mark.parametrize("live", [c[1] for c in LIVE_CASES],
+                         ids=[c[0] for c in LIVE_CASES])
+@pytest.mark.parametrize("step_form", STEP_CASES, indirect=True,
+                         ids=[c[0] for c in STEP_CASES])
+def test_step_rows_is_the_step_in_place(step_form, live):
+    """One token for rows 0..2 of layer 1 of a leaf of five rows and three
+    layers: the live rows' outputs and new state are the step's, the
+    outputs of the rows that are not live exactly 0, and every other number
+    of the leaf — the rows that are not live, the rows past the sequences',
+    the other layers — is as it came, bit for bit."""
+    _, forced, lanes = step_form
     leaf, x, dt, g, B, C, D = _rows(P=64 if lanes else 32)
     rows, (N, H, P) = x.shape[0], (leaf.shape[2],) + x.shape[1:]
-    live = jnp.asarray([True, False, True])
+    on = np.asarray(live)
     assert m.ssd_impl(x) == ("pallas" if forced else "lax")
     y, out = jax.jit(m.ssd_step_rows)(leaf, jnp.int32(1), x, dt, g, B, C, D,
-                                      live)
+                                      jnp.asarray(on))
     want_y, want_S = m.ssd_step(x, dt, g, B, C, D,
-                                leaf[:rows, 1].reshape(rows, N, H, P), live)
-    on = np.asarray(live)
+                                leaf[:rows, 1].reshape(rows, N, H, P),
+                                jnp.asarray(on))
     np.testing.assert_allclose(np.asarray(y)[on], np.asarray(want_y)[on],
                                atol=1e-5, rtol=1e-5)
+    assert (np.asarray(y)[~on] == 0).all()
     np.testing.assert_allclose(out[:rows, 1],
                                want_S.reshape(rows, N, H * P), atol=1e-6,
                                rtol=1e-6)
     untouched = np.array(out)
-    untouched[[0, 2], 1] = np.asarray(leaf)[[0, 2], 1]
+    untouched[:rows, 1][on] = np.asarray(leaf)[:rows, 1][on]
     assert (untouched == np.asarray(leaf)).all()
-    m._step_pallas.clear_cache()
+
+
+@pytest.mark.parametrize("live", [
+    [True] * 7, [False] * 7, [False, True, True, False, False, True, False],
+    [True, False, False, False, False, False, True]],
+    ids=["all", "none", "scattered", "ends"])
+def test_the_work_list_names_the_live_rows_first_in_order(live):
+    order, n_live = jax.jit(m.step_work_list)(jnp.asarray(live))
+    on = np.asarray(live)
+    assert int(n_live) == on.sum() and order.dtype == jnp.int32
+    assert list(order[:int(n_live)]) == list(np.flatnonzero(on))
+    assert (np.asarray(order[int(n_live):]) == 0).all()
+
+
+def test_a_list_made_ahead_serves_as_the_one_made_inside(monkeypatch):
+    """``work`` is what the step would have made of ``live`` itself."""
+    monkeypatch.setenv("MXNET_FA_DECODE_FORCE_PALLAS", "1")
+    leaf, x, dt, g, B, C, D = _rows()
+    live = jnp.asarray([False, True, True])
+    inside = m.ssd_step_rows(leaf, 2, x, dt, g, B, C, D, live)
+    ahead = m.ssd_step_rows(leaf, 2, x, dt, g, B, C, D, live,
+                            m.step_work_list(live))
+    for a, b in zip(inside, ahead):
+        assert (np.asarray(a) == np.asarray(b)).all()
 
 
 def test_the_step_is_one_token_of_the_scan():
