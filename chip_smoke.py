@@ -53,8 +53,8 @@ GPT2_124M = dict(vocab_size=50257, units=768, hidden_size=3072,
 BERT_LARGE = dict(vocab_size=30522, units=1024, hidden_size=4096,
                   num_layers=24, num_heads=16, max_length=512)
 SEQ_LEN, BATCH_PER_CHIP = 128, 8
-#: Adam with no warm-up spikes the loss before it falls (11.25 -> 13.1 at the
-#: bench's 1e-4 on the v5e, PR 21); at 1e-5 the spike is a few tenths and six
+#: Adam with no warm-up spikes the loss before it falls (11.25 -> 13.1 at
+#: 1e-4 on the v5e, PR 21); at 1e-5 the spike is a few tenths and six
 #: steps end clearly below the first
 ADAM = {"learning_rate": 1e-5}
 #: per-leg wall limits (cold, PR 21 on the v5e: serve 382 s, train 297 s, four 343 s);
@@ -148,7 +148,8 @@ def _bert_large_batch(batch):
 
 
 def _bert_large():
-    """The anchor net as ``bench.py`` builds it: BERT-large MLM+NSP, bf16."""
+    """The anchor net as the benchmark's pretraining job builds it
+    (``benchmark/chip/programs/bert_pretrain.py``): BERT-large MLM+NSP, bf16."""
     import numpy as np
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu.models import bert

@@ -1,6 +1,6 @@
 """Where compiled programs are kept between processes.
 
-One rule, shared by serve, train, ``bench.py`` and ``chip_smoke.py``:
+One rule, shared by serve, train, the benchmark and ``chip_smoke.py``:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set — jax already honours it; nothing here
   sets a directory.  A deployment (or ``mxtpu-supervise --compile-cache``)

@@ -359,7 +359,7 @@ class BERTForPretrain(HybridBlock):
 
 class MLMPretrainLoss(HybridBlock):
     """Masked-LM cross-entropy over flattened (B*T, V) scores — the loss
-    head bench.py and the driver's multichip dryrun both train with."""
+    head the distributed examples and the multichip dryrun train with."""
 
     def __init__(self, vocab_size, **kwargs):
         super().__init__(**kwargs)

@@ -31,8 +31,8 @@ pinning the state to ``P(axis)``) rather than ``shard_map``: the
 elementwise update cores need no index plumbing, and XLA places the
 reduce-scatter / all-gather around the constrained region.  Because the
 supported cores (sgd / momentum / nag / adam / adamw / rmsprop /
-adagrad) are purely elementwise, the sharded update is bit-identical to
-the replicated one; rules with per-tensor reductions (LAMB's trust
+adagrad) are purely elementwise, the sharded update is the replicated
+one's to rounding (another compiled program: docs/performance.md); rules with per-tensor reductions (LAMB's trust
 ratio) straddle shard boundaries and are excluded
 (``FunctionalOptimizer.elementwise`` is False → callers fall back).
 """

@@ -42,7 +42,9 @@ from .. import telemetry as _telemetry
 from .. import telemetry_device as _telemetry_device
 from .. import health as _health
 from . import metrics as _m
-from .kvcache import NO_SNAPSHOTS
+from .kvcache import (NO_SNAPSHOTS, BlockPool, KVLayout, blocks_for,
+                      cache_forms, grouped_pool_shape,
+                      pool_layout as _pool_layout)
 
 __all__ = ["InferenceEngine", "GenerationEngine", "derive_buckets",
            "derive_prefill_buckets"]
@@ -510,10 +512,9 @@ class GenerationEngine:
     * ``decode(last_tokens, positions)`` — ONE fixed-shape dispatch
       advancing every slot one token: embeds each slot's last token at
       its own position, appends K/V at that position, and attends over
-      its live prefix via
-      :func:`kernels.flash_attention.paged_decode_attention`.  Exactly
-      one compiled program, regardless of how many requests are in
-      flight or how long they run; ``decode_burst`` scans
+      its live prefix through the block table (the layer's cache form,
+      :mod:`~.kvcache`).  Exactly one compiled program, regardless of how
+      many requests are in flight or how long they run; ``decode_burst`` scans
       ``scan_steps`` of them into one dispatch, and ``verify`` scores a
       draft's proposals ``spec_k + 1`` positions wide.
 
@@ -620,7 +621,6 @@ class GenerationEngine:
             raise MXNetError(f"max_slots must be >= 1: {self.max_slots}")
         #: the model's own statement of what its layers cache
         #: (:class:`~.kvcache.KVLayout`): pools are allocated from it
-        from .kvcache import BlockPool, KVLayout
         self.layout = KVLayout.of(block.kv_layout())
         blk_len = self.layout.max_length
         self.max_len = min(int(max_len
@@ -684,37 +684,23 @@ class GenerationEngine:
                 f"num_blocks {nb} cannot hold even one max_len slot "
                 f"({self.max_blocks_per_slot} blocks + null block)")
         self.num_blocks = nb
-        # the layers that keep blocks (a K and a V pool each, in this
-        # order) and those that keep a state of constant size a sequence
-        # (``KVLayout.states``; one array a leaf, a row a sequence)
-        self._pool_of = {l: i for i, l in enumerate(self.layout.kv_layers)}
-        self._n_kv = len(self._pool_of)
-        # where each of those layers' pools lie in a program's cache: the
-        # layers' first rows in layer order, then their second rows (K
-        # pools, then V pools of a grouped-query model), the state
-        # layers' leaves after the last
-        kept = {l: len(self.layout.layer_rows(l)) for l in self._pool_of}
-        ids = {l: [] for l in self._pool_of}
-        self._n_pools = 0
-        for j in range(max(kept.values(), default=0)):
-            for l in self._pool_of:
-                if j < kept[l]:
-                    ids[l].append(self._n_pools)
-                    self._n_pools += 1
-        self._pool_ids = {l: tuple(v) for l, v in ids.items()}
-        #: the layers that keep rows of their own (``KVLayout.rows``: a
-        #: latent layer) and those among them that choose the keys they
-        #: read (layer -> how many, ``KVLayout.selects``)
-        self._row_layers = tuple(l for l in self._pool_of
-                                 if self.layout.rows[l] is not None)
-        self._select_layers = {l: self.layout.selects[l]
-                               for l in self._row_layers
-                               if self.layout.selects[l] is not None}
+        #: one form a layer: what it caches, where, how written and read
+        self._forms, self._n_pools = cache_forms(
+            self.layout, self._layers, self.block_size,
+            self.max_blocks_per_slot, self._note_paged_attention)
+        #: the layers that keep a state of constant size a sequence
+        self._state_layers = tuple(
+            f.layer for f in self._forms if f.keeps_state)
+        # (benchmark/chip/tools read these two off an engine)
+        self._pool_ids = {f.layer: f.ids for f in self._forms
+                          if not f.keeps_state}
+        self._n_kv = len(self._pool_ids)
+        #: the layers that choose the keys they read (layer -> how many)
+        self._select_layers = {f.layer: f.select for f in self._forms
+                               if f.select is not None}
         if self._select_layers:
             self._decode_counts.update(index_keys_scored=0,
                                        index_keys_selected=0)
-        self._state_layers = tuple(
-            l for l in range(self.num_layers) if l not in self._pool_of)
         # the state store's rows: one a slot (row s is slot s's), then the
         # snapshot rows the pool hands out, then one null row, where a
         # program writes a state nobody keeps
@@ -733,7 +719,7 @@ class GenerationEngine:
             int(_np.prod(shape)) * _np.dtype(dtype).itemsize
             for l in self._state_layers
             if getattr(self._layers[l], "ssm_layers", 0)
-            for shape, dtype in self.layout.states[l])
+            for shape, dtype in self._forms[l].leaves)
         if self._ssm_bytes:
             _m.SSM_STATE_BYTES.set(self._ssm_bytes, model=self.name)
             self._decode_counts.update(ssm_step_rows=0,
@@ -745,13 +731,12 @@ class GenerationEngine:
                               snapshot_every=self.state_snapshot_tokens,
                               snapshot_rows=self.state_snapshot_rows,
                               first_snapshot_row=self.max_slots)
-        #: the shape each pool is stored in on this device, and whether
-        #: that is position-major (:meth:`~.kvcache.KVLayout.pool_shape`:
-        #: where the stated [N, H, bs, D] would rest in a layout no
-        #: program keeps)
-        self._pool_shape, self._position_major = self.layout.pool_shape(
-            self.num_blocks, self.block_size, self._ctx.jax_device()) \
-            if len(self._row_layers) < self._n_kv else (None, False)
+        #: the shape each K and V pool is stored in on this device and
+        #: whether that is position-major (``KVLayout.pool_shape``); the
+        #: forms are handed them at every use, so a caller may assign them
+        self._pool_shape, self._position_major = grouped_pool_shape(
+            self.layout, self._forms, self.num_blocks, self.block_size,
+            self._ctx.jax_device())
         self._warming = False
         # multi-token decode bursts (docs/serving.md): lax.scan
         # ``scan_steps`` decode steps into ONE dispatch with in-program
@@ -1222,189 +1207,19 @@ class GenerationEngine:
                              step_keys(roots[:, None, :], pos_q + 1), live)
 
     # -- pure programs --------------------------------------------------
-    # The five bodies below know nothing of a model's insides: they call
-    # the layer interface (docs/serving.md "The layer interface") — the
-    # model embeds, its layers project and mix, and attention comes back
-    # here through ``attend``, where the cache is written and read.
-    def _block_rows(self, kv, pool):
-        """A prompt's K or V ``kv`` (Tb, H, D) laid as ``pool`` holds a
-        block's positions, once a layer: (H, Tb, D) for a pool stored as
-        stated, (Tb, H, Dp) for a position-major one; a latent layer's
-        rows (Tb, F) as its row pool ``[N, bs, Fp]`` holds them, (Tb,
-        Fp)."""
-        if self._position_major or pool.ndim == 3:
-            return self._to_lanes(kv, pool)
-        return kv.transpose(1, 0, 2)
-
-    def _strip(self, rows, j, pool):
-        """Block ``j``'s positions of :meth:`_block_rows`' ``rows``."""
-        bs = self.block_size
-        if self._position_major or pool.ndim == 3:
-            return rows[j * bs:(j + 1) * bs]
-        return rows[:, j * bs:(j + 1) * bs]
-
-    def _scatter_block(self, pool, strip, table, idx, traced_idx):
-        """Write a :meth:`_strip` — (H, w, D), or (w, H, Dp) of a
-        position-major pool — into block ``table[idx]`` of ``pool``.
-        ``idx`` may be traced (``traced_idx``) — out-of-range indices
-        redirect to the null block 0, where padded-garbage writes are
-        harmless."""
-        import jax.numpy as jnp
-        from jax import lax
-        NB = self.max_blocks_per_slot
-        if traced_idx:
-            blk = jnp.where(idx < NB,
-                            jnp.take(table, jnp.minimum(idx, NB - 1)), 0)
-        else:
-            blk = table[idx]
-        return lax.dynamic_update_slice(
-            pool, strip[None].astype(pool.dtype),
-            (blk,) + (0,) * (pool.ndim - 1))
-
-    @staticmethod
-    def _to_lanes(rows, pool):
-        """``rows`` (..., D) as a position-major pool ``[N, bs, H, Dp]``
-        holds them: zeros on the lanes past D."""
-        from jax import lax
-        pad = pool.shape[-1] - rows.shape[-1]
-        if not pad:
-            return rows
-        return lax.pad(rows, rows.dtype.type(0),
-                       ((0, 0, 0),) * (rows.ndim - 1) + ((0, pad, 0),))
-
-    def _write_rows(self, pool, blk, off, rows):
-        """Set position ``off`` of block ``blk`` — (S,) or (S, Q) each —
-        to ``rows`` (S, H, D) or (S, Q, H, D); of a latent layer's row
-        pool, to ``rows`` (S, F)."""
-        import jax.numpy as jnp
-        rows = rows.astype(pool.dtype)
-        if self._position_major or pool.ndim == 3:
-            return pool.at[blk, off].set(self._to_lanes(rows, pool))
-        N, H, bs, D = pool.shape
-        if H == 1 or D % self.layout.LANES:
-            return pool.at[blk, :, off].set(rows)
-        # several heads of whole lanes: the grouped kernel reads such a
-        # pool as [N, H * bs, D] (the same bytes), and a write through
-        # that view leaves the compiler no other order to keep the pool in
-        # than the one it rests in — written as [N, H, bs, D] it keeps
-        # positions before heads inside the program and copies every pool
-        # on the way in, for the kernel and on the way out
-        # (tests/test_paged_attention.py)
-        col = jnp.arange(H, dtype=off.dtype) * bs + off[..., None]
-        return pool.reshape(N, H * bs, D).at[blk[..., None], col].set(
-            rows).reshape(pool.shape)
-
-    def _scatter_prompt(self, caches, ids, rows, table, j0, traced):
-        """Write a prompt's ``rows`` — one array a pool of ``ids``, (Tb,
-        H, D) or a latent layer's (Tb, F) — into the blocks ``table``
-        names from column ``j0`` on (``traced``: ``j0`` is an operand,
-        and columns past the table go to the null block).  ``caches`` is
-        the program's list, updated in place."""
-        laid = [self._block_rows(r, caches[i]) for i, r in zip(ids, rows)]
-        for j in range(-(-rows[0].shape[0] // self.block_size)):
-            for i, a in zip(ids, laid):
-                caches[i] = self._scatter_block(
-                    caches[i], self._strip(a, j, caches[i]), table, j0 + j,
-                    traced)
-
-    def _latent_suffix_attend(self, caches, layer, table, ctx, Tb):
-        """``attend`` of the hit program for a latent layer
-        (``ServedLayer._block``): the suffix's rows written at ``ctx`` on,
-        then its queries over the slot's strip in the unabsorbed form —
-        every cached row a full layer keeps, its index keys with them; the
-        blocks a window can touch of a sliding one."""
-        import jax.numpy as jnp
-        from ..kernels.latent_attention import latent_prompt_attention
-        bs = self.block_size
-        window = self.layout.windows[layer]
-        ids = self._pools(layer)
-        NB = self.max_blocks_per_slot
-
-        def strip(pool, cols, features):
-            """The slot's rows of the table columns ``cols``."""
-            blocks = jnp.take(table, jnp.minimum(cols, NB - 1))
-            return pool[blocks].reshape(-1, pool.shape[-1])[:, :features]
-
-        def attend(q_n, q_r, row, w_uk, w_uv, scale, index=None):
-            rows = (row[0],) if index is None else (row[0], index[2][0])
-            self._scatter_prompt(caches, ids, rows, table, ctx // bs, True)
-            if window is None:
-                cols = jnp.arange(NB, dtype=jnp.int32)
-            else:                   # from the window's first block on
-                n = min(NB, -(-Tb // bs) + (window + bs - 2) // bs)
-                cols = jnp.maximum(ctx - window + 1, 0) // bs \
-                    + jnp.arange(n, dtype=jnp.int32)
-            key_pos = (cols[:, None] * bs
-                       + jnp.arange(bs, dtype=jnp.int32)[None]).reshape(-1)
-            # a column past the table holds no key whatever it names
-            key_pos = jnp.where(key_pos < NB * bs, key_pos,
-                                jnp.iinfo(jnp.int32).max)
-            q_pos = ctx + jnp.arange(Tb, dtype=jnp.int32)
-            select = None if index is None else (
-                index[0][0], index[1][0],
-                strip(caches[ids[1]], cols, index[2].shape[-1]),
-                self.layout.selects[layer])
-            return latent_prompt_attention(
-                q_n[0], q_r[0], strip(caches[ids[0]], cols, row.shape[-1]),
-                q_pos, key_pos, w_uk, w_uv, scale, window, select)[None]
-        return attend
-
-    def _latent_step_attend(self, caches, layer, blk, off, tables,
-                            positions):
-        """``attend`` of the two decode programs for a latent layer: one
-        position a slot, its row written to block ``blk`` at offset
-        ``off``, then the absorbed form over the pool — the window of a
-        sliding layer (:func:`paged_latent_decode`: a page read once, key
-        and value both), and in a layer that chooses its keys the index
-        key written beside the row, every cached index key scored, and
-        the rows of the chosen positions alone read."""
-        import jax
-        from ..kernels import latent_attention as la
-        window = self.layout.windows[layer]
-        ids = self._pools(layer)
-
-        def attend(q_n, q_r, row, w_uk, w_uv, scale, index=None):
-            r_kv = w_uk.shape[0]
-            caches[ids[0]] = pool = self._write_rows(
-                caches[ids[0]], blk, off, row[:, 0])
-            if index is None:
-                self._paged_impls.add(la.latent_decode_impl(row, pool))
-
-                def read(q_abs):
-                    return la.paged_latent_decode(
-                        q_abs, pool, tables, positions, r_kv, scale, window)
-            else:
-                q_i, w_i, k_i = index
-                caches[ids[1]] = keys = self._write_rows(
-                    caches[ids[1]], blk, off, k_i[:, 0])
-                self._paged_impls.add(la.index_select_impl(q_i, keys))
-                with jax.named_scope("attn.index"):
-                    chosen, valid = la.paged_index_select(
-                        q_i[:, 0], w_i[:, 0], keys, tables, positions,
-                        self.layout.selects[layer])
-
-                def read(q_abs):
-                    return la.paged_sparse_latent(
-                        q_abs, pool, chosen, valid, r_kv, scale)
-            self._paged_attention = "+".join(sorted(self._paged_impls))
-            return la.absorbed_attention(q_n[:, 0], q_r[:, 0], w_uk, w_uv,
-                                         read)[:, None]
-        return attend
-
-    def _note_paged_attention(self, layer, tables, pool, q_heads, window):
-        """Record what the paged attention entry points pick for
-        ``layer`` of the program being traced (``program_inventory``),
-        and whether it is the kernel that fetches by runs
-        (``mxtpu_paged_groups_total``)."""
-        from ..kernels.flash_attention import (paged_attention_impl,
-                                               paged_run_pages)
-        self._paged_impls.add(paged_attention_impl(
-            tables, pool, q_heads, window, self._position_major))
+    # The five bodies below know nothing of a model's insides nor of how
+    # its cache is stored: they call the layer interface (docs/serving.md
+    # "The layer interface") — the model embeds, its layers project and mix
+    # — and hand a layer its cache form's ``attend`` (``kvcache``).
+    def _note_paged_attention(self, layer, impl, step=None):
+        """What a form tells the engine as a program is traced: what
+        ``layer``'s read picked (``program_inventory``) and the ``step`` of
+        the kernel that fetches by runs (``mxtpu_paged_groups_total``)."""
+        self._paged_impls.add(impl)
         self._paged_attention = "+".join(sorted(self._paged_impls))
-        step = paged_run_pages(tables, pool, q_heads, window,
-                               tables.shape[1], self._position_major)
         if step:
-            self._run_layers[layer], self._run_step = window, step
+            self._run_layers[layer] = self.layout.windows[layer]
+            self._run_step = step
             for fetch in ("run", "blocks"):
                 self._decode_counts.setdefault("paged_groups_" + fetch, 0)
             if self._run_rows is None:
@@ -1413,27 +1228,6 @@ class GenerationEngine:
                     _np.zeros((self.max_slots, n_groups), _np.int64),
                     _np.zeros((self.max_slots, n_groups + 1), _np.int64))
                 self._run_stale.update(range(self.max_slots))
-
-    def _attn_scale(self, l):
-        """The softmax scale layer ``l`` states for its attention (None:
-        the kernels' own, ``head_dim ** -0.5``)."""
-        return getattr(self._layers[l], "attn_scale", None)
-
-    def _pools(self, l):
-        """Where layer ``l``'s pools lie in a program's cache: K and V of
-        a grouped-query layer, the rows a latent layer states."""
-        return self._pool_ids[l]
-
-    def _state_leaves(self, l):
-        """Where state layer ``l``'s leaves lie in a program's cache,
-        after the pools."""
-        at = self._n_pools
-        for s in self._state_layers:
-            n = len(self.layout.states[s])
-            if s == l:
-                return range(at, at + n)
-            at += n
-        raise MXNetError(f"{self.name}: layer {l} keeps no state")
 
     def _recur_prefill(self, caches, start, slot, snap_rows):
         """The state layers' hand in a prefill program: a layer starts
@@ -1450,7 +1244,7 @@ class GenerationEngine:
                 arr, row.astype(arr.dtype), (at,) + (0,) * (arr.ndim - 1))
 
         def recur(l, layer, h, pos, live):
-            leaves = self._state_leaves(l)
+            leaves = self._forms[l].ids
             rows = tuple(
                 jnp.zeros((1,) + caches[i].shape[1:], caches[i].dtype)
                 if start is None
@@ -1478,7 +1272,7 @@ class GenerationEngine:
         S = self.max_slots
 
         def recur(l, layer, h, pos, live):
-            leaves = self._state_leaves(l)
+            leaves = self._forms[l].ids
             whole = getattr(layer, "state_in_place", False)
             rows = tuple(caches[i] if whole
                          else lax.slice_in_dim(caches[i], 0, S)
@@ -1509,10 +1303,10 @@ class GenerationEngine:
         h = self.block.serve_embed(tokens, pos)
         counts = self._zero_counts()
         for l, layer in enumerate(self._layers):
-            if l in self._pool_of:
-                h, c = layer.serve_cached(h, pos, attend_for(l), live)
-            else:
+            if l in self._state_layers:
                 h, c = recur(l, layer, h, pos, live)
+            else:
+                h, c = layer.serve_cached(h, pos, attend_for(l), live)
             counts = self._sum_counts(counts, c)
         return self.block.serve_head(self._row(h, last)), counts
 
@@ -1552,17 +1346,17 @@ class GenerationEngine:
             h = self.block.serve_embed(tokens, pos)
             kept = {}
             for l, layer in enumerate(self._layers):
-                if l in self._pool_of:
-                    h, *kept[l] = layer.serve_prefill(h, pos, pos < n_valid)
-                else:
+                if l in self._state_layers:
                     h, _ = recur(l, layer, h, pos, pos < n_valid)
+                else:
+                    h, *kept[l] = layer.serve_prefill(h, pos, pos < n_valid)
             return self.block.serve_head(self._row(h, n_valid - 1)), kept
 
         logits, kept = self._with_params(param_vals, aux_vals, key, body,
                                          "prefill")
         for l, rows in kept.items():    # K and V (H, Tb, D), or the rows
-            self._scatter_prompt(out, self._pools(l),
-                                 [r[0] for r in rows], table, 0, False)
+            self._forms[l].write_prompt(out, [r[0] for r in rows], table, 0,
+                                        False, self._position_major)
         first, lp = self._sample_prefill(logits[0, 0], n_valid, samp)
         if lp is not None:
             return tuple(out), first, lp
@@ -1574,7 +1368,7 @@ class GenerationEngine:
         multiple of block_size) already hold valid K/V in shared blocks;
         run the layers over only the SUFFIX ``tokens`` (1, Tb), appending
         K/V at positions [ctx, ctx+Tb) and attending through the block
-        table (:func:`paged_prefix_attention`).  ``at`` int32 is
+        table (each layer's form, ``suffix_attend``).  ``at`` int32 is
         ``[n_valid, slot, ctx]`` — operands, so one program per suffix
         bucket serves every hit length and every slot.  For a model with
         state layers ``at`` goes on with the snapshot row those layers
@@ -1582,33 +1376,18 @@ class GenerationEngine:
         the boundaries of the bucket, which lie at ``ctx`` + multiples of
         the snapshot spacing: a hit always ends at a snapshot."""
         import jax.numpy as jnp
-        from ..kernels.flash_attention import paged_prefix_attention
         Tb = tokens.shape[1]
-        bs = self.block_size
         caches = list(cache)
         n_valid, ctx = at[0], at[2]
         table, samp = self._slot_row(state, at[1])
         key = state["key"]
-        j0 = ctx // bs
+        j0 = ctx // self.block_size
         recur = self._recur_prefill(caches, at[3], at[1], at[4:]) \
             if self._state_layers else None
 
-        def attend_for(layer):
-            if self.layout.rows[layer] is not None:
-                return self._latent_suffix_attend(caches, layer, table, ctx,
-                                                  Tb)
-            l, lv = self._pools(layer)
-
-            def attend(q, k, v):             # (1, Tb, heads, D) each
-                self._scatter_prompt(caches, (l, lv), (k[0], v[0]), table,
-                                     j0, True)
-                attn = paged_prefix_attention(
-                    q.transpose(0, 2, 1, 3), caches[l], caches[lv],
-                    table, ctx, self.layout.windows[layer],
-                    scale=self._attn_scale(layer),
-                    position_major=self._position_major)
-                return attn.transpose(0, 2, 1, 3)
-            return attend
+        def attend_for(l):
+            return self._forms[l].suffix_attend(caches, table, ctx, j0, Tb,
+                                                self._position_major)
 
         def body():
             q_idx = jnp.arange(Tb, dtype=jnp.int32)
@@ -1624,38 +1403,11 @@ class GenerationEngine:
             return tuple(caches), first, lp
         return tuple(caches), first
 
-    def _decode_attend_for(self, caches, blk, off, tables, positions):
-        """``attend_for(l)`` of the two decode programs: one position a
-        slot, K/V written to block ``blk`` at offset ``off``, attention
-        through :func:`paged_decode_attention` bounded by the layer's
-        window.  ``caches`` is the program's list, updated in place."""
-        from ..kernels.flash_attention import paged_decode_attention
-
-        def attend_for(layer):
-            window = self.layout.windows[layer]
-            if self.layout.rows[layer] is not None:
-                return self._latent_step_attend(caches, layer, blk, off,
-                                                tables, positions)
-            l, lv = self._pools(layer)
-
-            def attend(q, k, v):             # (S, 1, heads, D) each
-                ck = self._write_rows(caches[l], blk, off, k[:, 0])
-                cv = self._write_rows(caches[lv], blk, off, v[:, 0])
-                caches[l], caches[lv] = ck, cv
-                self._note_paged_attention(layer, tables, ck, q.shape[2],
-                                           window)
-                return paged_decode_attention(
-                    q[:, 0], ck, cv, tables, positions,
-                    scale=self._attn_scale(layer), window=window,
-                    position_major=self._position_major)[:, None]
-            return attend
-        return attend_for
-
     def _decode_paged_pure(self, cache, state, param_vals, aux_vals):
         """The decode program, paged: one token for EVERY slot, each
         slot's K/V write landing in block ``tables[s, pos//bs]`` at
-        offset ``pos % bs`` and attention reading through
-        :func:`paged_decode_attention`.  Last tokens, positions, tables
+        offset ``pos % bs`` and attention reading through the tables
+        (each layer's form, ``step_attend``).  Last tokens, positions, tables
         (S, max_blocks) and the sampling operands are the slot
         ``state``'s — join/leave never recompiles.  A free slot's table
         is all null block: it rides along, and the layers are told it is
@@ -1675,11 +1427,13 @@ class GenerationEngine:
         blk = tables[rows, positions // bs]                    # (S,)
         off = positions % bs                                   # (S,)
 
+        def attend_for(l):
+            return self._forms[l].step_attend(
+                caches, blk, off, tables, positions, self._position_major)
+
         def body():
             return self._cached_layers(
-                last_tokens, positions.reshape(S, 1),
-                self._decode_attend_for(caches, blk, off, tables,
-                                        positions),
+                last_tokens, positions.reshape(S, 1), attend_for,
                 (tables[:, 0] != 0)[:, None],
                 recur=self._recur_decode(caches))
 
@@ -1755,7 +1509,8 @@ class GenerationEngine:
                 off = pos % bs                                     # (S,)
                 logits, c = self._cached_layers(
                     lt, pos.reshape(S, 1),
-                    self._decode_attend_for(caches, blk, off, tables, pos),
+                    lambda l: self._forms[l].step_attend(
+                        caches, blk, off, tables, pos, self._position_major),
                     (~done)[:, None], recur=self._recur_decode(caches))
                 counts = self._sum_counts(counts, c)
                 lg = logits[:, 0, :]
@@ -1816,7 +1571,6 @@ class GenerationEngine:
         block.  With Q == 1 this is exactly decode."""
         import jax
         import jax.numpy as jnp
-        from ..kernels.flash_attention import paged_verify_decode_attention
         tables, samp = self._slot_operands(state)[5:]
         key_next, key = jax.random.split(state["key"])
         state = dict(state, key=key_next)
@@ -1834,22 +1588,9 @@ class GenerationEngine:
                                    jnp.minimum(col, NB - 1)], 0)  # (S, Q)
         off = pos_q % bs                                          # (S, Q)
 
-        def attend_for(layer):
-            window = self.layout.windows[layer]
-            l, lv = self._pools(layer)
-
-            def attend(q, k, v):             # (S, Q, heads, D) each
-                ck = self._write_rows(caches[l], blk, off, k)
-                cv = self._write_rows(caches[lv], blk, off, v)
-                caches[l], caches[lv] = ck, cv
-                self._note_paged_attention(layer, tables, ck, q.shape[2],
-                                           window)
-                attn = paged_verify_decode_attention(
-                    q.transpose(0, 2, 1, 3), ck, cv, tables, positions,
-                    scale=self._attn_scale(layer), window=window,
-                    position_major=self._position_major)
-                return attn.transpose(0, 2, 1, 3)
-            return attend
+        def attend_for(l):
+            return self._forms[l].verify_attend(
+                caches, blk, off, tables, positions, self._position_major)
 
         def body():
             return self._cached_layers(
@@ -1885,21 +1626,15 @@ class GenerationEngine:
         # still finds a cache of the programs' shape, and fails on it)
         for c in self._cache + self._recur:
             c.delete()
-        pools = [None] * self._n_pools
-        for l, ids in self._pool_ids.items():
-            for i, (features, dtype) in zip(ids, self.layout.layer_rows(l)):
-                shape = self._pool_shape if self.layout.rows[l] is None \
-                    else self.layout.row_pool_shape(
-                        self.num_blocks, self.block_size, features, dev)
-                pools[i] = jnp.zeros(shape, jnp.dtype(dtype), device=dev)
-        self._cache = tuple(pools)
         # the state rows go with the blocks: a snapshot must never outlive
         # the params that computed it either
-        self._recur = tuple(
-            jnp.zeros((self._null_row + 1,) + shape, jnp.dtype(dtype),
-                      device=dev)
-            for l in self._state_layers
-            for shape, dtype in self.layout.states[l])
+        arrays = {}
+        for form in self._forms:
+            for i, (shape, dtype) in zip(form.ids, form.allocate(
+                    self.num_blocks, dev, self._pool_shape,
+                    self._null_row + 1)):
+                arrays[i] = jnp.zeros(shape, jnp.dtype(dtype), device=dev)
+        self._rebind([arrays[i] for i in range(len(arrays))])
         self.pool.reset()
         # bytes behind one block across all layers, as stored — lets
         # the pool report occupancy in bytes (device-memory
@@ -1940,17 +1675,9 @@ class GenerationEngine:
 
     @property
     def pool_layout(self):
-        """How the pools are stored, for ``/programs``: ``"default"`` as
-        the model states them, ``[N, H, bs, D]`` (the CPU; a pool that
-        rests row-major as stated), else the position-major
-        shape ``[N, bs, H, Dp]`` they are stored in so that the device's
-        default layout is the one the programs keep."""
-        if not self._position_major:
-            return "default"
-        return {"stored": "position_major",
-                "shape": list(self._pool_shape),
-                "stated": [self.num_blocks, self.num_heads,
-                           self.block_size, self.head_dim]}
+        """How the K and V pools are stored, for ``/programs``."""
+        return _pool_layout(self.layout, self.num_blocks, self.block_size,
+                            self._pool_shape, self._position_major)
 
     @property
     def cache_bytes(self) -> int:
@@ -2425,19 +2152,9 @@ class GenerationEngine:
         if draft is self:
             raise MXNetError(f"{self.name}: a model cannot draft itself")
         for eng in (self, draft):
-            if eng._row_layers:
-                raise MXNetError(
-                    f"{eng.name}: no speculation over a latent cache yet: "
-                    "the verify program has no latent form (a block of "
-                    "drafted positions a slot, each with its own choice "
-                    "of keys in a layer that chooses them; ROADMAP M2)")
-            if eng._state_layers:
-                raise MXNetError(
-                    f"{eng.name}: no speculation over a recurrent state: "
-                    "a rejected draft token is rolled back by moving the "
-                    "position back, and a state that has consumed the "
-                    "token cannot be moved back (it would have to be "
-                    "snapshotted at every verify)")
+            for form in eng._forms:     # a cache no verify program reads
+                if form.no_verify:
+                    raise MXNetError(f"{eng.name}: {form.no_verify}")
         if int(draft.max_slots) != self.max_slots:
             raise MXNetError(
                 f"{self.name}: draft max_slots {draft.max_slots} != "
@@ -2614,7 +2331,6 @@ class GenerationEngine:
         """Worst-case blocks a request reserving ``reserve_tokens``
         positions can take (no sharing assumed) — the scheduler's
         discount unit for multi-admit steps."""
-        from .kvcache import blocks_for
         reserve = int(reserve_tokens) \
             + (self.spec_k if self.draft is not None else 0)
         return blocks_for(min(reserve, self.max_len), self.block_size)

@@ -1,4 +1,5 @@
-"""Paged KV-cache block pool with prefix sharing.
+"""Paged KV-cache block pool with prefix sharing, and the cache's form
+(:func:`cache_forms`: how what a layer caches is stored, written, read).
 
 The :class:`BlockPool` is the host-side allocator behind the paged
 ``GenerationEngine``: device KV storage is carved into fixed-size blocks
@@ -58,7 +59,8 @@ from ..base import MXNetError
 from . import metrics as _m
 
 __all__ = ["BlockPool", "KVLayout", "SnapshotPlan", "NO_SNAPSHOTS",
-           "blocks_for", "NULL_BLOCK"]
+           "blocks_for", "NULL_BLOCK", "cache_forms", "grouped_pool_shape",
+           "pool_layout", "GroupedKV", "StatedRows", "StateLeaves"]
 
 NULL_BLOCK = 0
 
@@ -216,6 +218,346 @@ class KVLayout(NamedTuple):
 def blocks_for(tokens: int, block_size: int) -> int:
     """Blocks needed to hold ``tokens`` positions."""
     return max(0, -(-int(tokens) // int(block_size)))
+
+
+# -- the cache's form (docs/serving.md "The cache's form") --------------------
+# What kind of thing a layer caches and how the pool that holds it is stored
+# is decided HERE; the kernels, which read a pool in place, are the format's
+# only other holder.  The engine keeps one form a layer and asks it.  A form
+# holds plain values; whether the K/V pools are stored position-major it is
+# TOLD at every call (a caller that traces for another device assigns
+# ``GenerationEngine._position_major``).
+
+def _to_lanes(rows, pool):
+    """``rows`` (..., D) as a pool whose last axis is whole lanes holds
+    them: zeros on the lanes past D."""
+    from jax import lax
+    pad = pool.shape[-1] - rows.shape[-1]
+    if not pad:
+        return rows
+    return lax.pad(rows, rows.dtype.type(0),
+                   ((0, 0, 0),) * (rows.ndim - 1) + ((0, pad, 0),))
+
+
+class _BlockForm:
+    """What the two kinds of layer that keep blocks share: where their pools
+    lie in a program's cache (``ids``), their window, ``note`` (the engine's
+    one callback) and the two writes, into a pool that holds a block's
+    positions one after another — position-major ``[N, bs, H, Dp]``, a row
+    pool ``[N, bs, Fp]`` — or as stated, ``[N, H, bs, D]``."""
+    keeps_state = False
+    select = None       # how many cached positions the layer's reads choose
+    no_verify = None    # why the layer has no verify program, if it has none
+    by_position = False     # a row pool: whatever order the K/V pools are in
+
+    def __init__(self, layer, ids, window, block_size, max_blocks, note):
+        self.layer, self.ids, self.window = layer, tuple(ids), window
+        self.block_size, self.max_blocks, self.note = \
+            block_size, max_blocks, note
+
+    def write_prompt(self, caches, rows, table, j0, traced, position_major):
+        """Write a prompt's ``rows`` — one array a pool of ``ids``, (Tb, H,
+        D) or a latent layer's (Tb, F) — into the blocks ``table`` names
+        from column ``j0`` on: the miss prefill's static 0, or the hit
+        prefill's operand (``traced``), where a column past the table goes
+        to the null block 0, in which padded garbage is harmless.
+        ``caches`` is the program's list, updated in place."""
+        import jax.numpy as jnp
+        from jax import lax
+        by_position = position_major or self.by_position
+        bs, NB = self.block_size, self.max_blocks
+        # as a pool holds a block's positions, once a layer: (H, Tb, D) for
+        # one stored as stated, else (Tb, H, Dp) or (Tb, Fp)
+        laid = [_to_lanes(r, caches[i]) if by_position
+                else r.transpose(1, 0, 2) for i, r in zip(self.ids, rows)]
+        for j in range(-(-rows[0].shape[0] // bs)):
+            for i, a in zip(self.ids, laid):
+                pool = caches[i]
+                strip = a[j * bs:(j + 1) * bs] if by_position \
+                    else a[:, j * bs:(j + 1) * bs]
+                idx = j0 + j
+                blk = jnp.where(idx < NB, jnp.take(
+                    table, jnp.minimum(idx, NB - 1)), 0) if traced \
+                    else table[idx]
+                caches[i] = lax.dynamic_update_slice(
+                    pool, strip[None].astype(pool.dtype),
+                    (blk,) + (0,) * (pool.ndim - 1))
+
+    def write_step(self, pool, blk, off, rows, position_major):
+        """``pool`` with position ``off`` of block ``blk`` — (S,) or (S, Q)
+        each — set to ``rows`` (S, H, D) or (S, Q, H, D); of a row pool, to
+        ``rows`` (S, F)."""
+        import jax.numpy as jnp
+        rows = rows.astype(pool.dtype)
+        if position_major or self.by_position:
+            return pool.at[blk, off].set(_to_lanes(rows, pool))
+        N, H, bs, D = pool.shape
+        if H == 1 or D % KVLayout.LANES:
+            return pool.at[blk, :, off].set(rows)
+        # several heads of whole lanes: the grouped kernel reads such a
+        # pool as [N, H * bs, D] (the same bytes), and a write through
+        # that view leaves the compiler no other order to keep the pool in
+        # than the one it rests in — written as [N, H, bs, D] it keeps
+        # positions before heads inside the program and copies every pool
+        # on the way in, for the kernel and on the way out
+        # (tests/test_paged_attention.py)
+        col = jnp.arange(H, dtype=off.dtype) * bs + off[..., None]
+        return pool.reshape(N, H * bs, D).at[blk[..., None], col].set(
+            rows).reshape(pool.shape)
+
+
+class GroupedKV(_BlockForm):
+    """A grouped-query layer: K and V of ``kv_heads x head_dim`` a position,
+    two pools stored as :meth:`KVLayout.pool_shape` has them.  ``scale`` is
+    the softmax scale the layer states (None: the kernels' own,
+    ``head_dim ** -0.5``).  Its ``attend`` take ``(q, k, v)``."""
+
+    def __init__(self, *facts, dtype, scale):
+        super().__init__(*facts)
+        self.dtype, self.scale = dtype, scale
+
+    def allocate(self, num_blocks, device, pool_shape, state_rows):
+        return [(pool_shape, self.dtype)] * 2
+
+    def _picked(self, tables, pool, q_heads, position_major):
+        # what the paged entry points pick, and the run kernel's step
+        from ..kernels.flash_attention import (paged_attention_impl,
+                                               paged_run_pages)
+        at = (tables, pool, q_heads, self.window)
+        self.note(self.layer, paged_attention_impl(*at, position_major),
+                  paged_run_pages(*at, tables.shape[1], position_major))
+
+    def suffix_attend(self, caches, table, ctx, j0, Tb, position_major):
+        """``attend`` of the hit program: the suffix's K/V written from
+        column ``j0`` (``ctx // block_size``, divided once a program) on,
+        its queries over the slot's blocks (``paged_prefix_attention``)."""
+        from ..kernels.flash_attention import paged_prefix_attention
+        l, lv = self.ids
+
+        def attend(q, k, v):             # (1, Tb, heads, D) each
+            self.write_prompt(caches, (k[0], v[0]), table, j0, True,
+                              position_major)
+            attn = paged_prefix_attention(
+                q.transpose(0, 2, 1, 3), caches[l], caches[lv],
+                table, ctx, self.window, scale=self.scale,
+                position_major=position_major)
+            return attn.transpose(0, 2, 1, 3)
+        return attend
+
+    def step_attend(self, caches, blk, off, tables, positions,
+                    position_major):
+        """``attend`` of the two decode programs: one position a slot, K/V
+        written to block ``blk`` at offset ``off``, attention through
+        ``paged_decode_attention`` bounded by the layer's window."""
+        from ..kernels.flash_attention import paged_decode_attention
+        l, lv = self.ids
+
+        def attend(q, k, v):             # (S, 1, heads, D) each
+            ck = self.write_step(caches[l], blk, off, k[:, 0],
+                                 position_major)
+            cv = self.write_step(caches[lv], blk, off, v[:, 0],
+                                 position_major)
+            caches[l], caches[lv] = ck, cv
+            self._picked(tables, ck, q.shape[2], position_major)
+            return paged_decode_attention(
+                q[:, 0], ck, cv, tables, positions, scale=self.scale,
+                window=self.window, position_major=position_major)[:, None]
+        return attend
+
+    def verify_attend(self, caches, blk, off, tables, positions,
+                      position_major):
+        """``attend`` of the verify program: Q positions a slot, ``blk`` and
+        ``off`` (S, Q).  (At Q == 1 it computes what :meth:`step_attend`
+        does by another program; folding them changes both: ROADMAP D1.)"""
+        from ..kernels.flash_attention import paged_verify_decode_attention
+        l, lv = self.ids
+
+        def attend(q, k, v):             # (S, Q, heads, D) each
+            ck = self.write_step(caches[l], blk, off, k, position_major)
+            cv = self.write_step(caches[lv], blk, off, v, position_major)
+            caches[l], caches[lv] = ck, cv
+            self._picked(tables, ck, q.shape[2], position_major)
+            attn = paged_verify_decode_attention(
+                q.transpose(0, 2, 1, 3), ck, cv, tables, positions,
+                scale=self.scale, window=self.window,
+                position_major=position_major)
+            return attn.transpose(0, 2, 1, 3)
+        return attend
+
+
+class StatedRows(_BlockForm):
+    """A layer that keeps the rows it states (``KVLayout.rows``), one row
+    pool ``[N, bs, Fp]`` each: a latent layer's one row that is key and
+    value both and, in a layer that chooses the keys it reads (``select``
+    of them), its index key.  Its ``attend`` take ``(q_n, q_r, row, w_uk,
+    w_uv, scale, index=None)`` and hold the latent cache's two forms: the
+    unabsorbed one over a prompt's suffix, the absorbed one over a step."""
+    by_position = True
+    no_verify = (
+        "no speculation over a latent cache yet: the verify program has no "
+        "latent form (a block of drafted positions a slot, each with its "
+        "own choice of keys in a layer that chooses them; ROADMAP M2)")
+
+    def __init__(self, *facts, layout):
+        super().__init__(*facts)
+        self.layout, self.select = layout, layout.selects[self.layer]
+
+    def allocate(self, num_blocks, device, pool_shape, state_rows):
+        return [(self.layout.row_pool_shape(num_blocks, self.block_size,
+                                            features, device), dtype)
+                for features, dtype in self.layout.rows[self.layer]]
+
+    def suffix_attend(self, caches, table, ctx, j0, Tb, position_major):
+        """``attend`` of the hit program (``ServedLayer._block``): the
+        suffix's rows written at ``ctx`` on, then its queries over the
+        slot's strip in the unabsorbed form — every cached row a full layer
+        keeps, its index keys with them; the blocks a window can touch of a
+        sliding one.  (Its own ``ctx // bs`` a layer, not ``j0``: D1.)"""
+        import jax.numpy as jnp
+        from ..kernels.latent_attention import latent_prompt_attention
+        bs, NB = self.block_size, self.max_blocks
+        window, ids = self.window, self.ids
+
+        def strip(pool, cols, features):
+            """The slot's rows of the table columns ``cols``."""
+            blocks = jnp.take(table, jnp.minimum(cols, NB - 1))
+            return pool[blocks].reshape(-1, pool.shape[-1])[:, :features]
+
+        def attend(q_n, q_r, row, w_uk, w_uv, scale, index=None):
+            rows = (row[0],) if index is None else (row[0], index[2][0])
+            self.write_prompt(caches, rows, table, ctx // bs, True,
+                              position_major)
+            if window is None:
+                cols = jnp.arange(NB, dtype=jnp.int32)
+            else:                   # from the window's first block on
+                n = min(NB, -(-Tb // bs) + (window + bs - 2) // bs)
+                cols = jnp.maximum(ctx - window + 1, 0) // bs \
+                    + jnp.arange(n, dtype=jnp.int32)
+            key_pos = (cols[:, None] * bs
+                       + jnp.arange(bs, dtype=jnp.int32)[None]).reshape(-1)
+            # a column past the table holds no key whatever it names
+            key_pos = jnp.where(key_pos < NB * bs, key_pos,
+                                jnp.iinfo(jnp.int32).max)
+            q_pos = ctx + jnp.arange(Tb, dtype=jnp.int32)
+            select = None if index is None else (
+                index[0][0], index[1][0],
+                strip(caches[ids[1]], cols, index[2].shape[-1]),
+                self.select)
+            return latent_prompt_attention(
+                q_n[0], q_r[0], strip(caches[ids[0]], cols, row.shape[-1]),
+                q_pos, key_pos, w_uk, w_uv, scale, window, select)[None]
+        return attend
+
+    def step_attend(self, caches, blk, off, tables, positions,
+                    position_major):
+        """``attend`` of the two decode programs: one position a slot, its
+        row written to block ``blk`` at offset ``off``, then the absorbed
+        form over the pool — the window of a sliding layer
+        (:func:`paged_latent_decode`: a page read once, key and value
+        both), and in a layer that chooses its keys the index key written
+        beside the row, every cached index key scored, and the rows of the
+        chosen positions alone read."""
+        import jax
+        from ..kernels import latent_attention as la
+        window, ids = self.window, self.ids
+
+        def attend(q_n, q_r, row, w_uk, w_uv, scale, index=None):
+            r_kv = w_uk.shape[0]
+            caches[ids[0]] = pool = self.write_step(
+                caches[ids[0]], blk, off, row[:, 0], position_major)
+            if index is None:
+                self.note(self.layer, la.latent_decode_impl(row, pool))
+
+                def read(q_abs):
+                    return la.paged_latent_decode(
+                        q_abs, pool, tables, positions, r_kv, scale, window)
+            else:
+                q_i, w_i, k_i = index
+                caches[ids[1]] = keys = self.write_step(
+                    caches[ids[1]], blk, off, k_i[:, 0], position_major)
+                self.note(self.layer, la.index_select_impl(q_i, keys))
+                with jax.named_scope("attn.index"):
+                    chosen, valid = la.paged_index_select(
+                        q_i[:, 0], w_i[:, 0], keys, tables, positions,
+                        self.select)
+
+                def read(q_abs):
+                    return la.paged_sparse_latent(
+                        q_abs, pool, chosen, valid, r_kv, scale)
+            return la.absorbed_attention(q_n[:, 0], q_r[:, 0], w_uk, w_uv,
+                                         read)[:, None]
+        return attend
+
+    def verify_attend(self, *where):
+        raise MXNetError(self.no_verify)
+
+
+class StateLeaves:
+    """A layer that keeps a state of constant size a sequence INSTEAD of
+    blocks (``KVLayout.states``): one array a leaf, a row a sequence.  The
+    engine's ``_recur_prefill`` / ``_recur_decode`` hand them to the layer."""
+    keeps_state = True
+    select = None
+    no_verify = (
+        "no speculation over a recurrent state: a rejected draft token is "
+        "rolled back by moving the position back, and a state that has "
+        "consumed the token cannot be moved back (it would have to be "
+        "snapshotted at every verify)")
+
+    def __init__(self, layer, ids, leaves):
+        self.layer, self.ids, self.leaves = layer, tuple(ids), leaves
+
+    def allocate(self, num_blocks, device, pool_shape, state_rows):
+        return [((state_rows,) + shape, dtype) for shape, dtype in self.leaves]
+
+
+def cache_forms(layout, layers, block_size, max_blocks, note):
+    """``(forms, n_pools)``: one form a layer of ``layout``, its kind read off
+    what the model states for the layer, and how many pools a program's
+    cache begins with: the layers' first rows in layer order, then their
+    second rows (K pools, then V pools of a grouped-query model); the state
+    layers' leaves follow.  ``layers`` are the model's ``serve_layers()`` (a
+    grouped layer may state ``attn_scale``); ``note(layer, impl,
+    run_step=None)`` is told what a layer's read picked as it is traced."""
+    ids = {l: [] for l in layout.kv_layers}
+    for n, (_, l) in enumerate(sorted(
+            (j, l) for l in ids for j in range(len(layout.layer_rows(l))))):
+        ids[l].append(n)
+    n = n_pools = sum(map(len, ids.values()))
+    forms = []
+    for l in range(layout.num_layers):
+        facts = (l, ids.get(l), layout.windows[l], int(block_size),
+                 int(max_blocks), note)
+        if layout.states[l] is not None:
+            leaves = layout.states[l]
+            forms.append(StateLeaves(l, range(n, n + len(leaves)), leaves))
+            n += len(leaves)
+        elif layout.rows[l] is not None:
+            forms.append(StatedRows(*facts, layout=layout))
+        else:
+            forms.append(GroupedKV(
+                *facts, dtype=layout.dtype,
+                scale=getattr(layers[l], "attn_scale", None)))
+    return forms, n_pools
+
+
+def grouped_pool_shape(layout, forms, num_blocks, block_size, device):
+    """:meth:`KVLayout.pool_shape` on ``device``; ``(None, False)`` for a
+    model none of whose layers keeps K and V."""
+    if not any(isinstance(f, GroupedKV) for f in forms):
+        return None, False
+    return layout.pool_shape(num_blocks, block_size, device)
+
+
+def pool_layout(layout, num_blocks, block_size, pool_shape, position_major):
+    """How the K and V pools are stored, for ``/programs``: ``"default"`` as
+    stated, ``[N, H, bs, D]``, else the position-major ``[N, bs, H, Dp]``
+    that rests in the layout the programs keep (``KVLayout.pool_shape``)."""
+    if not position_major:
+        return "default"
+    return {"stored": "position_major", "shape": list(pool_shape),
+            "stated": [num_blocks, layout.kv_heads, block_size,
+                       layout.head_dim]}
 
 
 class SnapshotPlan(NamedTuple):

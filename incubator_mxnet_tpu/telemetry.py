@@ -15,8 +15,7 @@ Two cooperating pieces:
 * **Metrics registry** — process-wide :class:`Counter` / :class:`Gauge` /
   :class:`Histogram` (bounded reservoir with p50/p95/p99/max), exported
   three ways: :func:`render_prometheus` (text exposition format),
-  :func:`snapshot` (JSON-ready dict, merged into ``bench.py``'s output
-  line), and counter samples woven into the profiler's chrome-trace
+  :func:`snapshot` (JSON-ready dict: ``mxtpu-stats --format json``), and counter samples woven into the profiler's chrome-trace
   ``dump()`` as ``ph:"C"`` events.
 
 Instrumented layers (see docs/observability.md):
@@ -1087,9 +1086,9 @@ def traced(arg=None, cat: str = "span"):
 # Device peak FLOP/s detection (MFU denominator)
 # ---------------------------------------------------------------------------
 # bf16 peak FLOP/s PER CHIP by TPU generation (public specs: Google Cloud
-# TPU documentation); longest key wins so 'v5 lite' beats 'v5'.  The ONE
-# table — bench.py delegates here — and a kind it does not list is an
-# error, never a default.
+# TPU documentation); longest key wins so 'v5 lite' beats 'v5'.  The
+# package's ONE table (the benchmark keeps its own beside its rooflines,
+# benchmark/chip/peaks.json), and a kind it does not list is an error.
 TPU_PEAK_FLOPS = {
     "v2": 46e12,
     "v3": 123e12,

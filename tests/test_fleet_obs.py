@@ -543,39 +543,3 @@ def test_pytest_jsonl_journal_roundtrip(tmp_path):
     assert passed == {"t.py::a", "t.py::b"}
     assert len(records) == 4
     assert pj.load_journal(str(tmp_path / "missing.jsonl")) == (set(), [])
-
-
-def test_bench_journal_resume(tmp_path, monkeypatch):
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "_bench_under_test", os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    path = str(tmp_path / "bench.jsonl")
-    monkeypatch.setattr(bench, "_JOURNAL_PATH", path)
-    monkeypatch.setattr(bench, "_RESUME", False)
-    monkeypatch.setattr(bench, "_JOURNAL_CACHE", None)
-    bench._journal_append("serve", {"qps": 12.5})
-    bench._journal_append("optim", {"error": "hung >5s"})
-
-    # without --resume nothing replays
-    assert bench._journal_lookup("serve") is None
-    monkeypatch.setattr(bench, "_RESUME", True)
-    monkeypatch.setattr(bench, "_JOURNAL_CACHE", None)
-    out = bench._journal_lookup("serve")
-    assert out == {"qps": 12.5, "resumed": True}
-    # error records re-run rather than replaying the failure
-    assert bench._journal_lookup("optim") is None
-    assert bench._journal_lookup("never_ran") is None
-    # _cpu_bench: resume hit short-circuits, miss runs + journals
-    calls = []
-    assert bench._cpu_bench("serve", lambda: calls.append(1)) == \
-        {"qps": 12.5, "resumed": True}
-    assert calls == []
-    rec = bench._cpu_bench("fresh", lambda: {"v": 1})
-    assert rec == {"v": 1}
-    monkeypatch.setattr(bench, "_JOURNAL_CACHE", None)
-    assert bench._journal_lookup("fresh") == {"v": 1, "resumed": True}
